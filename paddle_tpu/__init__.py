@@ -1,7 +1,7 @@
 """paddle_tpu: a TPU-native deep-learning framework.
 
 Brand-new design with the capability surface of the PaddlePaddle reference
-(/root/reference), built on JAX/XLA/Pallas:
+built on JAX/XLA/Pallas:
 
 - Tensors wrap jax.Array; XLA owns kernels, layouts, memory (replacing the phi
   kernel registry / allocator stack).

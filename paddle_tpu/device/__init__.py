@@ -80,15 +80,18 @@ def _live_bytes(dev) -> int:
 
 
 def memory_stats(device=None) -> dict:
-    """Raw PJRT allocator stats dict; synthesized from live arrays when the
-    backend publishes none (reference stats.cc DeviceMemoryStat*)."""
+    """Raw PJRT allocator stats dict (reference stats.cc DeviceMemoryStat*).
+    The CPU backend publishes none, so there — and only there — the dict is
+    synthesized from live arrays as a test convenience. A TPU that reports
+    no stats is an error: every HBM budget (KV pool sizing, peak memory)
+    would otherwise be read off a guess."""
     d = _dev(device)
-    stats = None
-    try:
-        stats = d.memory_stats()
-    except Exception:
-        stats = None
+    stats = d.memory_stats()
     if stats is None:
+        if d.platform != "cpu":
+            raise RuntimeError(
+                f"{d} ({d.device_kind}) reports no memory_stats(); refusing "
+                "to synthesize them off the CPU backend")
         in_use = _live_bytes(d)
         peak = max(_peaks.get(d.id, 0), in_use)
         _peaks[d.id] = peak

@@ -635,7 +635,7 @@ def barrier(group=None):
     if mp is not None:
         mp.barrier()
         return _Task()
-    jax.effects_barrier() if hasattr(jax, "effects_barrier") else None
+    jax.effects_barrier()
     return _Task()
 
 

@@ -195,29 +195,11 @@ def constrain_array(a, spec):
         return None if entry in manual else entry
 
     try:
-        # older jaxlibs (0.4.x) have no get_abstract_mesh — probing it with
-        # a bare attribute access used to throw into the broad except below
-        # and silently skip EVERY constraint (MoE ep layouts, TP hints) as
-        # a no-op warning. Probe with getattr and fall through to the plain
-        # global-mesh constraint instead.
-        get_ctx = getattr(jax.sharding, "get_abstract_mesh", None)
-        ctx = get_ctx() if get_ctx is not None else None
-        if (ctx is not None and not ctx.empty
-                and getattr(ctx, "manual_axes", None)):
+        ctx = jax.sharding.get_abstract_mesh()
+        if not ctx.empty and ctx.manual_axes:
             manual = set(ctx.manual_axes)
             spec = P(*[strip(s, manual) for s in spec])
             return jax.lax.with_sharding_constraint(a, NamedSharding(ctx, spec))
-        if ctx is None:
-            # 0.4.x manual-context detection: shard_map binds its mesh axes
-            # in the axis env; naming a bound axis in a constraint spec
-            # fails at lowering ("also found in manual_axes"), so strip
-            # every bound axis (conservative — auto axes are bound too on
-            # 0.4.x, losing only a hint, never correctness)
-            from jax._src import core as _jcore  # pragma: no cover - version path
-
-            bound = getattr(_jcore.get_axis_env(), "axis_sizes", None)
-            if bound:
-                spec = P(*[strip(s, set(bound)) for s in spec])
         return jax.lax.with_sharding_constraint(a, NamedSharding(mesh, spec))
     except Exception as e:  # pragma: no cover - diagnostic path
         warnings.warn(f"sharding constraint {spec} skipped: {e}")

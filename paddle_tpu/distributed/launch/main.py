@@ -8,11 +8,22 @@ controller spawns the worker pod, a watcher thread tails worker logs for
 fatal patterns and monitors liveness, and on worker failure the pod is torn
 down and — when --max_restart allows — respawned with PADDLE_RESTART_COUNT
 incremented (elastic level 1: in-place pod restart; the reference's etcd
-scale-in/out is the same loop keyed on a store watch)."""
+scale-in/out is the same loop keyed on a store watch).
+
+A chip belongs to one process at a time, so the supported TPU mode is ONE
+worker per host driving all local chips, started by a launcher that never
+initializes a JAX backend itself (it would hold the chips its worker
+needs). Both are checked before anything is spawned: a launcher process
+with a live backend refuses to spawn, and --nproc_per_node > 1 on a host
+whose workers would use the TPU is an error — the workers inherit one
+environment with no chip partition, so the first would take every chip and
+the rest would hang. Several workers per host remain the CPU test mode
+(JAX_PLATFORMS=cpu)."""
 
 from __future__ import annotations
 
 import argparse
+import glob
 import os
 import re
 import signal
@@ -77,6 +88,33 @@ class LogWatcher(threading.Thread):
         self.scan_once()
 
 
+def _workers_would_use_tpu(env) -> bool:
+    """True when a worker started with `env` would initialize the TPU
+    backend: JAX_PLATFORMS does not keep it off, and this host has TPU
+    device nodes. Decided without touching JAX."""
+    platforms = env.get("JAX_PLATFORMS", "")
+    if platforms and "tpu" not in platforms.split(","):
+        return False
+    return bool(glob.glob("/dev/accel*") or glob.glob("/dev/vfio/[0-9]*"))
+
+
+def _check_one_process_per_chip(nproc_per_node, env):
+    if nproc_per_node > 1 and _workers_would_use_tpu(env):
+        raise RuntimeError(
+            f"--nproc_per_node={nproc_per_node} on a TPU host: a chip "
+            "belongs to one process, and the workers would inherit one "
+            "environment with no chip partition — the first takes every "
+            "chip and the others hang. Run one worker per host (it drives "
+            "all local chips), or set JAX_PLATFORMS=cpu for a CPU pod.")
+    # sys.modules, not an import: the launcher itself must not pull jax in
+    xb = sys.modules.get("jax._src.xla_bridge")
+    if xb is not None and xb.backends_are_initialized():
+        raise RuntimeError(
+            "the launcher process has already initialized a JAX backend and "
+            "would hold the chips its worker needs; start workers from a "
+            "process that has not touched JAX")
+
+
 class Pod:
     """The set of worker processes on this host (reference Pod in
     launch/controllers/collective.py)."""
@@ -92,6 +130,7 @@ class Pod:
     def spawn(self):
         args = self.args
         nnodes = int(str(args.nnodes).split(":")[0])
+        _check_one_process_per_chip(args.nproc_per_node, os.environ)
         os.makedirs(args.log_dir, exist_ok=True)
         for local in range(args.nproc_per_node):
             env = dict(os.environ)
@@ -229,7 +268,7 @@ def _parse():
                    help="number of hosts, N or N:M (elastic range)")
     p.add_argument("--rank", type=int, default=int(os.environ.get("PADDLE_TRAINER_ID", 0)))
     p.add_argument("--nproc_per_node", type=int, default=1,
-                   help="controller processes per host (TPU: 1)")
+                   help="worker processes per host (TPU: 1, enforced)")
     p.add_argument("--log_dir", default="log")
     p.add_argument("--run_mode", default="collective")
     p.add_argument("--job_id", default="default")

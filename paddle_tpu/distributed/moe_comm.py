@@ -72,21 +72,18 @@ def drain_since(marker: int) -> tuple:
     return taken
 
 
-def estimated_seconds(nbytes: int) -> float:
+def estimated_seconds(nbytes: int, chip=None) -> float:
     """bytes / per-chip ICI bandwidth, resolved through the planner's chip
-    spec table (the same numbers the cost model's a2a term uses)."""
-    try:
-        import jax
+    spec table (the same numbers the cost model's a2a term uses). `chip`: a
+    device or `device_kind` string; None reads the live device. A kind the
+    table does not know raises there — never another chip's bandwidth."""
+    from .planner.cost_model import chip_specs
 
-        from .planner.cost_model import chip_specs
-
-        _peak, _hbm, ici, _kind = chip_specs(jax.devices()[0])
-    except Exception:  # graftlint: disable=GL003 spec probe must not break a train step; v4-class fallback below
-        ici = 0.27e12
-    return nbytes / max(ici, 1.0)
+    _peak, _hbm, ici, _kind = chip_specs(chip)
+    return nbytes / ici
 
 
-def emit_step(records, floor_ns: int = 0) -> None:
+def emit_step(records, floor_ns: int = 0, chip=None) -> None:
     """Host-side, once per executed step: bump the collective counters and
     fire comm_task observers with the estimated a2a intervals, anchored to
     reflect what the traced schedule arranges on device:
@@ -99,16 +96,28 @@ def emit_step(records, floor_ns: int = 0) -> None:
     - unchunked records (PADDLE_TPU_MOE_A2A_CHUNKS=1, the A/B baseline)
       anchor FORWARD from now, past the span's imminent end — counted as
       exposed comm, so the chunking knob's effect is visible in
-      overlap_fraction, not just wall clock."""
+      overlap_fraction, not just wall clock.
+
+    The byte/call counters are exact on any backend. The intervals are
+    bytes over the chip's ICI bandwidth, so they exist only for a chip the
+    spec table knows: the live TPU, or the `chip` a caller names. On the
+    CPU backend there is no interconnect to estimate and none is emitted."""
     if not records:
         return
     from . import comm_watchdog
     from .collective import record_collective_traffic
 
+    if chip is None:
+        import jax
+
+        live = jax.devices()[0]
+        chip = live if live.platform == "tpu" else None
     for rec in records:
         record_collective_traffic("all_to_all", rec["bytes"], rec["calls"])
+        if chip is None:
+            continue
         now = time.perf_counter_ns()
-        est = max(int(estimated_seconds(rec["bytes"]) * 1e9), 1)
+        est = max(int(estimated_seconds(rec["bytes"], chip) * 1e9), 1)
         if rec.get("overlapped", True):
             t0, t1 = now - est, now
             if floor_ns:

@@ -61,21 +61,26 @@ CHIP_SPECS = {
 # chip kind -> peak bf16 FLOP/s (bench.py imports this as its _PEAK table)
 PEAK_BF16_FLOPS = {k: v[0] for k, v in CHIP_SPECS.items()}
 
-# CPU smoke runs / unknown chips: assume v4-class (bench.py's fallback)
-_DEFAULT_KIND = "TPU v4"
+def chip_specs(chip=None):
+    """(peak_flops, hbm_Bps, ici_Bps, kind) for a chip, named either by a
+    jax device or by its `device_kind` string; None reads the first live
+    device. A kind missing from CHIP_SPECS is an error, never a default:
+    every number derived from these — a utilisation, an estimated time —
+    would silently be another chip's. Planning off the chip (CPU tests,
+    sizing a pod in advance) names the kind it plans for."""
+    if chip is None:
+        import jax
 
-
-def chip_specs(device=None):
-    """(peak_flops, hbm_Bps, ici_Bps, kind) for a jax device; `None` or an
-    unknown kind falls back to v4-class numbers so CPU smoke planning still
-    ranks (the ranking, not the absolute seconds, is what survives the
-    fallback)."""
-    kind = getattr(device, "device_kind", "") if device is not None else ""
+        chip = jax.devices()[0]
+    kind = chip if isinstance(chip, str) else getattr(chip, "device_kind", "")
     for k, v in CHIP_SPECS.items():
         if kind.startswith(k) or k in kind:
             return v[0], v[1], v[2], kind
-    v = CHIP_SPECS[_DEFAULT_KIND]
-    return v[0], v[1], v[2], kind or "unknown"
+    raise ValueError(
+        f"no peak FLOP/s / bandwidth entry for device kind {kind!r}; known "
+        f"kinds: {sorted(CHIP_SPECS)}. Name the chip to plan for "
+        f"(CostModel(chip=...), tuner_cfg['chip']) or add it to CHIP_SPECS "
+        f"with its source.")
 
 
 def measured_overlap_fraction(paths=None):
@@ -137,9 +142,10 @@ class CostModel:
 
     Parameters
     ----------
-    device : jax Device | None
-        Chip to read the spec table for (None: v4-class fallback; never
-        touches the backend, so planning works before/without jax init).
+    chip : jax Device | str | None
+        Chip to read the spec table for: a device, or a `device_kind`
+        string such as "TPU v5 lite" (planning before/without the chip).
+        None reads the first live device; an unknown kind raises.
     peak_flops, hbm_bandwidth, ici_bandwidth : float | None
         Explicit overrides of the spec-table numbers.
     mfu : float
@@ -156,10 +162,10 @@ class CostModel:
         `measured_overlap_fraction`, defaulting to 0.0 (all comm exposed).
     """
 
-    def __init__(self, device=None, peak_flops=None, hbm_bandwidth=None,
+    def __init__(self, chip=None, peak_flops=None, hbm_bandwidth=None,
                  ici_bandwidth=None, mfu=0.4, alpha=5e-6,
                  overlap_fraction=None, overlap_paths=None, a2a_chunks=None):
-        peak, hbm, ici, kind = chip_specs(device)
+        peak, hbm, ici, kind = chip_specs(chip)
         self.peak_flops = peak_flops or peak
         self.hbm_bandwidth = hbm_bandwidth or hbm
         self.ici_bandwidth = ici_bandwidth or ici

@@ -67,7 +67,7 @@ def rank_candidates(tuner_cfg, cost_model=None):
     """(ranked, pruned): ranked = [(cfg, breakdown)] sorted by predicted
     step time over every statically-feasible grid point; pruned =
     [(cfg, prune_rule_name, reason)]. No measurement happens here."""
-    cm = cost_model or CostModel()
+    cm = cost_model or CostModel(chip=tuner_cfg.get("chip"))
     tuner = AutoTuner(dict(tuner_cfg, task_limit=10 ** 9))
     survivors = []
     with _spans.span("planner/rank"):
@@ -124,7 +124,7 @@ def plan_and_tune(model_builder, loss_fn, optimizer_builder, tuner_cfg,
     shortlist reports. Configs the analytic ranking REJECTED (beyond
     top-K) are recorded with `pruned="analytic rank > K"`.
     """
-    cm = cost_model or CostModel()
+    cm = cost_model or CostModel(chip=tuner_cfg.get("chip"))
     recorder = recorder or Recorder()
     ranked, pruned = rank_candidates(tuner_cfg, cm)
     if not ranked:

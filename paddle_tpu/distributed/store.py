@@ -126,10 +126,6 @@ class TCPStore:
         node's heartbeat would stall the whole watch loop)."""
         import ctypes
 
-        if not hasattr(self._lib, "tcp_store_tryget"):
-            raise RuntimeError(
-                "native library predates tcp_store_tryget — rebuild with "
-                "`make -C native`")
         cap = 1 << 20
         with self._lock:
             for _ in range(8):

@@ -35,20 +35,16 @@ __all__ = ["DistributedTrainStep", "fsdp_spec", "shard_params_for_stage3",
 
 
 def host_memory_kind(mesh):
-    """The host-side memory kind this mesh's devices can address —
-    "pinned_host" on TPU, "unpinned_host" on the CPU backend (where host
-    and device memory coincide, so offload degenerates to a no-op
-    placement but exercises the same code path), None when the runtime
-    has no memories API at all."""
-    try:
-        dev = next(iter(mesh.devices.flat))
-        kinds = {m.kind for m in dev.addressable_memories()}
-    except Exception:  # graftlint: disable=GL003 probing an optional runtime API (pre-memories jaxlibs raise various types); the fallback IS the handling
-        return "pinned_host"  # pre-memories probing: keep the TPU default
-    for k in ("pinned_host", "unpinned_host"):
-        if k in kinds:
-            return k
-    return None
+    """The memory kind offloaded optimizer states live in: "pinned_host" on
+    TPU. XLA:CPU compiles memory-kind placements away — every buffer is
+    host RAM, and a compiled program's outputs come back in the default
+    kind whatever the program asked for — so on the CPU backend the default
+    kind IS the host kind and offload runs the same code path as a no-op
+    placement."""
+    dev = next(iter(mesh.devices.flat))
+    if dev.platform == "cpu":
+        return dev.default_memory().kind
+    return "pinned_host"
 
 
 def fsdp_spec(shape, axis="sharding", mesh=None, existing=None):
@@ -254,8 +250,7 @@ class DistributedTrainStep(TrainStep):
         compiled program itself device_puts them in at the start and back to
         host memory per-param after each update, so XLA overlaps the copies
         with compute instead of the host serializing them around the step."""
-        return (self.offload and self.comm_overlap
-                and self._host_kind is not None)
+        return self.offload and self.comm_overlap
 
     def _fetch_opt_states(self, opt_states):
         if not self._offload_streaming():
@@ -278,16 +273,6 @@ class DistributedTrainStep(TrainStep):
                 for sk, sv in st.items()}
 
     def _post_dispatch(self):
-        # non-streaming offload with overlap on: issue the d2h restream
-        # INSIDE the compute span, while the dispatched program is still
-        # executing — the device_puts queue behind the step's outputs, so
-        # they pipeline against the tail of the computation instead of
-        # running as a post-step barrier
-        if self.offload and self.comm_overlap and not self._offload_streaming():
-            from . import comm_watchdog
-
-            with comm_watchdog.comm_task("offload/d2h", kind="comm"):
-                self._move_opt_states(host=True)
         # MoE expert-parallel a2a accounting: the traced MoE fast path
         # registered its per-step dispatch/combine all-to-all volume during
         # this program's trace (moe_comm.note_a2a); any (re)trace inside
@@ -347,7 +332,7 @@ class DistributedTrainStep(TrainStep):
 
         streaming = self._offload_streaming()
         if self.offload and not streaming:
-            # host-side move barrier (legacy / no-memories-API path): stream
+            # host-side move barrier (comm_overlap off): stream
             # optimizer states host→device for the update (reference:
             # GroupSharded offload=True keeping the moments on CPU between
             # steps, group_sharded_stage3.py offload). With streaming the
@@ -390,10 +375,9 @@ class DistributedTrainStep(TrainStep):
         self._moe_t0 = time.perf_counter_ns()
         loss = super().__call__([Tensor(a) for a in placed_in], [Tensor(a) for a in placed_lb])
         self._inflight = loss._value
-        if self.offload and not streaming and not self.comm_overlap:
-            # pre-change semantics: the d2h restream runs as an exposed
-            # post-step barrier (comm_overlap=True issues it inside the
-            # compute span via _post_dispatch instead)
+        if self.offload and not streaming:
+            # comm_overlap off: the d2h restream runs as an exposed
+            # post-step barrier (streaming carries it inside the program)
             with comm_watchdog.comm_task("offload/d2h", kind="comm"):
                 self._move_opt_states(host=True)
         return loss

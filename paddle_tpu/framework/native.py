@@ -5,61 +5,83 @@ runtime into python. Here the runtime pieces that must be native (socket
 rendezvous, watchdog thread, shm transport) live in
 libpaddle_tpu_native.so, bound via ctypes; everything compute-side is XLA.
 
-The library is built lazily with `make -C native` on first use and cached;
-all consumers degrade gracefully (pure-python fallbacks) when no compiler
-is available.
+The library is a build product, never a tracked file: it is built with
+`make -C native` on first use from the sources of THIS checkout, and the
+digest of those sources is stamped beside it. A library whose stamp does not
+match the sources is rebuilt — not judged by mtime, which a copy of the tree
+resets. Only where no toolchain exists is a pre-existing library loaded, and
+then with a warning; all consumers have pure-python fallbacks for when there
+is none at all.
 """
 
 from __future__ import annotations
 
 import ctypes
+import hashlib
 import os
+import shutil
 import subprocess
 import threading
+import warnings
 
 _lib = None
 _lock = threading.Lock()
 _REPO_ROOT = os.path.dirname(os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 _NATIVE_DIR = os.path.join(_REPO_ROOT, "native")
 _SO = os.path.join(_NATIVE_DIR, "libpaddle_tpu_native.so")
+_STAMP = _SO + ".srchash"
 
 
-def _build():
+def _source_digest() -> str:
+    h = hashlib.sha256()
+    for f in sorted(os.listdir(_NATIVE_DIR)):
+        if f.endswith((".cc", ".h")) or f == "Makefile":
+            h.update(f.encode())
+            with open(os.path.join(_NATIVE_DIR, f), "rb") as fh:
+                h.update(fh.read())
+    return h.hexdigest()
+
+
+def _built_from(digest: str) -> bool:
+    """True if the .so on disk was built from sources with this digest."""
     try:
-        subprocess.run(["make", "-C", _NATIVE_DIR], check=True,
-                       capture_output=True, timeout=120)
-        return True
-    except Exception:
+        with open(_STAMP) as f:
+            return os.path.exists(_SO) and f.read().strip() == digest
+    except OSError:
         return False
 
 
-def _stale():
-    """True if any native source is newer than the built .so."""
-    if not os.path.exists(_SO):
-        return True
-    so_mtime = os.path.getmtime(_SO)
-    for f in os.listdir(_NATIVE_DIR):
-        if f.endswith((".cc", ".h")) or f == "Makefile":
-            if os.path.getmtime(os.path.join(_NATIVE_DIR, f)) > so_mtime:
-                return True
-    return False
+def _build(digest: str) -> bool:
+    """make the library and stamp it; False (with the compiler's words)
+    when the build fails."""
+    try:
+        os.remove(_SO)  # make must not judge a copied tree's .so up to date
+    except OSError:
+        pass
+    r = subprocess.run(["make", "-C", _NATIVE_DIR], capture_output=True,
+                       text=True, timeout=120)
+    if r.returncode != 0:
+        warnings.warn("native runtime build failed; running without it:\n"
+                      + (r.stderr or r.stdout)[-600:], RuntimeWarning)
+        return False
+    with open(_STAMP, "w") as f:
+        f.write(digest)
+    return True
 
 
 def load():
-    """Return the ctypes lib, (re)building when sources changed; None if unavailable."""
+    """Return the ctypes lib built from this checkout's sources; None if
+    unavailable."""
     global _lib
     if _lib is not None:
         return _lib
     with _lock:
         if _lib is not None:
             return _lib
-        # Rebuild whenever a source file is newer than the .so — a prebuilt
-        # library must never mask edits to native/*.cc. An exclusive file
-        # lock serializes concurrent ranks on one host (all ranks' first
-        # load() would otherwise race `make` against a sibling's dlopen);
-        # held through CDLL so no sibling truncates the .so mid-map. If no
-        # toolchain is available, fall back to an existing (possibly stale)
-        # build.
+        # An exclusive file lock serializes concurrent ranks on one host
+        # (all ranks' first load() would otherwise race `make` against a
+        # sibling's dlopen); held through CDLL so no sibling truncates the
+        # .so mid-map.
         import fcntl
 
         lock_path = os.path.join(_NATIVE_DIR, ".build.lock")
@@ -69,8 +91,19 @@ def load():
         except OSError:
             lock_fd = None
         try:
-            if _stale() and not _build() and not os.path.exists(_SO):
-                return None
+            digest = _source_digest()
+            if not _built_from(digest):
+                if shutil.which("make") and shutil.which(
+                        os.environ.get("CXX", "g++")):
+                    if not _build(digest):
+                        return None
+                elif os.path.exists(_SO):
+                    warnings.warn(
+                        f"no toolchain to build {_SO} from this checkout's "
+                        "sources; loading the pre-existing library, which "
+                        "may not match them", RuntimeWarning)
+                else:
+                    return None
             try:
                 lib = ctypes.CDLL(_SO)
             except OSError:
@@ -97,10 +130,9 @@ def load():
         lib.tcp_store_get.restype = ctypes.c_long
         lib.tcp_store_get.argtypes = [ctypes.c_ssize_t, ctypes.c_char_p,
                                       ctypes.c_char_p, ctypes.c_long]
-        if hasattr(lib, "tcp_store_tryget"):  # absent in pre-existing builds
-            lib.tcp_store_tryget.restype = ctypes.c_long
-            lib.tcp_store_tryget.argtypes = [ctypes.c_ssize_t, ctypes.c_char_p,
-                                             ctypes.c_char_p, ctypes.c_long]
+        lib.tcp_store_tryget.restype = ctypes.c_long
+        lib.tcp_store_tryget.argtypes = [ctypes.c_ssize_t, ctypes.c_char_p,
+                                         ctypes.c_char_p, ctypes.c_long]
         lib.tcp_store_add.restype = ctypes.c_int
         lib.tcp_store_add.argtypes = [ctypes.c_ssize_t, ctypes.c_char_p,
                                       ctypes.c_longlong,

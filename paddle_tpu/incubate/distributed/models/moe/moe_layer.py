@@ -183,8 +183,10 @@ class MoELayer(nn.Layer):
         # kernel-dispatch rule); the CPU fallback keeps the SAME sorted
         # layout and runs the groups as one batched einsum — dead rows are
         # zero by the scatter's construction, so values are identical
-        from .....ops.pallas.grouped_gemm import grouped_matmul, kernel_usable
-        use_kernel = kernel_usable()
+        from .....ops.pallas import kernels_available
+        from .....ops.pallas.grouped_gemm import grouped_matmul
+
+        use_kernel = kernels_available()
 
         def gmm3(x3, w, sizes):
             """[E, Rc, K] @ [E, K, N] grouped — under shard_map over `ep`
@@ -201,10 +203,8 @@ class MoELayer(nn.Layer):
                 return out.reshape(El, Rc, out.shape[-1])
 
             if ep > 1:
-                from .....parallel.shmap_compat import shard_map
-
                 spec3 = P(ep_axis, None, None)
-                return shard_map(
+                return jax.shard_map(
                     body, mesh=mesh, in_specs=(spec3, spec3, P(ep_axis)),
                     out_specs=spec3, axis_names={ep_axis},
                     check_vma=False)(x3, w, sizes)

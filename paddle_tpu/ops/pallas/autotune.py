@@ -185,13 +185,18 @@ def _bump(kind, kernel):
 
 
 def _record(kernel, tile, source):
+    # consults counts every trace that reached this kernel, so a caller can
+    # tell which kernels a given stretch of work (re)traced — chip_smoke.py
+    # uses the delta per phase to prove no phase fell back to a composite
+    consults = _chosen.get(kernel, {}).get("consults", 0) + 1
     _chosen[kernel] = {"bq": int(tile[0]), "bk": int(tile[1]),
-                       "source": source}
+                       "source": source, "consults": consults}
 
 
 def chosen_tiles() -> dict:
-    """{kernel: {bq, bk, source, hits, misses, fallbacks}} for every Pallas
-    kernel that consulted the tuner this process. `source`: "tuned" (cache
+    """{kernel: {bq, bk, source, consults, hits, misses, fallbacks}} for
+    every Pallas kernel that consulted the tuner this process (the hit/miss
+    counts only exist once tuning is enabled). `source`: "tuned" (cache
     winner), "measured" (swept this call), "fixed" (single legal candidate,
     nothing tunable at launch), "default" (tuning disabled or trace-time
     miss). The StepTimeline attaches this snapshot to each step record;

@@ -21,10 +21,13 @@ Single-token decode (q = one step per row), inference only (no VJP).
 Quantized fast path: with `kv_scales`, the caches are int8 page payloads and
 `kv_scales` the per-(page, head) f32 dequant scales (`x ≈ q * scale`,
 `BlockPool(quantized=True)` layout). The same grid loads the int8 page into
-VMEM, dequantizes there (one scalar multiply per page fetched as a (1, 1)
-block), and accumulates in f32 exactly like the full-precision kernel —
-decode is HBM-bound, so halving/quartering the streamed bytes is the whole
-win and the dequant multiply rides the VPU for free.
+VMEM, dequantizes there (one scalar multiply per page), and accumulates in
+f32 exactly like the full-precision kernel — decode is HBM-bound, so
+halving/quartering the streamed bytes is the whole win and the dequant
+multiply rides the VPU for free. The scale rides beside its page as the
+(8, Hkv) tile of the [n_pages, Hkv] scale array that holds it — a (1, 1)
+block of a 2-D array is not a shape Mosaic tiles — and the kernel selects
+its (page % 8, head) entry with an iota mask.
 """
 
 from __future__ import annotations
@@ -45,6 +48,9 @@ __all__ = ["paged_decode_attention", "dense_decode_attention",
 # symmetric int8 range for KV pages: ±127 (not -128) so the running-max
 # rescale in paged_kv_write_q8 can never overflow the negative extreme
 KV_QMAX = 127.0
+# rows of the [n_pages, Hkv] f32 scale array fetched per grid step: one f32
+# sublane tile
+_SCALE_ROWS = 8
 
 
 def _decode_kernel(tables_ref, lens_ref, q_ref, k_ref, v_ref, *rest,
@@ -55,6 +61,7 @@ def _decode_kernel(tables_ref, lens_ref, q_ref, k_ref, v_ref, *rest,
         ks_ref = vs_ref = None
         o_ref, m_scr, l_scr, acc_scr = rest
     b = pl.program_id(0)
+    h = pl.program_id(1)
     p = pl.program_id(2)
 
     @pl.when(p == 0)
@@ -69,13 +76,30 @@ def _decode_kernel(tables_ref, lens_ref, q_ref, k_ref, v_ref, *rest,
     if paged:
         valid_page = valid_page & (tables_ref[b, p] >= 0)
 
+    def _page_scales():
+        """This (page, head)'s K and V scales as (1, 1) arrays, picked out
+        of their (8, Hkv) tiles with one mask (padding rows past n_pages
+        never match)."""
+        phys = jnp.maximum(tables_ref[b, p], 0)
+        row = jax.lax.broadcasted_iota(jnp.int32, ks_ref.shape, 0)
+        col = jax.lax.broadcasted_iota(jnp.int32, ks_ref.shape, 1)
+        pick = (row == phys % _SCALE_ROWS) & (col == h)
+
+        def one(sc_ref):
+            lane = jnp.sum(jnp.where(pick, sc_ref[...], 0.0), axis=1,
+                           keepdims=True)
+            return jnp.sum(lane, axis=0, keepdims=True)
+
+        return one(ks_ref), one(vs_ref)
+
     # scratch rows are padded to >=8 for TPU tiling; compute on the first g
     @pl.when(valid_page)
     def _compute():
         q = q_ref[0, 0].astype(jnp.float32)      # [g, D]
         k = k_ref[0, 0].astype(jnp.float32)      # [ps, D]
         if quantized:
-            k = k * ks_ref[0, 0]                 # dequant in VMEM
+            k_scale, v_scale = _page_scales()
+            k = k * k_scale                      # dequant in VMEM
         s = jax.lax.dot_general(
             q, k, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
         ) * scale                                # [g, ps]
@@ -92,7 +116,7 @@ def _decode_kernel(tables_ref, lens_ref, q_ref, k_ref, v_ref, *rest,
             (g, l_scr.shape[1]))
         v = v_ref[0, 0].astype(jnp.float32)      # [ps, D]
         if quantized:
-            v = v * vs_ref[0, 0]
+            v = v * v_scale
         pv = jax.lax.dot_general(
             pr, v, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32
         )
@@ -134,8 +158,7 @@ def _run_decode(q, kc, vc, tables, lengths, scale, paged, ps=None,
             return (jnp.where(t < 0, 0, t), h, 0, 0)
 
         def smap(b, h, p, tabs, lens):
-            t = tabs[b, p]
-            return (jnp.where(t < 0, 0, t), h)
+            return (jnp.maximum(tabs[b, p], 0) // _SCALE_ROWS, 0)
     else:
         assert not quantized, "quantized cache is paged-only"
         S_max = kc.shape[2]
@@ -156,8 +179,9 @@ def _run_decode(q, kc, vc, tables, lengths, scale, paged, ps=None,
     ]
     operands = [q, kc, vc]
     if quantized:
-        # one f32 scalar per (page, head), fetched beside its page
-        in_specs += [pl.BlockSpec((1, 1), smap), pl.BlockSpec((1, 1), smap)]
+        # the (8, Hkv) scale tile holding this page's row, beside the page
+        sc_spec = pl.BlockSpec((_SCALE_ROWS, Hkv), smap)
+        in_specs += [sc_spec, sc_spec]
         operands += [kv_scales[0].astype(jnp.float32),
                      kv_scales[1].astype(jnp.float32)]
     grid_spec = pltpu.PrefetchScalarGridSpec(
@@ -318,7 +342,7 @@ def _tuned_dense_ps(q4, kc, vc, lengths, scale):
     def run_with(ps, _d):
         out = _run_decode(q4, kc, vc, dummy, lengths, scale, paged=False,
                           ps=ps)
-        jax.device_get(out.ravel()[0:1])
+        out.block_until_ready()
 
     concrete = not any(isinstance(x, jax.core.Tracer)
                        for x in (q4, kc, lengths))

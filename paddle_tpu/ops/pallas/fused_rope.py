@@ -41,6 +41,7 @@ time) selects between this kernel and the lax composite for A/B.
 from __future__ import annotations
 
 import functools
+import math
 import os
 
 import jax
@@ -48,7 +49,10 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from jax.sharding import PartitionSpec as P
+
 from . import interpret_mode
+from .partition import shard_plan
 
 __all__ = ["fused_rope_on", "apply_fused_rope"]
 
@@ -201,7 +205,7 @@ def _tuned_seq_block(tensors, cf, sins, shifts):
 
     def run_with(bs, _bk):
         outs = _rope_run(tensors, cf, sins, shifts, bs)
-        jax.device_get(outs[0].ravel()[0:1])  # real fetch, see flash tuner
+        jax.block_until_ready(outs)
 
     concrete = not any(isinstance(t, jax.core.Tracer)
                        for t in (*tensors, cf, *sins))
@@ -216,8 +220,26 @@ def apply_fused_rope(tensors, cos_half, sin_half, interleaved=False):
     """Apply rotary embedding to 1..3 tensors [B, S, Hi, D] in ONE kernel
     pass. cos_half/sin_half: [B|1, S, D/2] position tables (data — zero
     cotangent). Differentiable w.r.t. the tensors (custom VJP). Requires
-    even D; callers gate on that and fall back to the composite."""
-    d = tensors[0].shape[-1]
-    cf, sins, shifts = _rope_tables_full(cos_half, sin_half, d, interleaved)
-    bs = _tuned_seq_block(tensors, cf, sins, shifts)
-    return _rope(tuple(tensors), (cf, tuple(sins)), shifts, bs)
+    even D; callers gate on that and fall back to the composite. Under a
+    multi-device mesh the kernel runs per shard (partition.py): batch over
+    the data axes, heads over mp."""
+    tensors = tuple(tensors)
+
+    def local(cos_half, sin_half, *tensors):
+        d = tensors[0].shape[-1]
+        cf, sins, shifts = _rope_tables_full(cos_half, sin_half, d,
+                                             interleaved)
+        bs = _tuned_seq_block(tensors, cf, sins, shifts)
+        return _rope(tensors, (cf, tuple(sins)), shifts, bs)
+
+    plan = shard_plan(*tensors)
+    if plan is None:
+        return local(cos_half, sin_half, *tensors)
+    nb = tensors[0].shape[0]
+    b = plan.axes("batch", nb)
+    h = plan.axes("heads", math.gcd(*(t.shape[2] for t in tensors)))
+    ts = P(b, None, h, None)
+    tab = P(b if cos_half.shape[0] == nb else None, None, None)
+    return plan.run(local, [cos_half, sin_half, *tensors],
+                    [tab, tab] + [ts] * len(tensors),
+                    tuple(ts for _ in tensors))

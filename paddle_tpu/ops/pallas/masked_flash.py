@@ -32,7 +32,10 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from . import interpret_mode
+from jax.sharding import PartitionSpec as P
+
+from . import interpret_mode, mxu_dot
+from .partition import shard_plan
 from .flash_attention import NEG_INF, _block_sizes, _pad_seq
 
 __all__ = ["flashmask_attention_fwd", "varlen_flash_attention_fwd"]
@@ -102,7 +105,7 @@ def _fm_fwd_kernel(q_ref, kt_ref, v_ref, idx_ref, o_ref, lse_ref,
         # cost ~4x MXU passes); accumulation is f32 via preferred_element_type
         q = q_ref[0, 0]
         kt = kt_ref[0, 0]  # [D, bk]: MXU-native QK^T (see flash_attention.py)
-        s = jax.lax.dot_general(
+        s = mxu_dot(
             q, kt, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32
         ) * scale
         s = jnp.where(keep, s, NEG_INF)
@@ -116,7 +119,7 @@ def _fm_fwd_kernel(q_ref, kt_ref, v_ref, idx_ref, o_ref, lse_ref,
         l_new = alpha * l_scr[:, :1] + jnp.sum(p, axis=-1, keepdims=True)
 
         v = v_ref[0, 0]
-        pv = jax.lax.dot_general(
+        pv = mxu_dot(
             p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
             preferred_element_type=jnp.float32
         )
@@ -160,17 +163,17 @@ def _fm_bwd_dq_kernel(q_ref, kt_ref, vt_ref, k_ref, idx_ref, do_ref, lse_ref,
         do = do_ref[0, 0]
         lse = lse_ref[0, 0]  # [bq, 1]
         delta = delta_ref[0, 0]
-        s = jax.lax.dot_general(
+        s = mxu_dot(
             q, kt_ref[0, 0], (((1,), (0,)), ((), ())),
             preferred_element_type=jnp.float32
         ) * scale
         p = jnp.where(keep, jnp.exp(s - lse), 0.0)
-        dp = jax.lax.dot_general(
+        dp = mxu_dot(
             do, vt_ref[0, 0], (((1,), (0,)), ((), ())),
             preferred_element_type=jnp.float32
         )
         ds = (p * (dp - delta) * scale).astype(k.dtype)
-        dq_scr[:] = dq_scr[:] + jax.lax.dot_general(
+        dq_scr[:] = dq_scr[:] + mxu_dot(
             ds, k, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32
         )
 
@@ -204,21 +207,21 @@ def _fm_bwd_dkv_kernel(q_ref, kt_ref, vt_ref, idx_ref, do_ref, lse_ref,
         do = do_ref[0, 0]
         lse = lse_ref[0, 0]  # [bq, 1]
         delta = delta_ref[0, 0]
-        s = jax.lax.dot_general(
+        s = mxu_dot(
             q, kt_ref[0, 0], (((1,), (0,)), ((), ())),
             preferred_element_type=jnp.float32
         ) * scale
         p = jnp.where(keep, jnp.exp(s - lse), 0.0)
-        dv_scr[:] = dv_scr[:] + jax.lax.dot_general(
+        dv_scr[:] = dv_scr[:] + mxu_dot(
             p.astype(do.dtype), do, (((0,), (0,)), ((), ())),
             preferred_element_type=jnp.float32
         )
-        dp = jax.lax.dot_general(
+        dp = mxu_dot(
             do, vt_ref[0, 0], (((1,), (0,)), ((), ())),
             preferred_element_type=jnp.float32
         )
         ds = (p * (dp - delta) * scale).astype(q.dtype)
-        dk_scr[:] = dk_scr[:] + jax.lax.dot_general(
+        dk_scr[:] = dk_scr[:] + mxu_dot(
             ds, q, (((0,), (0,)), ((), ())), preferred_element_type=jnp.float32
         )
 
@@ -395,7 +398,7 @@ def _tuned_blocks_fm(q, k, v, idx, causal, scale):
         idxp = jnp.pad(idx, ((0, 0), (0, 0), (0, 0),
                              (0, kp.shape[2] - skv)))
         out, _ = _fm_fwd(qp, kp, vp, idxp, scale, causal, sq, skv, bq, bk)
-        jax.device_get(out.ravel()[0:1])  # real fetch, see flash tuner
+        out.block_until_ready()
 
     concrete = not any(isinstance(x, jax.core.Tracer)
                        for x in (q, k, v, idx))
@@ -408,9 +411,29 @@ def _tuned_blocks_fm(q, k, v, idx, causal, scale):
 def flashmask_attention_fwd(q, k, v, startend_row_indices, causal=True,
                             scale=None):
     """Paddle-layout entry: q [B,Sq,H,D], k/v [B,Skv,Hkv,D],
-    startend_row_indices [B,Hm,Skv,n] -> [B,Sq,H,D]. Differentiable."""
+    startend_row_indices [B,Hm,Skv,n] -> [B,Sq,H,D]. Differentiable. Under
+    a multi-device mesh the kernel runs per shard (partition.py): batch
+    over the data axes, heads — and the mask's heads, when it has its own —
+    over mp."""
     if scale is None:
         scale = 1.0 / math.sqrt(q.shape[-1])
+    plan = shard_plan(q, k, v)
+    if plan is not None:
+        hm = startend_row_indices.shape[1]
+        b = plan.axes("batch", q.shape[0])
+        h = plan.axes("heads", math.gcd(math.gcd(q.shape[2], k.shape[2]),
+                                        hm if hm > 1 else 0))
+        qkv = P(b, None, h, None)
+        idx_spec = P(b if startend_row_indices.shape[0] == q.shape[0]
+                     else None, h if hm > 1 else None, None, None)
+        return plan.run(
+            lambda q, k, v, idx: _flashmask_local(q, k, v, idx, causal,
+                                                  scale),
+            [q, k, v, startend_row_indices], [qkv, qkv, qkv, idx_spec], qkv)
+    return _flashmask_local(q, k, v, startend_row_indices, causal, scale)
+
+
+def _flashmask_local(q, k, v, startend_row_indices, causal, scale):
     qt = jnp.swapaxes(q, 1, 2)
     kt = jnp.swapaxes(k, 1, 2)
     vt = jnp.swapaxes(v, 1, 2)
@@ -461,7 +484,7 @@ def _vl_fwd_kernel(q_ref, k_ref, v_ref, sq_ref, sk_ref, pq_ref, pk_ref,
     def _compute():
         q = q_ref[0].astype(jnp.float32)
         k = k_ref[0].astype(jnp.float32)
-        s = jax.lax.dot_general(
+        s = mxu_dot(
             q, k, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
         ) * scale
         s = jnp.where(keep, s, NEG_INF)
@@ -471,7 +494,7 @@ def _vl_fwd_kernel(q_ref, k_ref, v_ref, sq_ref, sk_ref, pq_ref, pk_ref,
         p = jnp.where(keep, jnp.exp(s - m_new), 0.0)
         l_new = alpha * l_scr[:, :1] + jnp.sum(p, axis=-1, keepdims=True)
         v = v_ref[0].astype(jnp.float32)
-        pv = jax.lax.dot_general(
+        pv = mxu_dot(
             p, v, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32
         )
         acc_scr[:] = acc_scr[:] * alpha + pv
@@ -509,15 +532,15 @@ def _vl_bwd_dq_kernel(q_ref, k_ref, v_ref, sq_ref, sk_ref, pq_ref, pk_ref,
         do = do_ref[0].astype(jnp.float32)
         lse = lse_ref[0]  # [bq, 1]
         delta = delta_ref[0]
-        s = jax.lax.dot_general(
+        s = mxu_dot(
             q, k, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
         ) * scale
         p = jnp.where(keep, jnp.exp(s - lse), 0.0)
-        dp = jax.lax.dot_general(
+        dp = mxu_dot(
             do, v, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
         )
         ds = p * (dp - delta) * scale
-        dq_scr[:] = dq_scr[:] + jax.lax.dot_general(
+        dq_scr[:] = dq_scr[:] + mxu_dot(
             ds, k, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32
         )
 
@@ -550,18 +573,18 @@ def _vl_bwd_dkv_kernel(q_ref, k_ref, v_ref, sq_ref, sk_ref, pq_ref, pk_ref,
         do = do_ref[0].astype(jnp.float32)
         lse = lse_ref[0]  # [bq, 1]
         delta = delta_ref[0]
-        s = jax.lax.dot_general(
+        s = mxu_dot(
             q, k, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
         ) * scale
         p = jnp.where(keep, jnp.exp(s - lse), 0.0)
-        dv_scr[:] = dv_scr[:] + jax.lax.dot_general(
+        dv_scr[:] = dv_scr[:] + mxu_dot(
             p, do, (((0,), (0,)), ((), ())), preferred_element_type=jnp.float32
         )
-        dp = jax.lax.dot_general(
+        dp = mxu_dot(
             do, v, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
         )
         ds = p * (dp - delta) * scale
-        dk_scr[:] = dk_scr[:] + jax.lax.dot_general(
+        dk_scr[:] = dk_scr[:] + mxu_dot(
             ds, q, (((0,), (0,)), ((), ())), preferred_element_type=jnp.float32
         )
 
@@ -745,7 +768,7 @@ def _tuned_blocks_vl(q, k, v, seg_q, seg_k, pos_q, pos_k, causal, scale):
         pkp = _pad_vec(pos_k, bk, 0)[None, :]
         out, _ = _vl_fwd(qp, kp, vp, sqp, skp, pqp, pkp, scale, causal, tq,
                          tk, bq, bk)
-        jax.device_get(out.ravel()[0:1])  # real fetch, see flash tuner
+        out.block_until_ready()
 
     concrete = not any(isinstance(x, jax.core.Tracer)
                        for x in (q, k, v, seg_q, seg_k))
@@ -758,7 +781,22 @@ def _tuned_blocks_vl(q, k, v, seg_q, seg_k, pos_q, pos_k, causal, scale):
 def varlen_flash_attention_fwd(q, k, v, cu_seqlens_q, cu_seqlens_k, scale,
                                causal=False):
     """Packed varlen entry: q [Tq,H,D], k/v [Tk,Hkv,D], cu_seqlens [B+1].
-    Differentiable w.r.t. q/k/v. Reference: flash_attn_unpadded."""
+    Differentiable w.r.t. q/k/v. Under a multi-device mesh the kernel runs
+    per shard with heads over mp (partition.py; the packed token axis
+    carries the segments and stays whole). Reference: flash_attn_unpadded."""
+    plan = shard_plan(q, k, v)
+    if plan is not None:
+        h = plan.axes("heads", math.gcd(q.shape[1], k.shape[1]))
+        qkv = P(None, h, None)
+        return plan.run(
+            lambda q, k, v, cq, ck: _varlen_local(q, k, v, cq, ck, scale,
+                                                  causal),
+            [q, k, v, cu_seqlens_q, cu_seqlens_k],
+            [qkv, qkv, qkv, P(None), P(None)], qkv)
+    return _varlen_local(q, k, v, cu_seqlens_q, cu_seqlens_k, scale, causal)
+
+
+def _varlen_local(q, k, v, cu_seqlens_q, cu_seqlens_k, scale, causal):
     Tq, Tk = q.shape[0], k.shape[0]
     cq = cu_seqlens_q.astype(jnp.int32)
     ck = cu_seqlens_k.astype(jnp.int32)

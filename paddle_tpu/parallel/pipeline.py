@@ -47,8 +47,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-from .shmap_compat import shard_map as _shard_map
-
 
 def stack_pytrees(trees):
     """Stack a list of identical-structure pytrees along a new leading axis."""
@@ -197,7 +195,7 @@ def _build_pipelined(stage_fn, mesh, axis, remat, double_buffer, ptreedef,
     pspecs = jax.tree.unflatten(ptreedef, [P(axis)] * n_p)
     xspecs = jax.tree.unflatten(xtreedef, [P()] * n_x)
     ospecs = jax.tree.unflatten(xtreedef, [P()] * n_x)
-    pipelined = _shard_map(
+    pipelined = jax.shard_map(
         body, mesh=mesh, in_specs=(pspecs, xspecs), out_specs=ospecs,
         axis_names=frozenset({axis}), check_vma=False,
     )
@@ -479,7 +477,7 @@ def _build_1f1b(stage_fn, loss_fn, mesh, axis, M, sp_def, lp_def, in_def):
             is_leaf=lambda x: x is None)
         return loss, gacc, glacc, dxacc
 
-    shmapped = _shard_map(
+    shmapped = jax.shard_map(
         body, mesh=mesh,
         in_specs=(P(axis), P(), P()),
         out_specs=(P(), P(axis), P(), P()),
@@ -683,7 +681,7 @@ def _build_interleaved(stage_fn, mesh, axis, V, M, remat, double_buffer,
             carry["out"])
         return outs
 
-    shmapped = _shard_map(
+    shmapped = jax.shard_map(
         body, mesh=mesh, in_specs=(P(None, axis), P()),
         out_specs=P(), axis_names=frozenset({axis}),
         check_vma=False,

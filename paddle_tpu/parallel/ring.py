@@ -83,9 +83,7 @@ def _build(mesh, axis, causal, scale, jit):
     body = functools.partial(_local_ring_attention, axis=axis, n=n,
                              causal=causal, scale=scale)
     spec = P(None, axis, None, None)
-    from .shmap_compat import shard_map as _shard_map
-
-    mapped = _shard_map(
+    mapped = jax.shard_map(
         body, mesh=mesh, in_specs=(spec, spec, spec), out_specs=spec,
         axis_names=frozenset({axis}), check_vma=False,
     )

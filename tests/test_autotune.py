@@ -81,12 +81,11 @@ def test_failing_candidates_skipped(monkeypatch):
     assert best == (128, 128)
 
 
-def test_flash_entry_consults_tuner(monkeypatch):
+def test_flash_entry_consults_tuner(monkeypatch, pallas_interpret_unless_hw):
     """flash_attention_fwd routes through the tuner: a pre-seeded cache
     winner changes the block shape _fwd actually receives."""
     from paddle_tpu.ops.pallas import flash_attention as fa
 
-    monkeypatch.setenv("PADDLE_TPU_PALLAS_INTERPRET", "1")
     # force tuning on despite interpret mode so the cache lookup runs
     monkeypatch.setattr(autotune, "autotune_enabled", lambda: True)
 
@@ -113,10 +112,11 @@ def test_flash_entry_consults_tuner(monkeypatch):
     assert (256, 256) in seen, f"tuned blocks not used: {seen}"
 
 
-def test_flash_entry_default_under_interpret(monkeypatch):
-    """Interpret mode (tuning off) still runs correctly on defaults."""
+def test_flash_entry_default_under_interpret(monkeypatch,
+                                             pallas_interpret_unless_hw):
+    """Interpret mode (tuning off) still runs correctly on defaults (on the
+    chip the same call sweeps the candidates for real)."""
     monkeypatch.setenv("PADDLE_TPU_AUTOTUNE", "1")
-    monkeypatch.setenv("PADDLE_TPU_PALLAS_INTERPRET", "1")
     from paddle_tpu.ops.pallas.flash_attention import flash_attention_fwd
 
     rng = np.random.default_rng(0)
@@ -207,15 +207,14 @@ def test_disabled_still_records_default_tile(monkeypatch):
                                     lambda bq, bk: None)
     assert out == (256, 512)
     rec = autotune.chosen_tiles()["kdef"]
-    assert rec == {"bq": 256, "bk": 512, "source": "default"}
+    assert rec == {"bq": 256, "bk": 512, "source": "default", "consults": 1}
 
 
-def test_all_pallas_kernels_consult_tuner(monkeypatch):
+def test_all_pallas_kernels_consult_tuner(pallas_interpret_unless_hw):
     """Acceptance: every Pallas kernel entry lands a tile in the registry —
     flash, flashmask, varlen, dense+paged decode, fused norm, fused rope."""
     import jax.numpy as jnp
 
-    monkeypatch.setenv("PADDLE_TPU_PALLAS_INTERPRET", "1")
     from paddle_tpu.ops.pallas.decode_attention import (
         dense_decode_attention, paged_decode_attention)
     from paddle_tpu.ops.pallas.flash_attention import flash_attention_fwd

@@ -1,11 +1,13 @@
-"""bench.py rung-ladder robustness + the 1.3B low-memory recipe.
+"""bench.py: nothing hides the device, + the 1.3B low-memory recipe.
 
-Round-4 postmortem: the 1.3B rung OOMed at *construction* (params +
-optimizer-state allocation), outside the warmup-only try/except, so the
-350M/125M fallback never ran and the driver recorded `mfu_failed`. These
-tests pin (a) the fallback fires no matter where in the rung the failure
-happens, (b) failed rungs free their device buffers, (c) the bf16-moment
-AdamW recipe the 1.3B rung uses trains correctly.
+Five driver rounds produced one chip number: the others died, or silently
+fell back to the CPU (or to a smaller model) and still printed a metric.
+These tests pin that (a) the named rung runs or fails — no ladder, (b) a run
+that finds no TPU fails unless the harness check is asked for by name, (c) a
+failed matrix rung leaves a non-zero exit after the remaining rungs ran,
+(d) an unknown device kind has no peak, (e) failed rungs free their device
+buffers, (f) the bf16-moment AdamW recipe the 1.3B rung uses trains
+correctly.
 """
 
 import json
@@ -32,34 +34,78 @@ def _tiny_cfg():
                      vocab_size=512, max_position_embeddings=64)
 
 
-def test_ladder_falls_back_on_construction_failure(monkeypatch, capsys):
-    """Failures during model/optimizer ALLOCATION (not just warmup) must
-    fall through to the next rung."""
-    real = bench._decoder_step
+def test_named_rung_fails_loudly(monkeypatch):
+    """A failure anywhere in the named rung — construction included (round
+    4's 1.3B run OOMed there) — propagates. No smaller model stands in."""
     calls = []
 
-    def fake(cfg, batch, seq, on_tpu, low_mem=False, **kw):
-        calls.append(cfg)
-        if len(calls) < 3:
-            raise RuntimeError("RESOURCE_EXHAUSTED: fake construction OOM")
-        return real(_tiny_cfg(), 2, 32, False)
+    def fake(cfg, batch, seq, bf16_amp, low_mem=False, **kw):
+        calls.append(cfg.hidden_size)
+        raise RuntimeError("RESOURCE_EXHAUSTED: fake construction OOM")
 
     monkeypatch.setattr(bench, "_decoder_step", fake)
-    line = bench.run_gpt_rung(None, True, None)
-    assert len(calls) == 3  # 1.3b failed, 350m failed, 125m ran
-    assert "fell back" in line.get("note", "")
-    assert np.isfinite(line["value"]) and line["value"] > 0
-    out = capsys.readouterr().out.strip().splitlines()[-1]
-    assert json.loads(out)["metric"].startswith("mfu_")
+    with pytest.raises(RuntimeError, match="RESOURCE_EXHAUSTED"):
+        bench.run_gpt_rung("gpt3_1p3b")
+    assert calls == [2048]  # the 1.3B config, once; nothing else was tried
 
 
-def test_ladder_raises_if_all_rungs_fail(monkeypatch):
-    def fake(cfg, batch, seq, on_tpu, low_mem=False, **kw):
-        raise RuntimeError("RESOURCE_EXHAUSTED")
+def test_no_tpu_fails_unless_cpu_smoke_by_name(monkeypatch):
+    monkeypatch.delenv("BENCH_CONFIG", raising=False)
+    monkeypatch.delenv("BENCH_MATRIX", raising=False)
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", "/nonexistent-unused")
+    with pytest.raises(RuntimeError, match="platform='cpu'"):
+        bench.main([])
+    monkeypatch.setenv("BENCH_CONFIG", "gpt3_125m")
+    with pytest.raises(RuntimeError, match="platform='cpu'"):
+        bench.main([])
+    monkeypatch.setenv("BENCH_CONFIG", "cpu_smoke")
+    monkeypatch.setenv("BENCH_MATRIX", "1")  # the matrix always measures
+    with pytest.raises(RuntimeError, match="platform='cpu'"):
+        bench.main([])
 
-    monkeypatch.setattr(bench, "_decoder_step", fake)
-    with pytest.raises(RuntimeError):
-        bench.run_gpt_rung(None, True, None)
+
+def test_failed_matrix_rung_exits_nonzero(monkeypatch, capsys):
+    ran = []
+
+    def ok(name):
+        return lambda: ran.append(name)
+
+    def boom():
+        ran.append("boom")
+        raise ValueError("rung blew up")
+
+    monkeypatch.setenv("BENCH_MATRIX", "1")
+    monkeypatch.delenv("BENCH_CONFIG", raising=False)
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", "/nonexistent-unused")
+    monkeypatch.setattr(bench, "_require_tpu", lambda: None)
+    monkeypatch.setattr(bench, "_matrix_rungs", lambda mp: [
+        ("a", ok("a")), ("b", boom), ("c", ok("c"))])
+    monkeypatch.setattr(bench, "run_gpt_rung",
+                        lambda name, trace_dir=None: ran.append(name))
+    assert bench.main([]) == 1
+    assert ran == ["a", "boom", "c", "gpt3_1p3b"]  # the rest still ran
+    lines = [json.loads(ln) for ln in
+             capsys.readouterr().out.strip().splitlines()]
+    failed = [ln for ln in lines if ln["metric"] == "b_failed"]
+    assert failed and failed[0]["platform"] == "cpu"
+    assert "device_kind" in failed[0] and "device_count" in failed[0]
+
+    monkeypatch.setattr(bench, "_matrix_rungs", lambda mp: [("a", ok("a"))])
+    assert bench.main([]) == 0
+
+
+def test_unknown_device_kind_has_no_peak():
+    """No utilisation for a chip the table does not know — the old answer
+    was v4's peak."""
+    import jax
+
+    from paddle_tpu.distributed.planner import chip_specs
+
+    with pytest.raises(ValueError, match="cpu"):
+        bench._peak_flops(jax.devices()[0])
+    with pytest.raises(ValueError, match="TPU v9"):
+        chip_specs("TPU v9")
+    assert chip_specs("TPU v5 lite")[0] == 197e12
 
 
 def test_free_rung_drops_trainstep_state():

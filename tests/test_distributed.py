@@ -92,22 +92,19 @@ class TestEagerCollectives:
 class TestPrimitives:
     def test_psum_inside_shard_map(self):
         from jax.sharding import PartitionSpec as P
-        from jax.experimental.shard_map import shard_map
-
         mesh = dist.build_mesh(dp=8)
         x = jnp.arange(8.0)
 
         def body(v):
             return primitives.all_reduce(v, axis="dp")
 
-        f = shard_map(body, mesh=mesh, in_specs=P("dp"), out_specs=P("dp"))
+        f = jax.shard_map(body, mesh=mesh, in_specs=P("dp"),
+                          out_specs=P("dp"))
         out = f(x)
         np.testing.assert_allclose(np.asarray(out), np.full(8, 28.0))
 
     def test_ppermute_ring(self):
         from jax.sharding import PartitionSpec as P
-        from jax.experimental.shard_map import shard_map
-
         mesh = dist.build_mesh(pp=8)
         x = jnp.arange(8.0)
         perm = [(i, (i + 1) % 8) for i in range(8)]
@@ -115,7 +112,8 @@ class TestPrimitives:
         def body(v):
             return primitives.ppermute(v, "pp", perm)
 
-        out = shard_map(body, mesh=mesh, in_specs=P("pp"), out_specs=P("pp"))(x)
+        out = jax.shard_map(body, mesh=mesh, in_specs=P("pp"),
+                            out_specs=P("pp"))(x)
         np.testing.assert_allclose(np.asarray(out), np.roll(np.arange(8.0), 1))
 
 

@@ -7,6 +7,7 @@ python loop, and the expert-parallel path vs the replicated run.
 
 import os
 
+import jax
 import numpy as np
 import pytest
 
@@ -272,9 +273,11 @@ class TestGroupedGemm:
                                         jnp.asarray(sizes)))
         ref = np.stack([lhs.reshape(E, R, K)[e] @ rhs[e]
                         for e in range(E)]).reshape(E * R, N)
-        # live groups exact; the all-dead group's tiles are ZERO (skipped
-        # tiles write zeros, never garbage)
-        np.testing.assert_array_equal(out, ref)
+        # live groups to f32 accumulation order (a few ulp: the MXU and the
+        # numpy matmul sum in another order — observed on the v5e 1.9e-6);
+        # the all-dead group's tiles are exactly ZERO (skipped tiles write
+        # zeros, never garbage)
+        np.testing.assert_allclose(out, ref, rtol=1e-6, atol=2e-6)
         assert not out[R:2 * R].any()
 
     def test_vjp_matches_masked_einsum(self, pallas_interpret_unless_hw):
@@ -302,8 +305,11 @@ class TestGroupedGemm:
 
         g = jax.grad(f, (0, 1))(jnp.asarray(lhs), jnp.asarray(rhs))
         gr = jax.grad(fref, (0, 1))(jnp.asarray(lhs), jnp.asarray(rhs))
-        np.testing.assert_array_equal(np.asarray(g[0]), np.asarray(gr[0]))
-        np.testing.assert_array_equal(np.asarray(g[1]), np.asarray(gr[1]))
+        # f32, a few ulp: the kernel and the einsum accumulate in another
+        # order (observed under jaxlib 0.9: 1 ulp, max abs 1.9e-6)
+        for got, want in zip(g, gr):
+            np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+                                       rtol=1e-6, atol=2e-6)
 
     def test_autotune_consult_recorded(self, pallas_interpret_unless_hw):
         import jax.numpy as jnp
@@ -372,7 +378,7 @@ class TestFastPathParity:
         out_d, g_d, l_d = _moe_with_grads(gate_cfg, fast=False, x=x)
         out_f, g_f, l_f = _moe_with_grads(gate_cfg, fast=True, x=x)
         np.testing.assert_allclose(out_f, out_d, rtol=0, atol=self.ATOL)
-        assert l_f == l_d
+        np.testing.assert_allclose(l_f, l_d, rtol=1e-6, atol=self.ATOL)
         for k in g_d:
             np.testing.assert_allclose(g_f[k], g_d[k], rtol=0,
                                        atol=self.ATOL)
@@ -418,9 +424,9 @@ class TestFastPathParity:
     def test_kernel_path_parity(self, pallas_interpret_unless_hw):
         """One parity case with the Pallas grouped GEMM actually live
         (interpret mode) instead of the CPU einsum fallback."""
-        from paddle_tpu.ops.pallas.grouped_gemm import kernel_usable
+        from paddle_tpu.ops.pallas import kernels_available
 
-        assert kernel_usable()
+        assert kernels_available()
         x = np.random.RandomState(3).randn(24, 16).astype(np.float32)
         cfg = {"type": "gshard", "top_k": 2}
         out_d, g_d, _ = _moe_with_grads(cfg, fast=False, x=x)
@@ -518,10 +524,13 @@ class TestExpertParallelFast:
         for a, b in zip(dense, fast):
             np.testing.assert_allclose(a, b, rtol=0, atol=1e-6)
         assert "ep" in spec  # expert weights actually sharded on ep
-        # a2a accounting: counters + kind="a2a" intervals per executed step
+        # a2a accounting: exact counters per executed step; the [est]
+        # intervals are bytes over a chip's ICI bandwidth, and the CPU
+        # backend has none to estimate with
         assert delta.get("collective_bytes_total{op=all_to_all}", 0) > 0
         assert delta.get("collective_calls_total{op=all_to_all}", 0) >= 2
-        assert any(kind == "a2a" for _d, kind in seen)
+        assert any(kind == "a2a" for _d, kind in seen) == (
+            jax.default_backend() == "tpu")
 
     def test_emit_step_anchoring_follows_schedule(self):
         """Chunked records land behind now (covered by the open compute
@@ -540,7 +549,7 @@ class TestExpertParallelFast:
                 ({"desc": "a", "bytes": 10 ** 9, "calls": 2,
                   "overlapped": True},
                  {"desc": "b", "bytes": 10 ** 9, "calls": 2,
-                  "overlapped": False}), floor_ns=now)
+                  "overlapped": False}), floor_ns=now, chip="TPU v4")
         finally:
             comm_watchdog.remove_task_observer(obs)
         (da, a0, a1, ka), (db, b0, b1, kb) = seen

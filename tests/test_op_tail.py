@@ -489,14 +489,20 @@ class TestSoftmaxMaskFuse:
 
 class TestCOpsSurface:
     def test_audit_tool_passes(self):
+        import os
         import subprocess
         import sys
 
+        repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+        from tools import op_audit
+
+        if not os.path.exists(op_audit.OPS_YAML):
+            pytest.skip(f"reference tree not mounted ({op_audit.OPS_YAML})")
         r = subprocess.run(
             [sys.executable, "tools/op_audit.py"], capture_output=True,
-            text=True, cwd="/root/repo",
-            env={"PYTHONPATH": "/root/repo", "JAX_PLATFORMS": "cpu",
-                 "PATH": "/usr/bin:/bin:/opt/venv/bin"})
+            text=True, cwd=repo,
+            env={"PYTHONPATH": repo, "JAX_PLATFORMS": "cpu",
+                 "PATH": os.environ.get("PATH", "")})
         assert r.returncode == 0, r.stdout + r.stderr
         assert "resolution: 9" in r.stdout  # >= 90%
 

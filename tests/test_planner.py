@@ -39,8 +39,12 @@ def _cfg(dp=1, mp=1, pp=1, sh=1, mbs=1, stage=1, gbs=8, rc=False):
             "global_batch_size": gbs}
 
 
+# these tests plan on the CPU for a chip they name; nothing inherits a peak
+CHIP = "TPU v4"
+
+
 def _tcfg(**kw):
-    base = {"num_devices": 8, "global_batch_size": 8,
+    base = {"num_devices": 8, "global_batch_size": 8, "chip": CHIP,
             "model_cfg": dict(MODEL_CFG)}
     base.update(kw)
     return base
@@ -59,7 +63,7 @@ class TestCostModel:
         param-gradient volume shrinks faster than the activation volume
         grows; the launch-latency term still makes comm_s monotonic there,
         which is exactly the latency-bound-regime claim)."""
-        cm = CostModel()
+        cm = CostModel(chip=CHIP)
         big = _tcfg(global_batch_size=32,
                     model_cfg={"hidden_size": 2048, "num_layers": 24,
                                "num_heads": 16, "vocab_size": 50304,
@@ -78,7 +82,7 @@ class TestCostModel:
                 > cm.predict(tiny, _cfg(dp=2, mp=1))["comm_s"])
 
     def test_pp_bubble_shrinks_with_more_microbatches(self):
-        cm = CostModel()
+        cm = CostModel(chip=CHIP)
         t = _tcfg()
         few = cm.predict(t, _cfg(dp=2, pp=2, mbs=2))   # n_micro = 2
         many = cm.predict(t, _cfg(dp=2, pp=2, mbs=1))  # n_micro = 4
@@ -87,7 +91,7 @@ class TestCostModel:
         assert cm.predict(t, _cfg(dp=8))["bubble_s"] == 0.0
 
     def test_recompute_multiplier_and_memory(self):
-        cm = CostModel()
+        cm = CostModel(chip=CHIP)
         t = _tcfg()
         plain = cm.predict(t, _cfg(dp=8, rc=False))
         rc = cm.predict(t, _cfg(dp=8, rc=True))
@@ -102,7 +106,7 @@ class TestCostModel:
         """ISSUE-14: the MoE dispatch/combine a2a volume term. Dense models
         never see it; under ep it grows with (ep-1)/ep (byte volume) and
         with the chunk schedule (launch-latency alpha regime)."""
-        cm = CostModel()
+        cm = CostModel(chip=CHIP)
         moe_cfg = _tcfg(model_cfg=dict(MODEL_CFG, moe_num_experts=8,
                                        moe_top_k=2))
         dense = cm.predict(_tcfg(), _cfg(dp=8))
@@ -117,9 +121,9 @@ class TestCostModel:
             assert bd["comm_bytes_by_axis"]["ep_a2a"] > 0
             prev = cur
         # latency-bound regime: more chunks = more launches = more alpha
-        few = CostModel(a2a_chunks=1).predict(
+        few = CostModel(chip=CHIP, a2a_chunks=1).predict(
             moe_cfg, dict(_cfg(dp=2), ep_degree=4))
-        many = CostModel(a2a_chunks=4).predict(
+        many = CostModel(chip=CHIP, a2a_chunks=4).predict(
             moe_cfg, dict(_cfg(dp=2), ep_degree=4))
         assert many["comm_s_by_axis"]["ep_a2a"] > few["comm_s_by_axis"]["ep_a2a"]
         assert (many["comm_bytes_by_axis"]["ep_a2a"]
@@ -155,8 +159,8 @@ class TestCostModel:
         frac, src = measured_overlap_fraction(p)
         assert frac == 0.5 and "step_timeline" in src
         t = _tcfg()
-        cold = CostModel().predict(t, _cfg(dp=8))
-        warm = CostModel(overlap_paths=p).predict(t, _cfg(dp=8))
+        cold = CostModel(chip=CHIP).predict(t, _cfg(dp=8))
+        warm = CostModel(chip=CHIP, overlap_paths=p).predict(t, _cfg(dp=8))
         assert cold["overlap_fraction"] == 0.0
         assert warm["overlap_fraction"] == 0.5
         assert warm["exposed_comm_s"] == cold["exposed_comm_s"] * 0.5
@@ -306,7 +310,7 @@ class TestMeshPlan:
         assert sl.activations() == P(("dp", "sharding"), None, None)
         # stage-3 candidate round-trips its stage through the artifact
         plan = MeshPlan.from_candidate(
-            _cfg(dp=2, sh=4, stage=3), CostModel().predict(
+            _cfg(dp=2, sh=4, stage=3), CostModel(chip=CHIP).predict(
                 _tcfg(), _cfg(dp=2, sh=4, stage=3)))
         assert plan.sharding_stage == 3
         assert plan.partition_specs()["column_parallel"] == P("sharding", "mp")
@@ -322,7 +326,7 @@ class TestMeshPlan:
         moe_cfg = _tcfg(model_cfg=dict(MODEL_CFG, moe_num_experts=8,
                                        moe_top_k=2))
         plan = MeshPlan.from_candidate(
-            cfg, CostModel().predict(moe_cfg, cfg),
+            cfg, CostModel(chip=CHIP).predict(moe_cfg, cfg),
             model_cfg=moe_cfg["model_cfg"])
         assert plan.mesh["ep"] == 4 and plan.num_devices == 8
         assert plan.partition_specs()["expert_stacked"] == P("ep", None)

@@ -4,8 +4,8 @@ engine (inference/serving.py) on gpt3-125M-shaped decode.
 
 Prints one JSON line per configuration: prefill + steady-state decode
 tokens/s at several batch sizes, with and without weight-only int8.
-Run on the real chip via tools/hw_session.sh step 7; CPU runs are smoke
-only."""
+Meant for the real chip (one command of a chip-tool call); CPU runs are
+smoke only."""
 
 import json
 import os

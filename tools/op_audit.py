@@ -6,10 +6,15 @@ namespaces plus the _C_ops kernel surface. Prints the resolution ratio and
 any unresolved names (expected: exactly the 11 recorded scope-outs).
 """
 
+import os
 import re
 import sys
 
-OPS_YAML = "/root/reference/paddle/phi/ops/yaml/ops.yaml"
+_REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+# the reference tree is mounted beside the checkout where it exists at all;
+# another location is passed as the first argument
+OPS_YAML = os.path.join(os.path.dirname(_REPO), "reference", "paddle", "phi",
+                        "ops", "yaml", "ops.yaml")
 
 SCOPE_OUTS = {
     "batch_fc", "cvm", "match_matrix_tensor", "pyramid_hash",
@@ -19,8 +24,9 @@ SCOPE_OUTS = {
 
 
 def main():
+    ops_yaml = sys.argv[1] if len(sys.argv) > 1 else OPS_YAML
     names = []
-    for line in open(OPS_YAML):
+    for line in open(ops_yaml):
         m = re.match(r"- op\s*:\s*(\w+)", line)
         if m:
             names.append(m.group(1))
