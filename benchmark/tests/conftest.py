@@ -1,0 +1,8 @@
+"""CPU only; the repo root on the path so `benchmark` and `paddle_tpu` import."""
+
+import os
+import sys
+
+os.environ.setdefault("JAX_PLATFORMS", "cpu")
+sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.dirname(
+    os.path.abspath(__file__)))))
