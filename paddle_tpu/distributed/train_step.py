@@ -28,6 +28,7 @@ from jax.sharding import NamedSharding, PartitionSpec as P
 
 from ..framework.core import Tensor
 from ..jit import TrainStep, _unwrap_pytree
+from ..observability import spans as _obs_spans
 from . import env as _env
 
 __all__ = ["DistributedTrainStep", "fsdp_spec", "shard_params_for_stage3",
@@ -327,7 +328,15 @@ class DistributedTrainStep(TrainStep):
                         sv, self._sharding(self._opt_state_spec(k, sk, sv),
                                            host=host))
 
+    # span paths: train_step > place_inputs, dispatch > compiled, offload
+    _compiled_span = "compiled"
+
     def __call__(self, inputs, labels):
+        # `step_num`, so that XProf groups the trace by training step
+        with _obs_spans.span("train_step", step_num=self._step + 1):
+            return self._call(inputs, labels)
+
+    def _call(self, inputs, labels):
         from . import comm_watchdog
 
         streaming = self._offload_streaming()
@@ -337,7 +346,8 @@ class DistributedTrainStep(TrainStep):
             # GroupSharded offload=True keeping the moments on CPU between
             # steps, group_sharded_stage3.py offload). With streaming the
             # compiled program carries these transfers itself.
-            with comm_watchdog.comm_task("offload/h2d", kind="comm"):
+            with _obs_spans.span("offload", to="device"), \
+                    comm_watchdog.comm_task("offload/h2d", kind="comm"):
                 self._move_opt_states(host=False)
         if not isinstance(inputs, (list, tuple)):
             inputs = [inputs]
@@ -356,13 +366,12 @@ class DistributedTrainStep(TrainStep):
         prev = getattr(self, "_inflight", None)
         pipelined = (self.comm_overlap and prev is not None
                      and hasattr(prev, "is_ready") and not prev.is_ready())
-        with comm_watchdog.comm_task("h2d/inputs", kind="comm"):
+        with _obs_spans.span("place_inputs"), \
+                comm_watchdog.comm_task("h2d/inputs", kind="comm"):
             t0 = time.perf_counter_ns() if pipelined else 0
             placed_in = [jax.device_put(a, self._sharding(s)) for a, s in zip(raw_in, in_specs)]
             placed_lb = [jax.device_put(a, self._sharding(s)) for a, s in zip(raw_lb, lb_specs)]
             if pipelined and not prev.is_ready():
-                from ..observability import spans as _obs_spans
-
                 _obs_spans.record_span("train_step/prev_step_inflight",
                                        t0, time.perf_counter_ns(),
                                        kind="compute")
@@ -373,11 +382,13 @@ class DistributedTrainStep(TrainStep):
 
         self._moe_pre = _moe_comm.trace_marker()
         self._moe_t0 = time.perf_counter_ns()
-        loss = super().__call__([Tensor(a) for a in placed_in], [Tensor(a) for a in placed_lb])
+        with _obs_spans.span("dispatch"):
+            loss = super().__call__([Tensor(a) for a in placed_in], [Tensor(a) for a in placed_lb])
         self._inflight = loss._value
         if self.offload and not streaming:
             # comm_overlap off: the d2h restream runs as an exposed
             # post-step barrier (streaming carries it inside the program)
-            with comm_watchdog.comm_task("offload/d2h", kind="comm"):
+            with _obs_spans.span("offload", to="host"), \
+                    comm_watchdog.comm_task("offload/d2h", kind="comm"):
                 self._move_opt_states(host=True)
         return loss

@@ -114,6 +114,7 @@ class BlockPool:
         self._prefix: dict[bytes, int] = {}   # key -> page
         self._page_key: dict[int, bytes] = {}  # page -> key (registered only)
         self.allocs_total = 0  # lifetime allocations (tests/introspection)
+        self.cow_copies_total = 0
 
     # -- accounting ------------------------------------------------------ #
 
@@ -257,6 +258,7 @@ class BlockPool:
                 sk, sv = self.scales[li]
                 self.scales[li] = (sk.at[dst].set(sk[src]),
                                    sv.at[dst].set(sv[src]))
+        self.cow_copies_total += 1
         serving_metrics()["cow_copies"].inc()
 
     def read_pages(self, pages) -> list[tuple]:

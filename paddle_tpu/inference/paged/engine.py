@@ -33,7 +33,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from ..serving import GenerationRequest, _ServingEngineBase
+from ...observability.spans import span
+from ..serving import GenerationRequest, _bucket, _ServingEngineBase
 from ..slo import serving_metrics
 from .block_pool import BlockPool, prefix_page_key
 from .scheduler import TwoQueueScheduler, _pages_for_prompt
@@ -186,10 +187,12 @@ class PagedServingEngine(_ServingEngineBase):
     def _spill_row(self, row):
         req = self.active[row]
         pages = [int(p) for p in self.tables[row] if p >= 0]
-        kv_host = self.pool.read_pages(pages)
-        keys = [self.pool.page_key(p) for p in pages]
-        for p in pages:
-            self.pool.release(p)
+        with span("spill", rid=req.req_id, pages=len(pages)):
+            kv_host = self.pool.read_pages(pages)
+            keys = [self.pool.page_key(p) for p in pages]
+            for p in pages:
+                self.pool.release(p)
+        req.preemptions += 1
         self.sched.enqueue_resume(SpilledRequest(
             req, self.lengths[row], self.last_tok[row], kv_host, keys))
         self.tables[row, :] = -1
@@ -209,10 +212,11 @@ class PagedServingEngine(_ServingEngineBase):
 
     # -- admission ------------------------------------------------------- #
 
-    def _admit(self):
+    def _admit(self) -> int:
+        """Admit what the scheduler picks; returns how many."""
         free_rows = [i for i in range(self.B) if self.active[i] is None]
         if not free_rows:
-            return
+            return 0
         work = self.sched.pick(len(free_rows), self.pool.pages_free,
                                self.live_count)
         for item in work:
@@ -221,6 +225,7 @@ class PagedServingEngine(_ServingEngineBase):
                 self._resume_into(row, item)
             else:
                 self._prefill_into(row, item)
+        return len(work)
 
     def _stack_pages(self, arr, n, m):
         """[1, Sp, Hkv, D] prefill K/V -> [m, Hkv, ps, D] page-stacked."""
@@ -232,27 +237,37 @@ class PagedServingEngine(_ServingEngineBase):
             0, 2, 1, 3)
 
     def _prefill_into(self, row, req):
-        logits, new_c, n, _ = self._run_prefill(req)
+        rid, n = req.req_id, len(req.prompt)
+        req._t_admit = time.perf_counter()
+        bucket = _bucket(n)
+        with span("prefill", rid=rid, prompt_len=n, bucket=bucket,
+                  compiled=bucket not in self._prefill_cache):
+            logits, new_c, n, _ = self._run_prefill(req)
         m = _pages_for_prompt(n, self.ps)
         pages, write_mask = [], []
-        for j in range(m):
-            key = prefix_page_key(req.prompt, j, self.ps)
-            page = self.pool.lookup_prefix(key)
-            if page is not None:
+        with span("pages", rid=rid, pages=m) as sp:
+            for j in range(m):
+                key = prefix_page_key(req.prompt, j, self.ps)
+                page = self.pool.lookup_prefix(key)
+                if page is not None:
+                    pages.append(page)
+                    write_mask.append(False)
+                    continue
+                page = self._alloc_or_preempt()
+                self.pool.register_prefix(key, page)
                 pages.append(page)
-                write_mask.append(False)
-                continue
-            page = self._alloc_or_preempt()
-            self.pool.register_prefix(key, page)
-            pages.append(page)
-            write_mask.append(True)
+                write_mask.append(True)
+            sp.set(prefix_hits=m - sum(write_mask))
         if any(write_mask):
-            k_layers = [self._stack_pages(k_, n, m) for k_, _ in new_c]
-            v_layers = [self._stack_pages(v_, n, m) for _, v_ in new_c]
-            self.pool.write_prompt_pages(pages, write_mask,
-                                         k_layers, v_layers)
+            with span("write_pages", rid=rid,
+                      pages_written=sum(write_mask)):
+                k_layers = [self._stack_pages(k_, n, m) for k_, _ in new_c]
+                v_layers = [self._stack_pages(v_, n, m) for _, v_ in new_c]
+                self.pool.write_prompt_pages(pages, write_mask,
+                                             k_layers, v_layers)
         self.tables[row, :m] = pages
-        first = self._pick_token(logits[0, n - 1], req)
+        with span("first_token", rid=rid):  # the host waits for the prefill
+            first = self._pick_token(logits[0, n - 1], req)
         self.active[row] = req
         self.lengths[row] = n
         self.last_tok[row] = first
@@ -260,16 +275,18 @@ class PagedServingEngine(_ServingEngineBase):
 
     def _resume_into(self, row, sp: SpilledRequest):
         pages, restore_rows, restore_pages = [], [], []
-        for j, key in enumerate(sp.keys):
-            page = self.pool.lookup_prefix(key)
-            if page is None:
-                page = self._alloc_or_preempt()
-                if key is not None:
-                    self.pool.register_prefix(key, page)
-                restore_rows.append(j)
-                restore_pages.append(page)
-            pages.append(page)
-        self.pool.restore_pages(restore_pages, sp.kv_host, restore_rows)
+        with span("resume", rid=sp.req.req_id) as resume:
+            for j, key in enumerate(sp.keys):
+                page = self.pool.lookup_prefix(key)
+                if page is None:
+                    page = self._alloc_or_preempt()
+                    if key is not None:
+                        self.pool.register_prefix(key, page)
+                    restore_rows.append(j)
+                    restore_pages.append(page)
+                pages.append(page)
+            self.pool.restore_pages(restore_pages, sp.kv_host, restore_rows)
+            resume.set(pages_restored=len(restore_pages))
         self.tables[row, :len(pages)] = pages
         self.active[row] = sp.req
         self.lengths[row] = sp.length
@@ -308,23 +325,25 @@ class PagedServingEngine(_ServingEngineBase):
 
     # ------------------------------------------------------------------ #
 
-    def step(self):
-        """One scheduler tick: admit (resumes then prefills), ensure every
-        live row has a writable page, advance all live rows by one token
-        with the single compiled paged-decode program. Returns
-        {req_id: new_token} for the decode advance only — each request's
-        FIRST token is emitted at admission (onto req.generated and
-        serving_tokens_total), not in this dict."""
-        t_tick = time.perf_counter()
-        self._admit()
+    def _step(self, tick):
+        """Admit (resumes then prefills), ensure every live row has a
+        writable page, advance all live rows by one token with the single
+        compiled paged-decode program."""
+        with span("admit") as sp:
+            sp.set(picked=self._admit())
         live = [i for i in range(self.B) if self.active[i] is not None]
+        tick.set(live=len(live), waiting=self.sched.waiting_prefill)
         self.sched.update_gauges(self.engine_label, len(live))
         self.pool.update_gauges()
         if not live:
             return {}
-        for i in live:
-            if self.active[i] is not None:  # an earlier COW may have spilled i
-                self._ensure_write_target(i)
+        with span("write_targets") as sp:
+            allocs, cows = self.pool.allocs_total, self.pool.cow_copies_total
+            for i in live:
+                if self.active[i] is not None:  # an earlier COW may have spilled i
+                    self._ensure_write_target(i)
+            sp.set(pages_allocated=self.pool.allocs_total - allocs,
+                   cow_copies=self.pool.cow_copies_total - cows)
         live = [i for i in range(self.B) if self.active[i] is not None]
         if not live:
             return {}
@@ -341,34 +360,39 @@ class PagedServingEngine(_ServingEngineBase):
 
             self._decode_jit = jax.jit(decode, donate_argnums=(5,))
 
-        # quantized pool: each layer's cache rides as (k, v, k_scale,
-        # v_scale) so the int8 append + dequant-fused attention see payload
-        # and scales together inside the one compiled program
-        caches = ([kv + sc for kv, sc in zip(self.pool.kv, self.pool.scales)]
-                  if self.kv_quant else self.pool.kv)
-        greedy_tok, logits, new_kv = self._decode_jit(
-            self.params, self.buffers, jnp.asarray(self.last_tok),
-            jnp.asarray(self.lengths), jnp.asarray(self.tables), caches)
-        if self.kv_quant:
-            self.pool.kv = [tuple(c[:2]) for c in new_kv]
-            self.pool.scales = [tuple(c[2:]) for c in new_kv]
-        else:
-            self.pool.kv = [tuple(c) for c in new_kv]
-        self.last_logits = logits  # device array; tests probe divergence
-        greedy_np = np.asarray(greedy_tok)
-        out = {}
-        for i in live:
-            req = self.active[i]
-            if req.temperature == 0.0:
-                tok = int(greedy_np[i])
+        with span("decode_dispatch", rows=len(live)):
+            # quantized pool: each layer's cache rides as (k, v, k_scale,
+            # v_scale) so the int8 append + dequant-fused attention see
+            # payload and scales together inside the one compiled program
+            caches = ([kv + sc
+                       for kv, sc in zip(self.pool.kv, self.pool.scales)]
+                      if self.kv_quant else self.pool.kv)
+            greedy_tok, logits, new_kv = self._decode_jit(
+                self.params, self.buffers, jnp.asarray(self.last_tok),
+                jnp.asarray(self.lengths), jnp.asarray(self.tables), caches)
+            if self.kv_quant:
+                self.pool.kv = [tuple(c[:2]) for c in new_kv]
+                self.pool.scales = [tuple(c[2:]) for c in new_kv]
             else:
-                tok = self._pick_token(logits[i], req)
-            self.lengths[i] += 1
-            self.last_tok[i] = tok
-            out[req.req_id] = tok
-            self._emit(i, tok)
-        m = serving_metrics()
-        m["step_seconds"].observe(time.perf_counter() - t_tick,
-                                  engine=self.engine_label)
+                self.pool.kv = [tuple(c) for c in new_kv]
+            self.last_logits = logits  # device array; tests probe divergence
+        with span("host_read"):  # the host waits for the decode here
+            greedy_np = np.asarray(greedy_tok)
+        out = {}
+        with span("emit", rows=len(live)) as sp:
+            sampled = 0
+            for i in live:
+                req = self.active[i]
+                if req.temperature == 0.0:
+                    tok = int(greedy_np[i])
+                else:
+                    sampled += 1
+                    with span("sample", rid=req.req_id):
+                        tok = self._pick_token(logits[i], req)
+                self.lengths[i] += 1
+                self.last_tok[i] = tok
+                out[req.req_id] = tok
+                self._emit(i, tok)
+            sp.set(sampled_rows=sampled)
         self.pool.update_gauges()
         return out

@@ -34,6 +34,8 @@ import jax.numpy as jnp
 import numpy as np
 
 from ..framework.core import Tensor
+from ..observability import spans as _spans
+from ..observability.spans import span
 from .slo import BoundedCompileCache, serving_metrics
 
 __all__ = ["GenerationRequest", "ContinuousBatchingEngine"]
@@ -60,7 +62,9 @@ class GenerationRequest:
         # asked for (previously this truncation was silent)
         self.truncated = False
         self._t_arrival = time.perf_counter()
+        self._t_admit: float | None = None  # set by the admitting engine
         self._t_first: float | None = None
+        self.preemptions = 0  # times the paged engine spilled it to the host
         self._sample_key = None  # set by the admitting engine
 
     @property
@@ -129,6 +133,7 @@ class _ServingEngineBase:
         self._prefill_cache = BoundedCompileCache(max_prefill_buckets,
                                                   self.engine_label)
         self._decode_jit = None
+        self._tick = 0
         m = serving_metrics()
         for name in ("tokens", "requests", "truncations"):
             m[name].inc(0, engine=self.engine_label)  # series exists from t0
@@ -229,11 +234,21 @@ class _ServingEngineBase:
         if truncated:
             req.truncated = True
             m["truncations"].inc(engine=self.engine_label)
+        t_done = time.perf_counter()
         if req._t_first is not None and len(req.generated) > 1:
-            dt = time.perf_counter() - req._t_first
+            dt = t_done - req._t_first
             if dt > 0:
                 m["request_tps"].observe(len(req.generated) / dt,
                                          engine=self.engine_label)
+        if _spans.live():
+            # the request's life in one record (queue wait is
+            # t_admit - t_arrival); seconds on time.perf_counter
+            _spans.record_span(
+                "request", int(req._t_arrival * 1e9), int(t_done * 1e9),
+                rid=req.req_id, prompt_len=len(req.prompt),
+                generated=len(req.generated), t_arrival=req._t_arrival,
+                t_admit=req._t_admit, t_first=req._t_first, t_done=t_done,
+                preemptions=req.preemptions)
         self.finished.append(req)
 
     def run(self):
@@ -244,11 +259,25 @@ class _ServingEngineBase:
         done, self.finished = self.finished, []
         return done
 
+    def step(self) -> dict:
+        """One scheduler tick (the subclass's `_step`) under the
+        `engine.step` span, whose own two clock reads feed
+        `serving_step_seconds`. Returns {req_id: new_token} for the decode
+        advance only — each request's FIRST token is emitted at admission
+        (onto req.generated and serving_tokens_total), not in this dict."""
+        self._tick += 1
+        with span("engine.step", tick=self._tick) as tick:
+            out = self._step(tick)
+        if out:
+            serving_metrics()["step_seconds"].observe(
+                tick.seconds, engine=self.engine_label)
+        return out
+
     # subclass contract
     def has_work(self) -> bool:
         raise NotImplementedError
 
-    def step(self) -> dict:
+    def _step(self, tick) -> dict:
         raise NotImplementedError
 
 
@@ -293,22 +322,31 @@ class ContinuousBatchingEngine(_ServingEngineBase):
 
     def _admit(self):
         free = [i for i in range(self.B) if self.active[i] is None]
+        picked = 0
         while free and self.waiting:
             slot = free.pop(0)
             req = self.waiting.popleft()
-            logits, new_c, n, _ = self._run_prefill(req)
-            # scatter the prompt's kv into this slot's cache rows [0, n)
-            for li, (k_, v_) in enumerate(new_c):
-                bk, bv = self.caches[li]
-                bk = bk.at[slot, :n].set(k_[0, :n])
-                bv = bv.at[slot, :n].set(v_[0, :n])
-                self.caches[li] = (bk, bv)
+            picked += 1
+            req._t_admit = time.perf_counter()
+            bucket = _bucket(len(req.prompt))
+            with span("prefill", rid=req.req_id, prompt_len=len(req.prompt),
+                      bucket=bucket,
+                      compiled=bucket not in self._prefill_cache):
+                logits, new_c, n, _ = self._run_prefill(req)
+                # scatter the prompt's kv into this slot's cache rows [0, n)
+                for li, (k_, v_) in enumerate(new_c):
+                    bk, bv = self.caches[li]
+                    bk = bk.at[slot, :n].set(k_[0, :n])
+                    bv = bv.at[slot, :n].set(v_[0, :n])
+                    self.caches[li] = (bk, bv)
             # device row gather: only [vocab] of THIS row ever materializes
-            first = self._pick_token(logits[0, n - 1], req)
+            with span("first_token", rid=req.req_id):
+                first = self._pick_token(logits[0, n - 1], req)
             self.active[slot] = req
             self.lengths[slot] = n
             self.last_tok[slot] = first
             self._emit(slot, first)
+        return picked
 
     def _emit(self, slot, tok):
         req = self.active[slot]
@@ -322,15 +360,14 @@ class ContinuousBatchingEngine(_ServingEngineBase):
 
     # ------------------------------------------------------------------ #
 
-    def step(self):
-        """One scheduler tick: admit then decode-advance all live slots.
-        Returns {req_id: new_token} for the decode advance only — each
-        request's FIRST token is emitted at admission (onto req.generated
-        and serving_tokens_total), not in this dict."""
-        t_tick = time.perf_counter()
-        self._admit()
+    def _step(self, tick):
+        """Admit, then decode-advance all live slots; the paged engine's
+        span paths, where the dense engine has the phase."""
+        with span("admit") as sp:
+            sp.set(picked=self._admit())
         m = serving_metrics()
         live = [i for i in range(self.B) if self.active[i] is not None]
+        tick.set(live=len(live), waiting=len(self.waiting))
         m["queue_depth"].set(len(self.waiting),
                              engine=self.engine_label, queue="prefill")
         m["queue_depth"].set(len(live),
@@ -351,25 +388,30 @@ class ContinuousBatchingEngine(_ServingEngineBase):
 
             self._decode_jit = jax.jit(decode, donate_argnums=(4,))
 
-        offs = jnp.asarray(self.lengths)  # per-slot write offset
-        greedy_tok, logits, self.caches = self._decode_jit(
-            self.params, self.buffers, jnp.asarray(self.last_tok), offs,
-            self.caches)
-        self.last_logits = logits  # device array; tests probe divergence
-        greedy_np = np.asarray(greedy_tok)
+        with span("decode_dispatch", rows=len(live)):
+            offs = jnp.asarray(self.lengths)  # per-slot write offset
+            greedy_tok, logits, self.caches = self._decode_jit(
+                self.params, self.buffers, jnp.asarray(self.last_tok), offs,
+                self.caches)
+            self.last_logits = logits  # device array; tests probe divergence
+        with span("host_read"):
+            greedy_np = np.asarray(greedy_tok)
         out = {}
-        for i in live:
-            req = self.active[i]
-            if req.temperature == 0.0:
-                tok = int(greedy_np[i])
-            else:
-                # per-row device gather + on-device categorical: only the
-                # sampled token id is transferred, not [B, vocab]
-                tok = self._pick_token(logits[i], req)
-            self.lengths[i] += 1
-            self.last_tok[i] = tok
-            out[req.req_id] = tok
-            self._emit(i, tok)
-        m["step_seconds"].observe(time.perf_counter() - t_tick,
-                                  engine=self.engine_label)
+        with span("emit", rows=len(live)) as sp:
+            sampled = 0
+            for i in live:
+                req = self.active[i]
+                if req.temperature == 0.0:
+                    tok = int(greedy_np[i])
+                else:
+                    # per-row device gather + on-device categorical: only
+                    # the sampled token id is transferred, not [B, vocab]
+                    sampled += 1
+                    with span("sample", rid=req.req_id):
+                        tok = self._pick_token(logits[i], req)
+                self.lengths[i] += 1
+                self.last_tok[i] = tok
+                out[req.req_id] = tok
+                self._emit(i, tok)
+            sp.set(sampled_rows=sampled)
         return out

@@ -370,28 +370,30 @@ class TrainStep:
                     scale = clip_norm / jnp.maximum(gnorm, clip_norm)
                     grads = jax.tree.map(lambda g: (g.astype(jnp.float32) * scale).astype(g.dtype), grads)
             new_p, new_states = {}, {}
-            for k in p:
-                ctx = {"step": step_i,
-                       "weight_decay": 0.0 if k in reg_specs else wd}
-                st = opt_states[k]
-                master = st.get("master")
-                pv = master if master is not None else p[k]
-                # sharding-stage hooks (ZeRO-2/3): reduce-scatter the grad to
-                # its owner shard and compute the update sharded, then
-                # all-gather the fresh params (DistributedTrainStep overrides)
-                gv = self._shard_grad(k, grads[k].astype(pv.dtype))
-                pv = self._shard_param_for_update(k, pv)
-                rule_state = {kk: vv for kk, vv in st.items() if kk != "master"}
-                np_, ns_ = opt.update(pv, gv, rule_state, lr, ctx)
-                if master is not None:
-                    ns_ = dict(ns_)
-                    ns_["master"] = np_
-                    np_ = np_.astype(p[k].dtype)
-                new_p[k] = self._restore_param(k, np_)
-                # per-param d2h emission point: under offload streaming the
-                # fresh states head back to host memory HERE, pipelined
-                # against the remaining params' updates
-                new_states[k] = self._emit_opt_state(k, ns_)
+            # the optimizer's name ("adamw") on its operations in a trace
+            with jax.named_scope(type(opt).__name__.lower()):
+                for k in p:
+                    ctx = {"step": step_i,
+                           "weight_decay": 0.0 if k in reg_specs else wd}
+                    st = opt_states[k]
+                    master = st.get("master")
+                    pv = master if master is not None else p[k]
+                    # sharding-stage hooks (ZeRO-2/3): reduce-scatter the grad to
+                    # its owner shard and compute the update sharded, then
+                    # all-gather the fresh params (DistributedTrainStep overrides)
+                    gv = self._shard_grad(k, grads[k].astype(pv.dtype))
+                    pv = self._shard_param_for_update(k, pv)
+                    rule_state = {kk: vv for kk, vv in st.items() if kk != "master"}
+                    np_, ns_ = opt.update(pv, gv, rule_state, lr, ctx)
+                    if master is not None:
+                        ns_ = dict(ns_)
+                        ns_["master"] = np_
+                        np_ = np_.astype(p[k].dtype)
+                    new_p[k] = self._restore_param(k, np_)
+                    # per-param d2h emission point: under offload streaming the
+                    # fresh states head back to host memory HERE, pipelined
+                    # against the remaining params' updates
+                    new_states[k] = self._emit_opt_state(k, ns_)
             return loss, new_p, new_states, new_b
 
         donate = (0, 1, 2) if self._donate else ()
@@ -404,6 +406,10 @@ class TrainStep:
             return loss
 
         self._compiled_eval = jax.jit(eval_step)
+
+    # the span around the compiled call; DistributedTrainStep, whose
+    # `train_step` span is this one's parent, shortens it to "compiled"
+    _compiled_span = "train_step/compiled"
 
     # sharding-stage hooks; identity here, overridden by DistributedTrainStep
     def _shard_grad(self, name, g):
@@ -460,7 +466,7 @@ class TrainStep:
         # accounting (overlap_stats). The span covers the async dispatch and
         # _post_dispatch — transfers issued there run while the device is
         # still executing this step's program.
-        with _obs_spans.span("train_step/compiled", kind="compute"):
+        with _obs_spans.span(self._compiled_span, kind="compute"):
             loss, self.params, self.opt_states, self.buffers = self._compiled(
                 self.params, self.opt_states, self.buffers, rnd.next_key(), step_i, lr, batch
             )

@@ -376,19 +376,28 @@ class GPTDecoderLayer(nn.Layer):
 
     def forward(self, x, position_ids=None, cache=None, cache_offset=None,
                 startend_row_indices=None, block_tables=None):
+        # the scopes name the model's parts in a profiler trace (metadata
+        # only; docs/OBSERVABILITY.md lists them)
         residual = x
-        h = self.input_layernorm(x)
-        if cache is not None:
-            h, new_cache = self.self_attn(h, position_ids, cache, cache_offset,
-                                          block_tables=block_tables)
-        else:
-            h = self.self_attn(
-                h, position_ids, startend_row_indices=startend_row_indices)
-            new_cache = None
-        x = residual + self.dropout(h)
+        with jax.named_scope("ln"):
+            h = self.input_layernorm(x)
+        with jax.named_scope("attn"):
+            if cache is not None:
+                h, new_cache = self.self_attn(
+                    h, position_ids, cache, cache_offset,
+                    block_tables=block_tables)
+            else:
+                h = self.self_attn(
+                    h, position_ids,
+                    startend_row_indices=startend_row_indices)
+                new_cache = None
+            x = residual + self.dropout(h)
         residual = x
-        h = self.mlp(self.post_attention_layernorm(x))
-        x = residual + self.dropout(h)
+        with jax.named_scope("ln"):
+            h = self.post_attention_layernorm(x)
+        with jax.named_scope("mlp"):
+            h = self.mlp(h)
+            x = residual + self.dropout(h)
         if self.config.sequence_parallel:
             x = mark_as_sequence_parallel(x)
         if cache is not None:
@@ -434,10 +443,11 @@ class GPTModel(nn.Layer):
                 position_ids = Tensor(
                     jnp.broadcast_to(jnp.arange(S)[None, :], (B, S))
                 )
-        h = self.embed_tokens(input_ids)
-        if not self.config.use_rope:
-            h = h + self.embed_positions(position_ids)
-        h = self.embed_dropout(h)
+        with jax.named_scope("embed"):
+            h = self.embed_tokens(input_ids)
+            if not self.config.use_rope:
+                h = h + self.embed_positions(position_ids)
+            h = self.embed_dropout(h)
         if self.config.sequence_parallel:
             h = mark_as_sequence_parallel(h)
         new_caches = [] if caches is not None else None
@@ -469,7 +479,8 @@ class GPTModel(nn.Layer):
                     new_caches.append(nc)
                 else:
                     h = out
-        h = self.final_norm(h)
+        with jax.named_scope("ln"):
+            h = self.final_norm(h)
         if caches is not None:
             return h, new_caches
         return h
@@ -501,13 +512,15 @@ class GPTForCausalLM(nn.Layer):
             h, new_caches = out
         else:
             h = out
-        if self.config.tie_word_embeddings:
-            w = self.gpt.embed_tokens.weight
-            logits = run_op("lm_head_tied", lambda a, ww: jnp.matmul(a, ww.T), [h, w])
-            logits = _constrain(
-                logits, P(P.UNCONSTRAINED, P.UNCONSTRAINED, "mp"))
-        else:
-            logits = self.lm_head(h)
+        with jax.named_scope("lm_head"):
+            if self.config.tie_word_embeddings:
+                w = self.gpt.embed_tokens.weight
+                logits = run_op("lm_head_tied",
+                                lambda a, ww: jnp.matmul(a, ww.T), [h, w])
+                logits = _constrain(
+                    logits, P(P.UNCONSTRAINED, P.UNCONSTRAINED, "mp"))
+            else:
+                logits = self.lm_head(h)
         if caches is not None:
             return logits, new_caches
         return logits
@@ -530,11 +543,13 @@ class GPTPretrainingCriterion(nn.Layer):
         self.ce = ParallelCrossEntropy()
 
     def forward(self, logits, labels, loss_mask=None):
-        losses = self.ce(logits, labels)  # [B, S]
-        if loss_mask is not None:
-            m = loss_mask.reshape(losses.shape).astype("float32")
-            return (losses.astype("float32") * m).sum() / m.sum().clip(min=1.0)
-        return losses.mean()
+        with jax.named_scope("loss"):
+            losses = self.ce(logits, labels)  # [B, S]
+            if loss_mask is not None:
+                m = loss_mask.reshape(losses.shape).astype("float32")
+                return ((losses.astype("float32") * m).sum()
+                        / m.sum().clip(min=1.0))
+            return losses.mean()
 
 
 # ----------------------------------------------------------------------- #

@@ -1,33 +1,44 @@
-"""Nested host spans and the per-step StepTimeline.
+"""Nested host spans: one primitive, three listeners, free when nobody listens.
 
-`span("fwd")` is both a context manager and a decorator. Every span is
-reported to two sinks:
+`span("fwd")` is both a context manager and a decorator. Nesting is tracked
+per thread and the reported name is the slash-joined path
+("engine.step/admit/prefill"). A span is LIVE when somebody is listening:
 
-- the active `profiler.Profiler` record window (cat ``observability``), so
-  spans land on the same chrome-trace timeline as op dispatch events and
-  `RecordEvent` annotations;
-- the installed `StepTimeline` (if any), which stitches spans together with
-  the other per-step signals the framework already produces but previously
-  scattered across four log formats: observed host syncs
-  (`framework.core` sync-observer chain), `comm_watchdog.comm_task`
-  intervals, and eager dispatch-cache hit/miss/bypass deltas.
+- `jax.profiler`'s trace is being taken: the span enters a
+  `jax.profiler.TraceAnnotation(<path>, **attrs)`, so it lies on `/host:CPU`
+  of the same `.xplane.pb` as the device's `XLA Ops`, on one clock;
+- a `profiler.Profiler` record window is open: the span lands in its
+  chrome-trace export beside op dispatch events (cat ``observability``;
+  `RecordEvent` is the same primitive under cat ``user_defined``);
+- a `StepTimeline` is installed: the span joins the per-step record together
+  with observed host syncs, `comm_task` intervals and dispatch-cache deltas.
 
-One `StepTimeline` record per training step is the unit the flight recorder
-buffers and the JSONL exporter appends — see docs/OBSERVABILITY.md.
+Every live span is also kept in a bounded in-memory ring (`recorded()`,
+`clear_recorded()`): `{id, parent, path, t0_ns, t1_ns, attrs}` on
+`time.perf_counter_ns`. With no listener `__enter__`/`__exit__` read the
+clock, make the check and return: no stack push, no path join, no ring
+write. See docs/OBSERVABILITY.md for the span catalog.
 """
 
 from __future__ import annotations
 
 import functools
+import itertools
 import json
-import os
 import sys
 import threading
 import time
 from collections import deque
 
+import jax
+
+from .metrics import HandleCache
+
 __all__ = [
     "span",
+    "live",
+    "recorded",
+    "clear_recorded",
     "StepTimeline",
     "active_timeline",
     "enable_step_timeline",
@@ -38,7 +49,32 @@ __all__ = [
     "record_span",
 ]
 
+RING_KEEP = 65536  # records; a traced serving tick writes a few dozen
+
 _tls = threading.local()
+_ring: deque = deque(maxlen=RING_KEEP)
+_ids = itertools.count(1)
+_trace_enabled = jax.profiler.TraceAnnotation.is_enabled
+# the open `Profiler` record window's `_add_event`, set and cleared by the
+# profiler itself (it imports this module, not the other way round)
+_profiler_window = None
+
+
+def live() -> bool:
+    """True while somebody listens: the profiler's trace, a `Profiler`
+    record window, or an installed `StepTimeline`."""
+    return (_active_timeline is not None or _profiler_window is not None
+            or _trace_enabled())
+
+
+def recorded() -> list:
+    """The ring's records, oldest first: every span that ended while live,
+    `request` and `compile` records among them."""
+    return list(_ring)
+
+
+def clear_recorded():
+    _ring.clear()
 
 
 def _span_stack() -> list:
@@ -49,60 +85,129 @@ def _span_stack() -> list:
 
 
 class span:
-    """`with span("fwd"): ...` or `@span("fwd")`. Nesting is tracked per
-    thread; the reported name is the slash-joined path ("step/fwd/attn")."""
+    """`with span("fwd", rid=3) as sp: ...` or `@span("fwd")`.
+
+    `sp.set(pages=4)` adds attributes known only inside the span; they reach
+    the ring and the timeline, not the profiler's annotation, which is
+    written on entry. `sp.seconds` is the span's length on its own two clock
+    reads, taken whether or not anyone listens."""
+
+    __slots__ = ("name", "attrs", "t0_ns", "t1_ns", "_path", "_id", "_parent",
+                 "_annotation")
+    cat = "observability"
 
     def __init__(self, name: str, **attrs):
         self.name = name
         self.attrs = attrs
-        self._t0 = None
+        self.t0_ns = self.t1_ns = None
         self._path = None
+        self._annotation = None
 
     def __enter__(self):
-        stack = _span_stack()
-        self._path = "/".join([s._path for s in stack[-1:]] + [self.name]) \
-            if stack else self.name
-        stack.append(self)
-        self._t0 = time.perf_counter_ns()
+        if live():  # with the two clock reads, a span's whole cost when off
+            stack = _span_stack()
+            parent = stack[-1] if stack else None
+            self._path = (f"{parent._path}/{self.name}" if parent
+                          else self.name)
+            self._parent = parent._id if parent else None
+            self._id = next(_ids)
+            stack.append(self)
+            if _trace_enabled():
+                self._annotation = jax.profiler.TraceAnnotation(
+                    self._path, **self.attrs)
+                self._annotation.__enter__()
+        self.t0_ns = time.perf_counter_ns()
         return self
 
     def __exit__(self, *exc):
-        t1 = time.perf_counter_ns()
+        self.t1_ns = time.perf_counter_ns()
+        if self._path is None:
+            return False
+        if self._annotation is not None:
+            self._annotation.__exit__(None, None, None)
+            self._annotation = None
         stack = _span_stack()
         depth = len(stack) - 1
         if stack and stack[-1] is self:
             stack.pop()
-        _emit_span(self._path or self.name, self._t0, t1, depth, self.attrs)
+        _emit(self._path, self.t0_ns, self.t1_ns, depth, self.attrs,
+              self.cat, self._id, self._parent)
+        self._path = None
         return False
+
+    def set(self, **attrs):
+        if self._path is not None:
+            self.attrs.update(attrs)
+
+    @property
+    def seconds(self) -> float:
+        return (self.t1_ns - self.t0_ns) / 1e9
 
     def __call__(self, fn):
         @functools.wraps(fn)
         def wrapped(*args, **kwargs):
-            with span(self.name, **self.attrs):
+            with type(self)(self.name, **self.attrs):
                 return fn(*args, **kwargs)
 
         return wrapped
 
 
 def record_span(name, t0_ns, t1_ns, **attrs):
-    """Report an externally measured interval to the span sinks (profiler +
-    StepTimeline) after the fact — for windows whose qualification is only
-    known at their END (e.g. the input-h2d-behind-inflight-step compute
-    credit, which must verify the device was STILL busy when the window
-    closed before claiming overlap)."""
-    _emit_span(name, t0_ns, t1_ns, len(_span_stack()), attrs)
+    """Report an interval measured elsewhere to the span sinks after the
+    fact (ring, `Profiler` window, StepTimeline; the profiler's trace cannot
+    be written backwards) — for windows whose qualification is only known at
+    their END (the input-h2d-behind-inflight-step compute credit), and for
+    records that are not a `with` block: a retired `request`, a `compile`."""
+    if live():
+        stack = _span_stack()
+        _emit(name, t0_ns, t1_ns, len(stack), attrs, span.cat, next(_ids),
+              stack[-1]._id if stack else None)
 
 
-def _emit_span(path, t0_ns, t1_ns, depth, attrs):
-    # profiler sink: only while a record window is open
-    from ..profiler import profiler as _prof_mod
-
-    prof = _prof_mod._active_profiler
-    if prof is not None and prof._recording:
-        prof._add_event(path, t0_ns, t1_ns, cat="observability")
+def _emit(path, t0_ns, t1_ns, depth, attrs, cat, span_id, parent_id):
+    _ring.append({"id": span_id, "parent": parent_id, "path": path,
+                  "t0_ns": t0_ns, "t1_ns": t1_ns, "attrs": attrs})
+    window = _profiler_window
+    if window is not None:
+        window(path, t0_ns, t1_ns, cat=cat)
     tl = _active_timeline
     if tl is not None:
         tl._on_span(path, t0_ns, t1_ns, depth, attrs)
+
+
+# --------------------------------------------------------------------------- #
+# compile listener: runs only when JAX compiles, so it is always on
+# --------------------------------------------------------------------------- #
+
+_COMPILE_STAGES = {
+    "/jax/core/compile/jaxpr_to_mlir_module_duration": "lower",
+    "/jax/core/compile/backend_compile_duration": "backend_compile",
+}
+_CACHE_HIT_EVENT = "/jax/compilation_cache/cache_hits"
+_compile_counter = HandleCache(lambda reg: reg.counter(
+    "compiles_total",
+    "Programs JAX lowered (stage=lower), compiled (backend_compile) or "
+    "loaded from the persistent cache (cache_hit)", ("stage",)))
+
+
+def _on_compile_duration(event, duration_secs, fun_name=None, **_):
+    stage = _COMPILE_STAGES.get(event)
+    if stage is None:
+        return
+    _compile_counter.get().inc(stage=stage)
+    if live():
+        t1 = time.perf_counter_ns()
+        record_span("compile", t1 - int(duration_secs * 1e9), t1,
+                    stage=stage, fun=fun_name)
+
+
+def _on_compile_event(event, **_):
+    if event == _CACHE_HIT_EVENT:
+        _compile_counter.get().inc(stage="cache_hit")
+
+
+jax.monitoring.register_event_duration_secs_listener(_on_compile_duration)
+jax.monitoring.register_event_listener(_on_compile_event)
 
 
 # --------------------------------------------------------------------------- #

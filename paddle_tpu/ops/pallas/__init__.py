@@ -13,6 +13,9 @@ import os
 
 import jax
 import jax.numpy as jnp
+from jax.experimental import pallas as pl
+
+from .autotune import KERNEL_NAMES
 
 
 def interpret_mode() -> bool:
@@ -44,3 +47,20 @@ def mxu_dot(a, b, dimension_numbers, **kw):
     if a.dtype != jnp.float32:
         kw.setdefault("precision", jax.lax.Precision.DEFAULT)
     return jax.lax.dot_general(a, b, dimension_numbers, **kw)
+
+
+def named_pallas_call(name, kernel, **kw):
+    """`pl.pallas_call(kernel, **kw)` under `name`, which must be in
+    `autotune.KERNEL_NAMES`. The name is the kernel's `name=` (the compiled
+    custom call is the HLO instruction `%<name>.N`, which is what a profiler
+    trace shows) and the `jax.named_scope` the call runs under. Metadata
+    only: the compiled program's operations do not change."""
+    if name not in KERNEL_NAMES:
+        raise ValueError(f"{name!r} is not in autotune.KERNEL_NAMES")
+    call = pl.pallas_call(kernel, name=name, **kw)
+
+    def run(*args):
+        with jax.named_scope(name):
+            return call(*args)
+
+    return run

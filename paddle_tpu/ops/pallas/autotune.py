@@ -36,6 +36,22 @@ import warnings
 __all__ = ["autotune_enabled", "pick_block_sizes", "cache_path",
            "clear_cache", "chosen_tiles"]
 
+# Every Pallas kernel's name, one per `pallas_call` site: its `name=`, its
+# `jax.named_scope`, and so the HLO instruction a profiler trace shows
+# (`%flash_fwd.3`). A forward kernel's name is also its word in this
+# registry (`pick_block_sizes(kernel_name=...)`; the fused norms tune as
+# "fused_layer_norm"/"fused_rms_norm" and run as `_fwd`/`_bwd`). Readers of
+# traces hold on to these: renaming one silences a metric.
+KERNEL_NAMES = (
+    "flash_fwd", "flash_bwd_dq", "flash_bwd_dkv",
+    "flashmask_fwd", "flashmask_bwd_dq", "flashmask_bwd_dkv",
+    "varlen_fwd", "varlen_bwd_dq", "varlen_bwd_dkv",
+    "fused_layer_norm_fwd", "fused_layer_norm_bwd",
+    "fused_rms_norm_fwd", "fused_rms_norm_bwd",
+    "fused_rope", "grouped_gemm",
+    "decode_paged", "decode_paged_q8", "decode_dense",
+)
+
 _lock = threading.Lock()
 _memory: dict = {}
 _disk_loaded = [False]

@@ -39,7 +39,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from . import interpret_mode
+from . import interpret_mode, named_pallas_call
 from .flash_attention import NEG_INF
 
 __all__ = ["paged_decode_attention", "dense_decode_attention",
@@ -198,8 +198,10 @@ def _run_decode(q, kc, vc, tables, lengths, scale, paged, ps=None,
     )
     # paged: cache already [n_pages, Hkv, ps, D]; dense: the index_map views
     # the [B, Hkv, S_max, D] cache as ps-sized blocks of the sequence axis
-    out = pl.pallas_call(
-        kernel,
+    name = ("decode_dense" if not paged
+            else "decode_paged_q8" if quantized else "decode_paged")
+    out = named_pallas_call(
+        name, kernel,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((B, Hkv, g, D), q.dtype),
         interpret=interpret_mode(),

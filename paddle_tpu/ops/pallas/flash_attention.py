@@ -49,7 +49,7 @@ from jax.experimental.pallas import tpu as pltpu
 
 from jax.sharding import PartitionSpec as P
 
-from . import interpret_mode, mxu_dot
+from . import interpret_mode, mxu_dot, named_pallas_call
 from .partition import shard_plan
 
 __all__ = ["flash_attention_fwd", "flash_attention"]
@@ -282,8 +282,8 @@ def _fwd(q, k, v, scale, causal, sq, skv, bq=None, bk=None, kbias=None,
                                         lambda b, h, i, j: (b, 0, j))
         in_specs.append(spec)
         args.append(arg)
-    out, lse = pl.pallas_call(
-        kernel,
+    out, lse = named_pallas_call(
+        "flash_fwd", kernel,
         grid=(B, H, nq, nk),
         in_specs=in_specs,
         out_specs=[
@@ -528,7 +528,8 @@ def _bwd(scale, causal, sq, skv, residuals, dout, bq, bk, safe,
         pl.BlockSpec((1, 1, bq, 1), lambda b, h, i, j: (b, h, i, 0)),
         pl.BlockSpec((1, 1, bq, 1), lambda b, h, i, j: (b, h, i, 0)),
     ]
-    dq = pl.pallas_call(
+    dq = named_pallas_call(
+        "flash_bwd_dq",
         functools.partial(_bwd_dq_kernel, scale=scale, causal=causal,
                           sq=sq, skv=skv, bq=bq, bk=bk, nk=nk, safe=safe,
                           has_kbias=kbias is not None),
@@ -557,7 +558,8 @@ def _bwd(scale, causal, sq, skv, residuals, dout, bq, bk, safe,
         pl.BlockSpec((1, 1, bq, 1), lambda b, h, j, i: (b, h, i, 0)),
         pl.BlockSpec((1, 1, bq, 1), lambda b, h, j, i: (b, h, i, 0)),
     ]
-    dk, dv = pl.pallas_call(
+    dk, dv = named_pallas_call(
+        "flash_bwd_dkv",
         functools.partial(_bwd_dkv_kernel, scale=scale, causal=causal,
                           sq=sq, skv=skv, bq=bq, bk=bk, nq=nq, safe=safe,
                           has_kbias=kbias is not None),

@@ -46,8 +46,11 @@ from jax.experimental.pallas import tpu as pltpu
 
 from jax.sharding import PartitionSpec as P
 
-from . import interpret_mode
+from . import interpret_mode, named_pallas_call
 from .partition import shard_plan
+
+# kind -> the kernel's word in the autotune registry and the trace
+_KERNEL_OF = {"rms": "fused_rms_norm", "ln": "fused_layer_norm"}
 
 __all__ = ["fused_norm_on", "rms_norm_fwd", "layer_norm_fwd"]
 
@@ -190,8 +193,8 @@ def _norm_fwd(x2, w, b, kind, eps, br):
     kernel = functools.partial(
         _fwd_kernel, kind=kind, eps=eps, n=n,
         has_w=w is not None, has_b=b is not None)
-    outs = pl.pallas_call(
-        kernel,
+    outs = named_pallas_call(
+        f"{_KERNEL_OF[kind]}_fwd", kernel,
         grid=grid,
         in_specs=in_specs,
         out_specs=out_specs,
@@ -253,8 +256,8 @@ def _norm_bwd_dx(xp, w, dyp, rstd, mean, kind, n, br):
         args.append(mean)
     kernel = functools.partial(_bwd_kernel, kind=kind, n=n,
                                has_w=w is not None)
-    return pl.pallas_call(
-        kernel,
+    return named_pallas_call(
+        f"{_KERNEL_OF[kind]}_bwd", kernel,
         grid=grid,
         in_specs=in_specs,
         out_specs=row,

@@ -51,7 +51,7 @@ from jax.experimental.pallas import tpu as pltpu
 
 from jax.sharding import PartitionSpec as P
 
-from . import interpret_mode
+from . import interpret_mode, named_pallas_call
 from .partition import shard_plan
 
 __all__ = ["fused_rope_on", "apply_fused_rope"]
@@ -139,8 +139,8 @@ def _rope_run(tensors, cf, sins, shifts, bs):
     ]
     out_shape = [jax.ShapeDtypeStruct(t.shape, t.dtype) for t in tp]
     kernel = functools.partial(_rope_kernel, nt=len(tp), shifts=shifts)
-    outs = pl.pallas_call(
-        kernel,
+    outs = named_pallas_call(
+        "fused_rope", kernel,
         grid=grid,
         in_specs=in_specs,
         out_specs=out_specs,

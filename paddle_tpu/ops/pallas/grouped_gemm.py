@@ -46,7 +46,7 @@ from jax.experimental.pallas import tpu as pltpu
 
 from jax.sharding import PartitionSpec as P
 
-from . import interpret_mode, mxu_dot
+from . import interpret_mode, mxu_dot, named_pallas_call
 from .partition import shard_plan
 
 __all__ = ["grouped_matmul", "default_tiles", "row_stride"]
@@ -137,8 +137,8 @@ def _gg_call(lhs, rhs, sizes, bm, bn):
     grid = (E * tiles_per_group, Np // bn)
     kernel = functools.partial(_gg_kernel, bm=bm,
                                tiles_per_group=tiles_per_group)
-    out = pl.pallas_call(
-        kernel,
+    out = named_pallas_call(
+        "grouped_gemm", kernel,
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=1,
             grid=grid,

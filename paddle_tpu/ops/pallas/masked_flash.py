@@ -34,7 +34,7 @@ from jax.experimental.pallas import tpu as pltpu
 
 from jax.sharding import PartitionSpec as P
 
-from . import interpret_mode, mxu_dot
+from . import interpret_mode, mxu_dot, named_pallas_call
 from .partition import shard_plan
 from .flash_attention import NEG_INF, _block_sizes, _pad_seq
 
@@ -253,8 +253,8 @@ def _fm_fwd(q, k, v, idx, scale, causal, sq, skv, bq, bk):
     kernel = functools.partial(
         _fm_fwd_kernel, scale=scale, causal=causal, n=n, sq=sq, skv=skv,
         bq=bq, bk=bk, nk=nk)
-    return pl.pallas_call(
-        kernel,
+    return named_pallas_call(
+        "flashmask_fwd", kernel,
         grid=(B, H, nq, nk),
         in_specs=_fm_specs(B, H, Hm, Hkv, n, bq, bk, D),
         out_specs=[
@@ -291,7 +291,8 @@ def _fm_bwd(scale, causal, sq, skv, residuals, dout, bq, bk):
     vt = jnp.swapaxes(v, 2, 3)
     gm = H // Hm
 
-    dq = pl.pallas_call(
+    dq = named_pallas_call(
+        "flashmask_bwd_dq",
         functools.partial(_fm_bwd_dq_kernel, scale=scale, causal=causal, n=n,
                           sq=sq, skv=skv, bq=bq, bk=bk, nk=nk),
         grid=(B, H, nq, nk),
@@ -311,7 +312,8 @@ def _fm_bwd(scale, causal, sq, skv, residuals, dout, bq, bk):
         interpret=interpret_mode(),
     )(q, kt, vt, k, idx, dout, lse, delta)
 
-    dk, dv = pl.pallas_call(
+    dk, dv = named_pallas_call(
+        "flashmask_bwd_dkv",
         functools.partial(_fm_bwd_dkv_kernel, scale=scale, causal=causal, n=n,
                           sq=sq, skv=skv, bq=bq, bk=bk, nq=nq),
         grid=(B, H, nk, nq),
@@ -636,7 +638,8 @@ def _vl_fwd(q, k, v, seg_q, seg_k, pos_q, pos_k, scale, causal, tq, tk,
     Hkv, Tkp, _ = k.shape
     nq, nk = Tqp // bq, Tkp // bk
     group = H // Hkv
-    return pl.pallas_call(
+    return named_pallas_call(
+        "varlen_fwd",
         functools.partial(_vl_fwd_kernel, scale=scale, causal=causal,
                           tq=tq, tk=tk, bq=bq, bk=bk, nk=nk),
         grid=(H, nq, nk),
@@ -700,7 +703,8 @@ def _varlen_vjp_bwd(causal, scale, bq, bk, saved, dout):
     delta = jnp.sum(dop.astype(jnp.float32) * outp.astype(jnp.float32),
                     axis=-1, keepdims=True)
 
-    dq = pl.pallas_call(
+    dq = named_pallas_call(
+        "varlen_bwd_dq",
         functools.partial(_vl_bwd_dq_kernel, scale=scale, causal=causal,
                           tq=tq, tk=tk, bq=bq, bk=bk, nk=nk),
         grid=(H, nq, nk),
@@ -715,7 +719,8 @@ def _varlen_vjp_bwd(causal, scale, bq, bk, saved, dout):
         interpret=interpret_mode(),
     )(qp, kp, vp, sqp, skp, pqp, pkp, dop, lse, delta)
 
-    dk, dv = pl.pallas_call(
+    dk, dv = named_pallas_call(
+        "varlen_bwd_dkv",
         functools.partial(_vl_bwd_dkv_kernel, scale=scale, causal=causal,
                           tq=tq, tk=tk, bq=bq, bk=bk, nq=nq),
         grid=(H, nk, nq),

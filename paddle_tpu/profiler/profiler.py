@@ -12,6 +12,8 @@ import time
 from enum import Enum
 from typing import Callable, Iterable, Optional
 
+from ..observability import spans as _spans
+
 __all__ = [
     "Profiler",
     "ProfilerState",
@@ -131,39 +133,29 @@ class _HostEvent:
         self.cat = cat
 
 
-_active_profiler: Optional["Profiler"] = None
+class _UserEvent(_spans.span):
+    cat = "user_defined"
 
 
 class RecordEvent:
     """Host annotation context manager (reference python/paddle/profiler/
-    utils.py RecordEvent). Recorded into the active Profiler's host stream
-    and, when a device trace is running, mirrored as a
-    jax.profiler.TraceAnnotation so it shows up on the XLA timeline."""
+    utils.py RecordEvent): the Paddle-shaped face of `observability.span`.
+    It goes to the same sinks the same way — the active Profiler's host
+    stream (cat ``user_defined``) and, while a device trace is running, the
+    profiler's own trace beside the XLA timeline."""
 
     def __init__(self, name: str, event_type=None):
         self.name = name
-        self._t0 = None
-        self._ann = None
+        self._span = None
 
     def begin(self):
-        self._t0 = time.perf_counter_ns()
-        try:
-            import jax
-
-            self._ann = jax.profiler.TraceAnnotation(self.name)
-            self._ann.__enter__()
-        except Exception:
-            self._ann = None
+        self._span = _UserEvent(self.name)
+        self._span.__enter__()
 
     def end(self):
-        if self._ann is not None:
-            self._ann.__exit__(None, None, None)
-            self._ann = None
-        prof = _active_profiler
-        if prof is not None and self._t0 is not None and prof._recording:
-            prof._add_event(self.name, self._t0, time.perf_counter_ns(),
-                            cat="user_defined")
-        self._t0 = None
+        if self._span is not None:
+            self._span.__exit__(None, None, None)
+            self._span = None
 
     def __enter__(self):
         self.begin()
@@ -230,6 +222,7 @@ class Profiler:
             return
         self._recording = True
         self._events = []  # each record window exports only its own events
+        _spans._profiler_window = self._add_event
         if self.timer_only:
             return
         if self.record_op_events:
@@ -250,6 +243,8 @@ class Profiler:
         if not self._recording:
             return
         self._recording = False
+        if _spans._profiler_window == self._add_event:
+            _spans._profiler_window = None
         if self.record_op_events and not self.timer_only:
             from ..framework.core import set_op_event_hook
 
@@ -284,8 +279,6 @@ class Profiler:
     # ------------------------------------------------------------------ #
 
     def start(self) -> None:
-        global _active_profiler
-        _active_profiler = self
         self.step_num = 0
         self._last_step_t = time.perf_counter()
         self._transition(self.scheduler(0))
@@ -293,7 +286,6 @@ class Profiler:
     def stop(self) -> None:
         """Flush any in-flight record window (the reference invokes the
         trace handler on stop whenever the profiler is recording)."""
-        global _active_profiler
         if self.current_state in (ProfilerState.RECORD,
                                   ProfilerState.RECORD_AND_RETURN):
             self._end_record()
@@ -302,8 +294,6 @@ class Profiler:
             self.current_state = ProfilerState.CLOSED
         else:
             self._end_record()
-        if _active_profiler is self:
-            _active_profiler = None
 
     def step(self, num_samples: Optional[int] = None) -> None:
         now = time.perf_counter()

@@ -1,0 +1,443 @@
+"""The one span system (paddle_tpu/observability/spans.py): live exactly when
+somebody listens, written into the profiler's own trace, with spans inside
+the serving tick and the train step, a compile listener, and a stable name
+on every Pallas kernel (docs/OBSERVABILITY.md has the catalog)."""
+
+import ast
+import glob
+import os
+import re
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+import paddle_tpu as paddle
+import paddle_tpu.distributed as dist
+import paddle_tpu.nn as nn
+import paddle_tpu.optimizer as opt
+from paddle_tpu.inference.paged import PagedServingEngine
+from paddle_tpu.inference.serving import ContinuousBatchingEngine
+from paddle_tpu.models import GPTForCausalLM, gpt3_tiny
+from paddle_tpu.observability import spans
+from paddle_tpu.observability.metrics import default_registry
+from paddle_tpu.observability.spans import span
+from paddle_tpu.ops.pallas.autotune import KERNEL_NAMES
+from paddle_tpu.profiler import Profiler, RecordEvent
+
+PKG = os.path.dirname(os.path.abspath(paddle.__file__))
+
+# every path of a tick (docs/OBSERVABILITY.md); a tick holds each of the
+# first six once, the others once per admitted, preempted or sampled request
+TICK_PHASES = ["engine.step/admit", "engine.step/write_targets",
+               "engine.step/decode_dispatch", "engine.step/host_read",
+               "engine.step/emit"]
+TICK_PATHS = ["engine.step"] + TICK_PHASES + [
+    "engine.step/admit/prefill", "engine.step/admit/pages",
+    "engine.step/admit/write_pages", "engine.step/admit/first_token",
+    "engine.step/admit/resume", "engine.step/write_targets/spill",
+    "engine.step/emit/sample"]
+PER_REQUEST = [p for p in TICK_PATHS if p.count("/") == 2]
+
+
+@pytest.fixture(autouse=True)
+def _clean(pallas_interpret_unless_hw):
+    spans.clear_recorded()
+    yield
+    spans.clear_recorded()
+    assert not spans._span_stack(), "a span was left open"
+
+
+@pytest.fixture(scope="module")
+def model():
+    paddle.seed(0)
+    return GPTForCausalLM(gpt3_tiny())
+
+
+@pytest.fixture(scope="module")
+def traced_ticks(model, tmp_path_factory):
+    """(ring records, {host span name: stats of its first event}) of an
+    undersized paged engine driven to the end under a REAL profiler trace:
+    four requests, every second one sampled, a pool that forces a spill and
+    a resume (the recipe of test_preemption_recovers_all_requests)."""
+    os.environ["PADDLE_TPU_PALLAS_INTERPRET"] = "1"
+    try:
+        rng = np.random.default_rng(7)
+        eng = PagedServingEngine(model, max_batch_size=4, max_seq_len=64,
+                                 page_size=16, seed=3, num_pages=6,
+                                 watermark_pages=0, prefix_sharing=False)
+        eng.add_request(rng.integers(1, 1000, 14).astype(np.int32),
+                        max_new_tokens=2)
+        eng.run()  # compiles; nobody listens yet
+        assert spans.recorded() == []
+        trace_dir = str(tmp_path_factory.mktemp("trace"))
+        jax.profiler.start_trace(trace_dir)
+        try:
+            for i in range(4):
+                eng.add_request(rng.integers(1, 1000, 14).astype(np.int32),
+                                max_new_tokens=6, priority=-i,
+                                temperature=0.7 if i % 2 else 0.0)
+            done = eng.run()
+        finally:
+            jax.profiler.stop_trace()
+    finally:
+        del os.environ["PADDLE_TPU_PALLAS_INTERPRET"]
+    assert len(done) == 4
+    from jax.profiler import ProfileData
+
+    (pb,) = glob.glob(os.path.join(trace_dir, "plugins", "profile", "*",
+                                   "*.xplane.pb"))
+    host = {}
+    for plane in ProfileData.from_file(pb).planes:
+        if plane.name == "/host:CPU":
+            for line in plane.lines:
+                for ev in line.events:
+                    if ev.name.startswith("engine.step"):
+                        host.setdefault(ev.name, dict(ev.stats))
+    ring = spans.recorded()
+    spans.clear_recorded()
+    return ring, host
+
+
+# -- the tick -------------------------------------------------------------- #
+
+@pytest.mark.parametrize("path", TICK_PATHS)
+def test_a_traced_tick_yields_the_path_in_ring_and_trace(traced_ticks, path):
+    ring, host = traced_ticks
+    assert any(r["path"] == path for r in ring), path
+    assert path in host, f"{path} is not on /host:CPU of the .xplane.pb"
+
+
+def test_children_nest_inside_the_tick_and_cover_it(traced_ticks):
+    ring, _ = traced_ticks
+    by_id = {r["id"]: r for r in ring}
+    ticks = [r for r in ring if r["path"] == "engine.step"]
+    assert [r["attrs"]["tick"] for r in ticks] == sorted(
+        r["attrs"]["tick"] for r in ticks)
+    for r in ring:
+        if r["parent"] in by_id and r["path"] not in ("request", "compile"):
+            p = by_id[r["parent"]]
+            assert r["path"].startswith(p["path"] + "/")
+            assert p["t0_ns"] <= r["t0_ns"] <= r["t1_ns"] <= p["t1_ns"]
+    whole = [t for t in ticks if "live" in t["attrs"] and t["attrs"]["live"]]
+    assert whole
+    for t in whole:
+        kids = sorted((r for r in ring if r["parent"] == t["id"]
+                       and r["path"] in TICK_PHASES),
+                      key=lambda r: r["t0_ns"])
+        # every phase of the tick is a span, in order, none overlapping
+        assert [k["path"] for k in kids] == TICK_PHASES
+        assert all(a["t1_ns"] <= b["t0_ns"] for a, b in zip(kids, kids[1:]))
+        assert {"tick", "live", "waiting"} <= set(t["attrs"])
+
+
+@pytest.mark.parametrize("path", PER_REQUEST)
+def test_spans_of_one_request_carry_its_rid(traced_ticks, path):
+    ring, host = traced_ticks
+    mine = [r for r in ring if r["path"] == path]
+    assert mine and all(isinstance(r["attrs"].get("rid"), int) for r in mine)
+    assert "rid" in host[path]  # the attribute reached the profiler's trace
+
+
+def test_a_request_record_appears_on_retirement(traced_ticks):
+    ring, _ = traced_ticks
+    reqs = [r for r in ring if r["path"] == "request"]
+    assert len(reqs) == 4
+    for r in reqs:
+        a = r["attrs"]
+        assert {"rid", "prompt_len", "generated", "t_arrival", "t_admit",
+                "t_first", "t_done", "preemptions"} <= set(a)
+        assert a["prompt_len"] == 14 and a["generated"] == 6
+        assert a["t_arrival"] <= a["t_admit"] <= a["t_first"] <= a["t_done"]
+    assert sum(r["attrs"]["preemptions"] for r in reqs) >= 1
+    spilled = [r for r in ring if r["path"].endswith("/spill")]
+    assert sum(r["attrs"]["pages"] for r in spilled) >= 1
+
+
+def test_the_dense_engine_spans_the_same_phases(model):
+    eng = ContinuousBatchingEngine(model, max_batch_size=2, max_seq_len=32)
+    eng.add_request(np.arange(1, 9, dtype=np.int32), max_new_tokens=3,
+                    temperature=0.5)
+    tl = spans.enable_step_timeline()
+    try:
+        eng.run()
+    finally:
+        tl.uninstall()
+    paths = {r["path"] for r in spans.recorded()}
+    assert {"engine.step", "engine.step/admit", "engine.step/admit/prefill",
+            "engine.step/admit/first_token", "engine.step/decode_dispatch",
+            "engine.step/host_read", "engine.step/emit",
+            "engine.step/emit/sample", "request"} <= paths
+
+
+def test_the_step_histogram_takes_the_spans_own_clock_reads(model):
+    eng = PagedServingEngine(model, max_batch_size=2, max_seq_len=32,
+                             page_size=8)
+    eng.add_request(np.arange(1, 9, dtype=np.int32), max_new_tokens=2)
+    h = default_registry().get("serving_step_seconds")
+    n0 = h.count(engine="paged") if h is not None else 0
+    eng.run()
+    assert default_registry().get("serving_step_seconds").count(
+        engine="paged") > n0
+    src = [open(os.path.join(PKG, "inference", *f)).read()
+           for f in (("serving.py",), ("paged", "engine.py"))]
+    assert "tick.seconds" in src[0] and "t_tick" not in "".join(src)
+
+
+# -- live exactly when somebody listens ------------------------------------ #
+
+def test_with_no_listener_a_span_pushes_and_writes_nothing():
+    assert not spans.live()
+    with span("outer", rid=1) as outer:
+        with span("inner") as inner:
+            inner.set(pages=3)
+            assert spans._span_stack() == []
+            assert outer._path is None and inner._path is None
+    assert spans.recorded() == []
+    assert inner.attrs == {}  # set() kept nothing
+    assert outer.seconds >= inner.seconds >= 0  # the clock is read anyway
+    spans.record_span("request", 0, 1, rid=1)
+    assert spans.recorded() == []
+
+
+def test_each_listener_makes_spans_live_and_the_ring_is_bounded(tmp_path):
+    tl = spans.enable_step_timeline()
+    try:
+        assert spans.live()
+        with span("a", k=1) as a:
+            with span("b"):
+                pass
+            a.set(late=2)
+    finally:
+        tl.uninstall()
+    assert not spans.live()
+    b, a = spans.recorded()
+    assert (a["path"], b["path"], b["parent"]) == ("a", "a/b", a["id"])
+    assert a["attrs"] == {"k": 1, "late": 2} and a["parent"] is None
+    with Profiler(timer_only=True) as prof:
+        assert spans.live()
+        with span("in_window"):
+            pass
+    assert not spans.live()
+    assert [e.name for e in prof.events()] == ["in_window"]
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        assert spans.live()
+    finally:
+        jax.profiler.stop_trace()
+    assert not spans.live()
+    assert spans._ring.maxlen == spans.RING_KEEP
+
+
+def test_record_event_and_span_emit_through_one_function(monkeypatch):
+    seen = []
+    real = spans._emit
+    monkeypatch.setattr(spans, "_emit", lambda *a: (seen.append(a[0]),
+                                                    real(*a))[1])
+
+    @span("decorated")
+    def work():
+        return 1
+
+    with Profiler(timer_only=True) as prof:
+        with RecordEvent("user"):
+            with span("inner"):
+                pass
+        assert work() == 1
+    assert seen == ["user/inner", "user", "decorated"]
+    cats = {e.name: e.cat for e in prof.events()}
+    assert cats == {"user/inner": "observability", "user": "user_defined",
+                    "decorated": "observability"}
+    # and exactly one place in the program annotates the profiler's trace
+    users = [p for p in glob.glob(os.path.join(PKG, "**", "*.py"),
+                                  recursive=True)
+             if re.search(r"TraceAnnotation\(", open(p).read())]
+    assert [os.path.relpath(p, PKG) for p in users] == [
+        os.path.join("observability", "spans.py")]
+
+
+# -- the train step --------------------------------------------------------- #
+
+class _MLP(nn.Layer):
+    def __init__(self):
+        super().__init__()
+        self.fc = nn.Linear(8, 8)
+
+    def forward(self, x):
+        return self.fc(x)
+
+
+@pytest.mark.parametrize("offload", [False, True])
+def test_the_train_step_yields_its_children_with_step_num(offload):
+    paddle.seed(0)
+    model, crit = _MLP(), nn.MSELoss()
+    step = dist.DistributedTrainStep(
+        model, lambda o, t: crit(o, t),
+        opt.AdamW(learning_rate=1e-3, parameters=model.parameters()),
+        mesh=dist.build_mesh(sharding=2), sharding_stage=2,
+        offload=offload, comm_overlap=not offload)
+    x = paddle.to_tensor(np.ones((4, 8), np.float32))
+    tl = spans.enable_step_timeline()
+    try:
+        step(x, x)
+        step(x, x)
+    finally:
+        tl.uninstall()
+        dist.env.set_global_mesh(None)
+    ring = spans.recorded()
+    roots = [r for r in ring if r["path"] == "train_step"]
+    assert [r["attrs"]["step_num"] for r in roots] == [1, 2]
+    second = [r["path"] for r in ring
+              if r["parent"] == roots[1]["id"]]
+    want = ["train_step/place_inputs", "train_step/dispatch"]
+    if offload:
+        want = ["train_step/offload"] + want + ["train_step/offload"]
+    assert second == want
+    assert any(r["path"] == "train_step/dispatch/compiled" for r in ring)
+
+
+# -- the compile listener --------------------------------------------------- #
+
+def test_the_compile_listener_counts_a_recompile_and_names_it():
+    def value(stage):
+        m = default_registry().get("compiles_total")
+        return m.value(stage=stage) if m is not None else 0
+
+    @jax.jit
+    def a_program_of_this_test(x):
+        return x * 2 + 1
+
+    lower0, backend0 = value("lower"), value("backend_compile")
+    a_program_of_this_test(jnp.ones(3))
+    assert value("lower") > lower0 and value("backend_compile") > backend0
+    assert spans.recorded() == []  # counted always, recorded only when live
+    lower1 = value("lower")
+    tl = spans.enable_step_timeline()
+    try:
+        a_program_of_this_test(jnp.ones(3))   # cached: nothing compiles
+        assert value("lower") == lower1
+        a_program_of_this_test(jnp.ones(5))   # a new shape: a recompile
+    finally:
+        tl.uninstall()
+    assert value("lower") > lower1
+    named = [r["attrs"] for r in spans.recorded() if r["path"] == "compile"
+             and "a_program_of_this_test" in str(r["attrs"].get("fun"))]
+    assert {a["stage"] for a in named} == {"lower", "backend_compile"}
+
+
+# -- names on the device side ---------------------------------------------- #
+
+def _kernel_jaxprs():
+    """{kernel name: a function whose jaxpr launches that kernel}."""
+    from paddle_tpu.ops.pallas import (decode_attention as da,
+                                       flash_attention as fa,
+                                       fused_norm as fn, fused_rope as fr,
+                                       grouped_gemm as gg, masked_flash as mf)
+
+    f32 = jnp.float32
+    q = jnp.ones((1, 128, 2, 64), f32)
+    idx = jnp.zeros((1, 1, 128, 1), jnp.int32)
+    qv = jnp.ones((128, 2, 64), f32)
+    cu = jnp.asarray([0, 64, 128], jnp.int32)
+    x, w = jnp.ones((16, 128), f32), jnp.ones((128,), f32)
+    cos = jnp.ones((1, 128, 32), f32)
+    lhs, rhs = jnp.ones((256, 128), f32), jnp.ones((2, 128, 128), f32)
+    sizes = jnp.asarray([128, 100], jnp.int32)
+    qd = jnp.ones((2, 2, 64), f32)
+    pool = jnp.ones((4, 2, 16, 64), f32)
+    tables = jnp.asarray([[0, 1], [2, -1]], jnp.int32)
+    lens = jnp.asarray([20, 5], jnp.int32)
+    scales = jnp.ones((8, 2), f32)
+    dense = jnp.ones((2, 2, 32, 64), f32)
+
+    def fwd(f, *a):
+        return lambda: jax.make_jaxpr(f)(*a)
+
+    def bwd(f, *a):
+        return lambda: jax.make_jaxpr(jax.grad(
+            lambda *a: jnp.sum(f(*a)[0] if isinstance(f(*a), tuple)
+                               else f(*a))))(*a)
+
+    flash = lambda q, k, v: fa.flash_attention_fwd(q, k, v, causal=True)
+    mask = lambda q, k, v: mf.flashmask_attention_fwd(q, k, v, idx)
+    varlen = lambda q, k, v: mf.varlen_flash_attention_fwd(
+        q, k, v, cu, cu, 0.125, causal=True)
+    ln = lambda x, w: fn.layer_norm_fwd(x, w, w)
+    rms = lambda x, w: fn.rms_norm_fwd(x, w)
+    rope = lambda t: fr.apply_fused_rope((t,), cos, cos)[0]
+    gmm = lambda l, r: gg.grouped_matmul(l, r, sizes)
+    return {
+        "flash_fwd": fwd(flash, q, q, q),
+        "flash_bwd_dq": bwd(flash, q, q, q),
+        "flash_bwd_dkv": bwd(flash, q, q, q),
+        "flashmask_fwd": fwd(mask, q, q, q),
+        "flashmask_bwd_dq": bwd(mask, q, q, q),
+        "flashmask_bwd_dkv": bwd(mask, q, q, q),
+        "varlen_fwd": fwd(varlen, qv, qv, qv),
+        "varlen_bwd_dq": bwd(varlen, qv, qv, qv),
+        "varlen_bwd_dkv": bwd(varlen, qv, qv, qv),
+        "fused_layer_norm_fwd": fwd(ln, x, w),
+        "fused_layer_norm_bwd": bwd(ln, x, w),
+        "fused_rms_norm_fwd": fwd(rms, x, w),
+        "fused_rms_norm_bwd": bwd(rms, x, w),
+        "fused_rope": fwd(rope, q),
+        "grouped_gemm": fwd(gmm, lhs, rhs),
+        "decode_paged": fwd(lambda q: da.paged_decode_attention(
+            q, pool, pool, tables, lens), qd),
+        "decode_paged_q8": fwd(lambda q: da.paged_decode_attention(
+            q, pool.astype(jnp.int8), pool.astype(jnp.int8), tables, lens,
+            kv_scales=(scales, scales)), qd),
+        "decode_dense": fwd(lambda q: da.dense_decode_attention(
+            q, dense, dense, lens), qd),
+    }
+
+
+def test_the_name_table_has_a_recipe_for_every_kernel():
+    assert set(_kernel_jaxprs()) == set(KERNEL_NAMES)
+    assert len(set(KERNEL_NAMES)) == len(KERNEL_NAMES)
+
+
+def _pallas_calls(jaxpr):
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "pallas_call":
+            yield eqn.params["name"]
+        for value in eqn.params.values():
+            for sub in value if isinstance(value, (list, tuple)) else [value]:
+                inner = getattr(sub, "jaxpr", sub)
+                if hasattr(inner, "eqns"):
+                    yield from _pallas_calls(inner)
+
+
+@pytest.mark.parametrize("name", KERNEL_NAMES)
+def test_every_kernel_carries_its_name_from_the_table(name):
+    named = set(_pallas_calls(_kernel_jaxprs()[name]().jaxpr))
+    assert name in named, f"no pallas_call named {name} in the jaxpr"
+    assert named <= set(KERNEL_NAMES), named - set(KERNEL_NAMES)
+
+
+def test_every_pallas_call_site_goes_through_the_named_call():
+    """No bare `pl.pallas_call` outside the helper, and every literal name
+    at a call site is in the table (18 names over 14 call sites)."""
+    sites, helper = [], os.path.join(PKG, "ops", "pallas", "__init__.py")
+    for path in glob.glob(os.path.join(PKG, "**", "*.py"), recursive=True):
+        tree = ast.parse(open(path).read())
+        for node in ast.walk(tree):
+            if not isinstance(node, ast.Call):
+                continue
+            f = node.func
+            if isinstance(f, ast.Attribute) and f.attr == "pallas_call":
+                assert path == helper, f"bare pallas_call in {path}"
+            if isinstance(f, ast.Name) and f.id == "named_pallas_call":
+                sites.append((path, node.args[0]))
+    assert len(sites) == 14
+    for path, arg in sites:
+        if isinstance(arg, ast.Constant):
+            assert arg.value in KERNEL_NAMES, (path, arg.value)
+
+
+def test_a_name_outside_the_table_is_refused():
+    from paddle_tpu.ops.pallas import named_pallas_call
+
+    with pytest.raises(ValueError, match="KERNEL_NAMES"):
+        named_pallas_call("my_kernel", lambda *refs: None, out_shape=None)

@@ -6,8 +6,8 @@ Compares, at the GPT-125M training shape (and optional others):
     TPU kernel) as the achievable-performance oracle
   - the XLA composite (_ref_attention)
 
-Timing: device-side lax.scan loops (see tools/perf_breakdown.py) so the
-per-dispatch overhead divides out.
+Timing: device-side lax.scan loops, so the per-dispatch overhead divides
+out.
 
 Usage: python tools/attn_ab.py [B] [S] [H] [D]
 """
