@@ -121,6 +121,13 @@ class PagedServingEngine(_ServingEngineBase):
         self.lengths = np.zeros(self.B, np.int32)
         self.active: list[GenerationRequest | None] = [None] * self.B
         self.last_tok = np.zeros(self.B, np.int32)
+        # the paged-decode kernel's grid, for the `decode_dispatch` span:
+        # static, so computed once
+        from ...ops.pallas.decode_attention import pages_per_step
+        n = pages_per_step(cfg.kv_heads, self.ps, cfg.head_dim, self.P,
+                           self.pool.kv[0][0].dtype.itemsize)
+        self._decode_grid = {"pages_per_step": n,
+                             "grid_steps": self.B * -(-self.P // n)}
         self.pool.update_gauges()
         # materialize the pool/preemption series at zero so --emit-metrics
         # JSONL carries them from the first tick, not only after the first
@@ -360,7 +367,7 @@ class PagedServingEngine(_ServingEngineBase):
 
             self._decode_jit = jax.jit(decode, donate_argnums=(5,))
 
-        with span("decode_dispatch", rows=len(live)):
+        with span("decode_dispatch", rows=len(live), **self._decode_grid):
             # quantized pool: each layer's cache rides as (k, v, k_scale,
             # v_scale) so the int8 append + dequant-fused attention see
             # payload and scales together inside the one compiled program

@@ -6,28 +6,53 @@ block_attn.h) and masked_multihead_attention
 (fusion/gpu/masked_multihead_attention_kernel.cu, mmha_util.cu.h).
 
 TPU-native design: decode is HBM-bound — the entire job is streaming the KV
-cache through VMEM exactly once per step. The paged variant prefetches the
-block table as a scalar operand (pltpu.PrefetchScalarGridSpec) so the
-per-page physical index is resolved in the BlockSpec index_map: the pipeline
-DMAs each logical page straight from its physical slot, no gathered copy of
-the cache is ever materialized (the jnp composite's `kc[tables]` gather is
-exactly what XLA does badly — SURVEY §7 hard parts). Pages past a row's
-length are skipped (no DMA cost model change, but no MXU/VPU work), and the
-final page is masked per-slot. GQA: grid is (batch, kv_head, page) and each
-step attends the head-group [g, D] block against one [page, D] page.
+cache through VMEM exactly once per step — so a grid step does a DMA's worth
+of work, not one head of one page.
+
+Paged (`decode_paged`, `decode_paged_q8`): the grid is (batch, ceil(P / N))
+and a step takes ALL KV heads of N pages of one row: the pool's layout is
+[n_pages, Hkv, page_size, D], so a page of all heads is one contiguous DMA.
+The pool goes into the `pallas_call` as it is, N times, one
+`BlockSpec((1, Hkv, page_size, D))` per page slot whose index_map reads the
+physical page out of a prefetched scalar table (PrefetchScalarGridSpec): no
+gathered copy of the cache is ever materialized (the jnp composite's
+`kc[tables]` gather is exactly what XLA does badly — SURVEY §7 hard parts).
+That table is not the block table itself but `_fetch_table`'s view of it: a
+slot that is dead (past the row's length, a -1 hole, a free row, the padding
+behind P) names the page the same slot held one step earlier, so its block
+index does not change, the pipeline issues no DMA for it, and a step whose
+slots are all dead runs no arithmetic either. N follows from the shapes
+(`pages_per_step`: the largest of 16..1 that fits the VMEM budget and the
+table) and is recorded through the tuner as the tile (N * page_size, D);
+nothing is swept, so a serving process pays no measurement at set-up. N
+need divide neither P nor a row's page count: the last step is masked. A
+step makes ONE online-softmax update over its N * page_size keys for all
+heads: `q.K` and `p.V` are dots batched over the KV heads ([Hkv, g, D] x
+[Hkv, N * ps, D]); running maximum, sum and accumulator are f32 VMEM
+scratch, and the output is normalised once at the row's last step. Operand
+types: with q and the pool both bf16 the dots take bf16 operands and
+accumulate in f32 — `q.K` is then exact, and p (f32) goes in as three bf16
+addends that carry its whole mantissa, so nothing is rounded that the
+f32-cast dots of the old kernel kept; any other mix (f32 pool, int8 pool,
+f32 q) casts both operands to f32 and follows the ambient matmul precision
+as before.
+
+Dense (`decode_dense`): the cache is contiguous, the grid stays (batch,
+kv_head, block) with one [block, D] tile of one head a step and the sequence
+tile autotuned. It shares the online-softmax update with the paged kernel
+and nothing else.
 
 Single-token decode (q = one step per row), inference only (no VJP).
 
-Quantized fast path: with `kv_scales`, the caches are int8 page payloads and
+Quantized pool: with `kv_scales`, the caches are int8 page payloads and
 `kv_scales` the per-(page, head) f32 dequant scales (`x ≈ q * scale`,
-`BlockPool(quantized=True)` layout). The same grid loads the int8 page into
-VMEM, dequantizes there (one scalar multiply per page), and accumulates in
-f32 exactly like the full-precision kernel — decode is HBM-bound, so
-halving/quartering the streamed bytes is the whole win and the dequant
-multiply rides the VPU for free. The scale rides beside its page as the
-(8, Hkv) tile of the [n_pages, Hkv] scale array that holds it — a (1, 1)
-block of a 2-D array is not a shape Mosaic tiles — and the kernel selects
-its (page % 8, head) entry with an iota mask.
+`BlockPool(quantized=True)` layout). It is the same kernel body on the same
+grid: a page is dequantized in VMEM after its load (its [Hkv] row of scales,
+one multiply per head) and everything after is the f32 path — decode is
+HBM-bound, so halving/quartering the streamed bytes is the whole win. The
+scales ride beside their page as the (8, Hkv) tile of the [n_pages, Hkv]
+scale array that holds the page's row — a (1, Hkv) block of a 2-D array is
+not a shape Mosaic tiles — and the kernel reads row `page % 8` of it.
 """
 
 from __future__ import annotations
@@ -39,11 +64,11 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from . import interpret_mode, named_pallas_call
+from . import interpret_mode, mxu_dot, named_pallas_call
 from .flash_attention import NEG_INF
 
 __all__ = ["paged_decode_attention", "dense_decode_attention",
-           "paged_kv_write", "paged_kv_write_q8", "KV_QMAX"]
+           "pages_per_step", "paged_kv_write", "paged_kv_write_q8", "KV_QMAX"]
 
 # symmetric int8 range for KV pages: ±127 (not -128) so the running-max
 # rescale in paged_kv_write_q8 can never overflow the negative extreme
@@ -53,15 +78,23 @@ KV_QMAX = 127.0
 _SCALE_ROWS = 8
 
 
-def _decode_kernel(tables_ref, lens_ref, q_ref, k_ref, v_ref, *rest,
-                   scale, ps, np_, g, paged, quantized):
-    if quantized:
-        ks_ref, vs_ref, o_ref, m_scr, l_scr, acc_scr = rest
-    else:
-        ks_ref = vs_ref = None
-        o_ref, m_scr, l_scr, acc_scr = rest
+def _softmax_update(s, live, m_prev, l_prev):
+    """One online-softmax step over the keys of `s` (last axis), `live`
+    marking the keys that exist. Returns (m_new, alpha, p, l_new): the new
+    running maximum, the factor that rescales what was accumulated under the
+    old one, the unnormalised probabilities (0 where not live) and the new
+    running sum. Every array is f32; m and l keep a last axis of 1."""
+    s = jnp.where(live, s, NEG_INF)
+    m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
+    alpha = jnp.exp(m_prev - m_new)
+    p = jnp.where(live, jnp.exp(s - m_new), 0.0)
+    l_new = alpha * l_prev + jnp.sum(p, axis=-1, keepdims=True)
+    return m_new, alpha, p, l_new
+
+
+def _dense_kernel(lens_ref, q_ref, k_ref, v_ref, o_ref, m_scr, l_scr,
+                  acc_scr, *, scale, ps, np_, g):
     b = pl.program_id(0)
-    h = pl.program_id(1)
     p = pl.program_id(2)
 
     @pl.when(p == 0)
@@ -72,51 +105,20 @@ def _decode_kernel(tables_ref, lens_ref, q_ref, k_ref, v_ref, *rest,
 
     length = lens_ref[b]
     base = p * ps
-    valid_page = base < length
-    if paged:
-        valid_page = valid_page & (tables_ref[b, p] >= 0)
-
-    def _page_scales():
-        """This (page, head)'s K and V scales as (1, 1) arrays, picked out
-        of their (8, Hkv) tiles with one mask (padding rows past n_pages
-        never match)."""
-        phys = jnp.maximum(tables_ref[b, p], 0)
-        row = jax.lax.broadcasted_iota(jnp.int32, ks_ref.shape, 0)
-        col = jax.lax.broadcasted_iota(jnp.int32, ks_ref.shape, 1)
-        pick = (row == phys % _SCALE_ROWS) & (col == h)
-
-        def one(sc_ref):
-            lane = jnp.sum(jnp.where(pick, sc_ref[...], 0.0), axis=1,
-                           keepdims=True)
-            return jnp.sum(lane, axis=0, keepdims=True)
-
-        return one(ks_ref), one(vs_ref)
 
     # scratch rows are padded to >=8 for TPU tiling; compute on the first g
-    @pl.when(valid_page)
+    @pl.when(base < length)
     def _compute():
         q = q_ref[0, 0].astype(jnp.float32)      # [g, D]
         k = k_ref[0, 0].astype(jnp.float32)      # [ps, D]
-        if quantized:
-            k_scale, v_scale = _page_scales()
-            k = k * k_scale                      # dequant in VMEM
         s = jax.lax.dot_general(
             q, k, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
         ) * scale                                # [g, ps]
         slot = base + jax.lax.broadcasted_iota(jnp.int32, (g, ps), 1)
-        s = jnp.where(slot < length, s, NEG_INF)
-
-        m_prev = m_scr[0:g, 0:1]
-        m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
-        alpha = jnp.exp(m_prev - m_new)
-        pr = jnp.exp(s - m_new)
-        pr = jnp.where(slot < length, pr, 0.0)
-        l_scr[0:g, :] = jnp.broadcast_to(
-            alpha * l_scr[0:g, 0:1] + jnp.sum(pr, axis=-1, keepdims=True),
-            (g, l_scr.shape[1]))
+        m_new, alpha, pr, l_new = _softmax_update(
+            s, slot < length, m_scr[0:g, 0:1], l_scr[0:g, 0:1])
+        l_scr[0:g, :] = jnp.broadcast_to(l_new, (g, l_scr.shape[1]))
         v = v_ref[0, 0].astype(jnp.float32)      # [ps, D]
-        if quantized:
-            v = v * v_scale
         pv = jax.lax.dot_general(
             pr, v, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32
         )
@@ -139,74 +141,228 @@ def _default_dense_ps(s_max):
     return ps
 
 
-def _run_decode(q, kc, vc, tables, lengths, scale, paged, ps=None,
-                kv_scales=None):
-    """q: [B, Hkv, g, D]; kc/vc paged [n_pages, Hkv, ps, D] or dense
-    [B, Hkv, S_max, D] (viewed as ps-sized pages). tables: [B, P] (paged) or
-    a dummy [B, 1] (dense). For the dense layout `ps` selects the sequence
-    tile (autotunable); paged `ps` IS the cache's physical page size.
-    kv_scales: (k_scale, v_scale) per-(page, head) f32 [n_pages, Hkv] for
-    int8 caches (paged only) — dequant is fused into the page load."""
+def _run_dense(q, kc, vc, lengths, scale, ps):
+    """q: [B, Hkv, g, D]; kc/vc [B, Hkv, S_max, D], which the index_map
+    views as `ps`-sized blocks of the sequence axis (`ps` divides S_max)."""
     B, Hkv, g, D = q.shape
-    quantized = kv_scales is not None
-    if paged:
-        _, _, ps, _ = kc.shape
-        P = tables.shape[1]
-
-        def kmap(b, h, p, tabs, lens):
-            t = tabs[b, p]
-            return (jnp.where(t < 0, 0, t), h, 0, 0)
-
-        def smap(b, h, p, tabs, lens):
-            return (jnp.maximum(tabs[b, p], 0) // _SCALE_ROWS, 0)
-    else:
-        assert not quantized, "quantized cache is paged-only"
-        S_max = kc.shape[2]
-        if ps is None:
-            ps = _default_dense_ps(S_max)
-        P = S_max // ps
-
-        def kmap(b, h, p, tabs, lens):
-            return (b, h, p, 0)
-
-    kernel = functools.partial(
-        _decode_kernel, scale=scale, ps=ps, np_=P, g=g, paged=paged,
-        quantized=quantized)
-    in_specs = [
-        pl.BlockSpec((1, 1, g, D), lambda b, h, p, tabs, lens: (b, h, 0, 0)),
-        pl.BlockSpec((1, 1, ps, D), kmap),
-        pl.BlockSpec((1, 1, ps, D), kmap),
-    ]
-    operands = [q, kc, vc]
-    if quantized:
-        # the (8, Hkv) scale tile holding this page's row, beside the page
-        sc_spec = pl.BlockSpec((_SCALE_ROWS, Hkv), smap)
-        in_specs += [sc_spec, sc_spec]
-        operands += [kv_scales[0].astype(jnp.float32),
-                     kv_scales[1].astype(jnp.float32)]
+    P = kc.shape[2] // ps
+    kernel = functools.partial(_dense_kernel, scale=scale, ps=ps, np_=P, g=g)
+    kv_spec = pl.BlockSpec((1, 1, ps, D), lambda b, h, p, lens: (b, h, p, 0))
+    q_spec = pl.BlockSpec((1, 1, g, D), lambda b, h, p, lens: (b, h, 0, 0))
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,
+        num_scalar_prefetch=1,
         grid=(B, Hkv, P),
-        in_specs=in_specs,
-        out_specs=pl.BlockSpec((1, 1, g, D),
-                               lambda b, h, p, tabs, lens: (b, h, 0, 0)),
+        in_specs=[q_spec, kv_spec, kv_spec],
+        out_specs=q_spec,
         scratch_shapes=[
             pltpu.VMEM((max(g, 8), 128), jnp.float32),
             pltpu.VMEM((max(g, 8), 128), jnp.float32),
             pltpu.VMEM((max(g, 8), D), jnp.float32),
         ],
     )
-    # paged: cache already [n_pages, Hkv, ps, D]; dense: the index_map views
-    # the [B, Hkv, S_max, D] cache as ps-sized blocks of the sequence axis
-    name = ("decode_dense" if not paged
-            else "decode_paged_q8" if quantized else "decode_paged")
-    out = named_pallas_call(
-        name, kernel,
+    return named_pallas_call(
+        "decode_dense", kernel,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((B, Hkv, g, D), q.dtype),
         interpret=interpret_mode(),
-    )(tables.astype(jnp.int32), lengths.astype(jnp.int32), *operands)
-    return out
+    )(lengths.astype(jnp.int32), q, kc, vc)
+
+
+# ---- paged ----------------------------------------------------------------
+
+# What a grid step of the paged kernel may hold in VMEM: the double-buffered
+# K and V blocks of its N pages plus two pages' worth of f32 temporaries per
+# page. Under the compiler's default scoped limit of 16 MiB, so no
+# `vmem_limit_bytes` is asked for.
+_VMEM_BUDGET = 12 * 2 ** 20
+_PAGES_PER_STEP = (16, 8, 4, 2, 1)
+
+
+def pages_per_step(Hkv, ps, D, P, itemsize):
+    """N, the pages of one row a grid step of the paged kernel takes: the
+    largest of 16, 8, 4, 2, 1 that is no larger than the table's width and
+    whose VMEM need fits `_VMEM_BUDGET`. From shapes alone: nothing is
+    measured, so nothing sweeps inside a serving process."""
+    page = Hkv * ps * D
+    for n in _PAGES_PER_STEP:
+        blocks = 2 * 2 * n * page * itemsize   # K and V, double-buffered
+        temporaries = 2 * n * page * 4
+        if n <= P and blocks + temporaries <= _VMEM_BUDGET:
+            return n
+    return 1
+
+
+def _fetch_table(tables, lengths, ps, n):
+    """[B, steps * n] int32, what slot j of step i of row b fetches and
+    whether it counts. An entry >= 0 is a live slot's physical page: a table
+    entry that is not -1 and starts before the row's length. An entry < 0
+    is a dead slot, and `~entry` is the page the same slot held at the step
+    before (steps counted through the rows, in the grid's order): the
+    pipeline sees an unchanged block index and fetches nothing."""
+    B, P = tables.shape
+    steps = -(-P // n)
+    t = jnp.pad(tables.astype(jnp.int32), ((0, 0), (0, steps * n - P)),
+                constant_values=-1)
+    first_tok = jnp.arange(steps * n, dtype=jnp.int32) * ps
+    live = (t >= 0) & (first_tok[None, :] < lengths[:, None])
+    t, live = t.reshape(B * steps, n), live.reshape(B * steps, n)
+    step = jnp.arange(B * steps, dtype=jnp.int32)[:, None]
+    last_live = jax.lax.cummax(jnp.where(live, step, -1), axis=0)
+    held = jnp.where(
+        last_live >= 0,
+        jnp.take_along_axis(t, jnp.maximum(last_live, 0), axis=0), 0)
+    return jnp.where(live, t, ~held).reshape(B, steps * n)
+
+
+def _page_of(entry):
+    """The physical page a `_fetch_table` entry names, live or dead."""
+    return jnp.where(entry < 0, ~entry, entry)
+
+
+def _split_bf16(x):
+    """f32 `x` as three bf16 addends, highest first: together they carry
+    all 24 bits of an f32 mantissa, so a bf16 MXU pass over each loses
+    nothing."""
+    parts = []
+    for _ in range(3):
+        hi = x.astype(jnp.bfloat16)
+        parts.append(hi)
+        x = x - hi.astype(jnp.float32)
+    return parts
+
+
+def _paged_kernel(fetch_ref, lens_ref, *refs, scale, ps, n, steps, g,
+                  quantized, dot_dtype):
+    """One grid step: all KV heads of `n` pages of row b. refs: n K blocks,
+    n V blocks, each [1, Hkv, ps, D]; q [1, Hkv, g, D]; quantized: n K-scale
+    and n V-scale tiles [8, Hkv]; the output [1, Hkv, g, D]; scratch m, l
+    [Hkv, g, 1] and acc [Hkv, g, D], f32."""
+    k_refs, v_refs, q_ref = refs[:n], refs[n:2 * n], refs[2 * n]
+    ks_refs, vs_refs = refs[2 * n + 1:3 * n + 1], refs[3 * n + 1:4 * n + 1]
+    o_ref, m_scr, l_scr, acc_scr = refs[-4:]
+    b = pl.program_id(0)
+    i = pl.program_id(1)
+    Hkv = q_ref.shape[1]
+
+    @pl.when(i == 0)
+    def _init():
+        m_scr[...] = jnp.full_like(m_scr, NEG_INF)
+        l_scr[...] = jnp.zeros_like(l_scr)
+        acc_scr[...] = jnp.zeros_like(acc_scr)
+
+    length = lens_ref[b]
+    slot_live = [fetch_ref[b, i * n + j] >= 0 for j in range(n)]
+
+    def pages(refs, scale_refs):
+        """The step's n pages as one [Hkv, n * ps, D] array of `dot_dtype`,
+        an int8 page times its [Hkv] row of scales on the way."""
+        out = []
+        for j, ref in enumerate(refs):
+            if not quantized:
+                out.append(ref[0].astype(dot_dtype))
+                continue
+            phys = _page_of(fetch_ref[b, i * n + j])
+            row = scale_refs[j][pl.ds(phys % _SCALE_ROWS, 1), :]   # [1, Hkv]
+            head = jax.lax.broadcasted_iota(jnp.int32, row.shape, 1)
+            # a head's scale as a (1, 1) array by way of a masked sum: the
+            # only (1, 1) that Mosaic broadcasts over a [ps, D] page
+            out.append(jnp.stack([
+                ref[0, h].astype(jnp.float32)
+                * jnp.sum(jnp.where(head == h, row, 0.0), axis=1,
+                          keepdims=True)
+                for h in range(Hkv)]))
+        return out[0] if n == 1 else jnp.concatenate(out, axis=1)
+
+    @pl.when(functools.reduce(jnp.logical_or, slot_live))
+    def _compute():
+        q = q_ref[0].astype(dot_dtype)                       # [Hkv, g, D]
+        s = mxu_dot(q, pages(k_refs, ks_refs),
+                    (((2,), (2,)), ((0,), (0,))),
+                    preferred_element_type=jnp.float32) * scale  # [Hkv, g, T]
+        # a key counts if it lies before the row's length, in a live slot
+        lane = jax.lax.broadcasted_iota(jnp.int32, (1, 1, n * ps), 2)
+        live = (i * (n * ps) + lane) < length
+        for j, alive in enumerate(slot_live):
+            in_slot = (lane >= j * ps) & (lane < (j + 1) * ps)
+            live = live & (alive | jnp.logical_not(in_slot))
+        m_new, alpha, p, l_new = _softmax_update(
+            s, live, m_scr[...], l_scr[...])
+        v = pages(v_refs, vs_refs)                           # [Hkv, T, D]
+        pv_dims = (((2,), (1,)), ((0,), (0,)))
+        if dot_dtype == jnp.bfloat16:
+            pv = sum(mxu_dot(part, v, pv_dims,
+                             preferred_element_type=jnp.float32)
+                     for part in _split_bf16(p))
+        else:
+            pv = mxu_dot(p, v, pv_dims, preferred_element_type=jnp.float32)
+        acc_scr[...] = acc_scr[...] * alpha + pv
+        m_scr[...] = m_new
+        l_scr[...] = l_new
+
+    @pl.when(i == steps - 1)
+    def _finish():
+        l = l_scr[...]
+        l_safe = jnp.where(l == 0.0, 1.0, l)
+        o_ref[0] = (acc_scr[...] / l_safe).astype(o_ref.dtype)
+
+
+def _run_paged(q, kc, vc, tables, lengths, scale, n, kv_scales=None):
+    """q: [B, Hkv, g, D]; kc/vc: the pool's [n_pages, Hkv, ps, D] arrays as
+    they are, each passed `n` times, once per page slot of a grid step;
+    tables: [B, P]; kv_scales: (k_scale, v_scale) f32 [n_pages, Hkv] for
+    int8 pools."""
+    B, Hkv, g, D = q.shape
+    ps = kc.shape[2]
+    quantized = kv_scales is not None
+    lengths = lengths.astype(jnp.int32)
+    fetch = _fetch_table(tables, lengths, ps, n)
+    steps = fetch.shape[1] // n
+
+    def page_spec(j):
+        return pl.BlockSpec(
+            (1, Hkv, ps, D),
+            lambda b, i, fetch, lens: (_page_of(fetch[b, i * n + j]), 0, 0, 0))
+
+    def scale_spec(j):
+        # the (8, Hkv) tile of the [n_pages, Hkv] scales that holds the
+        # page's row: a (1, Hkv) block is not a shape Mosaic tiles
+        return pl.BlockSpec(
+            (_SCALE_ROWS, Hkv),
+            lambda b, i, fetch, lens: (
+                _page_of(fetch[b, i * n + j]) // _SCALE_ROWS, 0))
+
+    row_spec = pl.BlockSpec((1, Hkv, g, D),
+                            lambda b, i, fetch, lens: (b, 0, 0, 0))
+    slots = [page_spec(j) for j in range(n)]
+    in_specs = slots + slots + [row_spec]
+    operands = [kc] * n + [vc] * n + [q]
+    if quantized:
+        scale_slots = [scale_spec(j) for j in range(n)]
+        in_specs += scale_slots + scale_slots
+        operands += ([kv_scales[0].astype(jnp.float32)] * n
+                     + [kv_scales[1].astype(jnp.float32)] * n)
+    dot_dtype = (jnp.bfloat16
+                 if q.dtype == kc.dtype == jnp.bfloat16 else jnp.float32)
+    kernel = functools.partial(
+        _paged_kernel, scale=scale, ps=ps, n=n, steps=steps, g=g,
+        quantized=quantized, dot_dtype=dot_dtype)
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=2,
+        grid=(B, steps),
+        in_specs=in_specs,
+        out_specs=row_spec,
+        scratch_shapes=[
+            pltpu.VMEM((Hkv, g, 1), jnp.float32),
+            pltpu.VMEM((Hkv, g, 1), jnp.float32),
+            pltpu.VMEM((Hkv, g, D), jnp.float32),
+        ],
+    )
+    return named_pallas_call(
+        "decode_paged_q8" if quantized else "decode_paged", kernel,
+        grid_spec=grid_spec,
+        out_shape=jax.ShapeDtypeStruct((B, Hkv, g, D), q.dtype),
+        interpret=interpret_mode(),
+    )(fetch, lengths, *operands)
 
 
 def _split_heads(q, Hkv):
@@ -228,32 +384,33 @@ def paged_decode_attention(q, key_cache, value_cache, block_tables, lengths,
     if scale is None:
         scale = D ** -0.5
     q4, g = _split_heads(q, Hkv)
-    _consult_tuner_paged(q4, key_cache, block_tables,
-                         quantized=kv_scales is not None)
-    out = _run_decode(q4, key_cache, value_cache, block_tables, lengths,
-                      scale, paged=True, kv_scales=kv_scales)
+    n = _consult_tuner_paged(q4, key_cache, block_tables,
+                             quantized=kv_scales is not None)
+    out = _run_paged(q4, key_cache, value_cache, block_tables, lengths,
+                     scale, n, kv_scales=kv_scales)
     return out.reshape(B, H, D)
 
 
 def _consult_tuner_paged(q4, kc, tables, quantized=False):
-    """The paged kernel's tile (page_size, D) is the cache POOL's physical
-    layout — tunable at pool construction, not per launch — so the only
-    candidate is the layout itself. Consulting the tuner anyway keeps all
-    the Pallas kernels uniform in telemetry: the tile lands in
-    chosen_tiles() / the step-timeline record as source "fixed" (the
-    single-candidate consult never sweeps and never counts a fallback).
-    The dequant-fused int8 variant records under its own tuner name so the
-    telemetry distinguishes which decode path actually ran."""
+    """N, the pages a grid step takes, by way of the tuner. N follows from
+    the shapes (`pages_per_step`) and the page size is the POOL's physical
+    layout, so the tile (N * page_size, D) is the tuner's only candidate:
+    it never sweeps (a serving process must not) and never counts a
+    fallback, and the tile lands in chosen_tiles() / the step-timeline
+    record with its `consults`. The int8 pool records under its own tuner
+    name, so the telemetry tells which decode path ran."""
     from .autotune import pick_block_sizes
 
     B, Hkv, g, D = q4.shape
-    ps = kc.shape[2]
-    pick_block_sizes(
+    ps, P = kc.shape[2], tables.shape[1]
+    tile = (pages_per_step(Hkv, ps, D, P, kc.dtype.itemsize) * ps, D)
+    tile = pick_block_sizes(
         "decode_paged_q8" if quantized else "decode_paged",
-        1, ps, (ps, D), lambda bq, bk: None,
+        1, P * ps, tile, lambda bq, bk: None,
         allow_measure=False,
-        signature=(B, Hkv, g, D, str(q4.dtype), tables.shape[1]),
-        candidates=[(ps, D)])
+        signature=(B, Hkv, g, D, str(q4.dtype), P),
+        candidates=[tile])
+    return tile[0] // ps
 
 
 def paged_kv_write(cache, new, block_tables, lengths):
@@ -339,12 +496,9 @@ def _tuned_dense_ps(q4, kc, vc, lengths, scale):
     default = (_default_dense_ps(S_max), D)
     cands = sorted({default} | {
         (p, D) for p in (8, 16, 32, 64, 128, 256, 512) if S_max % p == 0})
-    dummy = jnp.zeros((B, 1), jnp.int32)
 
     def run_with(ps, _d):
-        out = _run_decode(q4, kc, vc, dummy, lengths, scale, paged=False,
-                          ps=ps)
-        out.block_until_ready()
+        _run_dense(q4, kc, vc, lengths, scale, ps).block_until_ready()
 
     concrete = not any(isinstance(x, jax.core.Tracer)
                        for x in (q4, kc, lengths))
@@ -364,7 +518,5 @@ def dense_decode_attention(q, key_cache, value_cache, lengths, scale=None):
         scale = D ** -0.5
     q4, g = _split_heads(q, Hkv)
     ps = _tuned_dense_ps(q4, key_cache, value_cache, lengths, scale)
-    dummy_tables = jnp.zeros((B, 1), jnp.int32)
-    out = _run_decode(q4, key_cache, value_cache, dummy_tables, lengths,
-                      scale, paged=False, ps=ps)
+    out = _run_dense(q4, key_cache, value_cache, lengths, scale, ps)
     return out.reshape(B, H, D)

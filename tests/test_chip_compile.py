@@ -1,0 +1,81 @@
+"""The decode kernels compiled for the chip at the serving cell's widths,
+with no chip: the TPU's compiler is installed and compiles for a v5e that
+is described, not attached. It refuses what the interpreter lets through (a
+broadcast Mosaic does not implement, a block it cannot tile, a kernel over
+its VMEM limit), at about two seconds a kernel and no chip time. Nothing
+runs, so nothing here is a result or a time.
+
+The topology is described inside a fixture, never at import: one process at
+a time may load the TPU's library, and every xdist worker imports this file.
+Keep such tests in this one file."""
+
+import re
+
+import pytest
+
+import jax
+import jax.numpy as jnp
+from jax.sharding import SingleDeviceSharding
+
+from paddle_tpu.ops.pallas.decode_attention import (
+    dense_decode_attention,
+    paged_decode_attention,
+)
+
+# serve-xl-sat: 64 rows, 16 heads of 128, pages of 32, table 64 wide
+B, H, D, PS, P, N_PAGES = 64, 16, 128, 32, 64, 1746
+
+
+@pytest.fixture(scope="module")
+def one_chip():
+    from jax.experimental import topologies
+    try:
+        topo = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.fixture(autouse=True)
+def _compiled_not_interpreted(monkeypatch):
+    monkeypatch.delenv("PADDLE_TPU_PALLAS_INTERPRET", raising=False)
+
+
+def _compile(fn, one_chip, *shapes):
+    args = [jax.ShapeDtypeStruct(s, d, sharding=one_chip) for s, d in shapes]
+    return jax.jit(fn).lower(*args).compile().as_text()
+
+
+@pytest.mark.parametrize("q_dtype,pool_dtype,hkv", [
+    (jnp.bfloat16, jnp.bfloat16, 16),   # the cell: bf16 operands, g = 1
+    (jnp.float32, jnp.bfloat16, 4),     # f32 operands, GQA g = 4
+    (jnp.bfloat16, jnp.int8, 16),       # the int8 pool, same grid
+], ids=["bf16", "f32q-gqa4", "int8"])
+def test_paged_decode_compiles_at_the_cells_shapes(one_chip, q_dtype,
+                                                   pool_dtype, hkv):
+    pool = ((N_PAGES, hkv, PS, D), pool_dtype)
+    shapes = [((B, H, D), q_dtype), pool, pool, ((B, P), jnp.int32),
+              ((B,), jnp.int32)]
+    if pool_dtype == jnp.int8:
+        shapes += [((N_PAGES, hkv), jnp.float32)] * 2
+
+    def fn(q, kc, vc, tables, lengths, *scales):
+        return paged_decode_attention(q, kc, vc, tables, lengths,
+                                      kv_scales=scales or None)
+
+    hlo = _compile(fn, one_chip, *shapes)
+    # what benchmark/readers/decode_attn_roofline.py holds on to: a Mosaic
+    # call under the kernel's name that takes the pool as it is
+    name = "decode_paged_q8" if pool_dtype == jnp.int8 else "decode_paged"
+    (call,) = [line for line in hlo.splitlines()
+               if re.match(r"\s*%" + name + r"[.\w]* = ", line)]
+    assert 'custom_call_target="tpu_custom_call"' in call
+    assert "[" + ",".join(str(d) for d in pool[0]) + "]" in call
+
+
+def test_dense_decode_compiles(one_chip):
+    cache = ((8, 4, 2048, D), jnp.bfloat16)
+    hlo = _compile(dense_decode_attention, one_chip,
+                   ((8, H, D), jnp.bfloat16), cache, cache, ((8,), jnp.int32))
+    assert re.search(r"%decode_dense[.\w]* = .*tpu_custom_call", hlo)

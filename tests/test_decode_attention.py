@@ -7,8 +7,11 @@ block-table entries, and the `l == 0` zero-length-row guard in _finish)."""
 import numpy as np
 import pytest
 
+import jax
 import jax.numpy as jnp
 
+from paddle_tpu.ops.pallas import autotune
+from paddle_tpu.ops.pallas import decode_attention as da
 from paddle_tpu.ops.pallas.decode_attention import (
     dense_decode_attention,
     paged_decode_attention,
@@ -37,7 +40,7 @@ def _ref_paged(q, kc, vc, tables, lengths):
     P = tables.shape[1]
     S = P * ps
     g = H // Hkv
-    kc, vc = np.asarray(kc), np.asarray(vc)
+    kc, vc = np.asarray(kc, np.float32), np.asarray(vc, np.float32)
     out = np.zeros((B, H, D), np.float32)
     for b in range(B):
         keys = np.zeros((S, Hkv, D), np.float32)
@@ -54,45 +57,131 @@ def _ref_paged(q, kc, vc, tables, lengths):
     return out
 
 
-def _make_case(B, H, Hkv, D, ps, P, lengths, seed=0, n_pages=None):
+def _make_case(B, H, Hkv, D, ps, P, lengths, seed=0, n_pages=None,
+               dead=(), shared=0, cache_dtype=jnp.float32):
     """Random paged cache + per-row block tables covering `lengths` tokens;
-    entries past each row's last page are -1."""
+    entries past each row's last page are -1. Rows in `dead` keep their
+    length and get a table of -1 alone (what the engine sends for a free
+    row: length 0 + 1). With `shared`, every later row's first `shared`
+    table entries are row 0's (a prefix hit: two rows, one physical page)."""
     rng = np.random.default_rng(seed)
     need = [-(-L // ps) if L else 0 for L in lengths]
     if n_pages is None:
         n_pages = 1 + sum(need)  # page 0 = null
     q = jnp.asarray(rng.standard_normal((B, H, D)), jnp.float32)
-    kc = jnp.asarray(rng.standard_normal((n_pages, Hkv, ps, D)), jnp.float32)
-    vc = jnp.asarray(rng.standard_normal((n_pages, Hkv, ps, D)), jnp.float32)
+    kc = jnp.asarray(rng.standard_normal((n_pages, Hkv, ps, D)), cache_dtype)
+    vc = jnp.asarray(rng.standard_normal((n_pages, Hkv, ps, D)), cache_dtype)
     tables = np.full((B, P), -1, np.int32)
     nxt = 1
     for b, m in enumerate(need):
+        if b in dead:
+            continue
         for j in range(m):
             tables[b, j] = nxt
             nxt += 1
+        if b:
+            tables[b, :shared] = tables[0, :shared]
     return q, kc, vc, jnp.asarray(tables), jnp.asarray(
         np.asarray(lengths, np.int32))
 
 
+def _case(*shape, **extra):
+    B, H, Hkv, D, ps, P, lengths = shape
+    return pytest.param(*shape, extra, id="-".join(
+        [f"B{B}H{H}kv{Hkv}D{D}ps{ps}P{P}"] + sorted(extra)))
+
+
 CASES = [
     # B, H, Hkv, D, ps, P, lengths
-    (2, 4, 4, 32, 16, 4, [64, 32]),          # MHA, full pages
-    (2, 4, 2, 32, 16, 4, [48, 16]),          # GQA head groups
-    (3, 4, 1, 16, 8, 8, [13, 27, 5]),        # MQA, partial final pages
-    (2, 2, 2, 16, 16, 2, [17, 31]),          # partial fill + -1 tail entries
+    _case(2, 4, 4, 32, 16, 4, [64, 32]),          # MHA, full pages
+    _case(2, 4, 2, 32, 16, 4, [48, 16]),          # GQA head groups
+    _case(3, 4, 1, 16, 8, 8, [13, 27, 5]),        # MQA, partial final pages
+    _case(2, 2, 2, 16, 16, 2, [17, 31]),          # partial fill + -1 tail entries
+    # the serving cell's head shape, fewer rows: one page exactly, one token,
+    # a ragged middle
+    _case(3, 16, 16, 128, 32, 8, [32, 1, 150]),
+    _case(2, 4, 2, 32, 8, 6, [40, 17]),           # P no multiple of N (4)
+    # N = 16 of P = 20: 18 and 6 pages, neither a multiple of N
+    _case(2, 2, 2, 16, 8, 20, [141, 41]),
+    # a free row as the engine sends it: length 0 + 1, table -1
+    _case(3, 4, 2, 32, 8, 4, [30, 1, 9], dead=(1,)),
+    # a prefix hit: rows 1 and 2 read row 0's first two physical pages
+    _case(3, 4, 2, 32, 8, 8, [37, 24, 50], shared=2),
+    _case(2, 4, 2, 32, 16, 4, [48, 19], cache_dtype=jnp.bfloat16),
+    _case(2, 8, 2, 32, 8, 8, [50, 23]),           # GQA g = 4, N = 8
 ]
 
 
-@pytest.mark.parametrize("B,H,Hkv,D,ps,P,lengths", CASES)
-def test_paged_decode_matches_reference(B, H, Hkv, D, ps, P, lengths):
-    q, kc, vc, tables, lens = _make_case(B, H, Hkv, D, ps, P, lengths)
+@pytest.mark.parametrize("B,H,Hkv,D,ps,P,lengths,extra", CASES)
+def test_paged_decode_matches_reference(B, H, Hkv, D, ps, P, lengths, extra):
+    q, kc, vc, tables, lens = _make_case(B, H, Hkv, D, ps, P, lengths,
+                                         **extra)
     out = paged_decode_attention(q, kc, vc, tables, lens)
     ref = _ref_paged(q, kc, vc, np.asarray(tables), np.asarray(lens))
     np.testing.assert_allclose(np.asarray(out), ref, rtol=1e-4, atol=2e-5)
 
 
+def test_bf16_operands_lose_nothing_to_the_f32_path():
+    """q and the pool both bf16 (the serving cell): the dots take bf16
+    operands, q.K exactly and p.V with p split into three bf16 terms, so
+    the result is the f32-operand path's, rounded once to bf16."""
+    q, kc, vc, tables, lens = _make_case(
+        2, 8, 2, 32, 8, 8, [50, 23], cache_dtype=jnp.bfloat16)
+    q = q.astype(jnp.bfloat16)
+    out = paged_decode_attention(q, kc, vc, tables, lens)
+    assert out.dtype == jnp.bfloat16
+    f32_path = paged_decode_attention(q.astype(jnp.float32), kc, vc, tables,
+                                      lens)
+    np.testing.assert_allclose(np.asarray(out, np.float32),
+                               np.asarray(f32_path), rtol=2 ** -8, atol=1e-6)
+
+
+def test_cell_shapes_take_several_pages_a_step():
+    """The tile of the serving cell's shapes (B 64, 16 heads of 128, pages
+    of 32, a table 64 wide, bf16 pool) is N > 1 pages, and chosen_tiles()
+    records it with its consults."""
+    q4 = jax.ShapeDtypeStruct((64, 16, 1, 128), jnp.bfloat16)
+    kc = jax.ShapeDtypeStruct((1746, 16, 32, 128), jnp.bfloat16)
+    tables = jax.ShapeDtypeStruct((64, 64), jnp.int32)
+    before = autotune.chosen_tiles().get("decode_paged", {}).get("consults", 0)
+    n = da._consult_tuner_paged(q4, kc, tables)
+    assert n > 1 and n == da.pages_per_step(16, 32, 128, 64, 2)
+    rec = autotune.chosen_tiles()["decode_paged"]
+    assert (rec["bq"], rec["bk"]) == (n * 32, 128)
+    assert rec["consults"] == before + 1
+    # never wider than the table, and a page too large for the budget goes alone
+    assert da.pages_per_step(16, 32, 128, 3, 2) == 2
+    assert da.pages_per_step(64, 256, 256, 64, 4) == 1
+
+
+def test_dead_slots_refetch_nothing():
+    """_fetch_table: a live slot carries its physical page; a dead one (past
+    the length, a -1 hole, a free row, the padding behind P) carries ~(the
+    page the same slot held one grid step earlier), so its block index does
+    not change and the pipeline issues no DMA for it."""
+    tables = np.array([[3, 4, 5, -1, -1, -1],
+                       [-1, -1, -1, -1, -1, -1],     # a free row
+                       [6, -1, 7, -1, -1, -1]], np.int32)   # a hole
+    lengths = np.array([20, 1, 24], np.int32)
+    n, ps = 4, 8
+    fetch = np.asarray(da._fetch_table(jnp.asarray(tables),
+                                       jnp.asarray(lengths), ps, n))
+    assert fetch.shape == (3, 8)
+    live = fetch >= 0
+    want_live = np.zeros((3, 8), bool)
+    want_live[0, :3] = True
+    want_live[2, [0, 2]] = True
+    np.testing.assert_array_equal(live, want_live)
+    np.testing.assert_array_equal(fetch[live], [3, 4, 5, 6, 7])
+    pages = np.where(live, fetch, ~fetch).reshape(-1, n)    # [step, slot]
+    for step in range(1, len(pages)):
+        dead = ~live.reshape(-1, n)[step]
+        np.testing.assert_array_equal(pages[step][dead],
+                                      pages[step - 1][dead])
+
+
 def test_zero_length_row_outputs_zeros():
-    """The `l == 0` guard in _decode_kernel._finish: a row with no valid
+    """The `l == 0` guard in _paged_kernel._finish: a row with no valid
     tokens (every page skipped) must return zeros, not NaN from 0/0."""
     q, kc, vc, tables, lens = _make_case(3, 4, 2, 16, 8, 4, [16, 0, 9])
     out = np.asarray(paged_decode_attention(q, kc, vc, tables, lens))
@@ -114,14 +203,13 @@ def test_unused_table_entries_are_skipped():
     np.testing.assert_allclose(np.asarray(out), ref, rtol=1e-4, atol=2e-5)
 
 
-def test_dense_decode_matches_reference():
+def _dense_case():
     rng = np.random.default_rng(3)
     B, H, Hkv, D, S = 2, 4, 2, 32, 64
     q = jnp.asarray(rng.standard_normal((B, H, D)), jnp.float32)
     kc = jnp.asarray(rng.standard_normal((B, Hkv, S, D)), jnp.float32)
     vc = jnp.asarray(rng.standard_normal((B, Hkv, S, D)), jnp.float32)
     lens = np.asarray([37, 64], np.int32)
-    out = dense_decode_attention(q, kc, vc, jnp.asarray(lens))
     g = H // Hkv
     ref = np.zeros((B, H, D), np.float32)
     for b in range(B):
@@ -130,7 +218,24 @@ def test_dense_decode_matches_reference():
                 np.asarray(q)[b, h],
                 np.asarray(kc)[b, h // g], np.asarray(vc)[b, h // g],
                 int(lens[b]), D ** -0.5)
+    return q, kc, vc, jnp.asarray(lens), ref
+
+
+def test_dense_decode_matches_reference():
+    q, kc, vc, lens, ref = _dense_case()
+    out = dense_decode_attention(q, kc, vc, lens)
     np.testing.assert_allclose(np.asarray(out), ref, rtol=1e-4, atol=2e-5)
+
+
+@pytest.mark.parametrize("ps", [16, 64])
+def test_dense_decode_at_a_given_tile(ps):
+    """The dense kernel at a sequence tile of its own (several blocks a row,
+    and the whole cache in one): it shares nothing with the paged grid."""
+    q, kc, vc, lens, ref = _dense_case()
+    q4, _ = da._split_heads(q, kc.shape[1])
+    out = da._run_dense(q4, kc, vc, lens, q.shape[-1] ** -0.5, ps)
+    np.testing.assert_allclose(np.asarray(out).reshape(ref.shape), ref,
+                               rtol=1e-4, atol=2e-5)
 
 
 class TestPagedKvWrite:

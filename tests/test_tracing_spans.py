@@ -418,7 +418,7 @@ def test_every_kernel_carries_its_name_from_the_table(name):
 
 def test_every_pallas_call_site_goes_through_the_named_call():
     """No bare `pl.pallas_call` outside the helper, and every literal name
-    at a call site is in the table (18 names over 14 call sites)."""
+    at a call site is in the table (18 names over 15 call sites)."""
     sites, helper = [], os.path.join(PKG, "ops", "pallas", "__init__.py")
     for path in glob.glob(os.path.join(PKG, "**", "*.py"), recursive=True):
         tree = ast.parse(open(path).read())
@@ -430,7 +430,7 @@ def test_every_pallas_call_site_goes_through_the_named_call():
                 assert path == helper, f"bare pallas_call in {path}"
             if isinstance(f, ast.Name) and f.id == "named_pallas_call":
                 sites.append((path, node.args[0]))
-    assert len(sites) == 14
+    assert len(sites) == 15
     for path, arg in sites:
         if isinstance(arg, ast.Constant):
             assert arg.value in KERNEL_NAMES, (path, arg.value)
