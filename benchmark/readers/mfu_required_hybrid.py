@@ -1,0 +1,25 @@
+"""Utilisation on REQUIRED operations of a `granite_hybrid` serving window,
+in % of the chip's bf16 peak: the FLOP the window's tokens require
+(costs_hybrid.window_flops: its decoded tokens at their mean context, plus
+its admissions x the mix's mean prompt length) over the window's seconds
+over the peak. Everything the window did is in it, prefill and decode, busy
+and idle: the whole-window share that bounds a later claim in the cell. An
+end-to-end utilisation, not a kernel's roofline share."""
+
+from benchmark import costs_hybrid, traffic_gen
+
+
+def read(run, obs):
+    ticks = obs["series"]["ticks"]
+    if not ticks or run.window is None:
+        return None
+    seconds = run.window[1] - run.window[0]
+    decoded = sum(t["decoded_rows"] for t in ticks)
+    if seconds <= 0 or not decoded:
+        return None
+    context = sum(t["context_tokens"] for t in ticks) / decoded
+    prompts = traffic_gen.levels(run.mix["prompt_len"])
+    flops = costs_hybrid.window_flops(
+        run.config, decoded, context,
+        sum(t["first_tokens"] for t in ticks), sum(prompts) / len(prompts))
+    return 100.0 * flops / seconds / run.peaks()["bf16_flops_per_s"]

@@ -61,7 +61,8 @@ from .....distributed import env as _env
 from .....distributed import moe_comm as _moe_comm
 from .gate import BaseGate, GShardGate, NaiveGate, SwitchGate
 
-__all__ = ["MoELayer", "ExpertFFN", "moe_fast_on", "moe_a2a_chunks"]
+__all__ = ["MoELayer", "ExpertFFN", "moe_fast_on", "moe_a2a_chunks",
+           "rank_in_group"]
 
 
 _constrain_value = _env.constrain_array
@@ -84,6 +85,23 @@ def moe_a2a_chunks() -> int:
     except ValueError:
         n = 2
     return max(1, min(n, 8))
+
+
+def rank_in_group(key, n_groups):
+    """(pos, counts): the rank of every entry among the entries of its group,
+    in the flat order given, and the entries per group. `key` [n] int32 is
+    the group of each entry, `n_groups` for an entry that takes part in
+    nothing (its pos is meaningless). A stable sort by group, then position
+    = index - the group's run start: O(n log n), no one-hot."""
+    n = key.shape[0]
+    order = jnp.argsort(key, stable=True)
+    counts = jax.ops.segment_sum(
+        jnp.ones_like(key), key, num_segments=n_groups + 1)[:n_groups]
+    start = jnp.cumsum(counts) - counts
+    srt = key[order]
+    pos_sorted = (jnp.arange(n, dtype=jnp.int32)
+                  - start[jnp.clip(srt, 0, n_groups - 1)].astype(jnp.int32))
+    return jnp.zeros((n,), jnp.int32).at[order].set(pos_sorted), counts
 
 
 class ExpertFFN(nn.Layer):
@@ -226,15 +244,7 @@ class MoELayer(nn.Layer):
             # sort by expert (invalid entries sort to the E sentinel), then
             # position = index - run start. Identical to the dense path's
             # cumsum-over-one-hot slot assignment, at O(kS log kS).
-            key = jnp.where(valid, eid, E)
-            order = jnp.argsort(key, stable=True)
-            counts = jax.ops.segment_sum(
-                jnp.ones_like(key), key, num_segments=E + 1)[:E]
-            start = jnp.cumsum(counts) - counts              # [E]
-            srt = key[order]
-            pos_sorted = (jnp.arange(k * S, dtype=jnp.int32)
-                          - start[jnp.clip(srt, 0, E - 1)].astype(jnp.int32))
-            pos = jnp.zeros((k * S,), jnp.int32).at[order].set(pos_sorted)
+            pos, counts = rank_in_group(jnp.where(valid, eid, E), E)
 
             # capacity overflow: a cheap drop mask, not one-hot pruning
             kept = valid & (pos < cap)

@@ -4,7 +4,8 @@
   `paged_decode_attention` kernel consumes, with free-list allocation,
   refcounted prefix sharing and copy-on-write; `quantized=True` stores int8
   payloads + per-(page, head) f32 scales for the dequant-fused kernel
-  (`PADDLE_TPU_KV_QUANT`).
+  (`PADDLE_TPU_KV_QUANT`). One cache spec per layer: `PagedKV` pages for
+  an attention layer, a `RowState` slot per decode row for a recurrent one.
 - `TwoQueueScheduler` — power-of-two prefill length buckets + decode/resume
   queues, admitting against a page-budget watermark.
 - `PagedServingEngine` — the continuous-batching engine over both, with
@@ -15,12 +16,14 @@ The dense `ContinuousBatchingEngine` remains the fallback:
 `paddle_tpu.inference.create_serving_engine(model, paged=False)`.
 """
 
-from .block_pool import BlockPool, prefix_page_key
+from .block_pool import BlockPool, PagedKV, RowState, prefix_page_key
 from .engine import PagedServingEngine, SpilledRequest
 from .scheduler import TwoQueueScheduler
 
 __all__ = [
     "BlockPool",
+    "PagedKV",
+    "RowState",
     "PagedServingEngine",
     "SpilledRequest",
     "TwoQueueScheduler",

@@ -26,6 +26,19 @@ that stops being shared (refcount 1) must be unregistered before an
 in-place write so a later identical prompt cannot adopt a page that now
 holds generated tokens.
 
+Layer kinds. What a layer keeps per request is a cache spec, one per layer
+(`specs`; a model that is not all attention gives them through
+`cache_specs()`): `PagedKV` is the layout above, with tables, prefix keys
+and copy-on-write; `RowState` is a fixed-size slot per DECODE ROW, a tuple of
+`[rows, ...]` arrays (a recurrent layer's conv and SSM state), indexed by
+the row a request decodes in, never shared, and spilled whole. Both live in
+`kv`, layer by layer, so the decode program donates and returns one list and
+`pool.kv = []` releases everything. Pages are written as above; a slot is
+written (admission, resume) and read (spill) by ONE jitted program each that
+takes the row as data, and the writing one donates the state arrays: an
+eager `.at[].set()` on a whole state array would copy all rows' state for
+one row's sake.
+
 Physical page 0 is the reserved NULL page: never allocated, never referenced
 by a live block table. Parked decode rows (batch padding) route their
 per-step K/V writes there, so the fixed-shape decode program needs no
@@ -47,14 +60,47 @@ and COW/spill/restore move payload + scales together, bit-exactly.
 from __future__ import annotations
 
 import collections
+import dataclasses
 import hashlib
+import math
 
+import jax
 import jax.numpy as jnp
 import numpy as np
 
 from ..slo import serving_metrics
 
-__all__ = ["BlockPool", "prefix_page_key"]
+__all__ = ["BlockPool", "PagedKV", "RowState", "prefix_page_key"]
+
+
+@dataclasses.dataclass(frozen=True)
+class PagedKV:
+    """An attention layer's cache: K and V pages
+    [n_pages, kv_heads, page_size, head_dim], block tables, prefix keys,
+    copy-on-write; spilled page by page."""
+
+    kv_heads: int
+    head_dim: int
+
+    def prefill_cache(self, seq, dtype):
+        """The zeroed dense cache a batch-1 prefill of `seq` tokens fills."""
+        return (jnp.zeros((1, seq, self.kv_heads, self.head_dim), dtype),) * 2
+
+
+@dataclasses.dataclass(frozen=True)
+class RowState:
+    """A recurrent layer's cache: one fixed-size slot per decode row, the
+    arrays `shapes` (per row); spilled and restored whole."""
+
+    shapes: tuple
+
+    def row_nbytes(self, dtype) -> int:
+        return sum(math.prod(s) for s in self.shapes) * jnp.dtype(
+            dtype).itemsize
+
+    def prefill_cache(self, seq, dtype):
+        """The zero state a batch-1 prefill starts from."""
+        return tuple(jnp.zeros((1,) + tuple(s), dtype) for s in self.shapes)
 
 
 def _quantize_pages(x):
@@ -85,7 +131,11 @@ class BlockPool:
     """Fixed pool of physical KV pages shared by every layer's cache."""
 
     def __init__(self, num_layers, kv_heads, head_dim, page_size, num_pages,
-                 dtype=jnp.float32, prefix_sharing=True, quantized=False):
+                 dtype=jnp.float32, prefix_sharing=True, quantized=False,
+                 specs=None, rows=0):
+        """`specs`: one cache spec per layer (default: every layer
+        `PagedKV(kv_heads, head_dim)`); `rows`: decode rows, the number of
+        slots a `RowState` layer gets."""
         if num_pages < 2:
             raise ValueError("num_pages must be >= 2 (page 0 is reserved)")
         if page_size < 1:
@@ -98,16 +148,32 @@ class BlockPool:
         self.dtype = jnp.dtype(dtype)  # unquantized payload dtype
         self.prefix_sharing = bool(prefix_sharing)
         self.quantized = bool(quantized)
+        self.specs = (list(specs) if specs is not None
+                      else [PagedKV(kv_heads, head_dim)] * num_layers)
+        if len(self.specs) != num_layers:
+            raise ValueError("one cache spec per layer")
+        self.page_layers = [i for i, s in enumerate(self.specs)
+                            if isinstance(s, PagedKV)]
+        self.state_layers = [i for i, s in enumerate(self.specs)
+                             if isinstance(s, RowState)]
+        if self.state_layers and self.quantized:
+            raise ValueError("an int8 pool beside recurrent state is not "
+                             "supported")
+        self.rows = int(rows)
         shape = (self.num_pages, kv_heads, self.page_size, head_dim)
         pay_dtype = jnp.dtype(jnp.int8) if self.quantized else self.dtype
         # immutable jnp zeros: (z,)*2 aliasing is safe, .at[] copies
         self.kv = [(jnp.zeros(shape, pay_dtype),) * 2
-                   for _ in range(num_layers)]
+                   if isinstance(spec, PagedKV) else
+                   tuple(jnp.zeros((self.rows,) + tuple(s), self.dtype)
+                         for s in spec.shapes)
+                   for spec in self.specs]
         # per-(page, head) f32 dequant scales beside the int8 payloads
         self.scales = ([(jnp.zeros((self.num_pages, kv_heads),
                                    jnp.float32),) * 2
                         for _ in range(num_layers)]
                        if self.quantized else None)
+        self._write_state = self._read_state = None
         self.free: collections.deque = collections.deque(
             range(1, self.num_pages))
         self.ref = np.zeros(self.num_pages, np.int32)
@@ -133,9 +199,15 @@ class BlockPool:
 
     @property
     def bytes_per_page(self) -> int:
-        return self.page_nbytes(self.num_layers, self.kv_heads,
+        return self.page_nbytes(len(self.page_layers), self.kv_heads,
                                 self.head_dim, self.page_size, self.dtype,
                                 self.quantized)
+
+    @property
+    def state_row_nbytes(self) -> int:
+        """HBM bytes one decode row's recurrent state costs, all layers."""
+        return sum(self.specs[li].row_nbytes(self.dtype)
+                   for li in self.state_layers)
 
     @property
     def bytes_per_token(self) -> float:
@@ -233,17 +305,17 @@ class BlockPool:
             return
         tgt = jnp.asarray([pages[j] for j in idx], jnp.int32)
         sel = jnp.asarray(idx, jnp.int32)
-        for li in range(self.num_layers):
+        for j, li in enumerate(self.page_layers):
             k, v = self.kv[li]
             if self.quantized:
-                kq, ks = _quantize_pages(k_layers[li][sel])
-                vq, vs = _quantize_pages(v_layers[li][sel])
+                kq, ks = _quantize_pages(k_layers[j][sel])
+                vq, vs = _quantize_pages(v_layers[j][sel])
                 sk, sv = self.scales[li]
                 self.kv[li] = (k.at[tgt].set(kq), v.at[tgt].set(vq))
                 self.scales[li] = (sk.at[tgt].set(ks), sv.at[tgt].set(vs))
             else:
-                self.kv[li] = (k.at[tgt].set(k_layers[li][sel]),
-                               v.at[tgt].set(v_layers[li][sel]))
+                self.kv[li] = (k.at[tgt].set(k_layers[j][sel]),
+                               v.at[tgt].set(v_layers[j][sel]))
         if self.quantized:
             serving_metrics()["kv_quant_pages"].inc(len(idx))
 
@@ -251,7 +323,7 @@ class BlockPool:
         """Copy-on-write body: duplicate src's content into dst (all
         layers; payload + scales for a quantized pool). Caller owns
         refcount/table updates."""
-        for li in range(self.num_layers):
+        for li in self.page_layers:
             k, v = self.kv[li]
             self.kv[li] = (k.at[dst].set(k[src]), v.at[dst].set(v[src]))
             if self.quantized:
@@ -273,7 +345,7 @@ class BlockPool:
                      np.asarray(sk[idx]), np.asarray(sv[idx]))
                     for (k, v), (sk, sv) in zip(self.kv, self.scales)]
         return [(np.asarray(k[idx]), np.asarray(v[idx]))
-                for k, v in self.kv]
+                for k, v in (self.kv[li] for li in self.page_layers)]
 
     def restore_pages(self, pages, kv_host, rows):
         """Write spilled host pages back: kv_host is read_pages() output for
@@ -284,13 +356,48 @@ class BlockPool:
             return
         tgt = jnp.asarray(list(pages), jnp.int32)
         sel = np.asarray(list(rows), np.int32)
-        for li in range(self.num_layers):
+        for j, li in enumerate(self.page_layers):
             k, v = self.kv[li]
-            k_h, v_h = kv_host[li][0], kv_host[li][1]
+            k_h, v_h = kv_host[j][0], kv_host[j][1]
             self.kv[li] = (k.at[tgt].set(jnp.asarray(k_h[sel])),
                            v.at[tgt].set(jnp.asarray(v_h[sel])))
             if self.quantized:
                 sk, sv = self.scales[li]
-                sk_h, sv_h = kv_host[li][2], kv_host[li][3]
+                sk_h, sv_h = kv_host[j][2], kv_host[j][3]
                 self.scales[li] = (sk.at[tgt].set(jnp.asarray(sk_h[sel])),
                                    sv.at[tgt].set(jnp.asarray(sv_h[sel])))
+
+    # -- device row state ------------------------------------------------- #
+
+    def write_state(self, row: int, values):
+        """Set decode row `row`'s slot in every `RowState` layer: `values`
+        holds, per such layer, the slot's arrays (a leading batch axis of 1
+        is dropped). One jitted program whatever the row; it donates the
+        state arrays, so only that row's bytes move."""
+        if not self.state_layers:
+            return
+        if self._write_state is None:
+            def write(states, row, values):
+                return [tuple(a.at[row].set(v.reshape(a.shape[1:])
+                                            .astype(a.dtype))
+                              for a, v in zip(layer, vals))
+                        for layer, vals in zip(states, values)]
+
+            self._write_state = jax.jit(write, donate_argnums=(0,))
+        new = self._write_state([self.kv[li] for li in self.state_layers],
+                                np.int32(row), values)
+        for li, layer in zip(self.state_layers, new):
+            self.kv[li] = layer
+
+    def read_state(self, row: int) -> list[tuple]:
+        """Host copies of decode row `row`'s slot, per `RowState` layer: the
+        state half of a preemption spill (`write_state` restores it)."""
+        if not self.state_layers:
+            return []
+        if self._read_state is None:
+            self._read_state = jax.jit(lambda states, row: [
+                tuple(jax.lax.dynamic_index_in_dim(a, row, 0, keepdims=False)
+                      for a in layer) for layer in states])
+        got = self._read_state([self.kv[li] for li in self.state_layers],
+                               np.int32(row))
+        return [tuple(np.asarray(a) for a in layer) for layer in got]

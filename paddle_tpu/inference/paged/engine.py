@@ -22,6 +22,16 @@ request re-enters through the scheduler's resume queue and continues
 decoding from exactly where it stopped — no tokens are lost or recomputed.
 Spilled pages that were prefix-shared re-attach by hash on resume when the
 shared copy still exists, and are restored from host otherwise.
+
+Layer kinds. A model whose layers are not all attention gives one cache spec
+per layer (`model.cache_specs()`, `block_pool.PagedKV` / `RowState`). A
+recurrent layer's state is a fixed-size slot per decode row: admission
+writes the slot from the prefill (`admit/write_state`), a spill carries it to
+the host beside the pages, a resume writes it back, retirement just frees the
+row. Admission is then by rows AND pages: a request needs a free row (its
+slot) and its pages under the watermark, and whichever runs out first is the
+limit. Prefix hits save such a model only the page writes: the prefill
+always runs, because the state after the prompt is the request's own.
 """
 
 from __future__ import annotations
@@ -36,24 +46,28 @@ import numpy as np
 from ...observability.spans import span
 from ..serving import GenerationRequest, _bucket, _ServingEngineBase
 from ..slo import serving_metrics
-from .block_pool import BlockPool, prefix_page_key
+from .block_pool import BlockPool, PagedKV, RowState, prefix_page_key
 from .scheduler import TwoQueueScheduler, _pages_for_prompt
 
 __all__ = ["PagedServingEngine", "SpilledRequest"]
 
 
 class SpilledRequest:
-    """A preempted request parked on host: generation state plus page
-    contents, enough to resume without recomputing anything."""
+    """A preempted request parked on host: generation state, the contents
+    of its pages and of its recurrent-state slot, enough to resume without
+    recomputing anything."""
 
-    __slots__ = ("req", "length", "last_tok", "kv_host", "keys")
+    __slots__ = ("req", "length", "last_tok", "kv_host", "keys",
+                 "state_host")
 
-    def __init__(self, req, length, last_tok, kv_host, keys):
+    def __init__(self, req, length, last_tok, kv_host, keys, state_host=()):
         self.req = req
         self.length = int(length)
         self.last_tok = int(last_tok)
-        self.kv_host = kv_host   # per layer (k, v) np [m, Hkv, ps, D]
+        self.kv_host = kv_host   # per paged layer (k, v) np [m, Hkv, ps, D]
         self.keys = keys         # per logical page: prefix key or None
+        # per recurrent layer the row's slot, np arrays (BlockPool.read_state)
+        self.state_host = state_host
 
     @property
     def n_pages(self) -> int:
@@ -95,13 +109,20 @@ class PagedServingEngine(_ServingEngineBase):
                 "pass num_pages OR kv_budget_bytes, not both — a page count "
                 "would silently override the byte budget and break the "
                 "equal-budget A/B contract")
+        specs = self.cache_specs
+        paged_layers = (cfg.num_layers if specs is None else
+                        sum(isinstance(s, PagedKV) for s in specs))
         if num_pages is None:
             if kv_budget_bytes is not None:
                 page_b = BlockPool.page_nbytes(
-                    cfg.num_layers, cfg.kv_heads, cfg.head_dim, self.ps,
+                    paged_layers, cfg.kv_heads, cfg.head_dim, self.ps,
                     self.kv_dtype, self.kv_quant)
-                # budget covers the whole pool, reserved null page included
-                num_pages = int(kv_budget_bytes) // page_b
+                # budget covers the whole pool, reserved null page included,
+                # and first of all every row's recurrent-state slot
+                slots = self.B * sum(
+                    s.row_nbytes(self.kv_dtype) for s in specs or ()
+                    if isinstance(s, RowState))
+                num_pages = (int(kv_budget_bytes) - slots) // page_b
                 if num_pages < 2:
                     raise ValueError(
                         f"kv_budget_bytes={int(kv_budget_bytes)} fits "
@@ -114,7 +135,12 @@ class PagedServingEngine(_ServingEngineBase):
         self.pool = BlockPool(cfg.num_layers, cfg.kv_heads, cfg.head_dim,
                               self.ps, num_pages, dtype=self.kv_dtype,
                               prefix_sharing=prefix_sharing,
-                              quantized=self.kv_quant)
+                              quantized=self.kv_quant, specs=specs,
+                              rows=self.B)
+        if self.pool.state_layers:
+            # the slot's two programs compile now, on the empty pool: a
+            # preemption must not compile in the middle of serving
+            self.pool.write_state(0, self.pool.read_state(0))
         self.sched = TwoQueueScheduler(self.ps, watermark_pages)
         self.preemption = bool(preemption)
         self.tables = np.full((self.B, self.P), -1, np.int32)
@@ -124,10 +150,14 @@ class PagedServingEngine(_ServingEngineBase):
         # the paged-decode kernel's grid, for the `decode_dispatch` span:
         # static, so computed once
         from ...ops.pallas.decode_attention import pages_per_step
+        pages0 = self.pool.kv[self.pool.page_layers[0]][0]
         n = pages_per_step(cfg.kv_heads, self.ps, cfg.head_dim, self.P,
-                           self.pool.kv[0][0].dtype.itemsize)
+                           pages0.dtype.itemsize)
         self._decode_grid = {"pages_per_step": n,
                              "grid_steps": self.B * -(-self.P // n)}
+        # a model with routed experts counts its routing in the decode
+        # program (incubate/.../held_moe.STAT_NAMES)
+        self._moe_groups = getattr(model, "moe_groups", 0)
         self.pool.update_gauges()
         # materialize the pool/preemption series at zero so --emit-metrics
         # JSONL carries them from the first tick, not only after the first
@@ -138,6 +168,9 @@ class PagedServingEngine(_ServingEngineBase):
                      "prefix_hits", "prefix_lookups", "cow_copies",
                      "kv_quant_pages"):
             m[name].inc(0)
+        if self._moe_groups:
+            for name in ("moe_routed_pairs_held", "moe_dropped_pairs"):
+                m[name].inc(0)
 
     # ------------------------------------------------------------------ #
 
@@ -194,20 +227,29 @@ class PagedServingEngine(_ServingEngineBase):
     def _spill_row(self, row):
         req = self.active[row]
         pages = [int(p) for p in self.tables[row] if p >= 0]
-        with span("spill", rid=req.req_id, pages=len(pages)):
+        with span("spill", rid=req.req_id, pages=len(pages),
+                  **self._state_attrs()):
             kv_host = self.pool.read_pages(pages)
+            state_host = self.pool.read_state(row)
             keys = [self.pool.page_key(p) for p in pages]
             for p in pages:
                 self.pool.release(p)
         req.preemptions += 1
         self.sched.enqueue_resume(SpilledRequest(
-            req, self.lengths[row], self.last_tok[row], kv_host, keys))
+            req, self.lengths[row], self.last_tok[row], kv_host, keys,
+            state_host))
         self.tables[row, :] = -1
         self.active[row] = None
         self.lengths[row] = 0
         m = serving_metrics()
         m["preemptions"].inc()
         m["preempted_pages"].inc(len(pages))
+
+    def _state_attrs(self):
+        """Span attributes of a spill or a resume that moves a slot."""
+        if not self.pool.state_layers:
+            return {}
+        return {"state_bytes": self.pool.state_row_nbytes}
 
     def _release_row(self, row):
         for p in self.tables[row]:
@@ -268,10 +310,15 @@ class PagedServingEngine(_ServingEngineBase):
         if any(write_mask):
             with span("write_pages", rid=rid,
                       pages_written=sum(write_mask)):
-                k_layers = [self._stack_pages(k_, n, m) for k_, _ in new_c]
-                v_layers = [self._stack_pages(v_, n, m) for _, v_ in new_c]
+                paged = [new_c[li] for li in self.pool.page_layers]
+                k_layers = [self._stack_pages(k_, n, m) for k_, _ in paged]
+                v_layers = [self._stack_pages(v_, n, m) for _, v_ in paged]
                 self.pool.write_prompt_pages(pages, write_mask,
                                              k_layers, v_layers)
+        if self.pool.state_layers:
+            with span("write_state", rid=rid, row=row):
+                self.pool.write_state(
+                    row, [new_c[li] for li in self.pool.state_layers])
         self.tables[row, :m] = pages
         with span("first_token", rid=rid):  # the host waits for the prefill
             first = self._pick_token(logits[0, n - 1], req)
@@ -282,7 +329,8 @@ class PagedServingEngine(_ServingEngineBase):
 
     def _resume_into(self, row, sp: SpilledRequest):
         pages, restore_rows, restore_pages = [], [], []
-        with span("resume", rid=sp.req.req_id) as resume:
+        with span("resume", rid=sp.req.req_id,
+                  **self._state_attrs()) as resume:
             for j, key in enumerate(sp.keys):
                 page = self.pool.lookup_prefix(key)
                 if page is None:
@@ -293,6 +341,7 @@ class PagedServingEngine(_ServingEngineBase):
                     restore_pages.append(page)
                 pages.append(page)
             self.pool.restore_pages(restore_pages, sp.kv_host, restore_rows)
+            self.pool.write_state(row, sp.state_host)
             resume.set(pages_restored=len(restore_pages))
         self.tables[row, :len(pages)] = pages
         self.active[row] = sp.req
@@ -330,7 +379,36 @@ class PagedServingEngine(_ServingEngineBase):
             self._note_finished(req, truncated)
             self._release_row(row)
 
+    def _note_routing(self, stats):
+        """One decode tick's routing counts (held_moe.STAT_NAMES, summed
+        over the layers) into the serving metrics."""
+        pairs, rows_max, rows_sum, dropped = (int(v) for v in stats)
+        m = serving_metrics()
+        m["moe_routed_pairs_held"].inc(pairs)
+        m["moe_dropped_pairs"].inc(dropped)
+        m["moe_expert_rows_max"].observe(rows_max)
+        m["moe_expert_rows_mean"].observe(rows_sum / self._moe_groups)
+
     # ------------------------------------------------------------------ #
+
+    def _decode_program(self):
+        """The ONE compiled decode program: every row advances by a token;
+        tables, lengths and last tokens are data."""
+        stats_kw = {"with_stats": True} if self._moe_groups else {}
+
+        def decode(p, b, tok, offs, tables, caches):
+            pos = offs[:, None]
+            logits, new_c, *stats = self._functional_forward(
+                p, b, tok[:, None], pos, caches, offs, tables=tables,
+                **stats_kw)
+            last = logits[:, -1]
+            # greedy picked ON DEVICE; [B, vocab] logits stay on device
+            # unless a sampled row gathers its own [vocab] slice. A model's
+            # routing counts ride beside the tokens: one host read
+            return jnp.argmax(last, axis=-1).astype(jnp.int32), \
+                last, new_c, stats
+
+        return jax.jit(decode, donate_argnums=(5,))
 
     def _step(self, tick):
         """Admit (resumes then prefills), ensure every live row has a
@@ -342,6 +420,8 @@ class PagedServingEngine(_ServingEngineBase):
         tick.set(live=len(live), waiting=self.sched.waiting_prefill)
         self.sched.update_gauges(self.engine_label, len(live))
         self.pool.update_gauges()
+        if self.pool.state_layers:
+            serving_metrics()["state_rows_live"].set(len(live))
         if not live:
             return {}
         with span("write_targets") as sp:
@@ -355,26 +435,19 @@ class PagedServingEngine(_ServingEngineBase):
         if not live:
             return {}
         if self._decode_jit is None:
-            def decode(p, b, tok, offs, tables, caches):
-                pos = offs[:, None]
-                logits, new_c = self._functional_forward(
-                    p, b, tok[:, None], pos, caches, offs, tables=tables)
-                last = logits[:, -1]
-                # greedy picked ON DEVICE; [B, vocab] logits stay on device
-                # unless a sampled row gathers its own [vocab] slice
-                return jnp.argmax(last, axis=-1).astype(jnp.int32), \
-                    last, new_c
+            self._decode_jit = self._decode_program()
 
-            self._decode_jit = jax.jit(decode, donate_argnums=(5,))
-
-        with span("decode_dispatch", rows=len(live), **self._decode_grid):
+        state_rows = ({"state_rows": len(live)} if self.pool.state_layers
+                      else {})
+        with span("decode_dispatch", rows=len(live), **self._decode_grid,
+                  **state_rows):
             # quantized pool: each layer's cache rides as (k, v, k_scale,
             # v_scale) so the int8 append + dequant-fused attention see
             # payload and scales together inside the one compiled program
             caches = ([kv + sc
                        for kv, sc in zip(self.pool.kv, self.pool.scales)]
                       if self.kv_quant else self.pool.kv)
-            greedy_tok, logits, new_kv = self._decode_jit(
+            greedy_tok, logits, new_kv, stats = self._decode_jit(
                 self.params, self.buffers, jnp.asarray(self.last_tok),
                 jnp.asarray(self.lengths), jnp.asarray(self.tables), caches)
             if self.kv_quant:
@@ -384,7 +457,9 @@ class PagedServingEngine(_ServingEngineBase):
                 self.pool.kv = [tuple(c) for c in new_kv]
             self.last_logits = logits  # device array; tests probe divergence
         with span("host_read"):  # the host waits for the decode here
-            greedy_np = np.asarray(greedy_tok)
+            greedy_np, stats = jax.device_get((greedy_tok, stats))
+        if stats:
+            self._note_routing(stats[0])
         out = {}
         with span("emit", rows=len(live)) as sp:
             sampled = 0

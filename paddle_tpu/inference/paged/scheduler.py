@@ -16,6 +16,11 @@ per live request — every live row can cross at most one page boundary per
 `page_size` decode steps, so this reserve makes same-tick pool exhaustion
 (and therefore preemption) the exception rather than the steady state.
 
+Admission is by rows AND pages: `pick` also takes the free decode rows, and
+for a model with recurrent layers a row is a resource of its own (the row's
+state slot, `block_pool.RowState`): where pages are plentiful, rows are what
+runs out, and a spilled request needs a free row again before it resumes.
+
 Ordering is strict arrival FIFO across buckets, with head-of-line blocking
 when the head doesn't fit the budget. Two deliberate consequences: no
 starvation (a big request is never overtaken forever by small ones), and

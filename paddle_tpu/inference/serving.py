@@ -126,6 +126,10 @@ class _ServingEngineBase:
             (jnp.dtype(v.dtype) for v in self.params.values()
              if jnp.issubdtype(v.dtype, jnp.floating)),
             jnp.dtype(jnp.float32))
+        # one cache spec per layer where the model is not all attention
+        # (inference/paged/block_pool.py); None: every layer keeps K and V
+        self.cache_specs = (model.cache_specs()
+                            if hasattr(model, "cache_specs") else None)
         self.last_logits = None  # last decode tick's [B, vocab] device array
         self.finished: list[GenerationRequest] = []
         self._key = jax.random.PRNGKey(seed)
@@ -151,31 +155,40 @@ class _ServingEngineBase:
 
     # -- shared forward plumbing ---------------------------------------- #
 
-    def _functional_forward(self, p, b, tok, pos, caches, off, tables=None):
+    def _functional_forward(self, p, b, tok, pos, caches, off, tables=None,
+                            **model_kw):
+        """The model's `(logits, new_caches)` — and whatever else a model
+        asked for through `model_kw` returns behind them."""
         from ..jit import functional_call
 
-        # per-layer cache entries are (k, v) — or (k, v, k_scale, v_scale)
-        # for the quantized paged layout; pass tuples through structurally
+        # per-layer cache entries are whatever the layer's kind defines:
+        # (k, v), (k, v, k_scale, v_scale) for the quantized paged layout,
+        # (conv_state, ssm_state) for a recurrent layer; pass tuples
+        # through structurally
         c = [tuple(Tensor(x) for x in layer_c) for layer_c in caches]
-        kwargs = {}
+        kwargs = {k: Tensor(v) if isinstance(v, jax.Array) else v
+                  for k, v in model_kw.items()}
         if tables is not None:
             kwargs["block_tables"] = Tensor(tables)
-        (logits, new_c), _ = functional_call(
+        out, _ = functional_call(
             self.model, p, b, [Tensor(tok), Tensor(pos), c, Tensor(off)],
             kwargs=kwargs, train=False)
-        return logits, new_c
+        return out
 
     def _run_prefill(self, req):
         """Batch-1 prefill over a zeroed bucket-length dense cache. Returns
-        (logits [1, Sp, V] device, new_caches per layer [1, Sp, Hkv, D],
-        n, Sp)."""
+        (logits [1, Sp, V] device, new_caches per layer — [1, Sp, Hkv, D]
+        K and V, or a recurrent layer's state after token n - 1 — n, Sp)."""
         n = len(req.prompt)
         Sp = _bucket(n)
 
         def compile_prefill():
-            def prefill(p, b, tok, pos, caches):
+            def prefill(p, b, tok, pos, caches, *lens):
+                # a recurrent layer sees the bucket's zero padding, which
+                # attention never does: such a model is told the real length
+                kw = {"seq_lens": lens[0]} if lens else {}
                 logits, new_c = self._functional_forward(
-                    p, b, tok, pos, caches, jnp.int32(0))
+                    p, b, tok, pos, caches, jnp.int32(0), **kw)
                 return logits, new_c
 
             return jax.jit(prefill)
@@ -185,11 +198,17 @@ class _ServingEngineBase:
         tok[0, :n] = req.prompt
         pos = np.arange(Sp, dtype=np.int32)[None]
         cfg = self.cfg
-        zero_c = [(jnp.zeros((1, Sp, cfg.kv_heads, cfg.head_dim),
-                             self.kv_dtype),) * 2
-                  for _ in range(cfg.num_layers)]
+        if self.cache_specs is None:
+            zero_c = [(jnp.zeros((1, Sp, cfg.kv_heads, cfg.head_dim),
+                                 self.kv_dtype),) * 2
+                      for _ in range(cfg.num_layers)]
+            lens = ()
+        else:
+            zero_c = [spec.prefill_cache(Sp, self.kv_dtype)
+                      for spec in self.cache_specs]
+            lens = (jnp.asarray([n], jnp.int32),)
         logits, new_c = pf(self.params, self.buffers,
-                           jnp.asarray(tok), jnp.asarray(pos), zero_c)
+                           jnp.asarray(tok), jnp.asarray(pos), zero_c, *lens)
         return logits, new_c, n, Sp
 
     # -- sampling -------------------------------------------------------- #

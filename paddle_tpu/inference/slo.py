@@ -28,6 +28,8 @@ __all__ = ["serving_metrics", "BoundedCompileCache"]
 
 # tokens/s per finished request: 0.5 .. 4096, x2 per bucket
 _TPS_BUCKETS = tuple(0.5 * 2 ** i for i in range(14))
+# rows an expert gets in a decode tick: 1 .. 1024, x2 per bucket
+_ROWS_BUCKETS = tuple(float(2 ** i) for i in range(11))
 
 
 def _build(reg):
@@ -88,6 +90,26 @@ def _build(reg):
         "resumes": reg.counter(
             "serving_resumes_total",
             "Spilled requests re-admitted from the host buffer"),
+        "state_rows_live": reg.gauge(
+            "serving_state_rows_live",
+            "Decode rows whose recurrent-state slot holds a live request "
+            "(models with recurrent layers only)"),
+        "moe_routed_pairs_held": reg.counter(
+            "moe_routed_pairs_held",
+            "(token, expert) picks of decode ticks that named an expert "
+            "held here, over all layers"),
+        "moe_dropped_pairs": reg.counter(
+            "moe_dropped_pairs",
+            "Held picks that found no row in the expert's group: must "
+            "stay 0, the group stride is the worst case"),
+        "moe_expert_rows_max": reg.histogram(
+            "moe_expert_rows_max",
+            "Per decode tick: the most rows any held expert of any layer "
+            "got", buckets=_ROWS_BUCKETS),
+        "moe_expert_rows_mean": reg.histogram(
+            "moe_expert_rows_mean",
+            "Per decode tick: rows per held expert, mean over layers and "
+            "held experts", buckets=_ROWS_BUCKETS),
         "prefill_compiles": reg.counter(
             "serving_prefill_compiles_total",
             "Prefill program compiles, one per live length bucket",

@@ -52,7 +52,13 @@ from .bert import (  # noqa: F401,E402
     bert_tiny,
 )
 from .unet import UNetConfig, UNetModel, unet_tiny  # noqa: F401,E402
+from .granite_hybrid import (  # noqa: F401,E402
+    GraniteHybridConfig,
+    GraniteHybridForCausalLM,
+    granite_hybrid_tiny,
+)
 __all__ += [
+    "GraniteHybridConfig", "GraniteHybridForCausalLM", "granite_hybrid_tiny",
     "BertConfig", "BertModel", "BertForPretraining",
     "BertForSequenceClassification", "BertPretrainingCriterion",
     "bert_base", "bert_tiny", "UNetConfig", "UNetModel", "unet_tiny",

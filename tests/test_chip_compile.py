@@ -17,6 +17,7 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import SingleDeviceSharding
 
+from paddle_tpu.ops.pallas import grouped_gemm, ssm_decode
 from paddle_tpu.ops.pallas.decode_attention import (
     dense_decode_attention,
     paged_decode_attention,
@@ -79,3 +80,56 @@ def test_dense_decode_compiles(one_chip):
     hlo = _compile(dense_decode_attention, one_chip,
                    ((8, H, D), jnp.bfloat16), cache, cache, ((8,), jnp.int32))
     assert re.search(r"%decode_dense[.\w]* = .*tpu_custom_call", hlo)
+
+
+# serve-granite-h-sat: 128 rows; state [128, 8192] a row and Mamba layer;
+# 36 held experts of 4096 x (2 x 768) and 768 x 4096; 4 query heads a KV head
+
+
+def test_ssm_decode_compiles_in_place_at_the_cells_shapes(one_chip,
+                                                          monkeypatch):
+    # on the CPU backend the entry point takes its jax.numpy form; here the
+    # kernel itself is what is compiled
+    monkeypatch.setattr(ssm_decode, "kernels_available", lambda: True)
+    rows, n, lanes = 128, 128, 8192
+    args = [jax.ShapeDtypeStruct(s, d, sharding=one_chip) for s, d in (
+        ((rows, n, lanes), jnp.bfloat16), ((rows, lanes), jnp.float32),
+        ((rows, lanes), jnp.float32), ((rows, n), jnp.float32),
+        ((rows, n), jnp.float32), ((rows,), jnp.bool_))]
+    compiled = jax.jit(ssm_decode.ssm_decode, donate_argnums=(0,)).lower(
+        *args).compile()
+    assert re.search(r"%ssm_decode[.\w]* = .*tpu_custom_call",
+                     compiled.as_text())
+    # the state goes in and comes out as one buffer: no second copy of it
+    memory = compiled.memory_analysis()
+    state_bytes = rows * n * lanes * 2
+    assert memory.alias_size_in_bytes >= state_bytes
+    assert memory.temp_size_in_bytes < state_bytes // 8
+
+
+@pytest.mark.parametrize("stride,k,n", [(128, 4096, 1536), (128, 768, 4096),
+                                        (1024, 4096, 1536)],
+                         ids=["decode-in", "decode-out", "prefill-in"])
+def test_grouped_gemm_compiles_at_the_held_experts_shapes(one_chip, stride,
+                                                          k, n):
+    held = 36
+    hlo = _compile(
+        lambda rows, w, sizes: grouped_gemm.grouped_matmul(
+            rows, w, sizes, block=(min(stride, 256), 128)),
+        one_chip, ((held * stride, k), jnp.bfloat16),
+        ((held, k, n), jnp.bfloat16), ((held,), jnp.int32))
+    # what benchmark/readers/kernel_roofline_hybrid.py holds on to
+    (call,) = [line for line in hlo.splitlines()
+               if re.match(r"\s*(ROOT )?%grouped_gemm[.\w]* = ", line)]
+    assert 'custom_call_target="tpu_custom_call"' in call
+    assert f"bf16[{held},{k},{n}]" in call
+
+
+def test_paged_decode_compiles_for_four_query_heads_a_kv_head(one_chip):
+    pool = ((9700, 8, PS, D), jnp.bfloat16)
+    hlo = _compile(
+        lambda q, kc, vc, t, n: paged_decode_attention(q, kc, vc, t, n,
+                                                       scale=0.0078125),
+        one_chip, ((128, 32, D), jnp.bfloat16), pool, pool,
+        ((128, P), jnp.int32), ((128,), jnp.int32))
+    assert re.search(r"%decode_paged[.\w]* = .*tpu_custom_call", hlo)

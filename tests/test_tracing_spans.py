@@ -333,7 +333,8 @@ def _kernel_jaxprs():
     from paddle_tpu.ops.pallas import (decode_attention as da,
                                        flash_attention as fa,
                                        fused_norm as fn, fused_rope as fr,
-                                       grouped_gemm as gg, masked_flash as mf)
+                                       grouped_gemm as gg, masked_flash as mf,
+                                       ssm_decode as sd)
 
     f32 = jnp.float32
     q = jnp.ones((1, 128, 2, 64), f32)
@@ -390,6 +391,9 @@ def _kernel_jaxprs():
             kv_scales=(scales, scales)), qd),
         "decode_dense": fwd(lambda q: da.dense_decode_attention(
             q, dense, dense, lens), qd),
+        "ssm_decode": fwd(lambda s: sd.ssm_decode(
+            s, x[:2], x[:2], jnp.ones((2, 8), f32), jnp.ones((2, 8), f32),
+            lens > 5), jnp.ones((2, 8, 128), f32)),
     }
 
 
@@ -418,7 +422,7 @@ def test_every_kernel_carries_its_name_from_the_table(name):
 
 def test_every_pallas_call_site_goes_through_the_named_call():
     """No bare `pl.pallas_call` outside the helper, and every literal name
-    at a call site is in the table (18 names over 15 call sites)."""
+    at a call site is in the table (19 names over 16 call sites)."""
     sites, helper = [], os.path.join(PKG, "ops", "pallas", "__init__.py")
     for path in glob.glob(os.path.join(PKG, "**", "*.py"), recursive=True):
         tree = ast.parse(open(path).read())
@@ -430,7 +434,7 @@ def test_every_pallas_call_site_goes_through_the_named_call():
                 assert path == helper, f"bare pallas_call in {path}"
             if isinstance(f, ast.Name) and f.id == "named_pallas_call":
                 sites.append((path, node.args[0]))
-    assert len(sites) == 15
+    assert len(sites) == 16
     for path, arg in sites:
         if isinstance(arg, ast.Constant):
             assert arg.value in KERNEL_NAMES, (path, arg.value)
