@@ -37,6 +37,10 @@ f32-cast dots of the old kernel kept; any other mix (f32 pool, int8 pool,
 f32 q) casts both operands to f32 and follows the ambient matmul precision
 as before.
 
+The KV append (`paged_kv_write`, `paged_kv_write_q8`) rewrites each row's
+whole target page: XLA's TPU scatter wants the scattered dimensions major, so
+only a scatter of whole pages leaves the pool where it lies.
+
 Dense (`decode_dense`): the cache is contiguous, the grid stays (batch,
 kv_head, block) with one [block, D] tile of one head a step and the sequence
 tile autotuned. It shares the online-softmax update with the paged kernel
@@ -414,7 +418,7 @@ def _consult_tuner_paged(q4, kc, tables, quantized=False):
 
 
 def paged_kv_write(cache, new, block_tables, lengths):
-    """Scatter one decode step's K (or V) rows into the paged cache.
+    """Write one decode step's K (or V) rows into the paged cache.
 
     cache: [n_pages, Hkv, page_size, D]; new: [B, Hkv, D] (this step's
     projection per row); block_tables: [B, P] physical page ids (-1 unused);
@@ -423,13 +427,24 @@ def paged_kv_write(cache, new, block_tables, lengths):
     lengths[b]%ps). Rows whose target table entry is -1 (parked/batch-pad
     rows) are routed to physical page 0, the pool's reserved null page, which
     no live block table ever references. Pure/jittable; owns the page layout
-    so callers never index the cache themselves."""
+    so callers never index the cache themselves.
+
+    The form is a read-modify-write of each row's WHOLE target page: a
+    scatter over the pool's major dimension alone is done in place, where
+    `cache.at[page, :, slot].set(new)` has the TPU compiler re-lay the whole
+    pool round the scatter. Live rows never share a write page (the engine's
+    copy-on-write); parked rows collide on page 0 only, where one writer's
+    page stays and nothing reads it."""
     B = new.shape[0]
     ps = cache.shape[2]
     lengths = lengths.astype(jnp.int32)
     page = block_tables[jnp.arange(B), lengths // ps]
     page = jnp.where(page < 0, 0, page)
-    return cache.at[page, :, lengths % ps].set(new.astype(cache.dtype))
+    at_slot = (jax.lax.broadcasted_iota(jnp.int32, (B, 1, ps, 1), 2)
+               == (lengths % ps)[:, None, None, None])
+    pg = jnp.where(at_slot, new.astype(cache.dtype)[:, :, None, :],
+                   cache[page])
+    return cache.at[page].set(pg)
 
 
 def paged_kv_write_q8(cache, scales, new, block_tables, lengths):

@@ -21,6 +21,7 @@ from paddle_tpu.ops.pallas import grouped_gemm, ssm_decode
 from paddle_tpu.ops.pallas.decode_attention import (
     dense_decode_attention,
     paged_decode_attention,
+    paged_kv_write,
 )
 
 # serve-xl-sat: 64 rows, 16 heads of 128, pages of 32, table 64 wide
@@ -43,9 +44,12 @@ def _compiled_not_interpreted(monkeypatch):
     monkeypatch.delenv("PADDLE_TPU_PALLAS_INTERPRET", raising=False)
 
 
+def _args(one_chip, *shapes):
+    return [jax.ShapeDtypeStruct(s, d, sharding=one_chip) for s, d in shapes]
+
+
 def _compile(fn, one_chip, *shapes):
-    args = [jax.ShapeDtypeStruct(s, d, sharding=one_chip) for s, d in shapes]
-    return jax.jit(fn).lower(*args).compile().as_text()
+    return jax.jit(fn).lower(*_args(one_chip, *shapes)).compile().as_text()
 
 
 @pytest.mark.parametrize("q_dtype,pool_dtype,hkv", [
@@ -92,10 +96,10 @@ def test_ssm_decode_compiles_in_place_at_the_cells_shapes(one_chip,
     # kernel itself is what is compiled
     monkeypatch.setattr(ssm_decode, "kernels_available", lambda: True)
     rows, n, lanes = 128, 128, 8192
-    args = [jax.ShapeDtypeStruct(s, d, sharding=one_chip) for s, d in (
-        ((rows, n, lanes), jnp.bfloat16), ((rows, lanes), jnp.float32),
+    args = _args(
+        one_chip, ((rows, n, lanes), jnp.bfloat16), ((rows, lanes), jnp.float32),
         ((rows, lanes), jnp.float32), ((rows, n), jnp.float32),
-        ((rows, n), jnp.float32), ((rows,), jnp.bool_))]
+        ((rows, n), jnp.float32), ((rows,), jnp.bool_))
     compiled = jax.jit(ssm_decode.ssm_decode, donate_argnums=(0,)).lower(
         *args).compile()
     assert re.search(r"%ssm_decode[.\w]* = .*tpu_custom_call",
@@ -133,3 +137,59 @@ def test_paged_decode_compiles_for_four_query_heads_a_kv_head(one_chip):
         one_chip, ((128, 32, D), jnp.bfloat16), pool, pool,
         ((128, P), jnp.int32), ((128,), jnp.int32))
     assert re.search(r"%decode_paged[.\w]* = .*tpu_custom_call", hlo)
+
+
+# the KV append of a decode tick: the pool goes in and comes out as one
+# buffer and the compiler lays no second pool array beside it (S15: a form of
+# the write that scatters pages AND slots has it copy the whole pool into
+# another layout and back, 229 MB and 639 MB at these shapes)
+POOLS = {"serve-xl-sat": ((1746, 16, PS, D), 64, 16),
+         "serve-granite-h-sat": ((9749, 8, PS, D), 128, 32)}
+
+
+def _pool_text(pool):
+    return "bf16[" + ",".join(str(d) for d in pool) + "]"
+
+
+def _assert_pool_stays_where_it_lies(compiled, pool, n_pools):
+    hlo = compiled.as_text()
+    assert not re.search(r"%copy[.\w]* = " + re.escape(_pool_text(pool)), hlo)
+    memory = compiled.memory_analysis()
+    pool_bytes = 2 * pool[0] * pool[1] * pool[2] * pool[3]
+    assert memory.alias_size_in_bytes >= n_pools * pool_bytes
+    assert memory.temp_size_in_bytes < pool_bytes // 8
+    return hlo
+
+
+@pytest.mark.parametrize("cell", sorted(POOLS))
+def test_paged_kv_write_compiles_in_place(one_chip, cell):
+    pool, rows, _ = POOLS[cell]
+    args = _args(one_chip, (pool, jnp.bfloat16),
+                 ((rows, pool[1], D), jnp.bfloat16), ((rows, P), jnp.int32),
+                 ((rows,), jnp.int32))
+    compiled = jax.jit(paged_kv_write, donate_argnums=(0,)).lower(
+        *args).compile()
+    _assert_pool_stays_where_it_lies(compiled, pool, 1)
+
+
+@pytest.mark.parametrize("cell", sorted(POOLS))
+def test_kv_writes_then_paged_decode_leave_the_pool_in_place(one_chip, cell):
+    """The model's own order in one program, both pool arrays donated."""
+    pool, rows, heads = POOLS[cell]
+
+    def layer(kc, vc, k, v, q, tables, lengths):
+        kc = paged_kv_write(kc, k, tables, lengths)
+        vc = paged_kv_write(vc, v, tables, lengths)
+        return paged_decode_attention(q, kc, vc, tables, lengths + 1), kc, vc
+
+    new = ((rows, pool[1], D), jnp.bfloat16)
+    args = _args(one_chip, (pool, jnp.bfloat16), (pool, jnp.bfloat16), new, new,
+                 ((rows, heads, D), jnp.bfloat16), ((rows, P), jnp.int32),
+                 ((rows,), jnp.int32))
+    compiled = jax.jit(layer, donate_argnums=(0, 1)).lower(*args).compile()
+    hlo = _assert_pool_stays_where_it_lies(compiled, pool, 2)
+    # what benchmark/readers/decode_attn_roofline.py holds on to
+    (call,) = [line for line in hlo.splitlines()
+               if re.match(r"\s*(ROOT )?%decode_paged[.\w]* = ", line)]
+    assert 'custom_call_target="tpu_custom_call"' in call
+    assert _pool_text(pool) + "{3,2,1,0" in call

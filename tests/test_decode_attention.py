@@ -271,3 +271,53 @@ class TestPagedKvWrite:
         assert out[1, :, 1].any()          # live row wrote its slot
         assert out[0, :, 0].any()          # parked row landed on null page
         assert not out[2:].any()           # no allocatable page touched
+
+    # (lengths, first table entries) per row; a row with no entries is parked
+    SCENES = {
+        # slot 0 and slot ps - 1 of first and later pages
+        "page-boundaries": [(0, [5]), (3, [6]), (4, [1, 7]), (11, [2, 3, 8])],
+        # the engine's parked rows: table -1, length 0
+        "all-parked": [(0, []), (0, []), (0, [])],
+        # parked rows (one at a slot of its own) beside live ones
+        "parked-beside-live": [(0, []), (6, [4, 2]), (0, []), (9, []),
+                               (3, [9])],
+    }
+
+    @pytest.mark.parametrize("scene", sorted(SCENES))
+    @pytest.mark.parametrize("hkv", [1, 8, 16])
+    @pytest.mark.parametrize("dtype", [jnp.bfloat16, jnp.float32],
+                             ids=["bf16", "f32"])
+    def test_equals_a_numpy_loop_over_the_whole_pool(self, dtype, hkv, scene):
+        """The docstring's semantics as a plain loop: every live row's slot
+        written, every other element of the pool as it was, bit for bit.
+        Parked rows all go to page 0, where ONE of them leaves its write."""
+        rows = self.SCENES[scene]
+        B, D, ps, P, n_pages = len(rows), 8, 4, 4, 10
+        rng = np.random.default_rng(hkv)
+        tables = np.full((B, P), -1, np.int32)
+        for b, (_, pages) in enumerate(rows):
+            tables[b, :len(pages)] = pages
+        lengths = np.asarray([n for n, _ in rows], np.int32)
+        pool = jnp.asarray(rng.standard_normal((n_pages, hkv, ps, D)), dtype)
+        new = jnp.asarray(rng.standard_normal((B, hkv, D)), jnp.float32)
+        before = np.array(pool)
+        cast = np.asarray(new.astype(dtype))
+
+        write = jax.jit(paged_kv_write, donate_argnums=(0,))
+        out = np.asarray(write(pool, new, jnp.asarray(tables),
+                               jnp.asarray(lengths)))
+        assert out.dtype == before.dtype
+
+        want = before.copy()
+        null_pages = []   # what page 0 may hold: one parked row's write
+        for b in range(B):
+            page, slot = tables[b, lengths[b] // ps], lengths[b] % ps
+            if page < 0:
+                null_pages.append(before[0].copy())
+                null_pages[-1][:, slot] = cast[b]
+            else:
+                want[page, :, slot] = cast[b]
+        bits = lambda a: a.view(np.uint16 if a.itemsize == 2 else np.uint32)
+        np.testing.assert_array_equal(bits(out[1:]), bits(want[1:]))
+        assert any((bits(out[0]) == bits(pg)).all()
+                   for pg in null_pages or [before[0]])
