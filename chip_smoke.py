@@ -8,8 +8,8 @@ with random weights made from a seed:
 
 - train: GPTForCausalLM + GPTPretrainingCriterion + amp.decorate(O2, bf16) +
   AdamW(bf16 moments) + per-layer recompute + DistributedTrainStep on a
-  one-device mesh, batch 4x2048, 24 layers — the recipe of bench.py's
-  gpt3_1p3b rung. A few steps on one fixed batch: every loss finite, the
+  one-device mesh, batch 4x2048, 24 layers — the recipe of the benchmark's
+  `train-xl-2k` cell. A few steps on one fixed batch: every loss finite, the
   last below the first.
 - serve: the same model class in bf16 behind PagedServingEngine (page size
   32, a pool sized from free HBM), prompts in two prefill buckets, one pair
@@ -64,7 +64,7 @@ EXPECTED_KERNELS = {
 }
 
 SIZES = {
-    # gpt3_1p3b width; bench.py's gpt3_1p3b rung shape
+    # gpt3_1p3b width; the shape of the benchmark's train-xl-2k cell
     "chip": dict(batch=4, seq=2048, steps=4, four_chip_layers=8,
                  max_batch=8, max_seq_len=2048, page_size=32, max_new=16,
                  warm_new=2, short=(300, 300, 450), shared=256,

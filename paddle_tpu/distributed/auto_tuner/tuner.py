@@ -303,6 +303,15 @@ class AutoTuner:
         self.history_cfgs.append(cfg)
 
 
+def _timed_steps(step, ids, labels, steps):
+    """(last loss, seconds a step) over `steps` steps of a warmed-up trial:
+    the one place a trial reads the clock."""
+    t0 = time.perf_counter()
+    for _i in range(steps):
+        loss = step(ids, labels)
+    return float(loss), (time.perf_counter() - t0) / steps
+
+
 def tune(model_builder, loss_fn, optimizer_builder, tuner_cfg, devices=None,
          steps=2, recorder=None):
     """Run the measurement loop: for each surviving config build the hybrid
@@ -358,11 +367,8 @@ def tune(model_builder, loss_fn, optimizer_builder, tuner_cfg, devices=None,
                 ids = paddle.to_tensor(rng.integers(0, vocab, (gbs, seq)))
                 labels = paddle.to_tensor(rng.integers(0, vocab, (gbs, seq)))
                 _ = float(step(ids, labels))  # compile + warmup
-                t0 = time.perf_counter()
-                for _i in range(steps):
-                    loss = step(ids, labels)
-                entry["loss"] = float(loss)
-                entry["step_time"] = (time.perf_counter() - t0) / steps
+                entry["loss"], entry["step_time"] = _timed_steps(
+                    step, ids, labels, steps)
             except Exception as e:  # OOM / infeasible compile
                 msg = str(e).lower()
                 entry["error"] = ("oom" if "resource exhausted" in msg

@@ -3,8 +3,7 @@ layout plans, elastic plan adoption (ROADMAP item 3; docs/PLANNER.md).
 
 - cost_model.py: the analytic roofline (compute + pipeline bubble +
   per-axis collective volumes discounted by the MEASURED overlap_fraction
-  from step-timeline history) and the chip spec table bench.py's MFU
-  denominator resolves through.
+  from step-timeline history) and the chip spec table (`chip_specs`).
 - planner.py: rank the full candidate grid analytically, hand only a
   top-K shortlist to the auto-tuner's measurement loop, record
   predicted-vs-measured error per trial.
@@ -14,7 +13,6 @@ layout plans, elastic plan adoption (ROADMAP item 3; docs/PLANNER.md).
 
 from .cost_model import (
     CHIP_SPECS,
-    PEAK_BF16_FLOPS,
     CostModel,
     chip_specs,
     measured_overlap_fraction,
@@ -31,7 +29,6 @@ from .planner import (
 
 __all__ = [
     "CHIP_SPECS",
-    "PEAK_BF16_FLOPS",
     "CostModel",
     "chip_specs",
     "measured_overlap_fraction",

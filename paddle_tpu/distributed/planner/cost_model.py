@@ -5,21 +5,20 @@ Predicts per-config step time as
     total = compute + bubble + exposed_comm
 
 * **compute** — a roofline max(FLOPs / (ndev * peak * mfu), hbm_bytes / hbm_bw)
-  over the decoder FLOPs formula bench.py uses for its MFU denominator
-  (6ND + attention quadratic), with a 4/3 recompute multiplier (recompute
-  re-runs the forward inside the backward: 8N vs 6N per token).
+  over the decoder FLOPs formula (6ND + attention quadratic), with a 4/3
+  recompute multiplier (recompute re-runs the forward inside the backward:
+  8N vs 6N per token).
 * **bubble** — the 1F1B pipeline bubble `compute * (pp-1)/n_micro`
   (arxiv 1909.09756 hand-tuned exactly this trade on TPU-v3 pods).
 * **exposed_comm** — per-axis collective byte volumes over ICI, plus a
   per-collective launch latency `alpha` (the term that dominates at small
   message sizes), discounted by the MEASURED `overlap_fraction` from the
-  step-timeline JSONL when BENCH history is available — the measured half
+  step-timeline JSONL when such history is available — the measured half
   of the hybrid (arxiv 2011.03641: pod-scale loss is mostly exposed
   collectives, which is precisely what overlap_fraction tracks).
 
-The table below is THE peak table: bench.py's `_peak_flops()` resolves
-through `PEAK_BF16_FLOPS`, so the bench MFU denominator and the planner's
-compute term can never disagree about what a chip can do.
+The table below is the program's one chip table (`chip_specs`); the
+benchmark keeps its own published peaks in `benchmark/peaks.json`.
 
 Byte-volume conventions (documented in docs/PLANNER.md):
 - ring all-reduce moves `2*(g-1)/g * bytes` per participant, reduce-scatter
@@ -40,7 +39,7 @@ from __future__ import annotations
 import json
 import os
 
-__all__ = ["CHIP_SPECS", "PEAK_BF16_FLOPS", "chip_specs", "CostModel",
+__all__ = ["CHIP_SPECS", "chip_specs", "CostModel",
            "measured_overlap_fraction"]
 
 # chip kind -> (peak bf16 FLOP/s, HBM bytes/s, ICI bytes/s) per chip
@@ -58,8 +57,6 @@ CHIP_SPECS = {
     "TPU7x": (2307e12, 7.40e12, 1.20e12),
 }
 
-# chip kind -> peak bf16 FLOP/s (bench.py imports this as its _PEAK table)
-PEAK_BF16_FLOPS = {k: v[0] for k, v in CHIP_SPECS.items()}
 
 def chip_specs(chip=None):
     """(peak_flops, hbm_Bps, ici_Bps, kind) for a chip, named either by a
@@ -85,8 +82,8 @@ def chip_specs(chip=None):
 
 def measured_overlap_fraction(paths=None):
     """The measured half of the hybrid: aggregate comm/compute
-    `overlap_fraction` out of step-timeline JSONL records (bench.py
-    --emit-metrics) and/or BENCH_*.json perf lines.
+    `overlap_fraction` out of step-timeline JSONL records
+    (`observability.enable_step_timeline(jsonl_path=...)`).
 
     `paths`: a path, a list of paths, or None (read the os.pathsep-separated
     PADDLE_TPU_PLAN_OVERLAP_JSONL env). Returns (fraction, source) or
@@ -98,7 +95,7 @@ def measured_overlap_fraction(paths=None):
         paths = [p for p in env.split(os.pathsep) if p]
     elif isinstance(paths, str):
         paths = [paths]
-    overlaps, fracs = [], []
+    overlaps = []
     for path in paths:
         if not path or not os.path.exists(path):
             continue
@@ -115,24 +112,12 @@ def measured_overlap_fraction(paths=None):
                     continue
                 if isinstance(rec.get("overlap"), dict):
                     overlaps.append(rec["overlap"])
-                elif "overlap_fraction" in rec:
-                    f_ = float(rec["overlap_fraction"])
-                    # overlap_stats reports 1.0 for a ZERO-comm step
-                    # ("nothing was exposed"); a bare perf line carries no
-                    # comm_s to tell that sentinel from genuinely perfect
-                    # overlap, and taking it at face value would rank
-                    # pod-scale meshes as if collectives were free — skip it
-                    if f_ < 1.0:
-                        fracs.append(f_)
     if overlaps:
         from ...observability.spans import aggregate_overlap
 
         agg = aggregate_overlap(overlaps)
         if agg["comm_s"] > 0:
             return agg["fraction"], f"step_timeline:{len(overlaps)}_records"
-    if fracs:
-        return (round(sum(fracs) / len(fracs), 6),
-                f"bench_lines:{len(fracs)}_records")
     return None, None
 
 
@@ -149,9 +134,8 @@ class CostModel:
     peak_flops, hbm_bandwidth, ici_bandwidth : float | None
         Explicit overrides of the spec-table numbers.
     mfu : float
-        Achievable fraction of peak for the compute term (calibration knob;
-        0.4 tracks the measured gpt3 ladder). Affects absolute predictions,
-        not the ranking.
+        Achievable fraction of peak for the compute term (calibration
+        knob). Affects absolute predictions, not the ranking.
     alpha : float
         Per-collective launch latency in seconds. This is what separates
         the latency-bound regime (tiny messages: collective COUNT dominates)

@@ -3,7 +3,7 @@
 Every chip-tool call starts on a fresh machine, and every process of a
 command compiles the same programs again; a cold gpt3_1p3b train step plus
 the serving programs is minutes of XLA/Mosaic compile. The entry points
-(chip_smoke.py, bench.py, tools/) call `place_compile_cache()` once, before
+(chip_smoke.py, benchmark/run.py) call `place_compile_cache()` once, before
 their first compilation.
 
 The cache directory is part of the cache key, so it must not move between

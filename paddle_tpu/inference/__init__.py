@@ -18,7 +18,7 @@ from ..framework.core import Tensor
 from .. import jit as _jit
 
 __all__ = ["Config", "Predictor", "create_predictor", "PlaceType", "DataType",
-           "create_serving_engine"]
+           "PagedServingEngine", "GenerationRequest"]
 
 
 class PlaceType:
@@ -141,16 +141,5 @@ def create_predictor(config: Config) -> Predictor:
     return Predictor(config)
 
 from . import serving  # noqa: E402
-from .serving import ContinuousBatchingEngine, GenerationRequest  # noqa: E402
-
-
-def create_serving_engine(model, paged=True, **kw):
-    """Generation engine factory. paged=True (default) builds the
-    block-pool `PagedServingEngine` (docs/SERVING.md); paged=False the
-    dense-cache `ContinuousBatchingEngine` fallback. Keyword args pass
-    through to the chosen engine."""
-    if paged:
-        from .paged import PagedServingEngine
-
-        return PagedServingEngine(model, **kw)
-    return ContinuousBatchingEngine(model, **kw)
+from .serving import GenerationRequest  # noqa: E402
+from .paged import PagedServingEngine  # noqa: E402

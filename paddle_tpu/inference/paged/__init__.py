@@ -4,7 +4,7 @@
   `paged_decode_attention` kernel consumes, with free-list allocation,
   refcounted prefix sharing and copy-on-write; `quantized=True` stores int8
   payloads + per-(page, head) f32 scales for the dequant-fused kernel
-  (`PADDLE_TPU_KV_QUANT`). One cache spec per layer: `PagedKV` pages for
+  (`PagedServingEngine(kv_quant=True)`). One cache spec per layer: `PagedKV` pages for
   an attention layer, a `RowState` slot per decode row for a recurrent one.
 - `TwoQueueScheduler` — power-of-two prefill length buckets + decode/resume
   queues, admitting against a page-budget watermark.
@@ -12,8 +12,8 @@
   preemption to a host spill buffer and SLO metrics through the
   observability registry.
 
-The dense `ContinuousBatchingEngine` remains the fallback:
-`paddle_tpu.inference.create_serving_engine(model, paged=False)`.
+The dense `paddle_tpu.inference.serving.ContinuousBatchingEngine` is the
+reference the tests hold this engine to, token for token; nothing selects it.
 """
 
 from .block_pool import BlockPool, PagedKV, RowState, prefix_page_key

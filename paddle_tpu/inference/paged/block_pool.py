@@ -44,7 +44,7 @@ by a live block table. Parked decode rows (batch padding) route their
 per-step K/V writes there, so the fixed-shape decode program needs no
 conditional writes.
 
-Quantized layout (`quantized=True`, the `PADDLE_TPU_KV_QUANT` serving fast
+Quantized layout (`quantized=True`, the `kv_quant=True` serving fast
 path): page payloads are int8 with one f32 dequant scale per (page, head)
 stored alongside (`scales[layer] = (k_scale, v_scale)`, each
 [n_pages, Hkv]); dequant is `payload * scale`, fused into the Pallas decode

@@ -36,7 +36,6 @@ always runs, because the state after the prompt is the request's own.
 
 from __future__ import annotations
 
-import os
 import time
 
 import jax
@@ -81,12 +80,11 @@ class PagedServingEngine(_ServingEngineBase):
     `page_size`, `num_pages` (default: the dense engine's HBM budget,
     `max_batch_size * max_seq_len` tokens worth of pages), `prefix_sharing`,
     `watermark_pages`, `preemption`, and the quantized fast path:
-    `kv_quant` (default: the `PADDLE_TPU_KV_QUANT` env toggle, captured at
-    construction — trace time for the decode program) stores int8 pages +
-    per-(page, head) f32 scales and decodes through the dequant-fused Pallas
-    kernel; `kv_budget_bytes` sizes the pool by HBM bytes instead of page
-    count (the equal-budget A/B knob — an int8 pool fits ~4x the pages of
-    an f32 one in the same budget).
+    `kv_quant` (fixed at construction — trace time for the decode
+    program) stores int8 pages + per-(page, head) f32 scales and decodes
+    through the dequant-fused Pallas kernel; `kv_budget_bytes` sizes the
+    pool by HBM bytes instead of page count (the equal-budget A/B knob — an
+    int8 pool fits ~4x the pages of an f32 one in the same budget).
     """
 
     engine_label = "paged"
@@ -94,15 +92,13 @@ class PagedServingEngine(_ServingEngineBase):
     def __init__(self, model, max_batch_size=8, max_seq_len=512, seed=0,
                  page_size=16, num_pages=None, prefix_sharing=True,
                  watermark_pages=None, preemption=True,
-                 max_prefill_buckets=None, kv_quant=None,
-                 kv_budget_bytes=None, serve_w8=None):
+                 max_prefill_buckets=None, kv_quant=False,
+                 kv_budget_bytes=None, serve_w8=False):
         super().__init__(model, max_batch_size, max_seq_len, seed,
                          max_prefill_buckets, serve_w8=serve_w8)
         cfg = self.cfg
         self.ps = int(page_size)
         self.P = _pages_for_prompt(self.S, self.ps)  # block-table width
-        if kv_quant is None:
-            kv_quant = os.environ.get("PADDLE_TPU_KV_QUANT", "0") == "1"
         self.kv_quant = bool(kv_quant)
         if num_pages is not None and kv_budget_bytes is not None:
             raise ValueError(
@@ -159,8 +155,8 @@ class PagedServingEngine(_ServingEngineBase):
         # program (incubate/.../held_moe.STAT_NAMES)
         self._moe_groups = getattr(model, "moe_groups", 0)
         self.pool.update_gauges()
-        # materialize the pool/preemption series at zero so --emit-metrics
-        # JSONL carries them from the first tick, not only after the first
+        # materialize the pool/preemption series at zero so an exported
+        # snapshot carries them from the first tick, not only after the first
         # event (a dashboard must distinguish "no preemptions" from
         # "no data")
         m = serving_metrics()
@@ -368,17 +364,6 @@ class PagedServingEngine(_ServingEngineBase):
         elif self.pool.is_registered(page):
             self.pool.unregister_page(page)
 
-    # -- token emission -------------------------------------------------- #
-
-    def _emit(self, row, tok):
-        req = self.active[row]
-        req.generated.append(int(tok))
-        self._note_token(req, tok)
-        done, truncated = self._retire_decision(req, tok, self.lengths[row])
-        if done:
-            self._note_finished(req, truncated)
-            self._release_row(row)
-
     def _note_routing(self, stats):
         """One decode tick's routing counts (held_moe.STAT_NAMES, summed
         over the layers) into the serving metrics."""
@@ -460,21 +445,6 @@ class PagedServingEngine(_ServingEngineBase):
             greedy_np, stats = jax.device_get((greedy_tok, stats))
         if stats:
             self._note_routing(stats[0])
-        out = {}
-        with span("emit", rows=len(live)) as sp:
-            sampled = 0
-            for i in live:
-                req = self.active[i]
-                if req.temperature == 0.0:
-                    tok = int(greedy_np[i])
-                else:
-                    sampled += 1
-                    with span("sample", rid=req.req_id):
-                        tok = self._pick_token(logits[i], req)
-                self.lengths[i] += 1
-                self.last_tok[i] = tok
-                out[req.req_id] = tok
-                self._emit(i, tok)
-            sp.set(sampled_rows=sampled)
+        out = self._emit_decoded(live, greedy_np, logits)
         self.pool.update_gauges()
         return out

@@ -13,20 +13,21 @@ wants.
 
 Two engines share the scaffolding in `_ServingEngineBase`:
 
+- `PagedServingEngine` (`paddle_tpu.inference.paged`) — THE serving
+  engine: block-pool paged KV cache with prefix sharing, preemption and a
+  two-queue scheduler; HBM is allocated per page actually used, not per
+  slot capacity. See docs/SERVING.md.
 - `ContinuousBatchingEngine` (this module) — dense per-slot KV caches,
-  every slot reserves max_seq_len rows of HBM. Simple, and the fallback
-  (`inference.create_serving_engine(..., paged=False)`).
-- `PagedServingEngine` (`paddle_tpu.inference.paged`) — block-pool paged
-  KV cache with prefix sharing, preemption and a two-queue scheduler; HBM
-  is allocated per page actually used, not per slot capacity. See
-  docs/SERVING.md.
+  every slot reserves max_seq_len rows of HBM. It is the dense REFERENCE
+  the paged engine's parity tests compare against token for token
+  (tests/test_serving_paged.py, test_serving.py, test_tracing_spans.py),
+  not a fallback: nothing selects it and no benchmark cell runs it.
 """
 
 from __future__ import annotations
 
 import collections
 import itertools
-import os
 import time
 
 import jax
@@ -88,17 +89,14 @@ class _ServingEngineBase:
     engine_label = "base"
 
     def __init__(self, model, max_batch_size=8, max_seq_len=512, seed=0,
-                 max_prefill_buckets=None, serve_w8=None):
+                 max_prefill_buckets=None, serve_w8=False):
         model.eval()
-        # weight-only int8 serving (PADDLE_TPU_SERVE_W8, captured HERE —
-        # construction is trace time for every program this engine compiles,
-        # the PR-7/12/14 toggle rule): swap the model's Linear-family
-        # projections for QuantizedLinear before the param/buffer snapshot,
-        # so the decode/prefill programs carry int8 weights + f32 scales
-        # instead of full-precision weight HBM. In-place on `model`
+        # weight-only int8 serving: swap the model's Linear-family
+        # projections for QuantizedLinear before the param/buffer snapshot
+        # (construction is trace time for every program this engine
+        # compiles), so the decode/prefill programs carry int8 weights + f32
+        # scales instead of full-precision weight HBM. In-place on `model`
         # (idempotent) — build a fresh model per engine when A/B-ing.
-        if serve_w8 is None:
-            serve_w8 = os.environ.get("PADDLE_TPU_SERVE_W8", "0") == "1"
         self.serve_w8 = bool(serve_w8)
         if self.serve_w8:
             from ..quantization import ptq_convert_for_serving
@@ -292,6 +290,42 @@ class _ServingEngineBase:
                 tick.seconds, engine=self.engine_label)
         return out
 
+    # -- token emission -------------------------------------------------- #
+
+    def _emit(self, row, tok):
+        req = self.active[row]
+        req.generated.append(int(tok))
+        self._note_token(req, tok)
+        done, truncated = self._retire_decision(req, tok, self.lengths[row])
+        if done:
+            self._note_finished(req, truncated)
+            self._release_row(row)
+
+    def _emit_decoded(self, live, greedy_np, logits) -> dict:
+        """The decoded tick's tail: every live row's token (greedy from the
+        host copy `greedy_np`, a sampled row's from its own slice of the
+        device `logits`), lengths and last tokens advanced, the token
+        emitted. Returns {req_id: token}."""
+        out = {}
+        with span("emit", rows=len(live)) as sp:
+            sampled = 0
+            for i in live:
+                req = self.active[i]
+                if req.temperature == 0.0:
+                    tok = int(greedy_np[i])
+                else:
+                    # per-row device gather + on-device categorical: only
+                    # the sampled token id is transferred, not [B, vocab]
+                    sampled += 1
+                    with span("sample", rid=req.req_id):
+                        tok = self._pick_token(logits[i], req)
+                self.lengths[i] += 1
+                self.last_tok[i] = tok
+                out[req.req_id] = tok
+                self._emit(i, tok)
+            sp.set(sampled_rows=sampled)
+        return out
+
     # subclass contract
     def has_work(self) -> bool:
         raise NotImplementedError
@@ -299,9 +333,14 @@ class _ServingEngineBase:
     def _step(self, tick) -> dict:
         raise NotImplementedError
 
+    def _release_row(self, row):
+        """Free what a retired request's row held."""
+        raise NotImplementedError
+
 
 class ContinuousBatchingEngine(_ServingEngineBase):
-    """Admit-while-decoding scheduler over a slotted DENSE KV cache.
+    """Admit-while-decoding scheduler over a slotted DENSE KV cache: the
+    reference `PagedServingEngine` is held to in the tests, token for token.
 
     add_request() enqueues; step() admits waiting requests into free slots
     (prefill) and advances every live slot by one token (single fixed-shape
@@ -311,7 +350,7 @@ class ContinuousBatchingEngine(_ServingEngineBase):
     engine_label = "dense"
 
     def __init__(self, model, max_batch_size=8, max_seq_len=512, seed=0,
-                 max_prefill_buckets=None, serve_w8=None):
+                 max_prefill_buckets=None, serve_w8=False):
         super().__init__(model, max_batch_size, max_seq_len, seed,
                          max_prefill_buckets, serve_w8=serve_w8)
         cfg = self.cfg
@@ -367,15 +406,9 @@ class ContinuousBatchingEngine(_ServingEngineBase):
             self._emit(slot, first)
         return picked
 
-    def _emit(self, slot, tok):
-        req = self.active[slot]
-        req.generated.append(int(tok))
-        self._note_token(req, tok)
-        done, truncated = self._retire_decision(req, tok, self.lengths[slot])
-        if done:
-            self._note_finished(req, truncated)
-            self.active[slot] = None
-            self.lengths[slot] = 0
+    def _release_row(self, slot):
+        self.active[slot] = None
+        self.lengths[slot] = 0
 
     # ------------------------------------------------------------------ #
 
@@ -415,22 +448,4 @@ class ContinuousBatchingEngine(_ServingEngineBase):
             self.last_logits = logits  # device array; tests probe divergence
         with span("host_read"):
             greedy_np = np.asarray(greedy_tok)
-        out = {}
-        with span("emit", rows=len(live)) as sp:
-            sampled = 0
-            for i in live:
-                req = self.active[i]
-                if req.temperature == 0.0:
-                    tok = int(greedy_np[i])
-                else:
-                    # per-row device gather + on-device categorical: only
-                    # the sampled token id is transferred, not [B, vocab]
-                    sampled += 1
-                    with span("sample", rid=req.req_id):
-                        tok = self._pick_token(logits[i], req)
-                self.lengths[i] += 1
-                self.last_tok[i] = tok
-                out[req.req_id] = tok
-                self._emit(i, tok)
-            sp.set(sampled_rows=sampled)
-        return out
+        return self._emit_decoded(live, greedy_np, logits)

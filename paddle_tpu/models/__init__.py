@@ -1,4 +1,4 @@
-"""Flagship model families (the training configs from BASELINE.md).
+"""Flagship model families (the north-star training configs, SURVEY.md).
 
 The reference ships vision models in-tree (python/paddle/vision/models/) and
 serves LLMs through fleet-parallel layer building blocks

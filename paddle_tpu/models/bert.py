@@ -1,5 +1,5 @@
 """BERT family (reference API: the PaddleNLP-style BertModel the reference
-ecosystem trains with fleet data-parallel — BASELINE.md config "BERT-base /
+ecosystem trains with fleet data-parallel — the north-star config "BERT-base /
 ERNIE-1.0 pretraining (fleet data-parallel only)"; encoder blocks are
 paddle.nn.TransformerEncoder, python/paddle/nn/layer/transformer.py:697).
 

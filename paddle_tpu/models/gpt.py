@@ -553,7 +553,7 @@ class GPTPretrainingCriterion(nn.Layer):
 
 
 # ----------------------------------------------------------------------- #
-# presets (sizes per GPT-3 paper table 2.1 — the BASELINE.md configs)
+# presets (sizes per GPT-3 paper table 2.1)
 # ----------------------------------------------------------------------- #
 
 

@@ -1,5 +1,5 @@
 """LLaMA family = the GPT decoder with RMSNorm + SwiGLU + RoPE + GQA + untied
-embeddings (BASELINE.md sharding-stage-2/3 + flash_attn configs)."""
+embeddings (the north-star sharding-stage-2/3 + flash_attn configs)."""
 
 from __future__ import annotations
 

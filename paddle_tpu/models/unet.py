@@ -1,4 +1,4 @@
-"""Diffusion UNet (BASELINE.md config "Stable Diffusion UNet: conv +
+"""Diffusion UNet (north-star config "Stable Diffusion UNet: conv +
 cross-attn"; architecture per the latent-diffusion UNet, built on
 paddle_tpu.nn — residual GroupNorm/SiLU conv blocks, self+cross attention
 at low resolutions, sinusoidal timestep embedding, skip connections).

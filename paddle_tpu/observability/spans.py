@@ -296,8 +296,8 @@ def overlap_stats(comm_tasks, spans) -> dict:
 def aggregate_overlap(overlaps) -> dict:
     """Roll per-step `overlap` dicts into one: fraction = total covered /
     total comm, 1.0 when there was no comm at all. The ONE definition of
-    the roll-up convention — bench.py, `fleet_step_summary`, and
-    tools/overlap_report.py all aggregate through here."""
+    the roll-up convention — `fleet_step_summary` and the planner's
+    `measured_overlap_fraction` both aggregate through here."""
     overlaps = list(overlaps)
     comm = sum(o.get("comm_s", 0.0) for o in overlaps)
     covered = sum(o.get("covered_s", 0.0) for o in overlaps)
@@ -362,9 +362,9 @@ class StepTimeline:
     """Stitch one structured record per training step.
 
     Install it (`enable_step_timeline()` or `.install()`), then have the
-    step driver — `hapi.Model.fit`, `ResilientTrainer`, `bench.py
-    --emit-metrics` — call `step_begin(i)` / `step_end()`. Everything else
-    is collected passively through chained hooks:
+    step driver — `hapi.Model.fit`, `ResilientTrainer` — call
+    `step_begin(i)` / `step_end()`. Everything else is collected passively
+    through chained hooks:
 
     - host syncs via `framework.core.add_sync_observer` (composes with the
       graftlint runtime checks — neither clobbers the other);

@@ -14,8 +14,8 @@ never break a run.
 Every decision — tuned or default — is recorded for telemetry:
 
 - `chosen_tiles()` returns the last tile picked per kernel plus per-kernel
-  hit/miss/fallback counts; the StepTimeline folds it into each step record
-  and bench.py into the perf line (`autotuned_tiles=`).
+  hit/miss/fallback counts; the StepTimeline folds it into each step
+  record.
 - a `pallas_autotune_{hits,misses,fallbacks}_total{kernel=}` counter family
   lands in the observability registry. A *fallback* is the silent failure
   mode this PR makes visible: tuning enabled, lookup under trace
@@ -216,8 +216,7 @@ def chosen_tiles() -> dict:
     counts only exist once tuning is enabled). `source`: "tuned" (cache
     winner), "measured" (swept this call), "fixed" (single legal candidate,
     nothing tunable at launch), "default" (tuning disabled or trace-time
-    miss). The StepTimeline attaches this snapshot to each step record;
-    bench.py prints it as `autotuned_tiles=`."""
+    miss). The StepTimeline attaches this snapshot to each step record."""
     out = {}
     for kernel, tile in list(_chosen.items()):
         rec = dict(tile)

@@ -81,9 +81,8 @@ def _safe_softmax():
 def _block_sizes(sq, skv, d=None):
     """Default tile sizes. Large blocks matter more than MXU-perfect ones on
     TPU: the grid is executed sequentially per core, and the per-tile VMEM
-    streaming rate is the binding constraint — 512x1024 measured best at the
-    GPT-125M shape on a v5e (tools/attn_ab.py), using <6MB of VMEM. Head
-    dims >=256 halve the cap to stay inside VMEM with double buffering.
+    streaming rate is the binding constraint — 512x1024 uses <6MB of VMEM.
+    Head dims >=256 halve the cap to stay inside VMEM with double buffering.
 
     PADDLE_TPU_FLASH_BLOCK=<n> overrides the cap (hardware escape hatch —
     e.g. =128 restores the round-2 tiling without a code change)."""

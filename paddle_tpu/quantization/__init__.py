@@ -187,7 +187,7 @@ class PTQ:
 
 
 def ptq_convert_for_serving(model, bits=8):
-    """Weight-only int8 serving convert (the `PADDLE_TPU_SERVE_W8` pass):
+    """Weight-only int8 serving convert (the engines' `serve_w8=True` pass):
     swap every Linear-family projection under `model` — `nn.Linear` plus the
     TP-sharded `ColumnParallelLinear`/`RowParallelLinear` the GPT/LLaMA
     decoder stacks are built from — for a `QuantizedLinear` holding int8

@@ -1,4 +1,4 @@
-"""BERT + diffusion UNet model families (BASELINE.md configs: "BERT-base /
+"""BERT + diffusion UNet model families (north-star configs: "BERT-base /
 ERNIE-1.0 pretraining (fleet data-parallel only)" and "Stable Diffusion
 UNet: conv + cross-attn")."""
 

@@ -237,6 +237,39 @@ class TestDistributedTrainStep:
         l0 = float(step(paddle.randn([8, 16]), paddle.randn([8, 16])).numpy())
         assert np.isfinite(l0)
 
+    def test_low_mem_recipe_trains(self):
+        """bf16 params (amp.decorate O2) + bf16 AdamW moments + recompute:
+        the recipe both training cells run (a 1.3B model on one 16 GB chip),
+        on a tiny config."""
+        import paddle_tpu.amp as amp
+        from paddle_tpu.models import (GPTConfig, GPTForCausalLM,
+                                       GPTPretrainingCriterion)
+
+        paddle.seed(0)
+        cfg = GPTConfig(hidden_size=64, num_layers=2, num_heads=2,
+                        vocab_size=512, max_position_embeddings=64)
+        cfg.use_recompute = True
+        model = GPTForCausalLM(cfg)
+        crit = GPTPretrainingCriterion(cfg)
+        amp.decorate(model, level="O2", dtype="bfloat16")
+        opt = paddle.optimizer.AdamW(learning_rate=1e-4,
+                                     moment_dtype="bfloat16",
+                                     parameters=model.parameters())
+        step = dist.DistributedTrainStep(
+            model, lambda lg, lb: crit(lg, lb), opt,
+            mesh=dist.build_mesh(devices=jax.devices()[:1]))
+        rng = np.random.default_rng(0)
+        ids = paddle.to_tensor(rng.integers(0, cfg.vocab_size, (2, 16)))
+        labels = paddle.to_tensor(rng.integers(0, cfg.vocab_size, (2, 16)))
+        dts = {str(v.dtype) for v in step.params.values()}
+        assert "bfloat16" in dts, dts
+        mdts = {str(st["m"].dtype) for st in step.opt_states.values()
+                if "m" in st}
+        assert mdts == {"bfloat16"}, mdts
+        losses = [float(step(ids, labels)) for _ in range(4)]
+        assert all(np.isfinite(v) for v in losses), losses
+        assert losses[-1] < losses[0], losses
+
 
 class TestGroupShardedAPI:
     def test_levels(self):

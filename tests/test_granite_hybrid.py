@@ -14,7 +14,6 @@ or expert moves a row by a good part of one.
 """
 
 import dataclasses
-import functools
 import importlib.util
 import json
 import os
@@ -424,32 +423,8 @@ def test_the_cells_rehearsal_runs_end_to_end_and_is_correct():
     assert line["rehearsal"]["would_report"] == ["serve_tok_s", "setup_s"]
 
 
-@functools.lru_cache(maxsize=None)
-def _manifest_tests():
-    path = os.path.join(ROOT, "benchmark", "tests", "test_benchmark.py")
-    spec = importlib.util.spec_from_file_location("_bm_manifest_tests", path)
-    module = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(module)
-    return module
-
-
-MANIFEST_TESTS = [
-    "test_manifest_has_exactly_the_contract_keys",
-    "test_every_name_and_layer_is_an_identifier",
-    "test_units_directions_sources_and_one_line_texts",
-    "test_moves_is_an_end_to_end_metric_of_every_cell_that_reports_it",
-    "test_every_cell_reports_setup_another_metric_and_a_layer_metric",
-    "test_every_file_a_cell_names_exists",
-    "test_four_chip_cells_stay_within_a_quarter",
-    "test_layer_metric_files_agree_with_the_manifest",
-]
-
-
-@pytest.mark.parametrize("name", MANIFEST_TESTS)
-def test_manifest_stays_sound_with_the_new_entries(name):
-    """`benchmark/tests` is outside tier-1: its manifest checks run here
-    too, on the manifest as this PR leaves it."""
+def test_the_cell_is_a_workload_of_the_manifest():
+    """The manifest's own checks run under `tests/test_benchmark_suite.py`."""
     with open(os.path.join(ROOT, "BENCHMARK.json")) as f:
         manifest = json.load(f)
     assert "serve-granite-h-sat" in [w["name"] for w in manifest["workloads"]]
-    getattr(_manifest_tests(), name)(manifest)

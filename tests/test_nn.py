@@ -367,6 +367,27 @@ class TestOptimizers:
         total = np.sqrt(sum((g.numpy() ** 2).sum() for _, g in clipped))
         assert total <= 0.1 + 1e-5
 
+    def test_adamw_moment_dtype_matches_f32_compute(self):
+        """bf16-stored moments with f32 update compute should track the
+        all-f32 AdamW closely on an f32 param."""
+        rng = np.random.default_rng(0)
+        w0 = rng.normal(size=(32, 32)).astype(np.float32)
+
+        def run(moment_dtype):
+            w = paddle.to_tensor(w0.copy())
+            w.stop_gradient = False
+            o = paddle.optimizer.AdamW(learning_rate=1e-2, parameters=[w],
+                                       moment_dtype=moment_dtype)
+            for i in range(5):
+                ((w * w).sum()).backward()
+                o.step()
+                o.clear_grad()
+            return w.numpy()
+
+        ref = run(None)
+        low = run("bfloat16")
+        assert np.max(np.abs(ref - low)) < 1e-2, np.max(np.abs(ref - low))
+
     def test_lr_scheduler(self):
         sched = paddle.optimizer.lr.StepDecay(0.1, step_size=2, gamma=0.5)
         opt = paddle.optimizer.SGD(learning_rate=sched, parameters=[paddle.framework.core.Parameter(paddle.zeros([1])._value)])

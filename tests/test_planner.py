@@ -10,6 +10,7 @@ import json
 import os
 
 import numpy as np
+import pytest
 
 import jax
 
@@ -17,11 +18,13 @@ import paddle_tpu as paddle
 import paddle_tpu.distributed as dist
 import paddle_tpu.optimizer as opt
 from paddle_tpu.distributed.auto_tuner import tune
+from paddle_tpu.distributed.auto_tuner import tuner as tuner_mod
 from paddle_tpu.distributed.planner import (
     CostModel,
     MeshPlan,
     SpecLayout,
     analytic_plan,
+    chip_specs,
     measured_overlap_fraction,
     plan_and_tune,
     rank_candidates,
@@ -165,26 +168,23 @@ class TestCostModel:
         assert warm["overlap_fraction"] == 0.5
         assert warm["exposed_comm_s"] == cold["exposed_comm_s"] * 0.5
         assert warm["total_s"] < cold["total_s"]
-
-    def test_overlap_from_bench_perf_lines(self, tmp_path):
-        p = str(tmp_path / "bench.jsonl")
-        with open(p, "w") as f:
-            f.write(json.dumps({"metric": "mfu_x", "value": 0.5,
-                                "overlap_fraction": 0.8}) + "\n")
-            # 1.0 in a bare perf line is the ZERO-comm sentinel (cpu_smoke /
-            # single-device runs) — taking it as evidence would make the
-            # planner rank pod meshes as if collectives were free
-            f.write(json.dumps({"metric": "mfu_smoke", "value": 0.5,
-                                "overlap_fraction": 1.0}) + "\n")
-        frac, src = measured_overlap_fraction(p)
-        assert frac == 0.8 and "bench_lines:1" in src
-        sentinel_only = str(tmp_path / "smoke.jsonl")
-        with open(sentinel_only, "w") as f:
-            f.write(json.dumps({"metric": "mfu_smoke",
-                                "overlap_fraction": 1.0}) + "\n")
-        assert measured_overlap_fraction(sentinel_only) == (None, None)
+        # a file with no step record, and no file at all: no history
+        nothing = str(tmp_path / "nothing.jsonl")
+        with open(nothing, "w") as f:
+            f.write(json.dumps({"metric": "x", "overlap_fraction": 1.0})
+                    + "\n")
+        assert measured_overlap_fraction(nothing) == (None, None)
         assert measured_overlap_fraction(
             str(tmp_path / "missing.jsonl")) == (None, None)
+
+    def test_unknown_device_kind_has_no_peak(self):
+        """No peak, bandwidth or estimated time for a chip the table does
+        not know: an unknown kind is an error, never another chip's row."""
+        with pytest.raises(ValueError, match="TPU v9"):
+            chip_specs("TPU v9")
+        with pytest.raises(ValueError, match="cpu"):
+            chip_specs(jax.devices()[0])
+        assert chip_specs("TPU v5 lite")[0] == 197e12
 
 
 # --------------------------------------------------------------------------- #
@@ -209,13 +209,19 @@ class TestPlannerRanking:
         assert pruned, "grid should have infeasible points"
         assert all(rule.startswith("prune_by_") for _c, rule, _r in pruned)
 
-    def test_hybrid_shortlist_agrees_with_full_measurement(self):
+    @pytest.mark.parametrize("winner_in_top5", [True, False])
+    def test_hybrid_shortlist_agrees_with_full_measurement(
+            self, monkeypatch, winner_in_top5):
         """Acceptance, on the 8-device CPU mesh with a gpt tuner fixture:
         plan_and_tune times only the K=5 shortlist of the N>5 feasible
         grid points, records predicted-vs-measured error per trial, and —
         measuring the analytically-rejected remainder the old way — the
-        measured-best of the FULL grid sits inside the analytic top-K
-        (the planner would not have pruned away the winner)."""
+        measured-best of the FULL grid is found and is told apart from the
+        analytic top-K. The step times are the test's own (a CPU's wall
+        time under load ranks nothing): the trials still build, compile
+        and run, and the tuner's one clock reading hands out the chosen
+        times in the order the candidates are measured, so the winner is
+        known — inside the top five, or the last of the rejected."""
         from paddle_tpu.models import (GPTConfig, GPTForCausalLM,
                                        GPTPretrainingCriterion)
 
@@ -237,11 +243,23 @@ class TestPlannerRanking:
         n_candidates = len(ranked)
         assert n_candidates > 5, "grid too small to make top-K meaningful"
 
+        # plan_and_tune measures ranked[:5] in ranked order, tune() below
+        # the rest in list order: times[i] goes to the candidate of rank i
+        winner = 2 if winner_in_top5 else n_candidates - 1
+        times = [0.2 + 0.01 * i for i in range(n_candidates)]
+        times[winner] = 0.1
+        handed = iter(times)
+        monkeypatch.setattr(
+            tuner_mod, "_timed_steps",
+            lambda step, ids, labels, steps: (float(step(ids, labels)),
+                                              next(handed)))
+
         plan, best, rec = plan_and_tune(
             builder, loss, optb, t, top_k=5,
             devices=jax.devices(), steps=1)
         measured = [h for h in rec.history if h.get("step_time")]
         assert len(measured) == 5 < n_candidates
+        assert [h["step_time"] for h in measured] == times[:5]
         for h in measured:
             assert h["predicted_step_time"] > 0
             assert "prediction_error_pct" in h
@@ -251,6 +269,7 @@ class TestPlannerRanking:
         assert best is not None
         assert plan.source == "measured"
         assert plan.measured_step_time_s == best["step_time"]
+        assert plan.measured_step_time_s == min(times[:5])
         assert plan.num_devices == 8
 
         # the old exhaustive way, over just the rejected remainder
@@ -263,10 +282,14 @@ class TestPlannerRanking:
         key = lambda c: (c["dp_degree"], c["mp_degree"], c["pp_degree"],
                          c["sharding_degree"], c["micro_batch_size"])
         best_overall = min(all_measured, key=lambda h: h["step_time"])
+        assert key(best_overall) == key(ranked[winner][0])
         top_k_keys = {key(c) for c, _bd in ranked[:5]}
-        assert key(best_overall) in top_k_keys, (
-            f"measured best {key(best_overall)} not in analytic top-5 "
+        assert (key(best_overall) in top_k_keys) == winner_in_top5, (
+            f"measured best {key(best_overall)} against analytic top-5 "
             f"{sorted(top_k_keys)}")
+        # the shortlist's own winner is the best of what it measured
+        assert key(best) == key(
+            ranked[winner if winner_in_top5 else 0][0])
 
 
 # --------------------------------------------------------------------------- #

@@ -2,7 +2,7 @@
 touched by the test_quant_audio_text.py smoke. Pins the weight-quantization
 error bound, QuantizedLinear forward parity at int8 tolerance, PTQ convert
 semantics, and the ptq_convert_for_serving pass the serving engines run
-under PADDLE_TPU_SERVE_W8."""
+under the engines' serve_w8=True."""
 
 import numpy as np
 import pytest

@@ -1,4 +1,4 @@
-"""Quantized serving fast path (PADDLE_TPU_KV_QUANT / PADDLE_TPU_SERVE_W8):
+"""Quantized serving fast path (kv_quant=True / serve_w8=True):
 the int8 BlockPool layout with per-(page, head) scales, the running-abs-max
 paged_kv_write_q8 append, the dequant-fused Pallas decode kernel, and the
 PagedServingEngine over all three.
@@ -343,16 +343,15 @@ class TestQuantEngine:
                 for q, e in engines.items()}
         assert toks[True] == toks[False]
 
-    def test_prefix_sharing_and_cow_under_kv_quant(self, monkeypatch):
-        """Two identical prompts through the env toggle: pages share (hits),
-        the first divergent write copies (COW), and both requests emit
+    def test_prefix_sharing_and_cow_under_kv_quant(self):
+        """Two identical prompts over int8 pages: pages share (hits), the
+        first divergent write copies (COW), and both requests emit
         identical tokens — determinism makes shared int8 pages bit-equal."""
-        monkeypatch.setenv("PADDLE_TPU_KV_QUANT", "1")
         hits0 = _counter("serving_prefix_hits_total")
         cow0 = _counter("serving_cow_copies_total")
         eng = PagedServingEngine(_model(), max_batch_size=4, max_seq_len=64,
-                                 page_size=16, seed=3)
-        assert eng.kv_quant  # captured from env at construction
+                                 page_size=16, seed=3, kv_quant=True)
+        assert eng.kv_quant
         prompt = np.random.default_rng(1).integers(1, 1000, 10).astype(
             np.int32)
         eng.add_request(prompt, max_new_tokens=4)
@@ -429,14 +428,14 @@ class TestQuantEngine:
                                page_size=16, num_pages=100,
                                kv_budget_bytes=200_000)
 
-    def test_serve_w8_weight_bytes_drop_and_tokens_flow(self, monkeypatch):
-        monkeypatch.setenv("PADDLE_TPU_SERVE_W8", "1")
+    def test_serve_w8_weight_bytes_drop_and_tokens_flow(self):
         model = _model()
         dense_bytes = sum(
             int(np.prod(p._value.shape)) * p._value.dtype.itemsize
             for _, p in model.named_parameters())
         eng = PagedServingEngine(model, max_batch_size=2, max_seq_len=64,
-                                 page_size=16, seed=3, kv_quant=True)
+                                 page_size=16, seed=3, kv_quant=True,
+                                 serve_w8=True)
         assert eng.serve_w8
         served = (sum(int(np.prod(v.shape)) * v.dtype.itemsize
                       for v in eng.params.values())
