@@ -43,7 +43,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from ...observability.spans import span
-from ..serving import GenerationRequest, _bucket, _ServingEngineBase
+from ..serving import _bucket, _ServingEngineBase
 from ..slo import serving_metrics
 from .block_pool import BlockPool, PagedKV, RowState, prefix_page_key
 from .scheduler import TwoQueueScheduler, _pages_for_prompt
@@ -140,9 +140,6 @@ class PagedServingEngine(_ServingEngineBase):
         self.sched = TwoQueueScheduler(self.ps, watermark_pages)
         self.preemption = bool(preemption)
         self.tables = np.full((self.B, self.P), -1, np.int32)
-        self.lengths = np.zeros(self.B, np.int32)
-        self.active: list[GenerationRequest | None] = [None] * self.B
-        self.last_tok = np.zeros(self.B, np.int32)
         # the paged-decode kernel's grid, for the `decode_dispatch` span:
         # static, so computed once
         from ...ops.pallas.decode_attention import pages_per_step
@@ -235,8 +232,7 @@ class PagedServingEngine(_ServingEngineBase):
             req, self.lengths[row], self.last_tok[row], kv_host, keys,
             state_host))
         self.tables[row, :] = -1
-        self.active[row] = None
-        self.lengths[row] = 0
+        self._vacate(row)
         m = serving_metrics()
         m["preemptions"].inc()
         m["preempted_pages"].inc(len(pages))
@@ -252,8 +248,7 @@ class PagedServingEngine(_ServingEngineBase):
             if p >= 0:
                 self.pool.release(int(p))
         self.tables[row, :] = -1
-        self.active[row] = None
-        self.lengths[row] = 0
+        self._vacate(row)
 
     # -- admission ------------------------------------------------------- #
 
@@ -318,9 +313,7 @@ class PagedServingEngine(_ServingEngineBase):
         self.tables[row, :m] = pages
         with span("first_token", rid=rid):  # the host waits for the prefill
             first = self._pick_token(logits[0, n - 1], req)
-        self.active[row] = req
-        self.lengths[row] = n
-        self.last_tok[row] = first
+        self._seat(row, req, n, first)
         self._emit(row, first)
 
     def _resume_into(self, row, sp: SpilledRequest):
@@ -340,9 +333,7 @@ class PagedServingEngine(_ServingEngineBase):
             self.pool.write_state(row, sp.state_host)
             resume.set(pages_restored=len(restore_pages))
         self.tables[row, :len(pages)] = pages
-        self.active[row] = sp.req
-        self.lengths[row] = sp.length
-        self.last_tok[row] = sp.last_tok
+        self._seat(row, sp.req, sp.length, sp.last_tok)
         serving_metrics()["resumes"].inc()
 
     # -- decode write-target maintenance -------------------------------- #
@@ -378,22 +369,21 @@ class PagedServingEngine(_ServingEngineBase):
 
     def _decode_program(self):
         """The ONE compiled decode program: every row advances by a token;
-        tables, lengths and last tokens are data."""
+        tables, lengths, last tokens, temperatures and keys are data."""
         stats_kw = {"with_stats": True} if self._moe_groups else {}
 
-        def decode(p, b, tok, offs, tables, caches):
+        def decode(p, b, tok, offs, tables, temps, keys, caches):
             pos = offs[:, None]
             logits, new_c, *stats = self._functional_forward(
                 p, b, tok[:, None], pos, caches, offs, tables=tables,
                 **stats_kw)
             last = logits[:, -1]
-            # greedy picked ON DEVICE; [B, vocab] logits stay on device
-            # unless a sampled row gathers its own [vocab] slice. A model's
-            # routing counts ride beside the tokens: one host read
-            return jnp.argmax(last, axis=-1).astype(jnp.int32), \
-                last, new_c, stats
+            # every row's token picked ON DEVICE, greedy or sampled; the
+            # [B, vocab] logits stay there. A model's routing counts ride
+            # beside the tokens and the keys: one host read
+            return *self._choose_tokens(last, temps, keys), last, new_c, stats
 
-        return jax.jit(decode, donate_argnums=(5,))
+        return jax.jit(decode, donate_argnums=(7,))
 
     def _step(self, tick):
         """Admit (resumes then prefills), ensure every live row has a
@@ -424,7 +414,9 @@ class PagedServingEngine(_ServingEngineBase):
 
         state_rows = ({"state_rows": len(live)} if self.pool.state_layers
                       else {})
-        with span("decode_dispatch", rows=len(live), **self._decode_grid,
+        sampled = np.flatnonzero(self.temps > 0)  # live rows all: _vacate
+        with span("decode_dispatch", rows=len(live),
+                  sampled_rows=len(sampled), **self._decode_grid,
                   **state_rows):
             # quantized pool: each layer's cache rides as (k, v, k_scale,
             # v_scale) so the int8 append + dequant-fused attention see
@@ -432,9 +424,10 @@ class PagedServingEngine(_ServingEngineBase):
             caches = ([kv + sc
                        for kv, sc in zip(self.pool.kv, self.pool.scales)]
                       if self.kv_quant else self.pool.kv)
-            greedy_tok, logits, new_kv, stats = self._decode_jit(
+            tokens, keys, logits, new_kv, stats = self._decode_jit(
                 self.params, self.buffers, jnp.asarray(self.last_tok),
-                jnp.asarray(self.lengths), jnp.asarray(self.tables), caches)
+                jnp.asarray(self.lengths), jnp.asarray(self.tables),
+                *self._sampling_inputs(sampled), caches)
             if self.kv_quant:
                 self.pool.kv = [tuple(c[:2]) for c in new_kv]
                 self.pool.scales = [tuple(c[2:]) for c in new_kv]
@@ -442,9 +435,9 @@ class PagedServingEngine(_ServingEngineBase):
                 self.pool.kv = [tuple(c) for c in new_kv]
             self.last_logits = logits  # device array; tests probe divergence
         with span("host_read"):  # the host waits for the decode here
-            greedy_np, stats = jax.device_get((greedy_tok, stats))
+            tokens, keys, stats = jax.device_get((tokens, keys, stats))
         if stats:
             self._note_routing(stats[0])
-        out = self._emit_decoded(live, greedy_np, logits)
+        out = self._emit_decoded(live, sampled, tokens, keys)
         self.pool.update_gauges()
         return out

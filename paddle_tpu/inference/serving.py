@@ -33,6 +33,7 @@ import time
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax import lax
 
 from ..framework.core import Tensor
 from ..observability import spans as _spans
@@ -129,6 +130,17 @@ class _ServingEngineBase:
         self.cache_specs = (model.cache_specs()
                             if hasattr(model, "cache_specs") else None)
         self.last_logits = None  # last decode tick's [B, vocab] device array
+        # per decode ROW, beside the subclass's caches or block tables: the
+        # request seated there, the tokens it has cached, its last token, and
+        # what the decode program samples it with (temperature 0: greedy; the
+        # request's key stream, which lives here while the row is live)
+        self.active: list[GenerationRequest | None] = [None] * self.B
+        self.lengths = np.zeros(self.B, np.int32)
+        self.last_tok = np.zeros(self.B, np.int32)
+        self.temps = np.zeros(self.B, np.float32)
+        self.keys = np.zeros((self.B, 2), np.uint32)
+        self._greedy_inputs = (jnp.zeros(self.B, jnp.float32),
+                               jnp.zeros((self.B, 2), jnp.uint32))
         self.finished: list[GenerationRequest] = []
         self._key = jax.random.PRNGKey(seed)
         self._req_seq = 0  # arrival index, keys each request's sample stream
@@ -137,7 +149,7 @@ class _ServingEngineBase:
         self._decode_jit = None
         self._tick = 0
         m = serving_metrics()
-        for name in ("tokens", "requests", "truncations"):
+        for name in ("tokens", "sampled_tokens", "requests", "truncations"):
             m[name].inc(0, engine=self.engine_label)  # series exists from t0
 
     def _make_request(self, prompt_ids, **kw):
@@ -212,15 +224,42 @@ class _ServingEngineBase:
     # -- sampling -------------------------------------------------------- #
 
     def _pick_token(self, logits_row, req):
-        """logits_row may be a DEVICE array: greedy argmax and categorical
-        sampling both run on device and only the chosen token id crosses to
-        host — never the [vocab] row, and never the whole [B, vocab] batch
-        (one sampled request used to force that transfer for everyone)."""
+        """A request's FIRST token, at admission, from the prefill's device
+        row: argmax or one step of the request's key stream, eagerly; the
+        token id and the advanced key cross to the host in one read. Every
+        later token is chosen inside the decode program (`_choose_tokens`),
+        which continues the stream."""
         if req.temperature == 0.0:
             return int(jnp.argmax(jnp.asarray(logits_row)))
-        req._sample_key, sub = jax.random.split(req._sample_key)
-        return int(jax.random.categorical(
-            sub, jnp.asarray(logits_row) / req.temperature))
+        key, sub = jax.random.split(req._sample_key)
+        tok, req._sample_key = jax.device_get((jax.random.categorical(
+            sub, jnp.asarray(logits_row) / req.temperature), key))
+        return int(tok)
+
+    @staticmethod
+    def _choose_tokens(last, temps, keys):
+        """Every row's next token, INSIDE the decode program, from the last
+        position's `[B, vocab]` logits: `argmax` where `temps [B]` is 0;
+        where it is not, one step of the row's own stream in `keys [B, 2]`,
+        `key, sub = split(key)` then `categorical(sub, row / temp)` in the
+        logits' dtype — what `_pick_token` does, a row at a time, bit for
+        bit. Returns (tokens [B] int32, the advanced keys; a greedy row's key
+        comes back as it went in). A tick with no sampled row draws nothing:
+        the `cond` reads that from `temps`."""
+        greedy = jnp.argmax(last, axis=-1).astype(jnp.int32)
+        sampled = temps > 0
+
+        def step_stream(key, row, temp):
+            key, sub = jax.random.split(key)
+            return key, jax.random.categorical(sub, row / temp.astype(row.dtype))
+
+        def draw():
+            stepped, drawn = jax.vmap(step_stream)(
+                keys, last, jnp.where(sampled, temps, 1.0))
+            return (jnp.where(sampled, drawn.astype(jnp.int32), greedy),
+                    jnp.where(sampled[:, None], stepped, keys))
+
+        return lax.cond(jnp.any(sampled), draw, lambda: (greedy, keys))
 
     # -- SLO bookkeeping ------------------------------------------------- #
 
@@ -290,6 +329,36 @@ class _ServingEngineBase:
                 tick.seconds, engine=self.engine_label)
         return out
 
+    # -- rows ------------------------------------------------------------ #
+
+    def _seat(self, row, req, length, last_tok):
+        """`req` takes decode row `row` (admitted or resumed) with `length`
+        tokens cached; a sampled request's key stream now lives in the row
+        (on the host since `_pick_token`; a greedy one's is never read)."""
+        self.active[row] = req
+        self.lengths[row] = length
+        self.last_tok[row] = last_tok
+        self.temps[row] = req.temperature
+        if req.temperature > 0:
+            self.keys[row] = req._sample_key
+
+    def _vacate(self, row):
+        """The row's request leaves it (retired or spilled) and takes its
+        key stream along, so a resume continues it."""
+        if self.temps[row] > 0:
+            self.active[row]._sample_key = self.keys[row].copy()
+        self.active[row] = None
+        self.lengths[row] = 0
+        self.temps[row] = 0.0  # an empty row is a greedy row to the program
+
+    def _sampling_inputs(self, sampled):
+        """(`temps`, `keys`) on the device for the decode program. A tick
+        with no sampled row places nothing: the program's `cond` reads
+        zeros that have been there since construction."""
+        if len(sampled):
+            return jnp.asarray(self.temps), jnp.asarray(self.keys)
+        return self._greedy_inputs
+
     # -- token emission -------------------------------------------------- #
 
     def _emit(self, row, tok):
@@ -301,29 +370,24 @@ class _ServingEngineBase:
             self._note_finished(req, truncated)
             self._release_row(row)
 
-    def _emit_decoded(self, live, greedy_np, logits) -> dict:
-        """The decoded tick's tail: every live row's token (greedy from the
-        host copy `greedy_np`, a sampled row's from its own slice of the
-        device `logits`), lengths and last tokens advanced, the token
-        emitted. Returns {req_id: token}."""
+    def _emit_decoded(self, live, sampled, tokens, keys) -> dict:
+        """The decoded tick's tail, on the host copy of what the program
+        chose: the `sampled` rows keep their advanced keys, every live row's
+        length and last token advance, the token is emitted. Returns
+        {req_id: token}."""
         out = {}
-        with span("emit", rows=len(live)) as sp:
-            sampled = 0
+        with span("emit", rows=len(live), sampled_rows=len(sampled)):
+            if len(sampled):
+                with span("sample", rows=len(sampled)):
+                    self.keys[sampled] = keys[sampled]
+                serving_metrics()["sampled_tokens"].inc(
+                    len(sampled), engine=self.engine_label)
             for i in live:
-                req = self.active[i]
-                if req.temperature == 0.0:
-                    tok = int(greedy_np[i])
-                else:
-                    # per-row device gather + on-device categorical: only
-                    # the sampled token id is transferred, not [B, vocab]
-                    sampled += 1
-                    with span("sample", rid=req.req_id):
-                        tok = self._pick_token(logits[i], req)
+                tok = int(tokens[i])
                 self.lengths[i] += 1
                 self.last_tok[i] = tok
-                out[req.req_id] = tok
+                out[self.active[i].req_id] = tok
                 self._emit(i, tok)
-            sp.set(sampled_rows=sampled)
         return out
 
     # subclass contract
@@ -334,8 +398,9 @@ class _ServingEngineBase:
         raise NotImplementedError
 
     def _release_row(self, row):
-        """Free what a retired request's row held."""
-        raise NotImplementedError
+        """Free what a retired request's row held (a subclass: its pages
+        too)."""
+        self._vacate(row)
 
 
 class ContinuousBatchingEngine(_ServingEngineBase):
@@ -358,9 +423,6 @@ class ContinuousBatchingEngine(_ServingEngineBase):
             (jnp.zeros((self.B, self.S, cfg.kv_heads, cfg.head_dim),
                        self.kv_dtype),) * 2
             for _ in range(cfg.num_layers)]
-        self.lengths = np.zeros(self.B, np.int32)   # tokens in each slot
-        self.active: list[GenerationRequest | None] = [None] * self.B
-        self.last_tok = np.zeros(self.B, np.int32)
         self.waiting: collections.deque = collections.deque()
 
     # ------------------------------------------------------------------ #
@@ -400,15 +462,9 @@ class ContinuousBatchingEngine(_ServingEngineBase):
             # device row gather: only [vocab] of THIS row ever materializes
             with span("first_token", rid=req.req_id):
                 first = self._pick_token(logits[0, n - 1], req)
-            self.active[slot] = req
-            self.lengths[slot] = n
-            self.last_tok[slot] = first
+            self._seat(slot, req, n, first)
             self._emit(slot, first)
         return picked
-
-    def _release_row(self, slot):
-        self.active[slot] = None
-        self.lengths[slot] = 0
 
     # ------------------------------------------------------------------ #
 
@@ -427,25 +483,25 @@ class ContinuousBatchingEngine(_ServingEngineBase):
         if not live:
             return {}
         if self._decode_jit is None:
-            def decode(p, b, tok, offs, caches):
+            def decode(p, b, tok, offs, temps, keys, caches):
                 pos = offs[:, None]
                 logits, new_c = self._functional_forward(
                     p, b, tok[:, None], pos, caches, offs)
                 last = logits[:, -1]
-                # greedy tokens picked ON DEVICE: the [B, vocab] logits
-                # only cross to host when a sampled-temperature request
-                # needs them (jax arrays materialize lazily)
-                return jnp.argmax(last, axis=-1).astype(jnp.int32), \
-                    last, new_c
+                # every row's token picked ON DEVICE: the [B, vocab] logits
+                # never cross to the host
+                return *self._choose_tokens(last, temps, keys), last, new_c
 
-            self._decode_jit = jax.jit(decode, donate_argnums=(4,))
+            self._decode_jit = jax.jit(decode, donate_argnums=(6,))
 
-        with span("decode_dispatch", rows=len(live)):
+        sampled = np.flatnonzero(self.temps > 0)  # live rows all: _vacate
+        with span("decode_dispatch", rows=len(live),
+                  sampled_rows=len(sampled)):
             offs = jnp.asarray(self.lengths)  # per-slot write offset
-            greedy_tok, logits, self.caches = self._decode_jit(
+            tokens, keys, logits, self.caches = self._decode_jit(
                 self.params, self.buffers, jnp.asarray(self.last_tok), offs,
-                self.caches)
+                *self._sampling_inputs(sampled), self.caches)
             self.last_logits = logits  # device array; tests probe divergence
         with span("host_read"):
-            greedy_np = np.asarray(greedy_tok)
-        return self._emit_decoded(live, greedy_np, logits)
+            tokens, keys = jax.device_get((tokens, keys))
+        return self._emit_decoded(live, sampled, tokens, keys)

@@ -48,6 +48,11 @@ def _build(reg):
             labelnames=("engine",), buckets=DEFAULT_BUCKETS),
         "tokens": reg.counter(
             "serving_tokens_total", "Generated tokens", ("engine",)),
+        "sampled_tokens": reg.counter(
+            "serving_sampled_tokens_total",
+            "Tokens the decode program drew from a T>0 row's key stream "
+            "(a request's first token, drawn at admission, is not counted)",
+            ("engine",)),
         "requests": reg.counter(
             "serving_requests_total", "Finished requests", ("engine",)),
         "truncations": reg.counter(

@@ -9,6 +9,8 @@ The topology is described inside a fixture, never at import: one process at
 a time may load the TPU's library, and every xdist worker imports this file.
 Keep such tests in this one file."""
 
+import json
+import os
 import re
 
 import pytest
@@ -193,3 +195,60 @@ def test_kv_writes_then_paged_decode_leave_the_pool_in_place(one_chip, cell):
                if re.match(r"\s*(ROOT )?%decode_paged[.\w]* = ", line)]
     assert 'custom_call_target="tpu_custom_call"' in call
     assert _pool_text(pool) + "{3,2,1,0" in call
+
+
+def test_the_decode_program_samples_under_one_conditional(one_chip,
+                                                          monkeypatch):
+    """The engine's whole decode program at `serve-xl-sat`'s widths (64 rows,
+    vocabulary 50304, the cell's pool; two layers of the 24, from shapes
+    alone): the sampler is ONE `conditional` that yields the tokens and the
+    advanced keys, so an all-greedy tick skips the draw; the program returns
+    both beside the logits; and no second pool array is laid beside the pool
+    (S15), sampler or not."""
+    import paddle_tpu.amp as amp
+    from paddle_tpu.inference.paged import PagedServingEngine
+    from paddle_tpu.models import GPTConfig, GPTForCausalLM
+    from paddle_tpu.nn import initializer
+
+    # nothing runs, so no value is ever read: parameters stay the zeros
+    # they are made as
+    for cls in (initializer.Normal, initializer.XavierUniform):
+        monkeypatch.setattr(cls, "__call__", lambda self, param, block=None: param)
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    with open(os.path.join(root, "benchmark", "configs", "gpt3-xl.json")) as f:
+        cell = json.load(f)
+    serve = cell["serve"]
+    model = GPTForCausalLM(GPTConfig(
+        vocab_size=cell["vocab_size"], hidden_size=cell["hidden_size"],
+        num_layers=2, num_heads=cell["num_heads"],
+        max_position_embeddings=cell["max_position_embeddings"]))
+    amp.decorate(model, level="O2", dtype="bfloat16")
+    eng = PagedServingEngine(
+        model, max_batch_size=serve["max_batch_size"],
+        max_seq_len=serve["max_seq_len"], page_size=serve["page_size"],
+        num_pages=2)
+    pool, rows, _ = POOLS["serve-xl-sat"]
+    assert (eng.B, eng.P, eng.pool.kv[0][0].shape[1:]) == (rows, P, pool[1:])
+
+    def shape(x, dims=None):
+        return jax.ShapeDtypeStruct(dims or x.shape, x.dtype,
+                                    sharding=one_chip)
+
+    args = (jax.tree.map(shape, eng.params), jax.tree.map(shape, eng.buffers),
+            *_args(one_chip, ((rows,), jnp.int32), ((rows,), jnp.int32),
+                   ((rows, P), jnp.int32), ((rows,), jnp.float32),
+                   ((rows, 2), jnp.uint32)),
+            [tuple(shape(x, pool) for x in layer) for layer in eng.pool.kv])
+    compiled = eng._decode_program().lower(*args).compile()
+    hlo = _assert_pool_stays_where_it_lies(compiled, pool, 4)
+    (cond,) = [line for line in hlo.splitlines() if " conditional(" in line]
+    assert re.search(r"= \(s32\[64\]\S*, u32\[64,2\]\S*\) conditional\(", cond)
+    # the draw (the generator's bits) is inside the branches only
+    entry = hlo[hlo.index("\nENTRY "):]
+    assert "rng-bit-generator" not in entry and "threefry" not in entry
+    (out,) = [line for line in entry.splitlines()
+              if re.match(r"\s*ROOT %\S+ = \(", line)]
+    assert re.search(r"= \(s32\[64\]\S*, u32\[64,2\]\S*, "
+                     r"bf16\[64,50304\]\S*, " + re.escape(_pool_text(pool)),
+                     out)
+    assert len(re.findall(r"%decode_paged[.\w]* = ", entry)) == 2

@@ -316,18 +316,21 @@ def test_gpt_decode_program_is_the_parents():
                              max_seq_len=64, page_size=8)
     assert eng.cache_specs is None and not eng.pool.state_layers
 
-    def decode(p, b, tok, offs, tables, caches):   # the parent's, verbatim
+    def decode(p, b, tok, offs, tables, temps, keys, caches):
+        # the program before cache specs (PR 28's, verbatim) with the
+        # sampler that every engine's program calls since PR 32
         pos = offs[:, None]
         logits, new_c = eng._functional_forward(
             p, b, tok[:, None], pos, caches, offs, tables=tables)
         last = logits[:, -1]
-        return jnp.argmax(last, axis=-1).astype(jnp.int32), last, new_c
+        return *eng._choose_tokens(last, temps, keys), last, new_c
 
     args = (eng.params, eng.buffers, jnp.zeros(4, jnp.int32),
             jnp.ones(4, jnp.int32), jnp.zeros((4, eng.P), jnp.int32),
+            jnp.zeros(4, jnp.float32), jnp.zeros((4, 2), jnp.uint32),
             eng.pool.kv)
     mine = jax.make_jaxpr(eng._decode_program())(*args)
-    parents = jax.make_jaxpr(jax.jit(decode, donate_argnums=(5,)))(
+    parents = jax.make_jaxpr(jax.jit(decode, donate_argnums=(7,)))(
         *args)
     assert str(mine) == str(parents)
 

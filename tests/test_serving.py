@@ -2,11 +2,16 @@
 dynamic batching scheduler; here admit-while-decoding over a slotted KV
 cache with one fixed-shape compiled decode program)."""
 
+import jax
+import jax.numpy as jnp
 import numpy as np
 import pytest
 
 import paddle_tpu as paddle
-from paddle_tpu.inference.serving import ContinuousBatchingEngine
+from paddle_tpu.inference.serving import (
+    ContinuousBatchingEngine,
+    _ServingEngineBase,
+)
 from paddle_tpu.models import GPTForCausalLM, gpt3_tiny
 from paddle_tpu.models.generation import generate
 
@@ -151,6 +156,64 @@ class TestServingSatellites:
         eng.add_request(np.arange(1, 6, dtype=np.int32), max_new_tokens=1)
         eng.run()
         assert compiles("16") == c16 + 2  # eviction made the recompile visible
+
+
+# the decode program's sampler against the per-row chain it replaced, kept
+# here as the reference: an eager split, then categorical(sub, row / T)
+TEMPS = {"all-greedy": [0.0] * 8,
+         "mixed": [0.7, 0.0, 0.0, 1.3, 0.0, 0.7, 0.2, 0.0],
+         "all-sampled": [0.7, 1.0, 0.2, 1.3, 0.7, 0.5, 2.0, 0.9]}
+
+
+def _per_row_chain(logits, temps, keys):
+    tokens, keys = [], [jnp.asarray(k) for k in keys]
+    for i, t in enumerate(temps):
+        if t == 0.0:
+            tokens.append(int(jnp.argmax(logits[i])))
+            continue
+        keys[i], sub = jax.random.split(keys[i])
+        tokens.append(int(jax.random.categorical(sub, logits[i] / t)))
+    return np.asarray(tokens, np.int32), np.stack([np.asarray(k)
+                                                   for k in keys])
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16],
+                         ids=["float32", "bfloat16"])
+@pytest.mark.parametrize("mix", sorted(TEMPS))
+def test_in_program_sampler_equals_the_per_row_chain(mix, dtype):
+    """Tokens and advanced keys bit for bit, at the serving cell's
+    vocabulary, over two steps of the stream; a greedy row's key comes back
+    as it went in."""
+    temps = TEMPS[mix]
+    rng = np.random.default_rng(5)
+    keys = np.stack([np.asarray(jax.random.fold_in(jax.random.PRNGKey(3), i))
+                     for i in range(8)])
+    choose = jax.jit(_ServingEngineBase._choose_tokens)
+    for _ in range(2):
+        logits = jnp.asarray(rng.normal(0, 2, (8, 50304)), dtype)
+        want_tok, want_keys = _per_row_chain(logits, temps, keys)
+        tok, new_keys = jax.device_get(
+            choose(logits, jnp.asarray(temps, jnp.float32), jnp.asarray(keys)))
+        assert tok.dtype == np.int32 and new_keys.dtype == np.uint32
+        np.testing.assert_array_equal(tok, want_tok)
+        np.testing.assert_array_equal(new_keys, want_keys)
+        greedy = np.asarray(temps) == 0.0
+        np.testing.assert_array_equal(new_keys[greedy], keys[greedy])
+        assert (new_keys[~greedy] != keys[~greedy]).any(axis=1).all()
+        keys = new_keys
+
+
+def test_in_program_sampler_draws_only_under_a_cond_on_the_temperatures():
+    """One program for every mix: the draw sits in a branch that the
+    program picks from `temps`, so an all-greedy tick pays an argmax."""
+    jaxpr = jax.make_jaxpr(_ServingEngineBase._choose_tokens)(
+        jnp.zeros((4, 64), jnp.bfloat16), jnp.zeros(4, jnp.float32),
+        jnp.zeros((4, 2), jnp.uint32))
+    (cond,) = [e for e in jaxpr.eqns if e.primitive.name == "cond"]
+    outside = {e.primitive.name for e in jaxpr.eqns} - {"cond"}
+    assert not any("random" in n or "threefry" in n for n in outside)
+    branches = [str(b) for b in cond.params["branches"]]
+    assert sum("threefry" in b or "random_bits" in b for b in branches) == 1
 
 
 class TestQuantizedServing:

@@ -18,6 +18,7 @@ from paddle_tpu.inference.paged import (
     prefix_page_key,
 )
 from paddle_tpu.models import GPTForCausalLM, gpt3_tiny
+from paddle_tpu.observability import spans
 from paddle_tpu.observability.metrics import default_registry
 
 
@@ -271,6 +272,70 @@ class TestPagedServingEngine:
         assert [r.generated for r in paged] == [r.generated for r in dense]
         assert _counter("serving_preemptions_total") > pre0
         assert _counter("serving_resumes_total") > res0
+
+    def test_sampled_streams_survive_spill_and_resume(self, model):
+        """Sampled requests through the undersized pool: the key stream
+        lives in the row, leaves with the spilled request and comes back
+        with the resume, so every token equals the dense engine's, which
+        never preempts."""
+        rng = np.random.default_rng(7)
+        prompts = [rng.integers(1, 1000, 14).astype(np.int32)
+                   for _ in range(6)]
+        temps = [0.7, 0.0, 1.3, 0.7, 0.0, 0.2]
+        prios = [0, -1, -2, -3, 0, -1]
+        dense = _drive(ContinuousBatchingEngine(
+            model, max_batch_size=4, max_seq_len=64, seed=3),
+            prompts, temps, [8] * 6, prios)
+        eng = PagedServingEngine(model, max_batch_size=4, max_seq_len=64,
+                                 page_size=16, seed=3, num_pages=6,
+                                 watermark_pages=0, prefix_sharing=False)
+        paged = _drive(eng, prompts, temps, [8] * 6, prios)
+        assert [r.generated for r in paged] == [r.generated for r in dense]
+        assert not any(r.preemptions for r in dense)
+        # sampled rows were among the spilled, more than once
+        assert sum(r.preemptions for r, t in zip(paged, temps) if t) >= 2
+        assert not eng.temps.any()  # an empty row is a greedy row
+
+    @pytest.mark.parametrize("engine", ["paged", "dense"])
+    @pytest.mark.parametrize("temps", [(0.7, 0.0, 0.9), (0.0, 0.0, 0.0)],
+                             ids=["two-sampled", "all-greedy"])
+    def test_one_sample_span_a_tick_and_the_counter(self, model, engine,
+                                                    temps):
+        """A tick with sampled rows has exactly one `emit/sample` span
+        (`rows` of them) and `serving_sampled_tokens_total` rises by that
+        many; an all-greedy tick has no such span and leaves the counter."""
+        if engine == "paged":
+            eng = PagedServingEngine(model, max_batch_size=4, max_seq_len=64,
+                                     page_size=16)
+        else:
+            eng = ContinuousBatchingEngine(model, max_batch_size=4,
+                                           max_seq_len=64)
+        for i, t in enumerate(temps):
+            eng.add_request(np.arange(3, 9 + i, dtype=np.int32),
+                            max_new_tokens=4, temperature=t)
+        want = sum(t > 0 for t in temps)
+        n0 = _counter("serving_sampled_tokens_total", engine=engine)
+        spans.clear_recorded()
+        tl = spans.enable_step_timeline()
+        try:
+            out = eng.step()   # admits the three (first tokens) and decodes
+        finally:
+            tl.uninstall()
+        ring = spans.recorded()
+        spans.clear_recorded()
+        assert len(out) == 3
+        by = {}
+        for r in ring:
+            by.setdefault(r["path"], []).append(r["attrs"])
+        assert by["engine.step/emit"] == [{"rows": 3, "sampled_rows": want}]
+        assert by["engine.step/decode_dispatch"][0]["sampled_rows"] == want
+        assert by.get("engine.step/emit/sample", []) == (
+            [{"rows": want}] if want else [])
+        assert _counter("serving_sampled_tokens_total",
+                        engine=engine) == n0 + want
+        eng.run()
+        assert _counter("serving_sampled_tokens_total",
+                        engine=engine) == n0 + 3 * want
 
     def test_truncation_is_flagged_and_counted(self, model):
         """A request whose prompt + budget exceeds max_seq_len retires at

@@ -29,7 +29,8 @@ from paddle_tpu.profiler import Profiler, RecordEvent
 PKG = os.path.dirname(os.path.abspath(paddle.__file__))
 
 # every path of a tick (docs/OBSERVABILITY.md); a tick holds each of the
-# first six once, the others once per admitted, preempted or sampled request
+# first six once, `emit/sample` once if it has sampled rows, the others once
+# per admitted or preempted request
 TICK_PHASES = ["engine.step/admit", "engine.step/write_targets",
                "engine.step/decode_dispatch", "engine.step/host_read",
                "engine.step/emit"]
@@ -38,7 +39,8 @@ TICK_PATHS = ["engine.step"] + TICK_PHASES + [
     "engine.step/admit/write_pages", "engine.step/admit/first_token",
     "engine.step/admit/resume", "engine.step/write_targets/spill",
     "engine.step/emit/sample"]
-PER_REQUEST = [p for p in TICK_PATHS if p.count("/") == 2]
+PER_REQUEST = [p for p in TICK_PATHS if p.count("/") == 2
+               and p != "engine.step/emit/sample"]
 
 
 @pytest.fixture(autouse=True)
@@ -138,6 +140,30 @@ def test_spans_of_one_request_carry_its_rid(traced_ticks, path):
     mine = [r for r in ring if r["path"] == path]
     assert mine and all(isinstance(r["attrs"].get("rid"), int) for r in mine)
     assert "rid" in host[path]  # the attribute reached the profiler's trace
+
+
+def test_sampling_is_one_span_a_tick_not_one_a_row(traced_ticks):
+    """The decode program picks the sampled rows' tokens: the host's part is
+    one `emit/sample` span in a tick that has such rows (`rows` of them, the
+    `sampled_rows` of the tick's `emit` and `decode_dispatch`), none in a
+    tick that has none."""
+    ring, host = traced_ticks
+    ticks = {r["id"]: {} for r in ring if r["path"] == "engine.step"}
+    emits = {r["id"]: r for r in ring if r["path"] == "engine.step/emit"}
+    for r in ring:
+        if r["path"] in ("engine.step/emit", "engine.step/decode_dispatch"):
+            ticks[r["parent"]][r["path"]] = r["attrs"]["sampled_rows"]
+        elif r["path"] == "engine.step/emit/sample":
+            ticks[emits[r["parent"]]["parent"]].setdefault(
+                "samples", []).append(r["attrs"])
+    decoded = [t for t in ticks.values() if "engine.step/emit" in t]
+    assert {t["engine.step/emit"] for t in decoded} >= {0, 1}
+    for t in decoded:
+        n = t["engine.step/emit"]
+        assert t["engine.step/decode_dispatch"] == n
+        assert t.get("samples", []) == ([{"rows": n}] if n else [])
+    assert "rows" in host["engine.step/emit/sample"]
+    assert "sampled_rows" in host["engine.step/decode_dispatch"]
 
 
 def test_a_request_record_appears_on_retirement(traced_ticks):
