@@ -5,7 +5,9 @@
   refcounted prefix sharing and copy-on-write; `quantized=True` stores int8
   payloads + per-(page, head) f32 scales for the dequant-fused kernel
   (`PagedServingEngine(kv_quant=True)`). One cache spec per layer: `PagedKV` pages for
-  an attention layer, a `RowState` slot per decode row for a recurrent one.
+  an attention layer, `WindowKV` pages that expire for a sliding-window one
+  (both out of one free list: layers share the pool's arrays by page group),
+  a `RowState` slot per decode row for a recurrent one.
 - `TwoQueueScheduler` — power-of-two prefill length buckets + decode/resume
   queues, admitting against a page-budget watermark.
 - `PagedServingEngine` — the continuous-batching engine over both, with
@@ -16,7 +18,8 @@ The dense `paddle_tpu.inference.serving.ContinuousBatchingEngine` is the
 reference the tests hold this engine to, token for token; nothing selects it.
 """
 
-from .block_pool import BlockPool, PagedKV, RowState, prefix_page_key
+from .block_pool import (BlockPool, PagedKV, RowState, WindowKV,
+                         prefix_page_key)
 from .engine import PagedServingEngine, SpilledRequest
 from .scheduler import TwoQueueScheduler
 
@@ -24,6 +27,7 @@ __all__ = [
     "BlockPool",
     "PagedKV",
     "RowState",
+    "WindowKV",
     "PagedServingEngine",
     "SpilledRequest",
     "TwoQueueScheduler",

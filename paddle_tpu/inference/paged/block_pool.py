@@ -9,9 +9,14 @@ gathered copy of the cache ever materializes).
 
 Host-side metadata (free list, refcounts, prefix map) is plain Python/numpy:
 it is touched once per admission / page-boundary crossing / preemption, never
-per token, and never inside a trace. Device arrays are immutable jnp values;
-every mutation (`.at[...]`) swaps in a fresh array, which composes with the
-engine's donated decode program.
+per token, and never inside a trace. Device arrays are immutable jnp values,
+and every program that changes one DONATES it: the decode program, and the
+pool's own two page programs, a gather (`read_pages`: a spill; the source of
+a `copy_page`) and a scatter (`write_prompt_pages`, `restore_pages`,
+`copy_page`), which take their page ids as data in counts bucketed to powers
+of two (at most `_PAGES_PER_CALL` a call), so that a pool of any size
+compiles some twenty of them and a write moves the written pages' bytes,
+never the pool's.
 
 Prefix sharing: a prompt page is keyed by the hash of the ENTIRE token
 prefix through that page's end — K/V at position i depends on every token
@@ -38,6 +43,24 @@ written (admission, resume) and read (spill) by ONE jitted program each that
 takes the row as data, and the writing one donates the state arrays: an
 eager `.at[].set()` on a whole state array would copy all rows' state for
 one row's sake.
+
+Window layers and page groups. `WindowKV` is a sliding-window attention
+layer's cache: the same pages, but a row keeps only those that hold one of
+its last `window` positions, at most `ceil(window / page_size) + 1` of them.
+They are private to the row (never in the prefix registry: a page behind
+another request's window may be gone), the engine releases each as the row's
+length passes it, and admission writes only the prompt's last window. So that
+ONE free list serves both kinds, the paged layers are split by kind into
+GROUPS of equal layer count `depth` (the greatest common divisor of the
+kinds' layer counts; 2 full and 6 window layers: one full group, three
+window groups, depth 2) and the pool has `depth` K/V arrays, not one per
+layer: a page is a slot in all `depth` arrays, the same bytes whatever group
+holds it, and it belongs to one group at a time. A request has one block
+table per group. `kv` holds one entry per array (`entry_of_layer` says which
+a layer reads and writes; layers of several groups share an entry, so the
+decode program threads it through them in layer order); a model whose paged
+layers are all of one kind has one group and `depth` = its paged layers: an
+entry per layer, as before.
 
 Physical page 0 is the reserved NULL page: never allocated, never referenced
 by a live block table. Parked decode rows (batch padding) route their
@@ -70,7 +93,12 @@ import numpy as np
 
 from ..slo import serving_metrics
 
-__all__ = ["BlockPool", "PagedKV", "RowState", "prefix_page_key"]
+__all__ = ["BlockPool", "PagedKV", "WindowKV", "RowState", "PageGroup",
+           "page_layout", "prefix_page_key"]
+
+# pages one call of the gather or the scatter program moves at most: larger
+# sets go in several calls, so the bucketed shapes end here
+_PAGES_PER_CALL = 512
 
 
 @dataclasses.dataclass(frozen=True)
@@ -85,6 +113,82 @@ class PagedKV:
     def prefill_cache(self, seq, dtype):
         """The zeroed dense cache a batch-1 prefill of `seq` tokens fills."""
         return (jnp.zeros((1, seq, self.kv_heads, self.head_dim), dtype),) * 2
+
+
+@dataclasses.dataclass(frozen=True)
+class WindowKV:
+    """A sliding-window attention layer's cache: K and V pages as `PagedKV`,
+    of which a row keeps only those holding one of the `window` positions its
+    next query sees (key j is visible to query i iff 0 <= i - j < window).
+    Private to the row, released as the row's length passes them."""
+
+    kv_heads: int
+    head_dim: int
+    window: int
+
+    def prefill_cache(self, seq, dtype):
+        """A prefill from position 0 reads no cache."""
+        return ()
+
+    def first_page(self, length, page_size) -> int:
+        """The first logical page a row with `length` tokens cached still
+        needs: the one holding position `length + 1 - window`, the oldest key
+        the query at position `length` sees."""
+        return max(0, length + 1 - self.window) // page_size
+
+    def table_width(self, page_size) -> int:
+        return -(-self.window // page_size) + 1
+
+
+@dataclasses.dataclass(frozen=True)
+class PageGroup:
+    """`depth` layers of one kind that share block tables: layer `layers[j]`
+    keeps its K and V in the pool's page array j."""
+
+    spec: object
+    layers: tuple
+
+    @property
+    def window(self):
+        return isinstance(self.spec, WindowKV)
+
+
+def page_layout(specs):
+    """(groups, entry_of_layer, group_of_layer) for one cache spec per layer.
+    Groups: the paged layers by kind (`PagedKV` kinds first), each kind cut
+    into runs of `depth` layers, `depth` the gcd of the kinds' layer counts.
+    `entry_of_layer[l]`: the index in `BlockPool.kv` of what layer l reads
+    and writes, entries numbered in layer order (a `RowState` layer has its
+    own; paged layer j of any group has array j's). `group_of_layer[l]`: the
+    group whose table layer l follows, None for a `RowState` layer."""
+    kinds = {}
+    for li, spec in enumerate(specs):
+        if isinstance(spec, (PagedKV, WindowKV)):
+            kinds.setdefault(spec, []).append(li)
+    if not kinds:
+        raise ValueError("no paged layer among the cache specs")
+    if len({(k.kv_heads, k.head_dim) for k in kinds}) > 1:
+        raise ValueError("paged layers of different KV head counts or sizes "
+                         "in one pool are not supported")
+    depth = math.gcd(*(len(v) for v in kinds.values()))
+    groups = [PageGroup(spec, tuple(layers[i:i + depth]))
+              for spec, layers in sorted(
+                  kinds.items(), key=lambda kv: isinstance(kv[0], WindowKV))
+              for i in range(0, len(layers), depth)]
+    group_of, array_of = {}, {}
+    for gi, group in enumerate(groups):
+        for j, li in enumerate(group.layers):
+            group_of[li], array_of[li] = gi, j
+    entry_of_layer, array_entry = [], {}
+    for li in range(len(specs)):
+        if li in array_of:
+            entry = array_entry.setdefault(
+                array_of[li], len(set(entry_of_layer)))
+        else:
+            entry = len(set(entry_of_layer))
+        entry_of_layer.append(entry)
+    return groups, entry_of_layer, [group_of.get(li)
+                                    for li in range(len(specs))]
 
 
 @dataclasses.dataclass(frozen=True)
@@ -116,6 +220,12 @@ def _quantize_pages(x):
     q = jnp.clip(jnp.round(x32 / safe[:, :, None, None]),
                  -KV_QMAX, KV_QMAX).astype(jnp.int8)
     return q, scale.astype(jnp.float32)
+
+
+def _pad_rows(x, pad):
+    """`x` with `pad` zero rows behind it (a host or a device array)."""
+    xp = np if isinstance(x, np.ndarray) else jnp
+    return xp.concatenate([x, xp.zeros((pad,) + x.shape[1:], x.dtype)])
 
 
 def prefix_page_key(prompt: np.ndarray, page_index: int, page_size: int):
@@ -152,27 +262,39 @@ class BlockPool:
                       else [PagedKV(kv_heads, head_dim)] * num_layers)
         if len(self.specs) != num_layers:
             raise ValueError("one cache spec per layer")
-        self.page_layers = [i for i, s in enumerate(self.specs)
-                            if isinstance(s, PagedKV)]
+        self.groups, self.entry_of_layer, self.group_of_layer = page_layout(
+            self.specs)
+        self.depth = len(self.groups[0].layers)
+        self.page_layers = [i for i, g in enumerate(self.group_of_layer)
+                            if g is not None]
         self.state_layers = [i for i, s in enumerate(self.specs)
                              if isinstance(s, RowState)]
         if self.state_layers and self.quantized:
             raise ValueError("an int8 pool beside recurrent state is not "
                              "supported")
+        # kv indices of the `depth` page arrays, array 0 first
+        self.page_entries = [self.entry_of_layer[li]
+                             for li in self.groups[0].layers]
         self.rows = int(rows)
         shape = (self.num_pages, kv_heads, self.page_size, head_dim)
         pay_dtype = jnp.dtype(jnp.int8) if self.quantized else self.dtype
-        # immutable jnp zeros: (z,)*2 aliasing is safe, .at[] copies
-        self.kv = [(jnp.zeros(shape, pay_dtype),) * 2
-                   if isinstance(spec, PagedKV) else
-                   tuple(jnp.zeros((self.rows,) + tuple(s), self.dtype)
-                         for s in spec.shapes)
-                   for spec in self.specs]
+        # immutable jnp zeros: (z,)*2 aliasing is safe until a donation,
+        # and the page programs below take each array once
+        self.kv = [None] * len(set(self.entry_of_layer))
+        for li, spec in enumerate(self.specs):
+            if self.kv[self.entry_of_layer[li]] is not None:
+                continue   # a page array that an earlier layer's group made
+            self.kv[self.entry_of_layer[li]] = (
+                (jnp.zeros(shape, pay_dtype), jnp.zeros(shape, pay_dtype))
+                if isinstance(spec, (PagedKV, WindowKV)) else
+                tuple(jnp.zeros((self.rows,) + tuple(s), self.dtype)
+                      for s in spec.shapes))
         # per-(page, head) f32 dequant scales beside the int8 payloads
-        self.scales = ([(jnp.zeros((self.num_pages, kv_heads),
-                                   jnp.float32),) * 2
-                        for _ in range(num_layers)]
+        self.scales = ([(jnp.zeros((self.num_pages, kv_heads), jnp.float32),
+                         jnp.zeros((self.num_pages, kv_heads), jnp.float32))
+                        for _ in self.kv]
                        if self.quantized else None)
+        self._gather = self._scatter = None
         self._write_state = self._read_state = None
         self.free: collections.deque = collections.deque(
             range(1, self.num_pages))
@@ -199,7 +321,7 @@ class BlockPool:
 
     @property
     def bytes_per_page(self) -> int:
-        return self.page_nbytes(len(self.page_layers), self.kv_heads,
+        return self.page_nbytes(self.depth, self.kv_heads,
                                 self.head_dim, self.page_size, self.dtype,
                                 self.quantized)
 
@@ -212,8 +334,9 @@ class BlockPool:
     @property
     def bytes_per_token(self) -> float:
         """KV HBM bytes one cached token costs (all layers, K+V, amortized
-        scale overhead) — the `serving_kv_bytes_per_token` series."""
-        return self.bytes_per_page / self.page_size
+        scale overhead) while every group still holds it — the
+        `serving_kv_bytes_per_token` series."""
+        return self.bytes_per_page * len(self.groups) / self.page_size
 
     @property
     def pages_total(self) -> int:
@@ -291,81 +414,129 @@ class BlockPool:
 
     # -- device page data ------------------------------------------------ #
 
-    def write_prompt_pages(self, pages, write_mask, k_layers, v_layers):
-        """Scatter a prefilled prompt into its pages, all layers.
-
-        pages: the request's m physical pages in logical order; write_mask[j]
-        False for shared pages (content already present — identical by key
-        construction, so it is never rewritten). k_layers/v_layers: per layer
-        [m, Hkv, page_size, D] page-stacked prompt K/V. One batched scatter
-        per layer per side. A quantized pool quantizes here (abs-max per
-        (page, head)) and scatters payload + scales together."""
-        idx = [j for j, w in enumerate(write_mask) if w]
-        if not idx:
-            return
-        tgt = jnp.asarray([pages[j] for j in idx], jnp.int32)
-        sel = jnp.asarray(idx, jnp.int32)
-        for j, li in enumerate(self.page_layers):
-            k, v = self.kv[li]
-            if self.quantized:
-                kq, ks = _quantize_pages(k_layers[j][sel])
-                vq, vs = _quantize_pages(v_layers[j][sel])
-                sk, sv = self.scales[li]
-                self.kv[li] = (k.at[tgt].set(kq), v.at[tgt].set(vq))
-                self.scales[li] = (sk.at[tgt].set(ks), sv.at[tgt].set(vs))
-            else:
-                self.kv[li] = (k.at[tgt].set(k_layers[j][sel]),
-                               v.at[tgt].set(v_layers[j][sel]))
+    def _page_arrays(self):
+        """Every array a page has a slot in, in a fixed order: K and V of
+        page array 0, 1, ..., then (int8 pool) their scales likewise."""
+        flat = [a for e in self.page_entries for a in self.kv[e]]
         if self.quantized:
-            serving_metrics()["kv_quant_pages"].inc(len(idx))
+            flat += [a for e in self.page_entries for a in self.scales[e]]
+        return flat
+
+    def _set_page_arrays(self, flat):
+        d = self.depth
+        for j, e in enumerate(self.page_entries):
+            self.kv[e] = (flat[2 * j], flat[2 * j + 1])
+            if self.quantized:
+                self.scales[e] = (flat[2 * d + 2 * j], flat[2 * d + 2 * j + 1])
+
+    @staticmethod
+    def _calls(count):
+        """(start, stop, bucket) of the calls that move `count` pages: as few
+        calls of at most `_PAGES_PER_CALL` as do it, of equal size (so that a
+        large set's last call is no small bucket of its own, which nothing
+        would have compiled), each padded to a power of two."""
+        calls = -(-count // _PAGES_PER_CALL)
+        per_call = -(-count // max(calls, 1))
+        for start in range(0, count, max(per_call, 1)):
+            stop = min(count, start + per_call)
+            yield start, stop, 1 << (stop - start - 1).bit_length()
+
+    def _gather_pages(self, idx):
+        """Device copies [len(idx), ...] of pages `idx` in every page
+        array."""
+        if self._gather is None:
+            self._gather = jax.jit(
+                lambda arrays, idx: [a[idx] for a in arrays])
+        return self._gather(self._page_arrays(), idx)
+
+    def _read(self, pages):
+        """Host copies [m, ...] of `pages` in every page array."""
+        pages = np.asarray(pages, np.int32)
+        parts = []
+        for start, stop, bucket in self._calls(len(pages)):
+            idx = np.zeros(bucket, np.int32)   # the padding reads page 0
+            idx[:stop - start] = pages[start:stop]
+            got = jax.device_get(self._gather_pages(idx))
+            parts.append([g[:stop - start] for g in got])
+        return [np.concatenate(cols) for cols in zip(*parts)]
+
+    def _write(self, pages, values):
+        """Set `pages` in every page array to `values` (one [m, ...] array
+        per array of `_page_arrays`, host or device). The program donates the
+        arrays; the padding of a bucket writes the null page."""
+        if self._scatter is None:
+            self._scatter = jax.jit(
+                lambda arrays, idx, values: [
+                    a.at[idx].set(v.astype(a.dtype))
+                    for a, v in zip(arrays, values)], donate_argnums=(0,))
+        pages = np.asarray(pages, np.int32)
+        for start, stop, bucket in self._calls(len(pages)):
+            idx = np.zeros(bucket, np.int32)
+            idx[:stop - start] = pages[start:stop]
+            pad = bucket - (stop - start)
+            whole = (start, stop) == (0, len(pages))
+            vals = [v if whole else v[start:stop] for v in values]
+            if pad:
+                vals = [_pad_rows(v, pad) for v in vals]
+            self._set_page_arrays(
+                self._scatter(self._page_arrays(), idx, vals))
+
+    def write_prompt_pages(self, pages, write_mask, k_layers, v_layers):
+        """Scatter a prefilled prompt into its pages, every array of the
+        pool (one group's layers).
+
+        pages: m physical pages in logical order; write_mask[j] False for a
+        page that is not to be written (a shared page, whose content is
+        already present and identical by key construction; a slot of the
+        bucket that the prompt does not reach): it goes to the null page.
+        k_layers/v_layers: per page array [m, Hkv, page_size, D] page-stacked
+        prompt K/V. A quantized pool quantizes here (abs-max per (page,
+        head)) and scatters payload + scales together."""
+        tgt = np.where(np.asarray(write_mask, bool),
+                       np.asarray(pages, np.int32), 0)
+        if not tgt.any():
+            return
+        values = [a for kv in zip(k_layers, v_layers) for a in kv]
+        if self.quantized:
+            quant = [_quantize_pages(a) for a in values]
+            values = [q for q, _ in quant] + [s for _, s in quant]
+            serving_metrics()["kv_quant_pages"].inc(
+                int(np.count_nonzero(tgt)))
+        self._write(tgt, values)
 
     def copy_page(self, src: int, dst: int):
         """Copy-on-write body: duplicate src's content into dst (all
-        layers; payload + scales for a quantized pool). Caller owns
-        refcount/table updates."""
-        for li in self.page_layers:
-            k, v = self.kv[li]
-            self.kv[li] = (k.at[dst].set(k[src]), v.at[dst].set(v[src]))
-            if self.quantized:
-                sk, sv = self.scales[li]
-                self.scales[li] = (sk.at[dst].set(sk[src]),
-                                   sv.at[dst].set(sv[src]))
+        arrays; payload + scales for a quantized pool), through the one-page
+        forms of the gather and scatter programs: the page never leaves the
+        device and nothing waits. Caller owns refcount/table updates."""
+        self._write([dst], self._gather_pages(np.asarray([src], np.int32)))
         self.cow_copies_total += 1
         serving_metrics()["cow_copies"].inc()
 
     def read_pages(self, pages) -> list[tuple]:
-        """Host copies of the given pages, per layer — the preemption spill
-        buffer. Unquantized: [(k, v), ...] each [m, Hkv, page_size, D];
+        """Host copies of the given pages, per page array — the preemption
+        spill buffer. Unquantized: [(k, v), ...] each [m, Hkv, page_size, D];
         quantized: [(k, v, k_scale, v_scale), ...] with [m, Hkv] scales
         (int8 payload + f32 scales round-trip the host bit-exactly, so a
         spilled quantized request resumes with zero extra error)."""
-        idx = jnp.asarray(list(pages), jnp.int32)
-        if self.quantized:
-            return [(np.asarray(k[idx]), np.asarray(v[idx]),
-                     np.asarray(sk[idx]), np.asarray(sv[idx]))
-                    for (k, v), (sk, sv) in zip(self.kv, self.scales)]
-        return [(np.asarray(k[idx]), np.asarray(v[idx]))
-                for k, v in (self.kv[li] for li in self.page_layers)]
+        flat, d = self._read(list(pages)), self.depth
+        return [(flat[2 * j], flat[2 * j + 1])
+                + ((flat[2 * d + 2 * j], flat[2 * d + 2 * j + 1])
+                   if self.quantized else ())
+                for j in range(d)]
 
     def restore_pages(self, pages, kv_host, rows):
         """Write spilled host pages back: kv_host is read_pages() output for
         the request's full logical page list; `rows` selects which logical
         indices need restoring (prefix-shared hits don't), `pages` the
         freshly allocated physical destinations, aligned with `rows`."""
-        if not pages:
+        if not len(pages):
             return
-        tgt = jnp.asarray(list(pages), jnp.int32)
         sel = np.asarray(list(rows), np.int32)
-        for j, li in enumerate(self.page_layers):
-            k, v = self.kv[li]
-            k_h, v_h = kv_host[j][0], kv_host[j][1]
-            self.kv[li] = (k.at[tgt].set(jnp.asarray(k_h[sel])),
-                           v.at[tgt].set(jnp.asarray(v_h[sel])))
-            if self.quantized:
-                sk, sv = self.scales[li]
-                sk_h, sv_h = kv_host[j][2], kv_host[j][3]
-                self.scales[li] = (sk.at[tgt].set(jnp.asarray(sk_h[sel])),
-                                   sv.at[tgt].set(jnp.asarray(sv_h[sel])))
+        values = [h[i][sel] for h in kv_host for i in (0, 1)]
+        if self.quantized:
+            values += [h[i][sel] for h in kv_host for i in (2, 3)]
+        self._write(list(pages), values)
 
     # -- device row state ------------------------------------------------- #
 
@@ -384,10 +555,11 @@ class BlockPool:
                         for layer, vals in zip(states, values)]
 
             self._write_state = jax.jit(write, donate_argnums=(0,))
-        new = self._write_state([self.kv[li] for li in self.state_layers],
+        entries = [self.entry_of_layer[li] for li in self.state_layers]
+        new = self._write_state([self.kv[e] for e in entries],
                                 np.int32(row), values)
-        for li, layer in zip(self.state_layers, new):
-            self.kv[li] = layer
+        for e, layer in zip(entries, new):
+            self.kv[e] = layer
 
     def read_state(self, row: int) -> list[tuple]:
         """Host copies of decode row `row`'s slot, per `RowState` layer: the
@@ -398,6 +570,7 @@ class BlockPool:
             self._read_state = jax.jit(lambda states, row: [
                 tuple(jax.lax.dynamic_index_in_dim(a, row, 0, keepdims=False)
                       for a in layer) for layer in states])
-        got = self._read_state([self.kv[li] for li in self.state_layers],
-                               np.int32(row))
+        got = self._read_state(
+            [self.kv[self.entry_of_layer[li]] for li in self.state_layers],
+            np.int32(row))
         return [tuple(np.asarray(a) for a in layer) for layer in got]

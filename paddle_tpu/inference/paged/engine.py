@@ -45,7 +45,8 @@ import numpy as np
 from ...observability.spans import span
 from ..serving import _bucket, _ServingEngineBase
 from ..slo import serving_metrics
-from .block_pool import BlockPool, PagedKV, RowState, prefix_page_key
+from .block_pool import (BlockPool, PagedKV, RowState, page_layout,
+                         prefix_page_key)
 from .scheduler import TwoQueueScheduler, _pages_for_prompt
 
 __all__ = ["PagedServingEngine", "SpilledRequest"]
@@ -57,16 +58,23 @@ class SpilledRequest:
     recomputing anything."""
 
     __slots__ = ("req", "length", "last_tok", "kv_host", "keys",
-                 "state_host")
+                 "state_host", "group_pages", "window_start")
 
-    def __init__(self, req, length, last_tok, kv_host, keys, state_host=()):
+    def __init__(self, req, length, last_tok, kv_host, keys, state_host=(),
+                 group_pages=None, window_start=0):
         self.req = req
         self.length = int(length)
         self.last_tok = int(last_tok)
-        self.kv_host = kv_host   # per paged layer (k, v) np [m, Hkv, ps, D]
-        self.keys = keys         # per logical page: prefix key or None
+        self.kv_host = kv_host   # per page array (k, v) np [m, Hkv, ps, D]
+        # per page, the groups' pages one after the other: prefix key or None
+        self.keys = keys
         # per recurrent layer the row's slot, np arrays (BlockPool.read_state)
         self.state_host = state_host
+        # pages held per page group, and the first logical page the window
+        # groups still held
+        self.group_pages = (list(group_pages) if group_pages is not None
+                            else [len(keys)])
+        self.window_start = int(window_start)
 
     @property
     def n_pages(self) -> int:
@@ -106,12 +114,13 @@ class PagedServingEngine(_ServingEngineBase):
                 "would silently override the byte budget and break the "
                 "equal-budget A/B contract")
         specs = self.cache_specs
-        paged_layers = (cfg.num_layers if specs is None else
-                        sum(isinstance(s, PagedKV) for s in specs))
+        groups = page_layout(
+            specs or [PagedKV(cfg.kv_heads, cfg.head_dim)] * cfg.num_layers
+        )[0]
         if num_pages is None:
             if kv_budget_bytes is not None:
                 page_b = BlockPool.page_nbytes(
-                    paged_layers, cfg.kv_heads, cfg.head_dim, self.ps,
+                    len(groups[0].layers), cfg.kv_heads, cfg.head_dim, self.ps,
                     self.kv_dtype, self.kv_quant)
                 # budget covers the whole pool, reserved null page included,
                 # and first of all every row's recurrent-state slot
@@ -137,17 +146,44 @@ class PagedServingEngine(_ServingEngineBase):
             # the slot's two programs compile now, on the empty pool: a
             # preemption must not compile in the middle of serving
             self.pool.write_state(0, self.pool.read_state(0))
-        self.sched = TwoQueueScheduler(self.ps, watermark_pages)
+        # one block table per page group (`block_pool.page_layout`): a full
+        # group's is as wide as a row can grow, a window group's as its
+        # window. `tables` is the first group's, and the only one of a model
+        # whose paged layers are of one kind. `window_start[row]`: the first
+        # logical page the row's window groups still hold (slot 0 of theirs)
+        self.groups = self.pool.groups
+        self.group_tables = [
+            np.full((self.B, min(self.P, g.spec.table_width(self.ps))
+                     if g.window else self.P), -1, np.int32)
+            for g in self.groups]
+        self.tables = self.group_tables[0]
+        self.window_start = np.zeros(self.B, np.int32)
+        windows = {g.spec for g in self.groups if g.window}
+        if len(windows) > 1:
+            raise ValueError("window layers of several window lengths in "
+                             "one model are not supported")
+        # the window layers' one spec, None for a model without any
+        self._window = windows.pop() if windows else None
+        self._windowed = self._window is not None
+        self._window_released = 0   # window pages released, lifetime
+        self.sched = TwoQueueScheduler(
+            self.ps, watermark_pages, pages_for=self._prompt_pages,
+            groups=len(self.groups))
         self.preemption = bool(preemption)
-        self.tables = np.full((self.B, self.P), -1, np.int32)
-        # the paged-decode kernel's grid, for the `decode_dispatch` span:
+        self._stack = None
+        # the paged-decode kernels' grids, for the `decode_dispatch` span:
         # static, so computed once
         from ...ops.pallas.decode_attention import pages_per_step
-        pages0 = self.pool.kv[self.pool.page_layers[0]][0]
-        n = pages_per_step(cfg.kv_heads, self.ps, cfg.head_dim, self.P,
-                           pages0.dtype.itemsize)
-        self._decode_grid = {"pages_per_step": n,
-                             "grid_steps": self.B * -(-self.P // n)}
+        pages0 = self.pool.kv[self.pool.page_entries[0]][0]
+        self._decode_grid = {}
+        for g, table in zip(self.groups, self.group_tables):
+            width = table.shape[1]
+            n = pages_per_step(cfg.kv_heads, self.ps, cfg.head_dim, width,
+                               pages0.dtype.itemsize)
+            tag = "window_" if g.window else ""
+            self._decode_grid.update({
+                tag + "pages_per_step": n,
+                tag + "grid_steps": self.B * -(-width // n)})
         # a model with routed experts counts its routing in the decode
         # program (incubate/.../held_moe.STAT_NAMES)
         self._moe_groups = getattr(model, "moe_groups", 0)
@@ -161,6 +197,8 @@ class PagedServingEngine(_ServingEngineBase):
                      "prefix_hits", "prefix_lookups", "cow_copies",
                      "kv_quant_pages"):
             m[name].inc(0)
+        if self._windowed:
+            m["window_pages_released"].inc(0)
         if self._moe_groups:
             for name in ("moe_routed_pairs_held", "moe_dropped_pairs"):
                 m[name].inc(0)
@@ -174,8 +212,7 @@ class PagedServingEngine(_ServingEngineBase):
             raise ValueError(
                 f"prompt length {n} >= max_seq_len {self.S}")
         # lifetime page need (capacity retirement caps a row at S tokens)
-        worst = _pages_for_prompt(min(self.S, n + req.max_new_tokens),
-                                  self.ps)
+        worst = self._held_pages(min(self.S, n + req.max_new_tokens))
         if worst > self.pool.pages_total:
             raise ValueError(
                 f"request needs up to {worst} pages but the pool only has "
@@ -183,6 +220,20 @@ class PagedServingEngine(_ServingEngineBase):
                 "request")
         self.sched.enqueue_prefill(req)
         return req.req_id
+
+    def _held_pages(self, length) -> int:
+        """Pages a row with `length` tokens cached holds at most, all
+        groups: a full group's grow with it, a window group's stop at its
+        table's width."""
+        m = _pages_for_prompt(length, self.ps)
+        return sum(min(m, t.shape[1]) for t in self.group_tables)
+
+    def _prompt_pages(self, n) -> int:
+        """Pages the admission of an `n`-token prompt takes, all groups: a
+        window group only those of the prompt's last window."""
+        m = _pages_for_prompt(n, self.ps)
+        return sum(m - (g.spec.first_page(n, self.ps) if g.window else 0)
+                   for g in self.groups)
 
     def has_work(self):
         return (self.sched.has_waiting()
@@ -217,9 +268,14 @@ class PagedServingEngine(_ServingEngineBase):
         self._spill_row(victim)
         return True
 
+    def _row_pages(self, row):
+        """The row's pages per group, each in logical order."""
+        return [[int(p) for p in t[row] if p >= 0] for t in self.group_tables]
+
     def _spill_row(self, row):
         req = self.active[row]
-        pages = [int(p) for p in self.tables[row] if p >= 0]
+        by_group = self._row_pages(row)
+        pages = [p for group in by_group for p in group]
         with span("spill", rid=req.req_id, pages=len(pages),
                   **self._state_attrs()):
             kv_host = self.pool.read_pages(pages)
@@ -230,12 +286,17 @@ class PagedServingEngine(_ServingEngineBase):
         req.preemptions += 1
         self.sched.enqueue_resume(SpilledRequest(
             req, self.lengths[row], self.last_tok[row], kv_host, keys,
-            state_host))
-        self.tables[row, :] = -1
+            state_host, [len(g) for g in by_group], self.window_start[row]))
+        self._clear_tables(row)
         self._vacate(row)
         m = serving_metrics()
         m["preemptions"].inc()
         m["preempted_pages"].inc(len(pages))
+
+    def _clear_tables(self, row):
+        for t in self.group_tables:
+            t[row, :] = -1
+        self.window_start[row] = 0
 
     def _state_attrs(self):
         """Span attributes of a spill or a resume that moves a slot."""
@@ -244,10 +305,10 @@ class PagedServingEngine(_ServingEngineBase):
         return {"state_bytes": self.pool.state_row_nbytes}
 
     def _release_row(self, row):
-        for p in self.tables[row]:
-            if p >= 0:
-                self.pool.release(int(p))
-        self.tables[row, :] = -1
+        for group in self._row_pages(row):
+            for p in group:
+                self.pool.release(p)
+        self._clear_tables(row)
         self._vacate(row)
 
     # -- admission ------------------------------------------------------- #
@@ -267,54 +328,96 @@ class PagedServingEngine(_ServingEngineBase):
                 self._prefill_into(row, item)
         return len(work)
 
-    def _stack_pages(self, arr, n, m):
-        """[1, Sp, Hkv, D] prefill K/V -> [m, Hkv, ps, D] page-stacked."""
-        a = arr[0, :n]
-        pad = m * self.ps - n
-        if pad:
-            a = jnp.pad(a, ((0, pad), (0, 0), (0, 0)))
-        return a.reshape(m, self.ps, a.shape[1], a.shape[2]).transpose(
-            0, 2, 1, 3)
+    def _stack_pages(self, kv_layers, n):
+        """Per layer (k, v), each [1, Sp, Hkv, D] from a prefill -> per layer
+        (k, v) page-stacked [mb, Hkv, ps, D] over the whole bucket, `mb` =
+        ceil(Sp / ps), zero behind the prompt's `n` tokens. ONE program a
+        bucket, the length is data."""
+        if self._stack is None:
+            ps = self.ps
+
+            def stack(kv_layers, n):
+                def one(a):
+                    a = a[0]
+                    sp = a.shape[0]
+                    a = jnp.where((jnp.arange(sp) < n)[:, None, None], a, 0)
+                    a = jnp.pad(a, ((0, -sp % ps), (0, 0), (0, 0)))
+                    return a.reshape(-1, ps, a.shape[1], a.shape[2]
+                                     ).transpose(0, 2, 1, 3)
+
+                return [(one(k), one(v)) for k, v in kv_layers]
+
+            self._stack = jax.jit(stack)
+        return self._stack(kv_layers, np.int32(n))
 
     def _prefill_into(self, row, req):
         rid, n = req.req_id, len(req.prompt)
         req._t_admit = time.perf_counter()
         bucket = _bucket(n)
         with span("prefill", rid=rid, prompt_len=n, bucket=bucket,
-                  compiled=bucket not in self._prefill_cache):
-            logits, new_c, n, _ = self._run_prefill(req)
+                  compiled=bucket not in self._prefill_cache,
+                  **self._prefill_attrs(bucket)):
+            logits_row, new_c, n, _ = self._run_prefill(req)
         m = _pages_for_prompt(n, self.ps)
-        pages, write_mask = [], []
-        with span("pages", rid=rid, pages=m) as sp:
-            for j in range(m):
-                key = prefix_page_key(req.prompt, j, self.ps)
-                page = self.pool.lookup_prefix(key)
-                if page is not None:
-                    pages.append(page)
-                    write_mask.append(False)
-                    continue
-                page = self._alloc_or_preempt()
-                self.pool.register_prefix(key, page)
-                pages.append(page)
-                write_mask.append(True)
-            sp.set(prefix_hits=m - sum(write_mask))
-        if any(write_mask):
+        mb = _pages_for_prompt(bucket, self.ps)
+        tables, masks = [], []
+        with span("pages", rid=rid, pages=self._prompt_pages(n)) as sp:
+            for gi, group in enumerate(self.groups):
+                # a window group takes the prompt's last window only, and
+                # its pages are the row's own: no key, no registry
+                first = (group.spec.first_page(n, self.ps) if group.window
+                         else 0)
+                pages = np.zeros(mb, np.int32)
+                mask = np.zeros(mb, bool)
+                for j in range(first, m):
+                    key = None
+                    if not group.window:
+                        # a second full group's page of the same prefix is
+                        # another page: the group is part of its key
+                        key = (prefix_page_key(req.prompt, j, self.ps)
+                               + (bytes([gi]) if gi else b""))
+                    page = self.pool.lookup_prefix(key)
+                    if page is None:
+                        page = self._alloc_or_preempt()
+                        if key is not None:
+                            self.pool.register_prefix(key, page)
+                        mask[j] = True
+                    pages[j] = page
+                tables.append(pages[first:m])
+                masks.append((pages, mask))
+            hits = sum(len(t) for t in tables) - sum(
+                int(k.sum()) for _, k in masks)
+            sp.set(prefix_hits=hits)
+        if any(k.any() for _, k in masks):
             with span("write_pages", rid=rid,
-                      pages_written=sum(write_mask)):
-                paged = [new_c[li] for li in self.pool.page_layers]
-                k_layers = [self._stack_pages(k_, n, m) for k_, _ in paged]
-                v_layers = [self._stack_pages(v_, n, m) for _, v_ in paged]
-                self.pool.write_prompt_pages(pages, write_mask,
-                                             k_layers, v_layers)
+                      pages_written=sum(int(k.sum()) for _, k in masks)):
+                for group, (pages, mask) in zip(self.groups, masks):
+                    if not mask.any():
+                        continue
+                    stacked = self._stack_pages(
+                        [new_c[li] for li in group.layers], n)
+                    self.pool.write_prompt_pages(
+                        pages, mask, [k for k, _ in stacked],
+                        [v for _, v in stacked])
         if self.pool.state_layers:
             with span("write_state", rid=rid, row=row):
                 self.pool.write_state(
                     row, [new_c[li] for li in self.pool.state_layers])
-        self.tables[row, :m] = pages
+        for group, table, pages in zip(self.groups, self.group_tables,
+                                       tables):
+            table[row, :len(pages)] = pages
+            if group.window:
+                self.window_start[row] = group.spec.first_page(n, self.ps)
         with span("first_token", rid=rid):  # the host waits for the prefill
-            first = self._pick_token(logits[0, n - 1], req)
+            first = self._pick_token(logits_row, req)
         self._seat(row, req, n, first)
         self._emit(row, first)
+
+    def _prefill_attrs(self, bucket):
+        """What a model adds to the `prefill` span (`prefill_span_attrs`:
+        an expert layer's chunks)."""
+        attrs = getattr(self.model, "prefill_span_attrs", None)
+        return attrs(bucket) if attrs is not None else {}
 
     def _resume_into(self, row, sp: SpilledRequest):
         pages, restore_rows, restore_pages = [], [], []
@@ -332,28 +435,53 @@ class PagedServingEngine(_ServingEngineBase):
             self.pool.restore_pages(restore_pages, sp.kv_host, restore_rows)
             self.pool.write_state(row, sp.state_host)
             resume.set(pages_restored=len(restore_pages))
-        self.tables[row, :len(pages)] = pages
+        at = 0
+        for table, count in zip(self.group_tables, sp.group_pages):
+            table[row, :count] = pages[at:at + count]
+            at += count
+        self.window_start[row] = sp.window_start
         self._seat(row, sp.req, sp.length, sp.last_tok)
         serving_metrics()["resumes"].inc()
 
     # -- decode write-target maintenance -------------------------------- #
 
     def _ensure_write_target(self, row):
-        """Guarantee this row can scatter its next K/V: allocate at page
-        boundaries, copy-on-write off shared pages, unregister a private
-        page before its first divergent write."""
+        """Guarantee this row can scatter its next K/V in every group:
+        release the window pages the row's length has passed, allocate at
+        page boundaries, copy-on-write off shared pages, unregister a
+        private page before its first divergent write."""
         L = int(self.lengths[row])
-        j = L // self.ps
-        page = int(self.tables[row, j])
-        if page < 0:
-            self.tables[row, j] = self._alloc_or_preempt(requester_row=row)
-        elif self.pool.is_shared(page):
-            dst = self._alloc_or_preempt(requester_row=row)
-            self.pool.copy_page(page, dst)
-            self.pool.release(page)
-            self.tables[row, j] = dst
-        elif self.pool.is_registered(page):
-            self.pool.unregister_page(page)
+        start = self._window.first_page(L, self.ps) if self._windowed else 0
+        gone = start - int(self.window_start[row])
+        for group, table in zip(self.groups, self.group_tables):
+            j = L // self.ps
+            if group.window:
+                if gone > 0:
+                    for p in table[row, :gone]:
+                        self.pool.release(int(p))
+                    table[row, :-gone] = table[row, gone:]
+                    table[row, -gone:] = -1
+                    self._window_released += gone
+                j -= start
+            page = int(table[row, j])
+            if page < 0:
+                table[row, j] = self._alloc_or_preempt(requester_row=row)
+            elif self.pool.is_shared(page):
+                dst = self._alloc_or_preempt(requester_row=row)
+                self.pool.copy_page(page, dst)
+                self.pool.release(page)
+                table[row, j] = dst
+            elif self.pool.is_registered(page):
+                self.pool.unregister_page(page)
+        self.window_start[row] = start
+
+    def _update_page_gauges(self):
+        """Live pages by kind of group, for a model that has both."""
+        g = serving_metrics()["pages_live"]
+        for kind in ("full", "window"):
+            g.set(sum(int((t >= 0).sum())
+                      for grp, t in zip(self.groups, self.group_tables)
+                      if grp.window == (kind == "window")), kind=kind)
 
     def _note_routing(self, stats):
         """One decode tick's routing counts (held_moe.STAT_NAMES, summed
@@ -372,11 +500,14 @@ class PagedServingEngine(_ServingEngineBase):
         tables, lengths, last tokens, temperatures and keys are data."""
         stats_kw = {"with_stats": True} if self._moe_groups else {}
 
-        def decode(p, b, tok, offs, tables, temps, keys, caches):
+        def decode(p, b, tok, offs, tables, temps, keys, caches, *starts):
             pos = offs[:, None]
+            # a model with window layers is told each row's first cached
+            # position of theirs; `tables` is then one table a page group
+            kw = {"window_starts": starts[0]} if starts else {}
             logits, new_c, *stats = self._functional_forward(
                 p, b, tok[:, None], pos, caches, offs, tables=tables,
-                **stats_kw)
+                **stats_kw, **kw)
             last = logits[:, -1]
             # every row's token picked ON DEVICE, greedy or sampled; the
             # [B, vocab] logits stay there. A model's routing counts ride
@@ -401,11 +532,17 @@ class PagedServingEngine(_ServingEngineBase):
             return {}
         with span("write_targets") as sp:
             allocs, cows = self.pool.allocs_total, self.pool.cow_copies_total
+            released = self._window_released
             for i in live:
                 if self.active[i] is not None:  # an earlier COW may have spilled i
                     self._ensure_write_target(i)
             sp.set(pages_allocated=self.pool.allocs_total - allocs,
                    cow_copies=self.pool.cow_copies_total - cows)
+            if self._windowed:
+                gone = self._window_released - released
+                sp.set(window_pages_released=gone)
+                serving_metrics()["window_pages_released"].inc(gone)
+                self._update_page_gauges()
         live = [i for i in range(self.B) if self.active[i] is not None]
         if not live:
             return {}
@@ -414,6 +551,13 @@ class PagedServingEngine(_ServingEngineBase):
 
         state_rows = ({"state_rows": len(live)} if self.pool.state_layers
                       else {})
+        if self._windowed:
+            # what the two decode kernels must read this tick, in tokens:
+            # every row's context, and of it what its window still holds
+            ctx = self.lengths[live].astype(np.int64) + 1
+            state_rows.update(
+                context_tokens=int(ctx.sum()),
+                window_tokens=int(np.minimum(ctx, self._window.window).sum()))
         sampled = np.flatnonzero(self.temps > 0)  # live rows all: _vacate
         with span("decode_dispatch", rows=len(live),
                   sampled_rows=len(sampled), **self._decode_grid,
@@ -424,10 +568,15 @@ class PagedServingEngine(_ServingEngineBase):
             caches = ([kv + sc
                        for kv, sc in zip(self.pool.kv, self.pool.scales)]
                       if self.kv_quant else self.pool.kv)
+            if not self._windowed:   # one group: one table, no starts
+                tables, starts = jnp.asarray(self.tables), ()
+            else:
+                tables = tuple(jnp.asarray(t) for t in self.group_tables)
+                starts = (jnp.asarray(self.window_start * self.ps),)
             tokens, keys, logits, new_kv, stats = self._decode_jit(
                 self.params, self.buffers, jnp.asarray(self.last_tok),
-                jnp.asarray(self.lengths), jnp.asarray(self.tables),
-                *self._sampling_inputs(sampled), caches)
+                jnp.asarray(self.lengths), tables,
+                *self._sampling_inputs(sampled), caches, *starts)
             if self.kv_quant:
                 self.pool.kv = [tuple(c[:2]) for c in new_kv]
                 self.pool.scales = [tuple(c[2:]) for c in new_kv]

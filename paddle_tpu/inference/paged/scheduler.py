@@ -16,6 +16,12 @@ per live request — every live row can cross at most one page boundary per
 `page_size` decode steps, so this reserve makes same-tick pool exhaustion
 (and therefore preemption) the exception rather than the steady state.
 
+A model with sliding-window layers beside full ones holds pages in several
+page groups (`block_pool.page_layout`) out of the one free list: a request is
+charged what ALL its groups need (`pages_for`: a window group only the
+prompt's last window), a spilled one every page it held, and the reserve is a
+page per live request and group.
+
 Admission is by rows AND pages: `pick` also takes the free decode rows, and
 for a model with recurrent layers a row is a resource of its own (the row's
 state slot, `block_pool.RowState`): where pages are plentiful, rows are what
@@ -45,10 +51,18 @@ def _pages_for_prompt(n_tokens: int, page_size: int) -> int:
 
 
 class TwoQueueScheduler:
-    def __init__(self, page_size: int, watermark_pages: int | None = None):
+    def __init__(self, page_size: int, watermark_pages: int | None = None,
+                 pages_for=None, groups: int = 1):
+        """`pages_for(n)`: the pages the admission of an n-token prompt
+        takes (default: ceil(n / page_size), one group of layers that keep
+        everything); `groups`: the page groups a live row holds a write page
+        in (a model with window layers beside full ones has several)."""
         self.page_size = int(page_size)
-        # None -> dynamic: one reserved page per live request (min 1)
+        # None -> dynamic: one reserved page per live request and group
         self.watermark_pages = watermark_pages
+        self.pages_for = pages_for or (
+            lambda n: _pages_for_prompt(n, self.page_size))
+        self.groups = int(groups)
         self._seq = 0
         # bucket -> deque[(seq, req)]; FIFO within, arrival-merged across
         self.prefill: dict[int, collections.deque] = {}
@@ -89,7 +103,7 @@ class TwoQueueScheduler:
     def _watermark(self, live: int) -> int:
         if self.watermark_pages is not None:
             return self.watermark_pages
-        return max(1, live)
+        return max(1, live) * self.groups
 
     def _head_bucket(self):
         """Bucket holding the earliest-arrived waiting request."""
@@ -134,8 +148,7 @@ class TwoQueueScheduler:
             b = self._head_bucket()
             if b is None:
                 break
-            need = _pages_for_prompt(len(self.prefill[b][0][1].prompt),
-                                     self.page_size)
+            need = self.pages_for(len(self.prefill[b][0][1].prompt))
             if not fits(need):
                 return out
             _, req = self.prefill[b].popleft()

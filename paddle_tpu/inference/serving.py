@@ -178,8 +178,8 @@ class _ServingEngineBase:
         c = [tuple(Tensor(x) for x in layer_c) for layer_c in caches]
         kwargs = {k: Tensor(v) if isinstance(v, jax.Array) else v
                   for k, v in model_kw.items()}
-        if tables is not None:
-            kwargs["block_tables"] = Tensor(tables)
+        if tables is not None:   # one table, or one a page group
+            kwargs["block_tables"] = jax.tree.map(Tensor, tables)
         out, _ = functional_call(
             self.model, p, b, [Tensor(tok), Tensor(pos), c, Tensor(off)],
             kwargs=kwargs, train=False)
@@ -187,19 +187,23 @@ class _ServingEngineBase:
 
     def _run_prefill(self, req):
         """Batch-1 prefill over a zeroed bucket-length dense cache. Returns
-        (logits [1, Sp, V] device, new_caches per layer — [1, Sp, Hkv, D]
-        K and V, or a recurrent layer's state after token n - 1 — n, Sp)."""
+        (the logits [V] of the prompt's last position, on the device,
+        new_caches per layer — [1, Sp, Hkv, D] K and V, or a recurrent
+        layer's state after token n - 1 — n, Sp). The model cuts the hidden
+        state to that one position before its final norm and its head
+        (`logits_at`): a bucket's worth of logits is never made."""
         n = len(req.prompt)
         Sp = _bucket(n)
 
         def compile_prefill():
-            def prefill(p, b, tok, pos, caches, *lens):
+            def prefill(p, b, tok, pos, caches, last, *lens):
                 # a recurrent layer sees the bucket's zero padding, which
                 # attention never does: such a model is told the real length
                 kw = {"seq_lens": lens[0]} if lens else {}
                 logits, new_c = self._functional_forward(
-                    p, b, tok, pos, caches, jnp.int32(0), **kw)
-                return logits, new_c
+                    p, b, tok, pos, caches, jnp.int32(0), logits_at=last,
+                    **kw)
+                return logits[0, 0], new_c
 
             return jax.jit(prefill)
 
@@ -217,9 +221,10 @@ class _ServingEngineBase:
             zero_c = [spec.prefill_cache(Sp, self.kv_dtype)
                       for spec in self.cache_specs]
             lens = (jnp.asarray([n], jnp.int32),)
-        logits, new_c = pf(self.params, self.buffers,
-                           jnp.asarray(tok), jnp.asarray(pos), zero_c, *lens)
-        return logits, new_c, n, Sp
+        row, new_c = pf(self.params, self.buffers, jnp.asarray(tok),
+                        jnp.asarray(pos), zero_c,
+                        jnp.asarray([n - 1], jnp.int32), *lens)
+        return row, new_c, n, Sp
 
     # -- sampling -------------------------------------------------------- #
 
@@ -452,16 +457,15 @@ class ContinuousBatchingEngine(_ServingEngineBase):
             with span("prefill", rid=req.req_id, prompt_len=len(req.prompt),
                       bucket=bucket,
                       compiled=bucket not in self._prefill_cache):
-                logits, new_c, n, _ = self._run_prefill(req)
+                logits_row, new_c, n, _ = self._run_prefill(req)
                 # scatter the prompt's kv into this slot's cache rows [0, n)
                 for li, (k_, v_) in enumerate(new_c):
                     bk, bv = self.caches[li]
                     bk = bk.at[slot, :n].set(k_[0, :n])
                     bv = bv.at[slot, :n].set(v_[0, :n])
                     self.caches[li] = (bk, bv)
-            # device row gather: only [vocab] of THIS row ever materializes
             with span("first_token", rid=req.req_id):
-                first = self._pick_token(logits[0, n - 1], req)
+                first = self._pick_token(logits_row, req)
             self._seat(slot, req, n, first)
             self._emit(slot, first)
         return picked

@@ -99,6 +99,15 @@ def _build(reg):
             "serving_state_rows_live",
             "Decode rows whose recurrent-state slot holds a live request "
             "(models with recurrent layers only)"),
+        "window_pages_released": reg.counter(
+            "serving_window_pages_released_total",
+            "Pages of sliding-window layers released because the row's "
+            "length passed them (models with window layers only)"),
+        "pages_live": reg.gauge(
+            "serving_pages_live",
+            "Pages held by live rows, by kind of page group: full (kept "
+            "until the request ends, shareable) or window (expire)",
+            ("kind",)),
         "moe_routed_pairs_held": reg.counter(
             "moe_routed_pairs_held",
             "(token, expert) picks of decode ticks that named an expert "

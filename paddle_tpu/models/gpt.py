@@ -405,6 +405,16 @@ class GPTDecoderLayer(nn.Layer):
         return x
 
 
+def hidden_at(h, at):
+    """[B, S, d] -> [B, 1, d]: each row's hidden state at position `at[b]`,
+    cut BEFORE the final norm and the head, so that a serving prefill makes
+    one row of logits and not a bucket's."""
+    return run_op(
+        "hidden_at",
+        lambda x, i: jnp.take_along_axis(
+            x, i.astype(jnp.int32)[:, None, None], axis=1), [h, at])
+
+
 class GPTModel(nn.Layer):
     """Embeddings + decoder stack + final norm."""
 
@@ -425,7 +435,7 @@ class GPTModel(nn.Layer):
 
     def forward(self, input_ids, position_ids=None, caches=None,
                 cache_offset=None, attn_startend_row_indices=None,
-                block_tables=None):
+                block_tables=None, logits_at=None):
         B, S = input_ids.shape[0], input_ids.shape[1]
         if position_ids is None:
             if caches is not None and cache_offset is not None:
@@ -479,6 +489,8 @@ class GPTModel(nn.Layer):
                     new_caches.append(nc)
                 else:
                     h = out
+        if logits_at is not None:
+            h = hidden_at(h, logits_at)
         with jax.named_scope("ln"):
             h = self.final_norm(h)
         if caches is not None:
@@ -504,10 +516,12 @@ class GPTForCausalLM(nn.Layer):
 
     def forward(self, input_ids, position_ids=None, caches=None,
                 cache_offset=None, attn_startend_row_indices=None,
-                block_tables=None):
+                block_tables=None, logits_at=None):
+        """`logits_at` [B] int32: logits of that one position a row only,
+        [B, 1, vocab] (a serving prefill reads its prompt's last)."""
         out = self.gpt(input_ids, position_ids, caches, cache_offset,
                        attn_startend_row_indices=attn_startend_row_indices,
-                       block_tables=block_tables)
+                       block_tables=block_tables, logits_at=logits_at)
         if caches is not None:
             h, new_caches = out
         else:

@@ -38,6 +38,7 @@ from .. import nn
 from ..framework.core import Tensor, run_op
 from ..incubate.distributed.models.moe.held_moe import HeldExpertsMoE
 from ..nn import initializer as I
+from .gpt import hidden_at
 
 __all__ = ["GraniteHybridConfig", "GraniteHybridForCausalLM",
            "granite_hybrid_tiny", "ssd_chunked"]
@@ -480,8 +481,9 @@ class GraniteHybridForCausalLM(nn.Layer):
 
     def forward(self, input_ids, position_ids=None, caches=None,
                 cache_offset=None, block_tables=None, seq_lens=None,
-                with_stats=False):
-        """logits [B, S, vocab]; with `caches` also the new caches; with
+                with_stats=False, logits_at=None):
+        """logits [B, S, vocab] (with `logits_at` [B]: of that one position
+        a row, [B, 1, vocab]); with `caches` also the new caches; with
         `with_stats` also the int32 row of `held_moe.STAT_NAMES` summed over
         the layers (`expert_rows_max`: the largest)."""
         cfg = self.config
@@ -506,6 +508,8 @@ class GraniteHybridForCausalLM(nn.Layer):
                 block_tables, live, seq_lens, token_live)
             new_caches.append(new_cache)
             stats.append(st)
+        if logits_at is not None:
+            x = hidden_at(x, logits_at)
         with jax.named_scope("ln"):
             x = self.norm(x)
         with jax.named_scope("lm_head"):

@@ -43,13 +43,13 @@ __all__ = ["autotune_enabled", "pick_block_sizes", "cache_path",
 # "fused_layer_norm"/"fused_rms_norm" and run as `_fwd`/`_bwd`). Readers of
 # traces hold on to these: renaming one silences a metric.
 KERNEL_NAMES = (
-    "flash_fwd", "flash_bwd_dq", "flash_bwd_dkv",
+    "flash_fwd", "flash_bwd_dq", "flash_bwd_dkv", "flash_fwd_window",
     "flashmask_fwd", "flashmask_bwd_dq", "flashmask_bwd_dkv",
     "varlen_fwd", "varlen_bwd_dq", "varlen_bwd_dkv",
     "fused_layer_norm_fwd", "fused_layer_norm_bwd",
     "fused_rms_norm_fwd", "fused_rms_norm_bwd",
     "fused_rope", "grouped_gemm",
-    "decode_paged", "decode_paged_q8", "decode_dense",
+    "decode_paged", "decode_paged_q8", "decode_dense", "decode_window",
     "ssm_decode",
 )
 

@@ -41,6 +41,12 @@ The KV append (`paged_kv_write`, `paged_kv_write_q8`) rewrites each row's
 whole target page: XLA's TPU scatter wants the scattered dimensions major, so
 only a scatter of whole pages leaves the pool where it lies.
 
+Windowed (`decode_window`): a sliding-window layer's decode is the paged
+kernel's body under its own name, with one more mask (keys before
+`length - window`) and a table that starts at the row's first cached page
+and is `ceil(window / page_size) + 1` wide whatever the longest sequence is:
+the grid does not walk pages the row released long ago.
+
 Dense (`decode_dense`): the cache is contiguous, the grid stays (batch,
 kv_head, block) with one [block, D] tile of one head a step and the sequence
 tile autotuned. It shares the online-softmax update with the paged kernel
@@ -196,10 +202,12 @@ def pages_per_step(Hkv, ps, D, P, itemsize):
     return 1
 
 
-def _fetch_table(tables, lengths, ps, n):
+def _fetch_table(tables, lengths, ps, n, window=None):
     """[B, steps * n] int32, what slot j of step i of row b fetches and
     whether it counts. An entry >= 0 is a live slot's physical page: a table
-    entry that is not -1 and starts before the row's length. An entry < 0
+    entry that is not -1 and starts before the row's length (with `window`,
+    and ends behind `length - window`: a page wholly before the window is
+    dead even while the table still names it). An entry < 0
     is a dead slot, and `~entry` is the page the same slot held at the step
     before (steps counted through the rows, in the grid's order): the
     pipeline sees an unchanged block index and fetches nothing."""
@@ -209,6 +217,8 @@ def _fetch_table(tables, lengths, ps, n):
                 constant_values=-1)
     first_tok = jnp.arange(steps * n, dtype=jnp.int32) * ps
     live = (t >= 0) & (first_tok[None, :] < lengths[:, None])
+    if window is not None:
+        live = live & (first_tok[None, :] + ps > lengths[:, None] - window)
     t, live = t.reshape(B * steps, n), live.reshape(B * steps, n)
     step = jnp.arange(B * steps, dtype=jnp.int32)[:, None]
     last_live = jax.lax.cummax(jnp.where(live, step, -1), axis=0)
@@ -236,7 +246,7 @@ def _split_bf16(x):
 
 
 def _paged_kernel(fetch_ref, lens_ref, *refs, scale, ps, n, steps, g,
-                  quantized, dot_dtype):
+                  quantized, dot_dtype, window=None):
     """One grid step: all KV heads of `n` pages of row b. refs: n K blocks,
     n V blocks, each [1, Hkv, ps, D]; q [1, Hkv, g, D]; quantized: n K-scale
     and n V-scale tiles [8, Hkv]; the output [1, Hkv, g, D]; scratch m, l
@@ -286,6 +296,8 @@ def _paged_kernel(fetch_ref, lens_ref, *refs, scale, ps, n, steps, g,
         # a key counts if it lies before the row's length, in a live slot
         lane = jax.lax.broadcasted_iota(jnp.int32, (1, 1, n * ps), 2)
         live = (i * (n * ps) + lane) < length
+        if window is not None:   # the query at length - 1 sees `window` keys
+            live = live & ((i * (n * ps) + lane) >= length - window)
         for j, alive in enumerate(slot_live):
             in_slot = (lane >= j * ps) & (lane < (j + 1) * ps)
             live = live & (alive | jnp.logical_not(in_slot))
@@ -310,16 +322,20 @@ def _paged_kernel(fetch_ref, lens_ref, *refs, scale, ps, n, steps, g,
         o_ref[0] = (acc_scr[...] / l_safe).astype(o_ref.dtype)
 
 
-def _run_paged(q, kc, vc, tables, lengths, scale, n, kv_scales=None):
+def _run_paged(q, kc, vc, tables, lengths, scale, n, kv_scales=None,
+               window=None):
     """q: [B, Hkv, g, D]; kc/vc: the pool's [n_pages, Hkv, ps, D] arrays as
     they are, each passed `n` times, once per page slot of a grid step;
     tables: [B, P]; kv_scales: (k_scale, v_scale) f32 [n_pages, Hkv] for
-    int8 pools."""
+    int8 pools. With `window` the same body runs as `decode_window`: slot 0
+    of a row's table is the page of its FIRST cached position, positions and
+    `lengths` count from there, and keys before `length - window` are
+    masked."""
     B, Hkv, g, D = q.shape
     ps = kc.shape[2]
     quantized = kv_scales is not None
     lengths = lengths.astype(jnp.int32)
-    fetch = _fetch_table(tables, lengths, ps, n)
+    fetch = _fetch_table(tables, lengths, ps, n, window)
     steps = fetch.shape[1] // n
 
     def page_spec(j):
@@ -349,7 +365,7 @@ def _run_paged(q, kc, vc, tables, lengths, scale, n, kv_scales=None):
                  if q.dtype == kc.dtype == jnp.bfloat16 else jnp.float32)
     kernel = functools.partial(
         _paged_kernel, scale=scale, ps=ps, n=n, steps=steps, g=g,
-        quantized=quantized, dot_dtype=dot_dtype)
+        quantized=quantized, dot_dtype=dot_dtype, window=window)
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
         grid=(B, steps),
@@ -362,11 +378,20 @@ def _run_paged(q, kc, vc, tables, lengths, scale, n, kv_scales=None):
         ],
     )
     return named_pallas_call(
-        "decode_paged_q8" if quantized else "decode_paged", kernel,
+        _paged_name(quantized, window), kernel,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((B, Hkv, g, D), q.dtype),
         interpret=interpret_mode(),
     )(fetch, lengths, *operands)
+
+
+def _paged_name(quantized, window):
+    """The paged kernel's name, in the trace and in the tuner's registry."""
+    if window is not None:
+        if quantized:
+            raise NotImplementedError("a window over an int8 pool")
+        return "decode_window"
+    return "decode_paged_q8" if quantized else "decode_paged"
 
 
 def _split_heads(q, Hkv):
@@ -376,41 +401,47 @@ def _split_heads(q, Hkv):
 
 
 def paged_decode_attention(q, key_cache, value_cache, block_tables, lengths,
-                           scale=None, kv_scales=None):
+                           scale=None, kv_scales=None, window=None):
     """q: [B, H, D] (one decode step); key/value_cache:
     [n_pages, Hkv, page_size, D]; block_tables: [B, P] physical page ids
     (-1 unused); lengths: [B] valid tokens incl. the current one (caller has
     already written the step's K/V into the cache). With `kv_scales`
     (= (k_scale, v_scale) f32 [n_pages, Hkv]) the caches are int8 payloads
-    and dequantization is fused into the page load. Returns [B, H, D]."""
+    and dequantization is fused into the page load. With `window` (a
+    sliding-window layer, `decode_window`): the row's query, at position
+    `lengths - 1`, sees keys `lengths - window .. lengths - 1` only, and
+    `block_tables` [B, ceil(window / ps) + 1] starts at the page of the row's
+    first CACHED position, from which `lengths` counts too (the engine
+    releases the pages before it: `inference/paged/block_pool.WindowKV`), so
+    its width does not grow with the longest sequence. Returns [B, H, D]."""
     B, H, D = q.shape
     Hkv = key_cache.shape[1]
     if scale is None:
         scale = D ** -0.5
     q4, g = _split_heads(q, Hkv)
     n = _consult_tuner_paged(q4, key_cache, block_tables,
-                             quantized=kv_scales is not None)
+                             _paged_name(kv_scales is not None, window))
     out = _run_paged(q4, key_cache, value_cache, block_tables, lengths,
-                     scale, n, kv_scales=kv_scales)
+                     scale, n, kv_scales=kv_scales, window=window)
     return out.reshape(B, H, D)
 
 
-def _consult_tuner_paged(q4, kc, tables, quantized=False):
+def _consult_tuner_paged(q4, kc, tables, name="decode_paged"):
     """N, the pages a grid step takes, by way of the tuner. N follows from
     the shapes (`pages_per_step`) and the page size is the POOL's physical
     layout, so the tile (N * page_size, D) is the tuner's only candidate:
     it never sweeps (a serving process must not) and never counts a
     fallback, and the tile lands in chosen_tiles() / the step-timeline
-    record with its `consults`. The int8 pool records under its own tuner
-    name, so the telemetry tells which decode path ran."""
+    record with its `consults`. The int8 pool and the windowed kernel record
+    under their own tuner names, so the telemetry tells which decode path
+    ran."""
     from .autotune import pick_block_sizes
 
     B, Hkv, g, D = q4.shape
     ps, P = kc.shape[2], tables.shape[1]
     tile = (pages_per_step(Hkv, ps, D, P, kc.dtype.itemsize) * ps, D)
     tile = pick_block_sizes(
-        "decode_paged_q8" if quantized else "decode_paged",
-        1, P * ps, tile, lambda bq, bk: None,
+        name, 1, P * ps, tile, lambda bq, bk: None,
         allow_measure=False,
         signature=(B, Hkv, g, D, str(q4.dtype), P),
         candidates=[tile])

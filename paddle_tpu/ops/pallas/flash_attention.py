@@ -52,7 +52,7 @@ from jax.sharding import PartitionSpec as P
 from . import interpret_mode, mxu_dot, named_pallas_call
 from .partition import shard_plan
 
-__all__ = ["flash_attention_fwd", "flash_attention"]
+__all__ = ["flash_attention_fwd", "flash_attention", "flash_window_fwd"]
 
 NEG_INF = -1e30
 # unshifted-softmax saturation bound: exact below, equal-weight above (see
@@ -126,8 +126,15 @@ def _block_mask(q_start, k_start, bq, bk, off, causal, pad_k, skv,
 # --------------------------------------------------------------------------- #
 
 
+def _band_first_block(q_start, off, window, bk):
+    """The first key block a query block starting at `q_start` can see under
+    a causal window: the one holding column q_start + off - window + 1."""
+    return jnp.maximum(q_start + off - window + 1, 0) // bk
+
+
 def _fwd_kernel(q_ref, kt_ref, v_ref, *rest_refs,
-                scale, causal, sq, skv, bq, bk, nk, safe, has_kbias):
+                scale, causal, sq, skv, bq, bk, nk, safe, has_kbias,
+                window=None):
     if has_kbias:
         kb_ref, o_ref, lse_ref, m_scr, l_scr, acc_scr = rest_refs
     else:
@@ -137,7 +144,12 @@ def _fwd_kernel(q_ref, kt_ref, v_ref, *rest_refs,
     j = pl.program_id(3)
 
     q_start = i * bq
-    k_start = j * bk
+    if window is None:
+        k_start = j * bk
+    else:
+        # the grid's last axis walks the band only: its step j is key block
+        # first + j (`_fwd`'s index maps fetch the same block)
+        k_start = (_band_first_block(q_start, skv - sq, window, bk) + j) * bk
     # bottom-right-aligned causal (flash-attn convention): query at true row r
     # attends to cols <= r + (skv - sq), so decode (sq=1) sees the whole cache
     off = skv - sq
@@ -202,7 +214,30 @@ def _fwd_kernel(q_ref, kt_ref, v_ref, *rest_refs,
         else:
             _update_fast(s, v)
 
-    if causal:
+    if window is not None:
+        # causal band: query row r sees columns r + off - window + 1 ..
+        # r + off. Blocks wholly inside it skip the mask, blocks wholly
+        # outside it (beyond the diagonal, past the keys) skip everything
+        interior = ((k_start + bk - 1 <= q_start + off)
+                    & (k_start >= q_start + bq - 1 + off - window + 1)
+                    & (k_start + bk <= skv))
+        needed = ((k_start <= q_start + bq - 1 + off)
+                  & (k_start + bk - 1 >= q_start + off - window + 1)
+                  & (k_start < skv))
+
+        @pl.when(interior)
+        def _compute_inside():
+            _update(_logits(), v_ref[0, 0])
+
+        @pl.when(needed & ~interior)
+        def _compute_edge():
+            s = _logits()
+            row = q_start + jax.lax.broadcasted_iota(jnp.int32, (bq, 1), 0)
+            col = k_start + jax.lax.broadcasted_iota(jnp.int32, (1, bk), 1)
+            mask = ((col <= row + off) & (col > row + off - window)
+                    & (col < skv))
+            _update(jnp.where(mask, s, NEG_INF), v_ref[0, 0])
+    elif causal:
         # three-way block split: interior blocks (fully below the diagonal)
         # skip ALL mask work — only diagonal-crossing blocks pay for it
         interior = k_start + bk - 1 <= q_start + off
@@ -235,7 +270,9 @@ def _fwd_kernel(q_ref, kt_ref, v_ref, *rest_refs,
         _update(_logits(), v_ref[0, 0])
 
     # last block for this row: nk-1 in general; for causal the last needed one
-    if causal:
+    if window is not None:
+        last = nk - 1
+    elif causal:
         last = jnp.clip((q_start + bq - 1 + off) // bk, 0, nk - 1)
     else:
         last = nk - 1
@@ -253,7 +290,11 @@ def _fwd_kernel(q_ref, kt_ref, v_ref, *rest_refs,
 
 
 def _fwd(q, k, v, scale, causal, sq, skv, bq=None, bk=None, kbias=None,
-         safe=None):
+         safe=None, window=None):
+    """With `window` (causal, no key bias) the kernel runs as
+    `flash_fwd_window`: the grid's last axis is as long as the widest band of
+    key blocks a query block can see, not as the keys, and the index maps
+    start each query block at its band's first key block."""
     B, H, Sqp, D = q.shape
     _, Hkv, Skvp, _ = k.shape
     if bq is None or bk is None:
@@ -265,15 +306,32 @@ def _fwd(q, k, v, scale, causal, sq, skv, bq=None, bk=None, kbias=None,
     group = H // Hkv
     kt = jnp.swapaxes(k, 2, 3)  # [B, Hkv, D, Skv]: MXU-native QK^T layout
 
+    if window is None:
+        def kblock(i, j):
+            return j
+    else:
+        # a band spans window + bq - 1 columns, which touch at most this
+        # many key blocks; a step past the keys refetches the last block
+        # (no DMA) and computes nothing
+        blocks = nk
+        nk = min(nk, (window + bq - 2) // bk + 2)
+
+        def kblock(i, j):
+            return jnp.minimum(
+                _band_first_block(i * bq, skv - sq, window, bk) + j,
+                blocks - 1)
+
     kernel = functools.partial(
         _fwd_kernel, scale=scale, causal=causal, sq=sq, skv=skv,
         bq=bq, bk=bk, nk=nk, safe=safe,
-        has_kbias=kbias is not None,
+        has_kbias=kbias is not None, window=window,
     )
     in_specs = [
         pl.BlockSpec((1, 1, bq, D), lambda b, h, i, j: (b, h, i, 0)),
-        pl.BlockSpec((1, 1, D, bk), lambda b, h, i, j, g=group: (b, h // g, 0, j)),
-        pl.BlockSpec((1, 1, bk, D), lambda b, h, i, j, g=group: (b, h // g, j, 0)),
+        pl.BlockSpec((1, 1, D, bk),
+                     lambda b, h, i, j, g=group: (b, h // g, 0, kblock(i, j))),
+        pl.BlockSpec((1, 1, bk, D),
+                     lambda b, h, i, j, g=group: (b, h // g, kblock(i, j), 0)),
     ]
     args = [q, kt, v]
     if kbias is not None:  # [B, Skvp] additive per-key bias (padding mask)
@@ -282,7 +340,7 @@ def _fwd(q, k, v, scale, causal, sq, skv, bq=None, bk=None, kbias=None,
         in_specs.append(spec)
         args.append(arg)
     out, lse = named_pallas_call(
-        "flash_fwd", kernel,
+        "flash_fwd" if window is None else "flash_fwd_window", kernel,
         grid=(B, H, nq, nk),
         in_specs=in_specs,
         out_specs=[
@@ -764,6 +822,34 @@ def _flash_local(q, k, v, causal, scale, key_bias):
     else:
         out = _flash(qt, kt, vt, causal, scale, bq, bk, safe)
     return jnp.swapaxes(out, 1, 2)
+
+
+def flash_window_fwd(q, k, v, window, scale=None):
+    """Causal sliding-window attention forward (`flash_fwd_window`),
+    paddle layout: q [B, S, H, D], k/v [B, S, Hkv, D] -> [B, S, H, D]; query
+    i sees keys j with 0 <= i - j < window. Blocks wholly behind the window
+    are neither fetched nor computed: the grid walks each query block's band
+    only. Inference only (no VJP); single device."""
+    from .autotune import pick_block_sizes
+
+    if scale is None:
+        scale = 1.0 / math.sqrt(q.shape[-1])
+    qt = jnp.swapaxes(q, 1, 2)
+    kt = jnp.swapaxes(k.astype(q.dtype), 1, 2)
+    vt = jnp.swapaxes(v.astype(q.dtype), 1, 2)
+    B, H, S, D = qt.shape
+    # the tile follows from the shapes and is the tuner's only candidate:
+    # nothing sweeps inside a serving process
+    default = _block_sizes(S, S, d=D)
+    bq, bk = pick_block_sizes(
+        "flash_fwd_window", S, S, default, lambda bq, bk: None,
+        allow_measure=False,
+        signature=(B, H, kt.shape[1], D, str(q.dtype), int(window)),
+        candidates=[default])
+    out, _ = _fwd(_pad_seq(qt, bq), _pad_seq(kt, bk), _pad_seq(vt, bk),
+                  scale, True, S, S, bq=bq, bk=bk, safe=_safe_softmax(),
+                  window=int(window))
+    return jnp.swapaxes(out[:, :, :S], 1, 2)
 
 
 flash_attention = flash_attention_fwd

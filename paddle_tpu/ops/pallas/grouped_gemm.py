@@ -17,7 +17,9 @@ row range in one launch. TPU redesign (the megablox formulation):
   scalar-prefetch operand: tiles whose row offset is past the group's live
   rows SKIP the MXU work entirely and write zeros (compute scales with
   routed tokens rounded to bm, not with capacity — the ragged half of
-  "grouped/ragged").
+  "grouped/ragged"), and their index maps name the blocks already in VMEM,
+  so a dead tile fetches neither rows nor weights: a group's weights are
+  read once per LIVE row tile, whatever the stride.
 - Accumulation is f32 (`preferred_element_type`) whatever the input dtype,
   like every other kernel in the ladder.
 
@@ -137,16 +139,29 @@ def _gg_call(lhs, rhs, sizes, bm, bn):
     grid = (E * tiles_per_group, Np // bn)
     kernel = functools.partial(_gg_kernel, bm=bm,
                                tiles_per_group=tiles_per_group)
+
+    # a dead tile computes nothing, so it fetches nothing either: its steps
+    # name the blocks the step before them held (the group's last live row
+    # tile, the last N tile), and the pipeline issues no DMA for an
+    # unchanged block index. Only the zeros it must write cost anything.
+    def lhs_block(i, j, szs):
+        group, tile = i // tiles_per_group, i % tiles_per_group
+        live_tiles = (szs[group] + bm - 1) // bm
+        last_live = group * tiles_per_group + jnp.maximum(live_tiles - 1, 0)
+        return jnp.where(tile < live_tiles, i, last_live), 0
+
+    def rhs_block(i, j, szs):
+        group, tile = i // tiles_per_group, i % tiles_per_group
+        return group, 0, jnp.where(szs[group] > tile * bm, j, Np // bn - 1)
+
     out = named_pallas_call(
         "grouped_gemm", kernel,
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=1,
             grid=grid,
             in_specs=[
-                pl.BlockSpec((bm, Kp), lambda i, j, szs: (i, 0)),
-                pl.BlockSpec((1, Kp, bn),
-                             lambda i, j, szs, _t=tiles_per_group:
-                             (i // _t, 0, j)),
+                pl.BlockSpec((bm, Kp), lhs_block),
+                pl.BlockSpec((1, Kp, bn), rhs_block),
             ],
             out_specs=pl.BlockSpec((bm, bn), lambda i, j, szs: (i, j)),
         ),

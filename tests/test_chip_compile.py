@@ -131,6 +131,61 @@ def test_grouped_gemm_compiles_at_the_held_experts_shapes(one_chip, stride,
     assert f"bf16[{held},{k},{n}]" in call
 
 
+# serve-trinity-mixed-sat: 160 rows, 32 query heads on 4 KV heads of 128,
+# pages of 32, a pool of about 41k pages in two arrays; the full layers'
+# table 512 wide, the window layers' 65 (window 2048); prefill buckets to 16k;
+# 64 held experts of 2048 x (2 x 1024) and 1024 x 2048
+
+T_POOL = ((41000, 4, PS, D), jnp.bfloat16)
+
+
+@pytest.mark.parametrize("width,window,name", [
+    (512, None, "decode_paged"), (65, 2048, "decode_window")])
+def test_trinity_decode_kernels_compile_at_the_cells_shapes(one_chip, width,
+                                                            window, name):
+    from paddle_tpu.ops.pallas.decode_attention import pages_per_step
+
+    hlo = _compile(
+        lambda q, kc, vc, tables, lengths: paged_decode_attention(
+            q, kc, vc, tables, lengths, window=window),
+        one_chip, ((160, 32, D), jnp.bfloat16), T_POOL, T_POOL,
+        ((160, width), jnp.int32), ((160,), jnp.int32))
+    # what benchmark/readers/kernel_roofline_afmoe.py holds on to
+    assert re.search(r"%" + name + r"[.\w]* = .*tpu_custom_call", hlo)
+    # the window's table is 65 wide whatever max_seq_len is: 5 grid steps a
+    # row at 16 pages a step, where the full-width table takes 32
+    assert pages_per_step(4, PS, D, width, 2) == 16
+
+
+@pytest.mark.parametrize("seq", [512, 16384])
+def test_window_prefill_compiles_at_the_cells_buckets(one_chip, seq):
+    from paddle_tpu.ops.pallas.flash_attention import flash_window_fwd
+
+    kv = ((1, seq, 4, D), jnp.bfloat16)
+    hlo = _compile(lambda q, k, v: flash_window_fwd(q, k, v, 2048), one_chip,
+                   ((1, seq, 32, D), jnp.bfloat16), kv, kv)
+    assert re.search(r"%flash_fwd_window[.\w]* = .*tpu_custom_call", hlo)
+
+
+@pytest.mark.parametrize("stride,k,n,bn", [
+    (256, 2048, 2048, 128), (256, 1024, 2048, 128),
+    (1024, 2048, 2048, 512), (1024, 1024, 2048, 512)],
+    ids=["decode-in", "decode-out", "chunk-in", "chunk-out"])
+def test_grouped_gemm_compiles_at_trinitys_held_experts(one_chip, stride, k,
+                                                        n, bn):
+    """A decode tick's calls (224 rows: stride 256, the column tile 128) and
+    a prefill chunk's (1024 tokens at their worst-case stride, the column
+    tile 512: `held_moe._column_tile`)."""
+    hlo = _compile(
+        lambda rows, w, sizes: grouped_gemm.grouped_matmul(
+            rows, w, sizes, block=(256, bn)),
+        one_chip, ((64 * stride, k), jnp.bfloat16),
+        ((64, k, n), jnp.bfloat16), ((64,), jnp.int32))
+    (call,) = [line for line in hlo.splitlines()
+               if re.match(r"\s*(ROOT )?%grouped_gemm[.\w]* = ", line)]
+    assert f"bf16[64,{k},{n}]" in call
+
+
 def test_paged_decode_compiles_for_four_query_heads_a_kv_head(one_chip):
     pool = ((9700, 8, PS, D), jnp.bfloat16)
     hlo = _compile(

@@ -398,6 +398,8 @@ def _kernel_jaxprs():
         "flash_fwd": fwd(flash, q, q, q),
         "flash_bwd_dq": bwd(flash, q, q, q),
         "flash_bwd_dkv": bwd(flash, q, q, q),
+        "flash_fwd_window": fwd(
+            lambda q, k, v: fa.flash_window_fwd(q, k, v, 48), q, q, q),
         "flashmask_fwd": fwd(mask, q, q, q),
         "flashmask_bwd_dq": bwd(mask, q, q, q),
         "flashmask_bwd_dkv": bwd(mask, q, q, q),
@@ -415,6 +417,8 @@ def _kernel_jaxprs():
         "decode_paged_q8": fwd(lambda q: da.paged_decode_attention(
             q, pool.astype(jnp.int8), pool.astype(jnp.int8), tables, lens,
             kv_scales=(scales, scales)), qd),
+        "decode_window": fwd(lambda q: da.paged_decode_attention(
+            q, pool, pool, tables, lens, window=24), qd),
         "decode_dense": fwd(lambda q: da.dense_decode_attention(
             q, dense, dense, lens), qd),
         "ssm_decode": fwd(lambda s: sd.ssm_decode(
