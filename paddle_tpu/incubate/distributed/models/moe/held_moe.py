@@ -4,16 +4,21 @@ One chip's part of a layer whose experts are spread over several chips by
 expert parallelism (`model-configs` guide, section 4): the router keeps its
 published width and its experts per token and routes over ALL experts; of
 each token's picks, those that name an expert held here are computed, the
-others are discarded before the scatter and contribute nothing. What comes
+others are computed nowhere and contribute nothing. What comes
 out is this chip's partial sum `sum over the picked experts held here of
 gate_e * expert_e(x)`; the parts of all shares add up to the whole layer.
 Nothing here stands in for the other chips or their exchange.
 
-No token is ever dropped: rows are sorted by held expert into
-`grouped_gemm`'s uniform stride (`MoELayer`'s sort, `rank_in_group`) with
-the stride set to the worst case, every row to one expert. A dead tile costs
-the grouped GEMM no MXU work, so the price of the worst case is the zeroed
-scatter target, not arithmetic.
+The layout has ONE form. The (token, choice) pairs are sorted by held
+expert once (stable; a pair not computed here, an expert of another share or
+a row that is not live, sorts behind every held expert) and their tokens'
+rows are gathered in that order into one dense `[tokens x top_k, d_model]`
+buffer: the held experts' rows lie END TO END, each group starting where the
+one before ended, with no stride between them. `grouped_gemm` takes the
+groups ragged (`ops/pallas/grouped_gemm.ragged_matmul`), `y` is gathered
+back by the inverse order, and each token's gated picks are summed. Every
+held pair has its row by construction, so no token can be dropped, whatever
+the routing: `dropped_pairs` is 0 and stays a stat for the series it feeds.
 
 The gate is part of the layer's definition (`gate=`):
 
@@ -26,14 +31,12 @@ The gate is part of the layer's definition (`gate=`):
 
 Experts are gated MLPs, `w_out (silu(a) * b)` with `[a | b] = w_in x`.
 
-A call of more than `CHUNK_TOKENS` tokens (a long prefill) runs as a scan
-over chunks of that many: the zeroed scatter target is `held x stride x
-d_model` with the stride the worst case of the CALL's tokens, which a 16k
-bucket would make gigabytes; chunked it is bounded whatever the bucket. A
-chunk runs at its own worst-case stride (the grouped GEMM's dead tiles fetch
-nothing, so that costs zero writes only), and a chunk with no live token
-(the tail of a padded bucket) is skipped whole. A call of `CHUNK_TOKENS` or
-fewer is one chunk and compiles as it always did.
+A pass's largest buffer is `tokens x top_k x d_model`, which bounds the
+tokens of one pass: a call of more than `CHUNK_TOKENS` tokens (a prefill
+bucket of 8192 or 16384) runs as a scan over passes of that many, the held
+weights read once a pass, and a pass with no live token (the tail of a
+padded bucket) is skipped whole. A call of `CHUNK_TOKENS` or fewer is one
+pass with no scan.
 """
 
 from __future__ import annotations
@@ -44,12 +47,15 @@ import jax.numpy as jnp
 import paddle_tpu.nn as nn
 from paddle_tpu.nn import initializer as I
 from .....framework.core import Tensor, run_op
-from .moe_layer import rank_in_group
 
-__all__ = ["HeldExpertsMoE", "STAT_NAMES", "CHUNK_TOKENS", "chunks_for"]
+__all__ = ["HeldExpertsMoE", "STAT_NAMES", "CHUNK_TOKENS", "chunks_for",
+           "total_stats"]
 
-# the most tokens one pass of the layer takes; a larger call is a scan
-CHUNK_TOKENS = 1024
+# the most tokens one pass of the layer takes; a larger call is a scan. From
+# bytes: at 4096 tokens, 8 picks and 2048-wide bf16 rows the routed buffer
+# is 134 MB, and a 4096-token pass has the arithmetic to hide one reading of
+# the held weights
+CHUNK_TOKENS = 4096
 
 
 def chunks_for(tokens: int) -> int:
@@ -58,23 +64,68 @@ def chunks_for(tokens: int) -> int:
 
 # what `forward(..., with_stats=True)` counts, in the order of its int32 row
 STAT_NAMES = ("routed_pairs_held", "expert_rows_max", "expert_rows_sum",
-              "dropped_pairs")
+              "dropped_pairs", "tile_rows")
 
 
-def _row_tile(stride):
-    """The grouped GEMM's row tile for this stride: the largest of 256, 128,
-    ... that divides it. A tile as tall as the stride reads an expert's
-    weights once; beyond 256 rows the lhs block no longer fits VMEM beside
-    them."""
-    bm = 256
-    while stride % bm:
+def total_stats(*rows):
+    """The stat rows of a model's expert layers as ONE row: every count
+    summed over the layers, `expert_rows_max` the largest."""
+    rows = jnp.stack(rows)
+    return jnp.stack([rows[:, 0].sum(), rows[:, 1].max(), rows[:, 2].sum(),
+                      rows[:, 3].sum(), rows[:, 4].sum()])
+
+
+def _sort_by_expert(key, held):
+    """(order, back, counts): the stable sort of the pairs by `key` (0 ..
+    held, `held` for a pair not computed here) as a COUNTING sort. `back`
+    [pairs] is where each pair goes, `order` its inverse (which pair lies at
+    each sorted place), `counts` [held] the held experts' rows.
+
+    A pair's place is its expert's first row, plus the pairs of that expert
+    in the blocks before its own, plus its rank inside its block of 256,
+    which is a lower-triangular matrix times the block's one-hot keys: small
+    matmuls of zeros and ones, exact in any precision. `jnp.argsort` gives
+    the same order, but the chip's compiler takes 15 s over a sort of 32768
+    keys (a 4096-token pass), in every expert layer of every prefill
+    program."""
+    n, block = key.shape[0], 256
+    keys = jnp.pad(key, (0, -n % block), constant_values=held).reshape(
+        -1, block)
+    onehot = (keys[..., None] == jnp.arange(held + 1, dtype=jnp.int32)
+              ).astype(jnp.float32)                       # [blocks, 256, E]
+    within = jnp.einsum("ij,bje->bie",
+                        jnp.tril(jnp.ones((block, block), jnp.float32)),
+                        onehot)                           # rank in the block
+    per_block = within[:, -1]
+    counts = per_block.sum(0)
+    first = (jnp.cumsum(counts) - counts
+             + jnp.cumsum(per_block, axis=0) - per_block)  # [blocks, E]
+    # a pair's own expert's entry, read as a sum against its one-hot key (a
+    # gather of 32768 scalars takes the chip ten times as long)
+    back = ((first[:, None, :] + within - 1) * onehot).sum(-1)
+    back = back.reshape(-1)[:n].astype(jnp.int32)
+    order = jnp.zeros(n, jnp.int32).at[back].set(
+        jnp.arange(n, dtype=jnp.int32))
+    return order, back, counts[:held].astype(jnp.int32)
+
+
+def _row_tile(pairs, num_experts):
+    """The grouped GEMM's row tile for a pass of `pairs` routed rows over
+    `num_experts` experts: 256 where an expert gets 128 rows or more on
+    average (a prefill pass of thousands of rows: the MXU runs 256-row tiles
+    at 73 % of its peak and 128-row tiles at 52 %), else 128 (a decode tick,
+    a short bucket: the groups are a few rows each and the call is bound by
+    reading the weights; smaller tiles only lengthen the grid), halved
+    until it divides the pairs. Measured on the chip, PERF.md §6 (PR 34)."""
+    bm = 256 if pairs >= 128 * num_experts else 128
+    while bm > 16 and pairs % bm:
         bm //= 2
     return bm
 
 
 def _column_tile(n):
     """The widest of 512, 256, 128 that divides the N the kernel pads to:
-    beside a 256-row tile of 2048-wide rows it still fits VMEM twice over."""
+    beside a 256-row tile of 4096-wide rows it still fits VMEM."""
     n = -(-n // 128) * 128
     return next(bn for bn in (512, 256, 128) if n % bn == 0)
 
@@ -87,8 +138,10 @@ class HeldExpertsMoE(nn.Layer):
 
     forward(x [..., d_model], live=None, with_stats=False): `live`
     [tokens] bool leaves rows out of the routing altogether (batch padding,
-    free decode rows). With `with_stats` also returns an int32 [4] row, see
-    STAT_NAMES (rows are counted over the held experts)."""
+    free decode rows). With `with_stats` also returns an int32 [5] row, see
+    STAT_NAMES (rows are counted over the held experts; `tile_rows` is the
+    rows the grouped GEMM's visited row tiles cover, so `expert_rows_sum`
+    over it is the share of the kernel's rows that are real)."""
 
     def __init__(self, d_model, d_expert, num_experts, top_k, held=None,
                  weight_attr=None, gate="softmax", route_scale=1.0):
@@ -131,45 +184,50 @@ class HeldExpertsMoE(nn.Layer):
     def _build_fn(self, tokens, has_live):
         from .....ops.pallas import kernels_available
         from .....ops.pallas.autotune import pick_block_sizes
-        from .....ops.pallas.grouped_gemm import grouped_matmul, row_stride
+        from .....ops.pallas.grouped_gemm import (end_to_end_visits,
+                                                  ragged_matmul)
 
         first, held = self.held
         k, f = self.top_k, self.d_expert
         sigmoid, route_scale = self.gate == "sigmoid", self.route_scale
         whole, tokens = tokens, min(tokens, CHUNK_TOKENS)
-        stride = row_stride(tokens)          # worst case: every row to one
+        pairs = tokens * k
+        bm = _row_tile(pairs, self.num_experts)
         use_kernel = kernels_available()
 
-        def gmm(rows, w, sizes, wide):
+        def gmm(rows, w, counts, visits):
             if not use_kernel:
-                out = jnp.einsum("erk,ekn->ern",
-                                 rows.reshape(held, stride, -1), w)
-                return out.reshape(held * stride, -1)
-            # the tile follows from the stride, so it is the tuner's only
+                return jax.lax.ragged_dot(
+                    rows, w, counts, preferred_element_type=jnp.float32
+                ).astype(rows.dtype)
+            # the tiles follow from the shapes, so they are the tuner's only
             # candidate: nothing is swept inside a serving process, and
             # chosen_tiles()["grouped_gemm"] counts the consults
-            block = (_row_tile(stride),
-                     _column_tile(w.shape[2]) if wide else 128)
+            block = (bm, _column_tile(w.shape[2]))
             tile = pick_block_sizes(
-                "grouped_gemm", rows.shape[0], w.shape[2], block,
+                "grouped_gemm", pairs, w.shape[2], block,
                 lambda bm, bn: None, allow_measure=False,
-                signature=(held, stride, w.shape[1], w.shape[2],
+                signature=(held, pairs, w.shape[1], w.shape[2],
                            str(rows.dtype)),
                 candidates=[block])
-            return grouped_matmul(rows, w, sizes, block=tuple(tile))
+            return ragged_matmul(rows, w, counts, tuple(tile), visits)
 
         def route(x, router, bias, live):
-            """Flat (token, choice) pairs: `key` the held expert's local
-            index (`held` for a pair not computed here, which sorts behind
-            every held expert), `pos` its rank in its expert's group,
-            `counts` [held], `gates` [T, k] f32."""
+            """The flat (token, choice) pairs: `key` [T * k] the held
+            expert's local index (`held` for a pair not computed here: an
+            expert of another share, a row that is not live), `gates`
+            [T, k] f32."""
             logits = jnp.matmul(x, router,
                                 preferred_element_type=jnp.float32)
             if sigmoid:
                 score = jax.nn.sigmoid(logits)                  # [T, E] f32
                 _, top_expert = jax.lax.top_k(
                     score + bias[0].astype(jnp.float32), k)
-                picked = jnp.take_along_axis(score, top_expert, axis=-1)
+                # the picked scores, each read as a sum against its pick's
+                # one-hot (exact: one score and zeros; a gather of scalars
+                # is slow on the chip)
+                picked = (score[:, None, :] * jax.nn.one_hot(
+                    top_expert, score.shape[-1], dtype=score.dtype)).sum(-1)
                 gates = (picked / (picked.sum(-1, keepdims=True) + 1e-20)
                          * route_scale)
             else:
@@ -179,62 +237,62 @@ class HeldExpertsMoE(nn.Layer):
             here = (local >= 0) & (local < held)
             if live:
                 here = here & live[0][:, None]
-            key = jnp.where(here, local, held).reshape(-1)
-            pos, counts = rank_in_group(key, held)
-            return key, pos, counts, gates
+            return jnp.where(here, local, held).reshape(-1), gates
 
-        def experts(x, w_in, w_out, key, pos, counts, gates, wide=False):
-            """(out [T, d], kept [T * k] bool): the routed pairs through the
-            held experts at the worst-case row stride; a pair is not kept,
-            and scatters nowhere, if it is not computed here. `wide`: the
-            grouped GEMM's widest column tile (a chunk's calls: long
-            contiguous weight rows, a quarter of the grid)."""
-            kept = (key < held) & (pos < stride)
-            slot = jnp.where(kept, key * stride + pos, held * stride)
-            token = jnp.repeat(jnp.arange(tokens, dtype=jnp.int32), k)
-            rows = jnp.zeros((held * stride, x.shape[1]), x.dtype).at[
-                slot].set(x[token], mode="drop")
-            sizes = jnp.minimum(counts, stride).astype(jnp.int32)
-            ab = gmm(rows, w_in, sizes, wide)
+        def experts(x, w_in, w_out, key, gates):
+            """(out [T, d], counts [held], rows the visited tiles cover):
+            the pairs sorted by held expert ONCE, their tokens' rows
+            gathered end to end in that order, both grouped GEMMs over the
+            same groups, and `y` gathered back by the inverse order. A pair
+            not computed here lies behind every group, where the kernel
+            writes zeros: it adds nothing to its token."""
+            order, back, counts = _sort_by_expert(key, held)
+            visits = end_to_end_visits(counts, pairs, bm)
+            ab = gmm(x[order // k], w_in, counts, visits)
             hidden = (jax.nn.silu(ab[:, :f].astype(jnp.float32))
                       * ab[:, f:].astype(jnp.float32)).astype(x.dtype)
-            y = gmm(hidden, w_out, sizes, wide)
-            picked = jnp.take(y, slot, axis=0, mode="fill", fill_value=0)
-            out = (gates.reshape(-1, 1) * picked.astype(jnp.float32)
-                   ).reshape(tokens, k, -1).sum(1).astype(x.dtype)
-            return out, kept
+            y = gmm(hidden, w_out, counts, visits)
+            # gathered choice-major and summed pick after pick, so that the
+            # k terms are k contiguous slices of ONE gather and the sum is
+            # one fusion over them ([tokens, k, d] with k = 10 is a relayout
+            # of the whole buffer in f32)
+            picked = y[back.reshape(tokens, k).T.reshape(-1)]
+            out = None
+            for j in range(k):
+                term = gates[:, j, None] * picked[
+                    j * tokens:(j + 1) * tokens].astype(jnp.float32)
+                out = term if out is None else out + term
+            out = out.astype(x.dtype)
+            return out, counts, visits.n[0] * bm
 
-        def stats_of(key, counts, kept):
-            pairs = jnp.sum(key < held)
-            return jnp.stack([pairs, counts.max(), counts.sum(),
-                              pairs - jnp.sum(kept)]).astype(jnp.int32)
+        def stats_of(counts, tile_rows):
+            # every held pair has its row by construction: dropped is 0
+            return jnp.stack([counts.sum(), counts.max(), counts.sum(),
+                              0, tile_rows]).astype(jnp.int32)
+
+        def one_pass(x, router, w_in, w_out, bias, live):
+            key, gates = route(x, router, bias, live)
+            return experts(x, w_in, w_out, key, gates)
 
         def fn(x, router, w_in, w_out, *rest):
-            bias, live = rest[:sigmoid], rest[sigmoid:]
-            key, pos, counts, gates = route(x, router, bias, live)
-            out, kept = experts(x, w_in, w_out, key, pos, counts, gates)
-            return out, stats_of(key, counts, kept)
+            out, counts, tile_rows = one_pass(
+                x, router, w_in, w_out, rest[:sigmoid], rest[sigmoid:])
+            return out, stats_of(counts, tile_rows)
 
         if whole <= tokens:
             return fn
         n, pad = chunks_for(whole), -whole % tokens
 
-        def chunk_fn(x, router, w_in, w_out, bias, live):
-            key, pos, counts, gates = route(x, router, bias, live)
-            out, kept = experts(x, w_in, w_out, key, pos, counts, gates,
-                                wide=True)
-            return out, stats_of(key, counts, kept), counts
-
         def chunked(x, router, w_in, w_out, *rest):
-            """The layer over `n` chunks of the call's tokens, one after the
-            other; the padding behind the last is dead, and a chunk with no
+            """The layer over `n` passes of the call's tokens, one after the
+            other; the padding behind the last is dead, and a pass with no
             live token (a bucket's tail) is skipped whole. An expert's rows
             are counted over the whole call."""
             bias, live = rest[:sigmoid], rest[sigmoid:]
             xs = jnp.pad(x, ((0, pad), (0, 0))).reshape(n, tokens, -1)
             if not (has_live or pad):
                 def one(_, xc):
-                    return None, chunk_fn(xc, router, w_in, w_out, bias, ())
+                    return None, one_pass(xc, router, w_in, w_out, bias, ())
                 scanned = xs
             else:
                 real = live[0] if has_live else jnp.ones(whole, bool)
@@ -244,17 +302,15 @@ class HeldExpertsMoE(nn.Layer):
                     xc, alive = chunk
                     return None, jax.lax.cond(
                         alive.any(),
-                        lambda: chunk_fn(xc, router, w_in, w_out, bias,
+                        lambda: one_pass(xc, router, w_in, w_out, bias,
                                          (alive,)),
                         lambda: (jnp.zeros_like(xc),
-                                 jnp.zeros(4, jnp.int32),
-                                 jnp.zeros(held, jnp.int32)))
+                                 jnp.zeros(held, jnp.int32),
+                                 jnp.zeros((), jnp.int32)))
 
-            _, (out, stats, counts) = jax.lax.scan(one, None, scanned)
-            counts = counts.sum(0)
-            stats = jnp.stack([stats[:, 0].sum(), counts.max(),
-                               counts.sum(), stats[:, 3].sum()])
-            return out.reshape(n * tokens, -1)[:whole], stats
+            _, (out, counts, tile_rows) = jax.lax.scan(one, None, scanned)
+            return (out.reshape(n * tokens, -1)[:whole],
+                    stats_of(counts.sum(0), tile_rows.sum()))
 
         return chunked
 

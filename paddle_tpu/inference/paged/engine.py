@@ -486,12 +486,15 @@ class PagedServingEngine(_ServingEngineBase):
     def _note_routing(self, stats):
         """One decode tick's routing counts (held_moe.STAT_NAMES, summed
         over the layers) into the serving metrics."""
-        pairs, rows_max, rows_sum, dropped = (int(v) for v in stats)
+        pairs, rows_max, rows_sum, dropped, tile_rows = (
+            int(v) for v in stats)
         m = serving_metrics()
         m["moe_routed_pairs_held"].inc(pairs)
         m["moe_dropped_pairs"].inc(dropped)
         m["moe_expert_rows_max"].observe(rows_max)
         m["moe_expert_rows_mean"].observe(rows_sum / self._moe_groups)
+        m["moe_rows_live"].observe(rows_sum)
+        m["moe_rows_tiled"].observe(tile_rows)
 
     # ------------------------------------------------------------------ #
 

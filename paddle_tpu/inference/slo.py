@@ -30,6 +30,8 @@ __all__ = ["serving_metrics", "BoundedCompileCache"]
 _TPS_BUCKETS = tuple(0.5 * 2 ** i for i in range(14))
 # rows an expert gets in a decode tick: 1 .. 1024, x2 per bucket
 _ROWS_BUCKETS = tuple(float(2 ** i) for i in range(11))
+# rows all held experts of all layers get in a decode tick: 64 .. 128k
+_TICK_ROWS_BUCKETS = tuple(float(2 ** i) for i in range(6, 18))
 
 
 def _build(reg):
@@ -115,7 +117,7 @@ def _build(reg):
         "moe_dropped_pairs": reg.counter(
             "moe_dropped_pairs",
             "Held picks that found no row in the expert's group: must "
-            "stay 0, the group stride is the worst case"),
+            "stay 0, every held pick has its row by construction"),
         "moe_expert_rows_max": reg.histogram(
             "moe_expert_rows_max",
             "Per decode tick: the most rows any held expert of any layer "
@@ -124,6 +126,15 @@ def _build(reg):
             "moe_expert_rows_mean",
             "Per decode tick: rows per held expert, mean over layers and "
             "held experts", buckets=_ROWS_BUCKETS),
+        "moe_rows_live": reg.histogram(
+            "moe_rows_live",
+            "Per decode tick: rows of the held experts' groups, summed "
+            "over layers and held experts", buckets=_TICK_ROWS_BUCKETS),
+        "moe_rows_tiled": reg.histogram(
+            "moe_rows_tiled",
+            "Per decode tick: rows the grouped GEMM's visited row tiles "
+            "cover, summed likewise: live over tiled is the share of the "
+            "kernel's rows that are real", buckets=_TICK_ROWS_BUCKETS),
         "prefill_compiles": reg.counter(
             "serving_prefill_compiles_total",
             "Prefill program compiles, one per live length bucket",

@@ -46,7 +46,8 @@ import jax.numpy as jnp
 from .. import nn
 from ..framework.core import Tensor, run_op
 from ..incubate.distributed.models.moe.held_moe import (HeldExpertsMoE,
-                                                        chunks_for)
+                                                        chunks_for,
+                                                        total_stats)
 from ..nn import initializer as I
 from .gpt import hidden_at
 
@@ -436,12 +437,7 @@ class AfmoeForCausalLM(nn.Layer):
         if caches is not None:
             out += (caches if decode else new_caches,)
         if with_stats:
-            def total(*rows):
-                rows = jnp.stack(rows)
-                return jnp.stack([rows[:, 0].sum(), rows[:, 1].max(),
-                                  rows[:, 2].sum(), rows[:, 3].sum()])
-
-            out += (run_op("moe_stats", total, stats),)
+            out += (run_op("moe_stats", total_stats, stats),)
         return out[0] if len(out) == 1 else out
 
 

@@ -4,41 +4,54 @@ Reference analog: paddle/phi/kernels/fusion/cutlass/fused_moe_kernel.cu —
 the cutlass grouped GEMM that runs every expert's FFN over its own ragged
 row range in one launch. TPU redesign (the megablox formulation):
 
-- Rows are pre-sorted by expert into a UNIFORM-STRIDE layout: `lhs` is
-  [E * R, K] where group e owns rows [e*R, (e+1)*R) and only the first
-  `group_sizes[e]` of them are live (the MoE dispatch scatters tokens into
-  exactly this layout; R is padded to the row-tile multiple). The uniform
-  stride is what makes the expert dim a real mesh-shardable axis — under
-  expert parallelism the same kernel runs per ep-shard on [E/ep * R, K]
-  with no layout change.
-- The grid walks (row tile, N tile); each row tile belongs to exactly one
-  group (bm divides R), so the group's weight block rides an ordinary
-  BlockSpec index map — no scalar-dependent DMA. `group_sizes` is a
-  scalar-prefetch operand: tiles whose row offset is past the group's live
-  rows SKIP the MXU work entirely and write zeros (compute scales with
-  routed tokens rounded to bm, not with capacity — the ragged half of
-  "grouped/ragged"), and their index maps name the blocks already in VMEM,
-  so a dead tile fetches neither rows nor weights: a group's weights are
-  read once per LIVE row tile, whatever the stride.
-- Accumulation is f32 (`preferred_element_type`) whatever the input dtype,
-  like every other kernel in the ladder.
+- ONE kernel body over row groups given by each group's FIRST ROW and SIZE
+  (scalar prefetch). The grid walks (N tile, visit): a VISIT is one (row
+  tile, group) pair whose rows meet, listed group after group
+  (`plan_visits`, `jax.numpy` on a few hundred integers), at most
+  `tiles + groups - 1` of them. A group of r rows is visited in every tile
+  it has a row in and an empty group nowhere; a tile that two groups share
+  is visited once for each, the other's rows masked (the later visit finds
+  the earlier one's rows in VMEM and keeps them). A tile no group touches
+  gets one visit that writes zeros, a visit past the real count does
+  nothing, and neither fetches: their index maps name the blocks the last
+  live visit left in VMEM.
+- The N tile is the OUTER grid dimension: inside one N tile the visits of a
+  group follow each other, so its [K, bn] weight block is fetched once
+  however many row tiles the group spans, and the visits of a row tile
+  follow each other, so its output block is written back once. A call reads
+  the stacked weights once and its live rows N / bn times.
+- Two layouts, the same body. `ragged_matmul`: groups END TO END, each
+  starting where the one before ended (`jax.lax.ragged_dot`'s semantics;
+  the held experts' layer, `moe/held_moe.py`). `grouped_matmul`: the
+  UNIFORM STRIDE, `lhs` [E * R, K] with group e at rows [e*R, (e+1)*R)
+  (`MoELayer`'s dispatch scatters into it; the stride is what makes the
+  expert dim a mesh-shardable axis: under expert parallelism the same
+  kernel runs per ep-shard on [E/ep * R, K]), as `starts = e * R` with each
+  size rounded up to whole row tiles.
+- Whole-K blocks and f32 accumulation (`preferred_element_type`) whatever
+  the input dtype, like every other kernel in the ladder: a row's dot is
+  one MXU dot whatever tile it lies in.
 
-Semantics (pinned by tests/test_moe.py::TestGroupedGemm): rows inside a
-partially-live tile are still computed (they cost nothing extra — the MXU
+Semantics: every row of a group is its row times the group's matrix; every
+row of NO group is exactly zero, never garbage. For `grouped_matmul`
+(pinned by tests/test_moe.py::TestGroupedGemm) that reads: rows inside a
+partially-live tile are still computed (they cost nothing extra: the MXU
 runs whole tiles); rows in fully-dead tiles are zero. Callers that scatter
 zeros into dead rows (the MoE layer does) therefore get exact parity with
 the dense batched-GEMM formulation.
 
-Backward (custom VJP): dlhs reuses THIS kernel with the weights transposed
-(same tile skipping — dead tiles have zero cotangent by the same
-semantics); dgroup weights are a batched jnp matmul over the uniform
-stride, masked to the rows the forward actually computed. Autotune: tuner
-name "grouped_gemm", tile family (bm over the row stride, bn over N).
+Backward (custom VJPs): dlhs reuses THIS kernel with the weights transposed
+(the same visits); the group weights' gradient is `ragged_dot`'s own for
+groups end to end, and for the uniform stride a batched jnp matmul masked
+to the rows the forward computed. Autotune: tuner name "grouped_gemm";
+`grouped_matmul` offers the family (bm over the row stride, bn over N),
+`ragged_matmul`'s callers bring the one tile their shapes give.
 """
 
 from __future__ import annotations
 
 import functools
+from typing import NamedTuple
 
 import jax
 import jax.numpy as jnp
@@ -51,7 +64,8 @@ from jax.sharding import PartitionSpec as P
 from . import interpret_mode, mxu_dot, named_pallas_call
 from .partition import shard_plan
 
-__all__ = ["grouped_matmul", "default_tiles", "row_stride"]
+__all__ = ["grouped_matmul", "ragged_matmul", "plan_visits",
+           "end_to_end_visits", "Visits", "default_tiles", "row_stride"]
 
 
 def _pad_to(n, m):
@@ -96,79 +110,162 @@ def _tile_candidates(R, K, N, default):
 
 
 # --------------------------------------------------------------------------- #
+# the walk over (row tile, group) visits
+# --------------------------------------------------------------------------- #
+
+
+class Visits(NamedTuple):
+    """What the kernel's grid walks, as scalar-prefetch operands. A VISIT is
+    one (row tile, group) pair whose rows meet; `group`, `lhs_tile` and
+    `out_tile` are [tiles + groups - 1] int32, one entry a visit:
+
+    - visits 0 .. n[0] - 1 are LIVE, in group order and inside a group in
+      tile order, so one group's weights and one row tile's output stay in
+      VMEM over the visits that share them;
+    - visits n[0] .. n[1] - 1 are the row tiles no group touches, which are
+      written as zeros: `group` and `lhs_tile` still name the last live
+      visit's blocks, so nothing is fetched;
+    - visits from n[1] on do nothing and name the blocks of visit n[1] - 1.
+
+    `starts`, `ends` [groups]: each group's first row and the row behind its
+    last."""
+    group: jax.Array
+    lhs_tile: jax.Array
+    out_tile: jax.Array
+    starts: jax.Array
+    ends: jax.Array
+    n: jax.Array
+
+
+def plan_visits(starts, sizes, rows, bm) -> Visits:
+    """The visits of groups `[starts[g], starts[g] + sizes[g])` (ascending,
+    none overlapping) over the `rows // bm` row tiles of `bm` rows. A group
+    of r rows is visited in every tile it has a row in (at most
+    ceil(r / bm) + 1 of them), an empty group nowhere, a tile two groups
+    share once for each: at most tiles + groups - 1 visits with the dead
+    tiles' one each, whatever the sizes. All of it is `jax.numpy` on a few
+    hundred integers."""
+    tiles, groups = rows // bm, sizes.shape[0]
+    starts, sizes = starts.astype(jnp.int32), sizes.astype(jnp.int32)
+    ends = starts + sizes
+    first = starts // bm
+    per_group = jnp.where(sizes > 0, (ends - 1) // bm - first + 1, 0)
+    before = jnp.cumsum(per_group)
+    n_live = before[-1]
+    v = jnp.arange(tiles + groups - 1, dtype=jnp.int32)
+    # a dead visit reads the entries of the last live one
+    at = jnp.clip(v, 0, jnp.maximum(n_live - 1, 0))
+    group = jnp.minimum(jnp.searchsorted(before, at, side="right"),
+                        groups - 1).astype(jnp.int32)
+    tile = jnp.clip(first[group] + at - (before[group] - per_group[group]),
+                    0, tiles - 1)
+    touched = jnp.zeros(tiles, jnp.int32).at[
+        jnp.where(v < n_live, tile, tiles)].add(1, mode="drop") > 0
+    untouched = jnp.argsort(touched, stable=True).astype(jnp.int32)
+    n_all = n_live + tiles - jnp.sum(touched)
+    last = jnp.minimum(v, n_all - 1)
+    out_tile = jnp.where(last < n_live, tile[last],
+                         untouched[jnp.clip(last - n_live, 0, tiles - 1)])
+    return Visits(group, tile, out_tile, starts, ends,
+                  jnp.stack([n_live, n_all]).astype(jnp.int32))
+
+
+def end_to_end_visits(group_sizes, rows, bm) -> Visits:
+    """`plan_visits` for groups that start where the one before ended, over
+    `rows` rows padded to whole tiles of `bm`."""
+    sizes = group_sizes.astype(jnp.int32)
+    return plan_visits(jnp.cumsum(sizes) - sizes, sizes, _pad_to(rows, bm), bm)
+
+
+# --------------------------------------------------------------------------- #
 # kernel
 # --------------------------------------------------------------------------- #
 
 
-def _gg_kernel(sizes_ref, lhs_ref, rhs_ref, o_ref, *, bm, tiles_per_group):
-    i = pl.program_id(0)
-    group = i // tiles_per_group
-    off = (i % tiles_per_group) * bm
-    live = sizes_ref[group]
+def _gg_kernel(group_ref, lhs_tile_ref, out_tile_ref, starts_ref, ends_ref,
+               n_ref, lhs_ref, rhs_ref, o_ref, *, bm):
+    del lhs_tile_ref                      # the index maps' alone
+    v = pl.program_id(1)
+    tile = out_tile_ref[v]
+    # the first visit of a row tile writes all of it; a later one (the tile
+    # holds the end of one group and the start of the next) only its rows
+    fresh = jnp.logical_or(
+        v == 0, out_tile_ref[jnp.maximum(v - 1, 0)] != tile)
 
-    @pl.when(live > off)
+    @pl.when(v < n_ref[0])
     def _():
-        o_ref[...] = mxu_dot(
+        group = group_ref[v]
+        acc = mxu_dot(
             lhs_ref[...], rhs_ref[0],
             dimension_numbers=(((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        ).astype(o_ref.dtype)
+            preferred_element_type=jnp.float32)
+        row = tile * bm + jax.lax.broadcasted_iota(jnp.int32, acc.shape, 0)
+        mine = jnp.logical_and(row >= starts_ref[group],
+                               row < ends_ref[group])
 
-    @pl.when(live <= off)
+        @pl.when(fresh)
+        def _():
+            o_ref[...] = jnp.where(mine, acc, 0.0).astype(o_ref.dtype)
+
+        @pl.when(jnp.logical_not(fresh))
+        def _():
+            o_ref[...] = jnp.where(
+                mine, acc, o_ref[...].astype(jnp.float32)).astype(o_ref.dtype)
+
+    @pl.when(jnp.logical_and(v >= n_ref[0], v < n_ref[1]))
     def _():
-        # dead tile: zeros, not garbage — downstream reductions (dweight
-        # batched matmuls, combine gathers) must never meet uninitialized
-        # VMEM
+        # a tile no group touches: zeros, not garbage — downstream
+        # reductions (dweight batched matmuls, combine gathers) must never
+        # meet uninitialized VMEM
         o_ref[...] = jnp.zeros_like(o_ref)
 
 
-def _gg_call(lhs, rhs, sizes, bm, bn):
-    """lhs [E*R, K], rhs [E, K, N], sizes [E] -> [E*R, N]."""
-    E, K, N = rhs.shape
-    G = lhs.shape[0]
-    R = G // E
+def _gg_call(lhs, rhs, visits, bm, bn):
+    """lhs [M, K] (bm divides M), rhs [G, K, N], the visits of the G groups
+    over lhs's row tiles -> [M, N]: a group's rows times its matrix, zeros
+    in every row of no group."""
+    G, K, N = rhs.shape
+    M = lhs.shape[0]
     Kp, Np = max(128, _pad_to(K, 128)), max(128, _pad_to(N, 128))
     bn = min(bn, Np)
     if Np % bn:
         bn = Np
-    if lhs.shape != (G, Kp):
+    if lhs.shape != (M, Kp):
         lhs = jnp.pad(lhs, ((0, 0), (0, Kp - K)))
-    if rhs.shape != (E, Kp, Np):
+    if rhs.shape != (G, Kp, Np):
         rhs = jnp.pad(rhs, ((0, 0), (0, Kp - K), (0, Np - N)))
-    tiles_per_group = R // bm
-    grid = (E * tiles_per_group, Np // bn)
-    kernel = functools.partial(_gg_kernel, bm=bm,
-                               tiles_per_group=tiles_per_group)
-
-    # a dead tile computes nothing, so it fetches nothing either: its steps
-    # name the blocks the step before them held (the group's last live row
-    # tile, the last N tile), and the pipeline issues no DMA for an
-    # unchanged block index. Only the zeros it must write cost anything.
-    def lhs_block(i, j, szs):
-        group, tile = i // tiles_per_group, i % tiles_per_group
-        live_tiles = (szs[group] + bm - 1) // bm
-        last_live = group * tiles_per_group + jnp.maximum(live_tiles - 1, 0)
-        return jnp.where(tile < live_tiles, i, last_live), 0
-
-    def rhs_block(i, j, szs):
-        group, tile = i // tiles_per_group, i % tiles_per_group
-        return group, 0, jnp.where(szs[group] > tile * bm, j, Np // bn - 1)
-
+    # the column tile is the OUTER grid dimension: inside one column tile
+    # the visits come group after group, so a group's [K, bn] block is
+    # fetched once however many row tiles the group spans, and the visits
+    # of one row tile follow each other, so its output block is written
+    # back once. A visit that is not live names blocks already in VMEM.
     out = named_pallas_call(
-        "grouped_gemm", kernel,
+        "grouped_gemm", functools.partial(_gg_kernel, bm=bm),
         grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=1,
-            grid=grid,
+            num_scalar_prefetch=len(visits),
+            grid=(Np // bn, visits.group.shape[0]),
             in_specs=[
-                pl.BlockSpec((bm, Kp), lhs_block),
-                pl.BlockSpec((1, Kp, bn), rhs_block),
+                pl.BlockSpec((bm, Kp),
+                             lambda j, v, group, lhs_tile, *_: (lhs_tile[v], 0)),
+                pl.BlockSpec((1, Kp, bn),
+                             lambda j, v, group, *_: (group[v], 0, j)),
             ],
-            out_specs=pl.BlockSpec((bm, bn), lambda i, j, szs: (i, j)),
+            out_specs=pl.BlockSpec(
+                (bm, bn),
+                lambda j, v, group, lhs_tile, out_tile, *_: (out_tile[v], j)),
         ),
-        out_shape=jax.ShapeDtypeStruct((G, Np), lhs.dtype),
+        out_shape=jax.ShapeDtypeStruct((M, Np), lhs.dtype),
         interpret=interpret_mode(),
-    )(sizes.astype(jnp.int32), lhs, rhs)
+    )(*visits, lhs, rhs)
     return out[:, :N]
+
+
+def _uniform_visits(sizes, E, R, bm):
+    """The uniform stride as ragged groups: group e starts at row e * R and
+    is its live rows rounded up to whole row tiles (a partially-live tile
+    is computed whole)."""
+    sizes = jnp.minimum(-(-sizes.astype(jnp.int32) // bm) * bm, R)
+    return plan_visits(jnp.arange(E, dtype=jnp.int32) * R, sizes, E * R, bm)
 
 
 # --------------------------------------------------------------------------- #
@@ -178,11 +275,13 @@ def _gg_call(lhs, rhs, sizes, bm, bn):
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4))
 def _grouped_matmul(lhs, rhs, sizes, bm, bn):
-    return _gg_call(lhs, rhs, sizes, bm, bn)
+    E = rhs.shape[0]
+    return _gg_call(lhs, rhs, _uniform_visits(sizes, E, lhs.shape[0] // E, bm),
+                    bm, bn)
 
 
 def _gmm_fwd(lhs, rhs, sizes, bm, bn):
-    return _gg_call(lhs, rhs, sizes, bm, bn), (lhs, rhs, sizes)
+    return _grouped_matmul(lhs, rhs, sizes, bm, bn), (lhs, rhs, sizes)
 
 
 def _gmm_bwd(bm, bn, res, dout):
@@ -192,7 +291,8 @@ def _gmm_bwd(bm, bn, res, dout):
     # dlhs: the same grouped kernel against the transposed weights — dead
     # tiles write zeros, matching the forward's "dead rows are zero" output
     # semantics exactly
-    dlhs = _gg_call(dout, jnp.swapaxes(rhs, 1, 2), sizes, bm,
+    dlhs = _gg_call(dout, jnp.swapaxes(rhs, 1, 2),
+                    _uniform_visits(sizes, E, R, bm), bm,
                     min(bn, max(128, _pad_to(K, 128))))
     # drhs[e] = lhs_e^T @ dout_e over the rows the forward COMPUTED —
     # live tiles in full (partially-live tiles run whole), dead tiles not
@@ -225,7 +325,7 @@ def _tuned_tiles(lhs, rhs, sizes, R):
     default = default_tiles(R, K, N)
 
     def run_with(bm, bn):
-        out = _gg_call(lhs, rhs, sizes, bm, bn)
+        out = _gg_call(lhs, rhs, _uniform_visits(sizes, E, R, bm), bm, bn)
         out.block_until_ready()
 
     concrete = not any(isinstance(v, jax.core.Tracer)
@@ -235,6 +335,57 @@ def _tuned_tiles(lhs, rhs, sizes, R):
         allow_measure=concrete,
         signature=(E, R, K, N, str(lhs.dtype)),
         candidates=_tile_candidates(R, K, N, default))
+
+
+def ragged_matmul(lhs, rhs, group_sizes, block, visits=None):
+    """Groups that lie END TO END: out[r] = lhs[r] @ rhs[g] for the rows r of
+    group g, which starts where group g - 1 ended (group 0 at row 0) and has
+    `group_sizes[g]` rows; rows behind the last group come back zero
+    (`jax.lax.ragged_dot`'s semantics, which is what the tests hold it to).
+
+    lhs: [M, K]; rhs: [G, K, N]; group_sizes: [G] int32, their sum at most M.
+    `block` = (bm, bn): M is padded to whole row tiles, so choose a bm that
+    divides it. `visits`: `end_to_end_visits` of these groups at this bm,
+    for a caller that makes several calls over the same groups or counts the
+    visits. Differentiable in lhs and rhs: dlhs is this kernel against the
+    transposed weights, drhs is `jax.lax.ragged_dot`'s own."""
+    bm, bn = block
+    M = lhs.shape[0]
+    if visits is None:
+        visits = end_to_end_visits(group_sizes, M, bm)
+    if M % bm:
+        lhs = jnp.pad(lhs, ((0, -M % bm), (0, 0)))
+    return _ragged_matmul(lhs, rhs, group_sizes.astype(jnp.int32), visits,
+                          bm, bn)[:M]
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5))
+def _ragged_matmul(lhs, rhs, sizes, visits, bm, bn):
+    return _gg_call(lhs, rhs, visits, bm, bn)
+
+
+def _rmm_fwd(lhs, rhs, sizes, visits, bm, bn):
+    return _gg_call(lhs, rhs, visits, bm, bn), (lhs, rhs, sizes, visits)
+
+
+def _rmm_bwd(bm, bn, res, dout):
+    lhs, rhs, sizes, visits = res
+    K = rhs.shape[1]
+    dlhs = _gg_call(dout, jnp.swapaxes(rhs, 1, 2), visits, bm,
+                    min(bn, max(128, _pad_to(K, 128))))
+    _, pull = jax.vjp(
+        lambda w: jax.lax.ragged_dot(
+            lhs, w, sizes, preferred_element_type=jnp.float32), rhs)
+    (drhs,) = pull(dout.astype(jnp.float32))
+
+    def no_grad(a):
+        return np.zeros(a.shape, jax.dtypes.float0)
+
+    return (dlhs.astype(lhs.dtype), drhs, no_grad(sizes),
+            jax.tree.map(no_grad, visits))
+
+
+_ragged_matmul.defvjp(_rmm_fwd, _rmm_bwd)
 
 
 def grouped_matmul(lhs, rhs, group_sizes, block=None):

@@ -404,15 +404,15 @@ _MASKS = {None: None, "sparse": lambda i: i % 7 != 0,
 @pytest.mark.parametrize("tokens,live,chunk", [
     (96, None, 32), (100, "sparse", 32), (64, "sparse", 32),
     (96, "prefix", 32),        # a bucket's tail: the third chunk is dead
-    # two full chunks through the grouped GEMM's 256-row tiles (largest
-    # group 390-450 rows), then a padded last chunk of 76 tokens
+    # two full passes through the grouped GEMM's 256-row tiles (2048 pairs
+    # a pass), then a padded last pass of 76 tokens
     (1100, None, 512), (1100, "sparse", 512)])
 def test_chunked_calls_equal_the_unchunked_layer(monkeypatch, tokens, live,
                                                  chunk):
-    """A call of more tokens than a chunk runs as a scan over chunks (here 32
-    or 512 for 1024) and gives what one pass gives, its stats summed over the
-    call. Every chunk runs at its own worst-case stride, so no pair is
-    dropped; a chunk with no live token is skipped."""
+    """A call of more tokens than a pass takes runs as a scan over passes
+    (here 32 or 512 for 4096) and gives what one pass gives, its stats summed
+    over the call. Every held pair has its row in its pass, so no pair is
+    dropped; a pass with no live token is skipped."""
     layer = _moe((2, 4))
     x = np.random.default_rng(6).normal(size=(tokens, 32)).astype(np.float32)
     mask = (paddle.to_tensor(_MASKS[live](np.arange(tokens))) if live
@@ -423,11 +423,139 @@ def test_chunked_calls_equal_the_unchunked_layer(monkeypatch, tokens, live,
     assert held_moe.chunks_for(tokens) == -(-tokens // chunk)
     many, stats2 = layer(paddle.to_tensor(x), live=mask, with_stats=True)
     assert np.abs(np.asarray(one._value) - np.asarray(many._value)).max() < 1e-6
-    assert np.array_equal(np.asarray(stats1._value), np.asarray(stats2._value))
-    assert int(stats2._value[3]) == 0            # no pair is ever dropped
-    if chunk == 512:    # the chunks went through the kernel's tiles
+    stats1, stats2 = np.asarray(stats1._value), np.asarray(stats2._value)
+    # pairs, the largest group, the groups' rows, dropped: those of the call
+    assert np.array_equal(stats1[:4], stats2[:4])
+    assert int(stats2[3]) == 0                   # no pair is ever dropped
+    # the row tiles are each pass's own: they cover the groups' rows, and
+    # leave at most a tile open at either end of a group
+    names = held_moe.STAT_NAMES
+    rows, tiled = names.index("expert_rows_sum"), names.index("tile_rows")
+    for stats, passes in ((stats1, 1), (stats2, held_moe.chunks_for(tokens))):
+        bm = held_moe._row_tile(min(tokens, 4096 if passes == 1 else chunk)
+                                * 4, 8)
+        assert stats[rows] <= stats[tiled] <= stats[rows] + passes * 4 * 2 * bm
+        assert stats[tiled] % bm == 0
+    if chunk == 512:    # the passes went through the kernel's tiles
         tiles = autotune.chosen_tiles()["grouped_gemm"]
         assert tiles["consults"] > 0
+
+
+def _dense_reference(layer, x, live):
+    """Every expert over every token, then each token's gated picks of the
+    HELD experts summed in pick order: what the layer is, with no sort, no
+    layout and no kernel."""
+    first, held = layer.held
+    k = layer.top_k
+    logits = x @ np.asarray(layer.router._value, np.float64)
+    if layer.gate == "sigmoid":
+        score = 1 / (1 + np.exp(-logits))
+        picks = np.argsort(-(score + np.asarray(layer.expert_bias._value)),
+                           axis=-1, kind="stable")[:, :k]
+        chosen = np.take_along_axis(score, picks, -1)
+        gates = chosen / (chosen.sum(-1, keepdims=True) + 1e-20) \
+            * layer.route_scale
+    else:
+        picks = np.argsort(-logits, axis=-1, kind="stable")[:, :k]
+        chosen = np.take_along_axis(logits, picks, -1)
+        e = np.exp(chosen - chosen.max(-1, keepdims=True))
+        gates = e / e.sum(-1, keepdims=True)
+    w_in = np.asarray(layer.w_in._value, np.float64)
+    w_out = np.asarray(layer.w_out._value, np.float64)
+    f = layer.d_expert
+    out = np.zeros_like(x)
+    counts = np.zeros(held, np.int64)
+    for t in range(x.shape[0]):
+        if live is not None and not live[t]:
+            continue
+        for j in range(k):
+            e = picks[t, j] - first
+            if 0 <= e < held:
+                ab = x[t] @ w_in[e]
+                hidden = ab[:f] / (1 + np.exp(-ab[:f])) * ab[f:]
+                out[t] += gates[t, j] * (hidden @ w_out[e])
+                counts[e] += 1
+    return out, counts
+
+
+@pytest.mark.parametrize("live", [None, "sparse", "prefix"])
+@pytest.mark.parametrize("gate", ["softmax", "sigmoid"])
+@pytest.mark.parametrize("held", [(0, 8), (2, 4)])
+def test_the_held_layer_is_the_dense_per_expert_sum(gate, held, live):
+    """Both gates, the whole layer and a share, with and without `live`:
+    the rows end to end through the ragged kernel against every expert over
+    every token, and the stats against the picks counted by hand."""
+    paddle.seed(11)
+    layer = HeldExpertsMoE(32, 16, 8, 4, held=held, gate=gate,
+                           route_scale=2.826)
+    if gate == "sigmoid":
+        layer.expert_bias._value = jnp.asarray(
+            np.random.default_rng(2).normal(0, 0.05, 8), jnp.float32)
+    tokens = 100
+    x = np.random.default_rng(8).normal(size=(tokens, 32))
+    mask = _MASKS[live](np.arange(tokens)) if live else None
+    got, stats = layer(paddle.to_tensor(x.astype(np.float32)),
+                       live=None if mask is None else paddle.to_tensor(mask),
+                       with_stats=True)
+    want, counts = _dense_reference(layer, x.astype(np.float32).astype(
+        np.float64), mask)
+    assert np.abs(np.asarray(got._value) - want).max() < 1e-5
+    if mask is not None:
+        assert not np.asarray(got._value)[~mask].any()
+    stats = dict(zip(held_moe.STAT_NAMES, np.asarray(stats._value).tolist()))
+    assert stats["routed_pairs_held"] == stats["expert_rows_sum"] \
+        == counts.sum()
+    assert stats["expert_rows_max"] == counts.max()
+    assert stats["dropped_pairs"] == 0
+    # the visited row tiles by hand: the groups lie end to end in 16-row
+    # tiles (400 pairs: the largest of 256, 128, ... that divides them)
+    bm = held_moe._row_tile(tokens * 4, 8)
+    assert bm == 16
+    ends = np.cumsum(counts)
+    visits = sum(-(-e // bm) - (e - n) // bm
+                 for e, n in zip(ends, counts) if n)
+    assert stats["tile_rows"] == visits * bm
+
+
+def test_most_rows_of_a_prefill_pass_are_real():
+    """A call of a prefill's shape (the prefill program returns no stats):
+    with the rows end to end the row tiles the kernel visits hold mostly
+    real rows, where a worst-case stride made them one in sixteen."""
+    layer = _moe((0, 4))
+    x = np.random.default_rng(12).normal(size=(2048, 32)).astype(np.float32)
+    _, stats = layer(paddle.to_tensor(x), with_stats=True)
+    stats = dict(zip(held_moe.STAT_NAMES, np.asarray(stats._value).tolist()))
+    assert stats["expert_rows_sum"] > 3000       # about half of 8192 pairs
+    assert stats["expert_rows_sum"] / stats["tile_rows"] > 0.5
+    assert stats["dropped_pairs"] == 0
+
+
+@pytest.mark.parametrize("bucket,passes", [(512, 1), (4096, 1), (8192, 2),
+                                           (16384, 4)])
+def test_a_prefills_span_says_how_many_passes_its_bucket_takes(model, bucket,
+                                                               passes):
+    """A bucket up to `CHUNK_TOKENS` = 4096 is ONE pass of each expert layer
+    (no scan); 8192 and 16384 are scans of 2 and 4."""
+    assert held_moe.CHUNK_TOKENS == 4096
+    assert model.prefill_span_attrs(bucket) == {"chunks": passes}
+
+
+def test_a_ticks_routing_is_observed_once_on_both_row_histograms(model):
+    eng = _engine(model)
+    reg = default_registry()
+    eng.add_request(_prompt(20, 3), max_new_tokens=4)
+    eng.run()
+    live, tiled = reg.get("moe_rows_live"), reg.get("moe_rows_tiled")
+    mean = reg.get("moe_expert_rows_mean")
+    before = (live.count(), live.sum(), tiled.count(), tiled.sum(),
+              mean.count(), mean.sum())
+    eng._note_routing(np.array([70, 9, 70, 0, 192], np.int32))
+    assert live.count() == before[0] + 1 and live.sum() == before[1] + 70
+    assert tiled.count() == before[2] + 1 and tiled.sum() == before[3] + 192
+    # one observation a decode tick, as the other routing series
+    assert before[0] == before[2] == before[4] > 0
+    assert before[1] == pytest.approx(before[5] * eng._moe_groups)
+    assert before[3] >= before[1]
 
 
 # -- 9. the two window kernels against plain masked attention --------------- #

@@ -113,22 +113,36 @@ def test_ssm_decode_compiles_in_place_at_the_cells_shapes(one_chip,
     assert memory.temp_size_in_bytes < state_bytes // 8
 
 
-@pytest.mark.parametrize("stride,k,n", [(128, 4096, 1536), (128, 768, 4096),
-                                        (1024, 4096, 1536)],
-                         ids=["decode-in", "decode-out", "prefill-in"])
-def test_grouped_gemm_compiles_at_the_held_experts_shapes(one_chip, stride,
-                                                          k, n):
-    held = 36
+def _held_experts_call(one_chip, held, experts, tokens, top_k, k, n):
+    """The held layer's `grouped_gemm` call over `tokens x top_k` routed rows
+    laid end to end, at the tiles the layer takes from those shapes, compiled
+    for the chip: the HLO line of the kernel's call."""
+    from paddle_tpu.incubate.distributed.models.moe import held_moe
+
+    pairs = tokens * top_k
+    block = (held_moe._row_tile(pairs, experts), held_moe._column_tile(n))
     hlo = _compile(
-        lambda rows, w, sizes: grouped_gemm.grouped_matmul(
-            rows, w, sizes, block=(min(stride, 256), 128)),
-        one_chip, ((held * stride, k), jnp.bfloat16),
-        ((held, k, n), jnp.bfloat16), ((held,), jnp.int32))
-    # what benchmark/readers/kernel_roofline_hybrid.py holds on to
+        lambda rows, w, sizes: grouped_gemm.ragged_matmul(rows, w, sizes,
+                                                          block),
+        one_chip, ((pairs, k), jnp.bfloat16), ((held, k, n), jnp.bfloat16),
+        ((held,), jnp.int32))
     (call,) = [line for line in hlo.splitlines()
                if re.match(r"\s*(ROOT )?%grouped_gemm[.\w]* = ", line)]
     assert 'custom_call_target="tpu_custom_call"' in call
-    assert f"bf16[{held},{k},{n}]" in call
+    return call
+
+
+@pytest.mark.parametrize("tokens,k,n", [
+    (128, 4096, 1536), (128, 768, 4096), (1024, 4096, 1536),
+    (1024, 768, 4096)],
+    ids=["decode-in", "decode-out", "prefill-in", "prefill-out"])
+def test_grouped_gemm_compiles_at_the_held_experts_shapes(one_chip, tokens,
+                                                          k, n):
+    """serve-granite-h-sat: 36 of 72 experts held, 10 picks a token; a decode
+    tick's 128 rows and a prefill's largest bucket, each one pass."""
+    call = _held_experts_call(one_chip, 36, 72, tokens, 10, k, n)
+    # what benchmark/readers/kernel_roofline_hybrid.py holds on to
+    assert f"bf16[36,{k},{n}]" in call
 
 
 # serve-trinity-mixed-sat: 160 rows, 32 query heads on 4 KV heads of 128,
@@ -167,22 +181,20 @@ def test_window_prefill_compiles_at_the_cells_buckets(one_chip, seq):
     assert re.search(r"%flash_fwd_window[.\w]* = .*tpu_custom_call", hlo)
 
 
-@pytest.mark.parametrize("stride,k,n,bn", [
-    (256, 2048, 2048, 128), (256, 1024, 2048, 128),
-    (1024, 2048, 2048, 512), (1024, 1024, 2048, 512)],
-    ids=["decode-in", "decode-out", "chunk-in", "chunk-out"])
-def test_grouped_gemm_compiles_at_trinitys_held_experts(one_chip, stride, k,
-                                                        n, bn):
-    """A decode tick's calls (224 rows: stride 256, the column tile 128) and
-    a prefill chunk's (1024 tokens at their worst-case stride, the column
-    tile 512: `held_moe._column_tile`)."""
-    hlo = _compile(
-        lambda rows, w, sizes: grouped_gemm.grouped_matmul(
-            rows, w, sizes, block=(256, bn)),
-        one_chip, ((64 * stride, k), jnp.bfloat16),
-        ((64, k, n), jnp.bfloat16), ((64,), jnp.int32))
-    (call,) = [line for line in hlo.splitlines()
-               if re.match(r"\s*(ROOT )?%grouped_gemm[.\w]* = ", line)]
+@pytest.mark.parametrize("tokens,k,n", [
+    (224, 2048, 2048), (224, 1024, 2048), (1024, 2048, 2048),
+    (1024, 1024, 2048), (4096, 2048, 2048), (4096, 1024, 2048)],
+    ids=["decode-in", "decode-out", "1024-in", "1024-out", "pass-in",
+         "pass-out"])
+def test_grouped_gemm_compiles_at_trinitys_held_experts(one_chip, tokens, k,
+                                                        n):
+    """64 of 128 experts held, 8 picks a token: a decode tick's 224 rows, a
+    1024-token bucket and a whole pass of 4096 tokens (`CHUNK_TOKENS`; the
+    8192 and 16384 buckets are scans of it)."""
+    from paddle_tpu.incubate.distributed.models.moe import held_moe
+
+    assert held_moe.CHUNK_TOKENS == 4096
+    call = _held_experts_call(one_chip, 64, 128, tokens, 8, k, n)
     assert f"bf16[64,{k},{n}]" in call
 
 

@@ -28,6 +28,7 @@ import pytest
 import paddle_tpu as paddle
 from benchmark.reference import granite_hybrid as reference
 from paddle_tpu.incubate.distributed.models.moe import HeldExpertsMoE
+from paddle_tpu.incubate.distributed.models.moe import held_moe
 from paddle_tpu.inference.paged import (BlockPool, PagedKV,
                                         PagedServingEngine, RowState)
 from paddle_tpu.models import GPTForCausalLM, gpt3_tiny
@@ -192,8 +193,14 @@ def test_no_token_is_dropped_when_every_row_goes_to_one_expert():
     u = np.abs(np.random.default_rng(6).normal(size=(70, 64))).astype(
         np.float32) + 0.1
     out, stats = layer(paddle.to_tensor(u), with_stats=True)
-    pairs, rows_max, rows_sum, dropped = (int(v) for v in stats._value)
+    pairs, rows_max, rows_sum, dropped, tile_rows = (
+        int(v) for v in stats._value)
     assert (pairs, rows_max, rows_sum, dropped) == (70, 70, 70, 0)
+    # the one group's 70 rows lie from row 0 of the 140 pairs (the 70 picks
+    # of expert 6, held elsewhere, sort behind them): the row tiles that
+    # hold them and no others are visited
+    bm = held_moe._row_tile(140, 8)
+    assert tile_rows == -(-70 // bm) * bm
     p = {"moe.router": layer.router._value, "moe.w_in": layer.w_in._value,
          "moe.w_out": layer.w_out._value,
          "shared_mlp.input_linear.weight": jnp.zeros((64, 2)),
@@ -207,6 +214,15 @@ def test_no_token_is_dropped_when_every_row_goes_to_one_expert():
                        with_stats=True)
     assert int(stats._value[0]) == 35
     assert np.abs(np.asarray(out._value)[~live]).max() == 0.0
+    np.testing.assert_allclose(np.asarray(out._value)[live], want[live],
+                               rtol=0, atol=ATOL)
+    assert int(stats._value[4]) == -(-35 // bm) * bm
+    # every row to two experts that are NOT held: nothing to compute, no tile
+    router[0, 2], router[0, 7] = 0.0, 3.0
+    layer.router._value = jnp.asarray(router)
+    out, stats = layer(paddle.to_tensor(u), with_stats=True)
+    assert [int(v) for v in stats._value] == [0, 0, 0, 0, 0]
+    assert not np.asarray(out._value).any()
 
 
 # -- 6. preemption carries the recurrent state ------------------------------ #

@@ -325,6 +325,153 @@ class TestGroupedGemm:
             "default", "tuned", "measured", "fixed")
 
 
+# group sizes over `rows` rows of 8-row tiles; what is left behind the last
+# group is the tail of pairs no held expert takes
+_RAGGED = {
+    "an-empty-group": ([5, 0, 8, 3], 32),
+    "every-row-to-one-group": ([0, 32, 0, 0], 32),
+    "no-multiple-of-the-tile": ([13, 7, 9, 2], 40),
+    "two-groups-inside-one-tile": ([3, 2, 1, 1], 16),
+    "a-tail-of-unheld-pairs": ([9, 4, 6, 0], 64),
+    "zero-live-rows": ([0, 0, 0, 0], 24),
+    "rows-no-multiple-of-the-tile": ([6, 5, 4, 3], 30),
+    "one-row-a-group": ([1] * 8, 8),
+}
+
+
+class TestRaggedGroups:
+    """`ragged_matmul`: groups that lie end to end, each starting where the
+    one before ended, against `jax.lax.ragged_dot` and a per-group matmul."""
+
+    @pytest.mark.parametrize("case", sorted(_RAGGED))
+    def test_forward_against_ragged_dot(self, pallas_interpret_unless_hw,
+                                        case):
+        import jax
+        import jax.numpy as jnp
+        from paddle_tpu.ops.pallas.grouped_gemm import ragged_matmul
+
+        sizes, rows = _RAGGED[case]
+        sizes = np.array(sizes, np.int32)
+        rng = np.random.RandomState(3)
+        K, N = 16, 24
+        lhs = rng.randn(rows, K).astype(np.float32)   # garbage in the tail
+        rhs = rng.randn(len(sizes), K, N).astype(np.float32)
+        out = np.asarray(ragged_matmul(
+            jnp.asarray(lhs), jnp.asarray(rhs), jnp.asarray(sizes), (8, 128)))
+        want = np.zeros((rows, N), np.float32)
+        at = 0
+        for g, n in enumerate(sizes):
+            want[at:at + n] = lhs[at:at + n] @ rhs[g]
+            at += n
+        np.testing.assert_allclose(out, want, rtol=1e-6, atol=4e-6)
+        # rows of no group are exactly ZERO, never what the tile held before
+        assert not out[at:].any()
+        ref = np.asarray(jax.lax.ragged_dot(
+            jnp.asarray(lhs), jnp.asarray(rhs), jnp.asarray(sizes)))
+        np.testing.assert_allclose(out, ref, rtol=1e-6, atol=4e-6)
+
+    @pytest.mark.parametrize("case", sorted(_RAGGED))
+    def test_the_visits_by_hand(self, case):
+        """A group is visited in every tile it has a row in, a tile no group
+        touches once (it is written as zeros), and nothing past
+        tiles + groups - 1 visits is ever needed."""
+        import jax.numpy as jnp
+        from paddle_tpu.ops.pallas.grouped_gemm import end_to_end_visits
+
+        sizes, rows = _RAGGED[case]
+        bm = 8
+        tiles = -(-rows // bm)
+        v = end_to_end_visits(jnp.asarray(np.array(sizes, np.int32)), rows,
+                              bm)
+        live, at = [], 0
+        for g, n in enumerate(sizes):
+            live += [(g, t) for t in range(at // bm, -(-(at + n) // bm))
+                     if n]
+            at += n
+        n_live, n_all = (int(x) for x in v.n)
+        assert n_live == len(live)
+        assert list(zip(np.asarray(v.group)[:n_live].tolist(),
+                        np.asarray(v.out_tile)[:n_live].tolist())) == live
+        assert np.array_equal(np.asarray(v.lhs_tile)[:n_live],
+                              np.asarray(v.out_tile)[:n_live])
+        untouched = sorted(set(range(tiles)) - {t for _, t in live})
+        assert np.asarray(v.out_tile)[n_live:n_all].tolist() == untouched
+        assert n_all <= tiles + len(sizes) - 1 == v.group.shape[0]
+        # what is not live names the blocks already in VMEM: nothing to fetch
+        if live:
+            assert set(np.asarray(v.group)[n_live:].tolist()) <= {live[-1][0]}
+            assert set(np.asarray(v.lhs_tile)[n_live:].tolist()) <= {
+                live[-1][1]}
+        assert len(set(np.asarray(v.out_tile)[n_all - 1:].tolist())) == 1
+
+    def test_bf16_rows_accumulate_in_f32(self, pallas_interpret_unless_hw):
+        import jax
+        import jax.numpy as jnp
+        from paddle_tpu.ops.pallas.grouped_gemm import ragged_matmul
+
+        rng = np.random.RandomState(4)
+        sizes = jnp.asarray(np.array([40, 0, 25, 31], np.int32))
+        lhs = jnp.asarray(rng.randn(128, 256), jnp.bfloat16)
+        rhs = jnp.asarray(rng.randn(4, 256, 128), jnp.bfloat16)
+        out = ragged_matmul(lhs, rhs, sizes, (32, 128))
+        ref = jax.lax.ragged_dot(lhs, rhs, sizes,
+                                 preferred_element_type=jnp.float32)
+        assert out.dtype == jnp.bfloat16
+        # the f32 sums differ in their order by an ulp of f32, which rounds
+        # to another bf16 only at a tie: at most one bf16 ulp, and seldom
+        got, want = np.asarray(out, np.float32), np.asarray(ref)
+        np.testing.assert_allclose(got, want, rtol=2 ** -7, atol=2 ** -7)
+        rounded = np.asarray(ref.astype(jnp.bfloat16), np.float32)
+        assert (got != rounded).mean() < 0.01
+
+    def test_vjp_is_ragged_dots(self, pallas_interpret_unless_hw):
+        import jax
+        import jax.numpy as jnp
+        from paddle_tpu.ops.pallas.grouped_gemm import ragged_matmul
+
+        rng = np.random.RandomState(5)
+        sizes = jnp.asarray(np.array([5, 0, 11, 3], np.int32))
+        lhs = jnp.asarray(rng.randn(30, 16).astype(np.float32))
+        rhs = jnp.asarray(rng.randn(4, 16, 24).astype(np.float32))
+        co = jnp.asarray(rng.randn(30, 24).astype(np.float32))
+        got = jax.grad(lambda l, r: (ragged_matmul(l, r, sizes, (8, 128))
+                                     * co).sum(), (0, 1))(lhs, rhs)
+        want = jax.grad(lambda l, r: (jax.lax.ragged_dot(l, r, sizes)
+                                      * co).sum(), (0, 1))(lhs, rhs)
+        for g, w in zip(got, want):
+            np.testing.assert_allclose(np.asarray(g), np.asarray(w),
+                                       rtol=1e-6, atol=4e-6)
+
+    def test_the_uniform_stride_is_the_same_body(self,
+                                                 pallas_interpret_unless_hw):
+        """`grouped_matmul`'s stride layout is the ragged kernel with group e
+        at row e * R: what it gives for groups packed end to end is what
+        `ragged_matmul` gives for the same rows."""
+        import jax.numpy as jnp
+        from paddle_tpu.ops.pallas.grouped_gemm import (grouped_matmul,
+                                                        ragged_matmul)
+
+        rng = np.random.RandomState(6)
+        E, R, K, N = 3, 16, 16, 24
+        sizes = np.array([16, 8, 16], np.int32)        # whole 8-row tiles
+        rhs = rng.randn(E, K, N).astype(np.float32)
+        packed = rng.randn(int(sizes.sum()), K).astype(np.float32)
+        strided = np.zeros((E * R, K), np.float32)
+        at = 0
+        for e, n in enumerate(sizes):
+            strided[e * R:e * R + n] = packed[at:at + n]
+            at += n
+        a = np.asarray(grouped_matmul(jnp.asarray(strided), jnp.asarray(rhs),
+                                      jnp.asarray(sizes), block=(8, 128)))
+        b = np.asarray(ragged_matmul(jnp.asarray(packed), jnp.asarray(rhs),
+                                     jnp.asarray(sizes), (8, 128)))
+        at = 0
+        for e, n in enumerate(sizes):
+            assert np.array_equal(a[e * R:e * R + n], b[at:at + n])
+            at += n
+        assert not a[R + 8:2 * R].any()                # the dead tile
+
+
 def _moe_with_grads(gate_cfg, fast, x, seed=7, E=4, M=16, H=32,
                     train=False, capacity=None):
     """(out, {param grads}) for one fresh seeded layer; fast/dense toggled
