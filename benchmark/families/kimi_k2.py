@@ -1,0 +1,119 @@
+"""The `kimi_k2` family: how a configuration file becomes the program's model
+(`paddle_tpu.models.kimi_k2`) behind a PagedServingEngine, and how what it
+served is held against the plain reference. Serving only: at 16 bytes a
+parameter no share of this model that is still the model trains on one chip
+(PERF.md section 4)."""
+
+import dataclasses
+import sys
+
+import numpy as np
+
+from benchmark.reference import kimi_k2 as reference
+
+# a sample's prompt plus answer is padded to a multiple of this for the
+# reference's one forward (causal: the padding is unseen), so that the
+# reference compiles a few shapes and not one a sample
+PAD_TO = 2048
+
+
+def _model_config(config):
+    from paddle_tpu.models.kimi_k2 import KimiK2Config
+
+    unsupported = [
+        f"{key}={config[key]!r}" for key, want in (
+            ("scoring_func", "sigmoid"), ("norm_topk_prob", True),
+            ("topk_method", "noaux_tc"), ("n_group", 1), ("topk_group", 1),
+            ("moe_layer_freq", 1), ("attention_bias", False),
+            ("tie_word_embeddings", False), ("hidden_act", "silu"),
+            ("num_key_value_heads", config["num_attention_heads"]))
+        if config[key] != want]
+    if unsupported:
+        raise SystemExit("benchmark: models/kimi_k2.py does not compute "
+                         + ", ".join(unsupported))
+    # the model's config has the source's own keys: take them by name
+    shared = {f.name: config[f.name]
+              for f in dataclasses.fields(KimiK2Config) if f.name in config}
+    shared.update(
+        # the router is as wide as the published model; this chip holds
+        # `n_routed_experts` of its experts
+        n_routed_experts=config["published"]["n_routed_experts"],
+        held_experts=held(config), dtype="bfloat16")
+    return KimiK2Config(**shared)
+
+
+def held(config):
+    """(first, count) of the routed experts this chip holds."""
+    return config["held_experts_first"], config["n_routed_experts"]
+
+
+def build_server(config, seed, kv_budget):
+    """The bf16 model behind a PagedServingEngine. The model casts itself a
+    layer at a time as it is built and frees each float32 form before it
+    returns, so what `kv_budget()` reads from the device is what the model
+    left. The engine takes `kv_budget()` bytes for the pool of latent
+    pages."""
+    import paddle_tpu as paddle
+    import paddle_tpu.distributed as dist
+    from paddle_tpu.inference.paged import PagedServingEngine
+    from paddle_tpu.models.kimi_k2 import KimiK2ForCausalLM
+
+    serve = config["serve"]
+    dist.env.set_global_mesh(None)
+    paddle.seed(seed)
+    model = KimiK2ForCausalLM(_model_config(config))
+    budget = kv_budget()
+    print(f"[kimi_k2] model on the device; {budget / 1e9:.3f} GB for pages",
+          file=sys.stderr, flush=True)
+    return PagedServingEngine(
+        model, max_batch_size=serve["max_batch_size"],
+        max_seq_len=serve["max_seq_len"], page_size=serve["page_size"],
+        kv_budget_bytes=budget, seed=seed)
+
+
+def check_served(config, model, samples, lower_precision=False):
+    """(ok, detail): each sample is (prompt ids, served ids) of a greedy
+    request. The reference runs prompt + answer in ONE forward in the
+    EXPANDED form (keys and values per head, dense masked attention, no
+    cache, no pages, no absorbed projection), the head only over the answered
+    positions; the served path cached the prompt by its expanded prefill and
+    read every answered token back through the absorbed kernel over latent
+    pages, so the comparison crosses the two forms. At each answered position
+    the served token's reference logit sits some share of the row's standard
+    deviation below the row's largest (0 where the reference picks the same
+    token). Two limits, as the `afmoe` family's and for its reasons
+    (`families/afmoe.py`): the MEAN of that share over a sample's positions
+    may be at most `serve.gap_tolerance` (what tells precision apart: the
+    reference at an 8-bit float's precision, `lower_precision=True`, must
+    come out NOT correct by it), and the share at its WORST position at most
+    `serve.worst_gap_tolerance` (what tells one wrong token among thousands
+    of right ones apart, which the mean hardly sees). The readings of both
+    are in PERF.md section 6 (PR 35) and beside the limits in the
+    configuration file."""
+    import jax.numpy as jnp
+
+    params = {k: p._value for k, p in model.named_parameters()}
+    params.update({k: b._value for k, b in model.named_buffers()})
+    tol = config["serve"]["gap_tolerance"]
+    worst_tol = config["serve"]["worst_gap_tolerance"]
+    shares = []
+    for prompt, served in samples:
+        n, g = len(prompt), len(served)
+        ids = np.zeros(-(-(n + g) // PAD_TO) * PAD_TO, np.int32)
+        ids[:n] = prompt
+        ids[n:n + g - 1] = served[:-1]
+        rows = reference.logits(
+            params, ids, config, held(config),
+            rows=np.arange(n - 1, n - 1 + g),
+            lower_precision=lower_precision)
+        picked = jnp.take_along_axis(
+            rows, jnp.asarray(served, jnp.int32)[:, None], axis=-1)[:, 0]
+        share = (rows.max(axis=-1) - picked) / rows.std(axis=-1)
+        shares.append({"prompt": n, "answer": g,
+                       "mean_share": float(share.mean()),
+                       "worst_share": float(share.max())})
+    ok = bool(shares) and all(
+        s["mean_share"] <= tol and s["worst_share"] <= worst_tol
+        for s in shares)
+    return ok, {"samples": shares, "tolerance": tol,
+                "worst_tolerance": worst_tol}

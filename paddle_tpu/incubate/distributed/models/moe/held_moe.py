@@ -123,11 +123,21 @@ def _row_tile(pairs, num_experts):
     return bm
 
 
-def _column_tile(n):
-    """The widest of 512, 256, 128 that divides the N the kernel pads to:
-    beside a 256-row tile of 4096-wide rows it still fits VMEM."""
+# what the grouped GEMM's double-buffered operand blocks (whole-K: a row tile
+# of `lhs`, a column tile of one expert's matrix) may take of VMEM; the
+# output block and the f32 accumulator come on top, under the compiler's 16 MiB
+_OPERAND_VMEM = 13 * 2 ** 20
+
+
+def _column_tile(n, k, bm, itemsize=2):
+    """The widest of 512, 256, 128 that divides the N the kernel pads to and
+    whose [K, bn] block fits VMEM beside a [bm, K] row tile, both double
+    buffered: 512 beside a 256-row tile of 4096-wide rows, 256 beside a
+    128-row tile of 7168-wide ones."""
     n = -(-n // 128) * 128
-    return next(bn for bn in (512, 256, 128) if n % bn == 0)
+    fits = [bn for bn in (512, 256, 128) if n % bn == 0
+            and 2 * (bn + bm) * k * itemsize <= _OPERAND_VMEM]
+    return fits[0] if fits else 128
 
 
 class HeldExpertsMoE(nn.Layer):
@@ -203,7 +213,8 @@ class HeldExpertsMoE(nn.Layer):
             # the tiles follow from the shapes, so they are the tuner's only
             # candidate: nothing is swept inside a serving process, and
             # chosen_tiles()["grouped_gemm"] counts the consults
-            block = (bm, _column_tile(w.shape[2]))
+            block = (bm, _column_tile(w.shape[2], w.shape[1], bm,
+                                      w.dtype.itemsize))
             tile = pick_block_sizes(
                 "grouped_gemm", pairs, w.shape[2], block,
                 lambda bm, bn: None, allow_measure=False,

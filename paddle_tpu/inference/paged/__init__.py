@@ -7,7 +7,9 @@
   (`PagedServingEngine(kv_quant=True)`). One cache spec per layer: `PagedKV` pages for
   an attention layer, `WindowKV` pages that expire for a sliding-window one
   (both out of one free list: layers share the pool's arrays by page group),
-  a `RowState` slot per decode row for a recurrent one.
+  `LatentKV` pages for a latent-attention one (one latent and one rotated key
+  a token, shared by all heads), a `RowState` slot per decode row for a
+  recurrent one. A spec says what a page is; the pool and the engine ask it.
 - `TwoQueueScheduler` — power-of-two prefill length buckets + decode/resume
   queues, admitting against a page-budget watermark.
 - `PagedServingEngine` — the continuous-batching engine over both, with
@@ -18,13 +20,14 @@ The dense `paddle_tpu.inference.serving.ContinuousBatchingEngine` is the
 reference the tests hold this engine to, token for token; nothing selects it.
 """
 
-from .block_pool import (BlockPool, PagedKV, RowState, WindowKV,
+from .block_pool import (BlockPool, LatentKV, PagedKV, RowState, WindowKV,
                          prefix_page_key)
 from .engine import PagedServingEngine, SpilledRequest
 from .scheduler import TwoQueueScheduler
 
 __all__ = [
     "BlockPool",
+    "LatentKV",
     "PagedKV",
     "RowState",
     "WindowKV",

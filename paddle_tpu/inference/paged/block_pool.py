@@ -62,6 +62,21 @@ decode program threads it through them in layer order); a model whose paged
 layers are all of one kind has one group and `depth` = its paged layers: an
 entry per layer, as before.
 
+Latent pages. `LatentKV` is a latent-attention layer's cache: what a token
+leaves behind in a layer is ONE normed latent `latent_dim` wide and ONE rotated
+key `rope_dim` wide that all the heads share, not keys and values per head. To
+the host it is `PagedKV` in everything (block tables as wide as a row can
+grow, prefix keys over the whole prefix, copy-on-write, a spill page by page,
+one group); what differs is what a page IS: one array a layer,
+`[n_pages, page_size, stored_dim]`, a token's latent and its key side by side
+in one row, the row padded with zeros to a whole lane tile (512 + 64 -> 640),
+which is what the device would store for 576 anyway. The decode kernel
+(`ops.pallas.decode_attention.latent_decode_attention`) reads each page ONCE
+and uses it as key (the whole row) and as value (its first `latent_dim`).
+Every spec says its own page arrays and bytes (`page_arrays`, `page_nbytes`,
+`pages_per_step`); the pool and the engine ask the spec and never multiply
+head counts themselves. An int8 pool of latents is refused.
+
 Physical page 0 is the reserved NULL page: never allocated, never referenced
 by a live block table. Parked decode rows (batch padding) route their
 per-step K/V writes there, so the fixed-shape decode program needs no
@@ -93,8 +108,8 @@ import numpy as np
 
 from ..slo import serving_metrics
 
-__all__ = ["BlockPool", "PagedKV", "WindowKV", "RowState", "PageGroup",
-           "page_layout", "prefix_page_key"]
+__all__ = ["BlockPool", "PagedKV", "WindowKV", "LatentKV", "RowState",
+           "PageGroup", "page_layout", "prefix_page_key"]
 
 # pages one call of the gather or the scatter program moves at most: larger
 # sets go in several calls, so the bucketed shapes end here
@@ -110,21 +125,43 @@ class PagedKV:
     kv_heads: int
     head_dim: int
 
+    kind = "full"    # the `serving_pages_live` gauge's label
+
     def prefill_cache(self, seq, dtype):
         """The zeroed dense cache a batch-1 prefill of `seq` tokens fills."""
         return (jnp.zeros((1, seq, self.kv_heads, self.head_dim), dtype),) * 2
 
+    def page_arrays(self, page_size):
+        """The shape of one page in each array a layer keeps: K and V."""
+        return ((self.kv_heads, page_size, self.head_dim),) * 2
+
+    def page_nbytes(self, page_size, dtype, quantized=False) -> int:
+        """HBM bytes one page costs in ONE layer, both sides: payload plus,
+        when quantized, the per-(page, head) f32 scales."""
+        values = self.kv_heads * page_size * self.head_dim
+        if quantized:
+            return 2 * (values + self.kv_heads * 4)
+        return 2 * values * jnp.dtype(dtype).itemsize
+
+    def pages_per_step(self, page_size, width, itemsize) -> int:
+        """Pages of a row one grid step of this kind's decode kernel takes,
+        for a block table `width` wide."""
+        from ...ops.pallas.decode_attention import pages_per_step
+
+        return pages_per_step(self.kv_heads, page_size, self.head_dim, width,
+                              itemsize)
+
 
 @dataclasses.dataclass(frozen=True)
-class WindowKV:
+class WindowKV(PagedKV):
     """A sliding-window attention layer's cache: K and V pages as `PagedKV`,
     of which a row keeps only those holding one of the `window` positions its
     next query sees (key j is visible to query i iff 0 <= i - j < window).
     Private to the row, released as the row's length passes them."""
 
-    kv_heads: int
-    head_dim: int
     window: int
+
+    kind = "window"
 
     def prefill_cache(self, seq, dtype):
         """A prefill from position 0 reads no cache."""
@@ -138,6 +175,45 @@ class WindowKV:
 
     def table_width(self, page_size) -> int:
         return -(-self.window // page_size) + 1
+
+
+@dataclasses.dataclass(frozen=True)
+class LatentKV:
+    """A latent-attention layer's cache: per token ONE normed latent
+    `latent_dim` wide and ONE rotated key `rope_dim` wide, shared by all the
+    heads, side by side in a row of `stored_dim` values (zero behind them up
+    to a whole lane tile). Pages [n_pages, page_size, stored_dim], one array
+    a layer; to the host everything `PagedKV` is."""
+
+    latent_dim: int
+    rope_dim: int
+
+    kind = "latent"
+
+    @property
+    def stored_dim(self) -> int:
+        return -(-(self.latent_dim + self.rope_dim) // 128) * 128
+
+    def prefill_cache(self, seq, dtype):
+        """A prefill from position 0 reads no cache."""
+        return ()
+
+    def page_arrays(self, page_size):
+        return ((page_size, self.stored_dim),)
+
+    def page_nbytes(self, page_size, dtype, quantized=False) -> int:
+        if quantized:
+            raise ValueError("an int8 pool of latent pages is not supported")
+        return page_size * self.stored_dim * jnp.dtype(dtype).itemsize
+
+    def pages_per_step(self, page_size, width, itemsize) -> int:
+        from ...ops.pallas.decode_attention import latent_pages_per_step
+
+        return latent_pages_per_step(page_size, self.stored_dim, width,
+                                     itemsize)
+
+
+_PAGED = (PagedKV, LatentKV)    # `WindowKV` is a `PagedKV`
 
 
 @dataclasses.dataclass(frozen=True)
@@ -163,11 +239,11 @@ def page_layout(specs):
     group whose table layer l follows, None for a `RowState` layer."""
     kinds = {}
     for li, spec in enumerate(specs):
-        if isinstance(spec, (PagedKV, WindowKV)):
+        if isinstance(spec, _PAGED):
             kinds.setdefault(spec, []).append(li)
     if not kinds:
         raise ValueError("no paged layer among the cache specs")
-    if len({(k.kv_heads, k.head_dim) for k in kinds}) > 1:
+    if len({k.page_arrays(1) for k in kinds}) > 1:
         raise ValueError("paged layers of different KV head counts or sizes "
                          "in one pool are not supported")
     depth = math.gcd(*(len(v) for v in kinds.values()))
@@ -240,12 +316,13 @@ def prefix_page_key(prompt: np.ndarray, page_index: int, page_size: int):
 class BlockPool:
     """Fixed pool of physical KV pages shared by every layer's cache."""
 
-    def __init__(self, num_layers, kv_heads, head_dim, page_size, num_pages,
-                 dtype=jnp.float32, prefix_sharing=True, quantized=False,
-                 specs=None, rows=0):
+    def __init__(self, num_layers, kv_heads=None, head_dim=None, page_size=16,
+                 num_pages=2, dtype=jnp.float32, prefix_sharing=True,
+                 quantized=False, specs=None, rows=0):
         """`specs`: one cache spec per layer (default: every layer
-        `PagedKV(kv_heads, head_dim)`); `rows`: decode rows, the number of
-        slots a `RowState` layer gets."""
+        `PagedKV(kv_heads, head_dim)`; with `specs`, `kv_heads` and
+        `head_dim` are not read: a page is what its spec says); `rows`:
+        decode rows, the number of slots a `RowState` layer gets."""
         if num_pages < 2:
             raise ValueError("num_pages must be >= 2 (page 0 is reserved)")
         if page_size < 1:
@@ -253,8 +330,6 @@ class BlockPool:
         self.page_size = int(page_size)
         self.num_pages = int(num_pages)
         self.num_layers = int(num_layers)
-        self.kv_heads = int(kv_heads)
-        self.head_dim = int(head_dim)
         self.dtype = jnp.dtype(dtype)  # unquantized payload dtype
         self.prefix_sharing = bool(prefix_sharing)
         self.quantized = bool(quantized)
@@ -265,6 +340,10 @@ class BlockPool:
         self.groups, self.entry_of_layer, self.group_of_layer = page_layout(
             self.specs)
         self.depth = len(self.groups[0].layers)
+        # every group's pages are the same bytes: one spec says what a page is
+        self.page_spec = self.groups[0].spec
+        self.kv_heads = getattr(self.page_spec, "kv_heads", None)
+        self.head_dim = getattr(self.page_spec, "head_dim", None)
         self.page_layers = [i for i, g in enumerate(self.group_of_layer)
                             if g is not None]
         self.state_layers = [i for i, s in enumerate(self.specs)
@@ -276,7 +355,12 @@ class BlockPool:
         self.page_entries = [self.entry_of_layer[li]
                              for li in self.groups[0].layers]
         self.rows = int(rows)
-        shape = (self.num_pages, kv_heads, self.page_size, head_dim)
+        shapes = [(self.num_pages,) + tuple(s)
+                  for s in self.page_spec.page_arrays(self.page_size)]
+        self._arrays_per_layer = len(shapes)   # K and V; a latent layer's one
+        # bytes first: a spec that has no int8 form refuses here
+        self._bytes_per_page = self.depth * self.page_spec.page_nbytes(
+            self.page_size, self.dtype, self.quantized)
         pay_dtype = jnp.dtype(jnp.int8) if self.quantized else self.dtype
         # immutable jnp zeros: (z,)*2 aliasing is safe until a donation,
         # and the page programs below take each array once
@@ -285,14 +369,15 @@ class BlockPool:
             if self.kv[self.entry_of_layer[li]] is not None:
                 continue   # a page array that an earlier layer's group made
             self.kv[self.entry_of_layer[li]] = (
-                (jnp.zeros(shape, pay_dtype), jnp.zeros(shape, pay_dtype))
-                if isinstance(spec, (PagedKV, WindowKV)) else
+                tuple(jnp.zeros(shape, pay_dtype) for shape in shapes)
+                if isinstance(spec, _PAGED) else
                 tuple(jnp.zeros((self.rows,) + tuple(s), self.dtype)
                       for s in spec.shapes))
         # per-(page, head) f32 dequant scales beside the int8 payloads
-        self.scales = ([(jnp.zeros((self.num_pages, kv_heads), jnp.float32),
-                         jnp.zeros((self.num_pages, kv_heads), jnp.float32))
-                        for _ in self.kv]
+        self.scales = ([(jnp.zeros((self.num_pages, self.kv_heads),
+                                   jnp.float32),
+                         jnp.zeros((self.num_pages, self.kv_heads),
+                                   jnp.float32)) for _ in self.kv]
                        if self.quantized else None)
         self._gather = self._scatter = None
         self._write_state = self._read_state = None
@@ -312,18 +397,14 @@ class BlockPool:
         """HBM bytes one physical page costs across all layers and both K/V
         sides — payload plus, when quantized, the per-(page, head) f32
         scales. The unit of the equal-budget serving A/B."""
-        if quantized:
-            per_side = kv_heads * page_size * head_dim + kv_heads * 4
-        else:
-            per_side = (kv_heads * page_size * head_dim
-                        * jnp.dtype(dtype).itemsize)
-        return int(num_layers) * 2 * per_side
+        return int(num_layers) * PagedKV(kv_heads, head_dim).page_nbytes(
+            page_size, dtype, quantized)
 
     @property
     def bytes_per_page(self) -> int:
-        return self.page_nbytes(self.depth, self.kv_heads,
-                                self.head_dim, self.page_size, self.dtype,
-                                self.quantized)
+        """HBM bytes one physical page costs, all `depth` arrays: what its
+        spec says of one layer's page, times the depth."""
+        return self._bytes_per_page
 
     @property
     def state_row_nbytes(self) -> int:
@@ -415,17 +496,18 @@ class BlockPool:
     # -- device page data ------------------------------------------------ #
 
     def _page_arrays(self):
-        """Every array a page has a slot in, in a fixed order: K and V of
-        page array 0, 1, ..., then (int8 pool) their scales likewise."""
+        """Every array a page has a slot in, in a fixed order: the arrays
+        (K and V; a latent layer's one) of page array 0, 1, ..., then (int8
+        pool) their scales likewise."""
         flat = [a for e in self.page_entries for a in self.kv[e]]
         if self.quantized:
             flat += [a for e in self.page_entries for a in self.scales[e]]
         return flat
 
     def _set_page_arrays(self, flat):
-        d = self.depth
+        d, k = self.depth, self._arrays_per_layer
         for j, e in enumerate(self.page_entries):
-            self.kv[e] = (flat[2 * j], flat[2 * j + 1])
+            self.kv[e] = tuple(flat[k * j:k * (j + 1)])
             if self.quantized:
                 self.scales[e] = (flat[2 * d + 2 * j], flat[2 * d + 2 * j + 1])
 
@@ -481,9 +563,11 @@ class BlockPool:
             self._set_page_arrays(
                 self._scatter(self._page_arrays(), idx, vals))
 
-    def write_prompt_pages(self, pages, write_mask, k_layers, v_layers):
+    def write_prompt_pages(self, pages, write_mask, *sides):
         """Scatter a prefilled prompt into its pages, every array of the
-        pool (one group's layers).
+        pool (one group's layers). `sides`: one list per array a layer keeps
+        (K's and V's, `k_layers, v_layers`; a latent layer's one), each with
+        an entry per page array.
 
         pages: m physical pages in logical order; write_mask[j] False for a
         page that is not to be written (a shared page, whose content is
@@ -496,7 +580,7 @@ class BlockPool:
                        np.asarray(pages, np.int32), 0)
         if not tgt.any():
             return
-        values = [a for kv in zip(k_layers, v_layers) for a in kv]
+        values = [a for layer in zip(*sides) for a in layer]
         if self.quantized:
             quant = [_quantize_pages(a) for a in values]
             values = [q for q, _ in quant] + [s for _, s in quant]
@@ -515,12 +599,14 @@ class BlockPool:
 
     def read_pages(self, pages) -> list[tuple]:
         """Host copies of the given pages, per page array — the preemption
-        spill buffer. Unquantized: [(k, v), ...] each [m, Hkv, page_size, D];
+        spill buffer. Unquantized: [(k, v), ...] each [m, Hkv, page_size, D]
+        (a latent layer's: [(latents,), ...], [m, page_size, stored_dim]);
         quantized: [(k, v, k_scale, v_scale), ...] with [m, Hkv] scales
         (int8 payload + f32 scales round-trip the host bit-exactly, so a
         spilled quantized request resumes with zero extra error)."""
         flat, d = self._read(list(pages)), self.depth
-        return [(flat[2 * j], flat[2 * j + 1])
+        k = self._arrays_per_layer
+        return [tuple(flat[k * j:k * (j + 1)])
                 + ((flat[2 * d + 2 * j], flat[2 * d + 2 * j + 1])
                    if self.quantized else ())
                 for j in range(d)]
@@ -533,7 +619,8 @@ class BlockPool:
         if not len(pages):
             return
         sel = np.asarray(list(rows), np.int32)
-        values = [h[i][sel] for h in kv_host for i in (0, 1)]
+        k = self._arrays_per_layer
+        values = [h[i][sel] for h in kv_host for i in range(k)]
         if self.quantized:
             values += [h[i][sel] for h in kv_host for i in (2, 3)]
         self._write(list(pages), values)

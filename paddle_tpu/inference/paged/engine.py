@@ -32,6 +32,14 @@ row. Admission is then by rows AND pages: a request needs a free row (its
 slot) and its pages under the watermark, and whichever runs out first is the
 limit. Prefix hits save such a model only the page writes: the prefill
 always runs, because the state after the prompt is the request's own.
+
+What a page IS belongs to its spec (`PagedKV`, `WindowKV`, `LatentKV`): the
+arrays a layer keeps and their shapes, the bytes a page costs, the decode
+kernel's pages per grid step. The engine asks the spec wherever bytes or
+shapes matter (`kv_budget_bytes` -> `num_pages`, the page stacking of a
+prefill, the `decode_dispatch` span's grid) and handles every paged kind
+alike on the host: a latent-attention model's pages hold one latent and one
+rotated key a token, and nothing here knows.
 """
 
 from __future__ import annotations
@@ -113,19 +121,19 @@ class PagedServingEngine(_ServingEngineBase):
                 "pass num_pages OR kv_budget_bytes, not both — a page count "
                 "would silently override the byte budget and break the "
                 "equal-budget A/B contract")
-        specs = self.cache_specs
-        groups = page_layout(
-            specs or [PagedKV(cfg.kv_heads, cfg.head_dim)] * cfg.num_layers
-        )[0]
+        # what a page IS is its spec's to say (block_pool.PagedKV,
+        # WindowKV, LatentKV): arrays, bytes, the decode kernel's grid
+        specs = (self.cache_specs
+                 or [PagedKV(cfg.kv_heads, cfg.head_dim)] * cfg.num_layers)
+        groups = page_layout(specs)[0]
         if num_pages is None:
             if kv_budget_bytes is not None:
-                page_b = BlockPool.page_nbytes(
-                    len(groups[0].layers), cfg.kv_heads, cfg.head_dim, self.ps,
-                    self.kv_dtype, self.kv_quant)
+                page_b = len(groups[0].layers) * groups[0].spec.page_nbytes(
+                    self.ps, self.kv_dtype, self.kv_quant)
                 # budget covers the whole pool, reserved null page included,
                 # and first of all every row's recurrent-state slot
                 slots = self.B * sum(
-                    s.row_nbytes(self.kv_dtype) for s in specs or ()
+                    s.row_nbytes(self.kv_dtype) for s in specs
                     if isinstance(s, RowState))
                 num_pages = (int(kv_budget_bytes) - slots) // page_b
                 if num_pages < 2:
@@ -137,8 +145,8 @@ class PagedServingEngine(_ServingEngineBase):
                         "A/B contract")
             else:
                 num_pages = (self.B * self.S) // self.ps + 1  # +1: null page
-        self.pool = BlockPool(cfg.num_layers, cfg.kv_heads, cfg.head_dim,
-                              self.ps, num_pages, dtype=self.kv_dtype,
+        self.pool = BlockPool(len(specs), page_size=self.ps,
+                              num_pages=num_pages, dtype=self.kv_dtype,
                               prefix_sharing=prefix_sharing,
                               quantized=self.kv_quant, specs=specs,
                               rows=self.B)
@@ -166,6 +174,11 @@ class PagedServingEngine(_ServingEngineBase):
         self._window = windows.pop() if windows else None
         self._windowed = self._window is not None
         self._window_released = 0   # window pages released, lifetime
+        # a model whose pages are not all one kind of full K and V reports
+        # its live pages by kind, and a latent one what its kernel must read
+        self._latent = any(g.spec.kind == "latent" for g in self.groups)
+        kinds = sorted({g.spec.kind for g in self.groups})
+        self._page_kinds = kinds if kinds != ["full"] else []
         self.sched = TwoQueueScheduler(
             self.ps, watermark_pages, pages_for=self._prompt_pages,
             groups=len(self.groups))
@@ -173,13 +186,11 @@ class PagedServingEngine(_ServingEngineBase):
         self._stack = None
         # the paged-decode kernels' grids, for the `decode_dispatch` span:
         # static, so computed once
-        from ...ops.pallas.decode_attention import pages_per_step
         pages0 = self.pool.kv[self.pool.page_entries[0]][0]
         self._decode_grid = {}
         for g, table in zip(self.groups, self.group_tables):
             width = table.shape[1]
-            n = pages_per_step(cfg.kv_heads, self.ps, cfg.head_dim, width,
-                               pages0.dtype.itemsize)
+            n = g.spec.pages_per_step(self.ps, width, pages0.dtype.itemsize)
             tag = "window_" if g.window else ""
             self._decode_grid.update({
                 tag + "pages_per_step": n,
@@ -329,23 +340,25 @@ class PagedServingEngine(_ServingEngineBase):
         return len(work)
 
     def _stack_pages(self, kv_layers, n):
-        """Per layer (k, v), each [1, Sp, Hkv, D] from a prefill -> per layer
-        (k, v) page-stacked [mb, Hkv, ps, D] over the whole bucket, `mb` =
-        ceil(Sp / ps), zero behind the prompt's `n` tokens. ONE program a
-        bucket, the length is data."""
+        """Per layer the arrays a prefill returns for it, each [1, Sp, ...]
+        ((k, v), each [1, Sp, Hkv, D]; a latent layer's one [1, Sp, W]) ->
+        per layer the same arrays page-stacked over the whole bucket
+        ([mb, Hkv, ps, D]; [mb, ps, W]), `mb` = ceil(Sp / ps), zero behind
+        the prompt's `n` tokens. ONE program a bucket, the length is data."""
         if self._stack is None:
             ps = self.ps
 
             def stack(kv_layers, n):
                 def one(a):
                     a = a[0]
-                    sp = a.shape[0]
-                    a = jnp.where((jnp.arange(sp) < n)[:, None, None], a, 0)
-                    a = jnp.pad(a, ((0, -sp % ps), (0, 0), (0, 0)))
-                    return a.reshape(-1, ps, a.shape[1], a.shape[2]
-                                     ).transpose(0, 2, 1, 3)
+                    sp, rest = a.shape[0], a.shape[1:]
+                    real = (jnp.arange(sp) < n).reshape((sp,) + (1,) * len(rest))
+                    a = jnp.pad(jnp.where(real, a, 0),
+                                ((0, -sp % ps),) + ((0, 0),) * len(rest))
+                    # a page's token axis lies second to last
+                    return jnp.moveaxis(a.reshape((-1, ps) + rest), 1, -2)
 
-                return [(one(k), one(v)) for k, v in kv_layers]
+                return [tuple(one(a) for a in layer) for layer in kv_layers]
 
             self._stack = jax.jit(stack)
         return self._stack(kv_layers, np.int32(n))
@@ -396,9 +409,7 @@ class PagedServingEngine(_ServingEngineBase):
                         continue
                     stacked = self._stack_pages(
                         [new_c[li] for li in group.layers], n)
-                    self.pool.write_prompt_pages(
-                        pages, mask, [k for k, _ in stacked],
-                        [v for _, v in stacked])
+                    self.pool.write_prompt_pages(pages, mask, *zip(*stacked))
         if self.pool.state_layers:
             with span("write_state", rid=rid, row=row):
                 self.pool.write_state(
@@ -476,12 +487,13 @@ class PagedServingEngine(_ServingEngineBase):
         self.window_start[row] = start
 
     def _update_page_gauges(self):
-        """Live pages by kind of group, for a model that has both."""
+        """Live pages by kind of group, for a model whose pages are not all
+        full K and V."""
         g = serving_metrics()["pages_live"]
-        for kind in ("full", "window"):
+        for kind in self._page_kinds:
             g.set(sum(int((t >= 0).sum())
                       for grp, t in zip(self.groups, self.group_tables)
-                      if grp.window == (kind == "window")), kind=kind)
+                      if grp.spec.kind == kind), kind=kind)
 
     def _note_routing(self, stats):
         """One decode tick's routing counts (held_moe.STAT_NAMES, summed
@@ -545,6 +557,7 @@ class PagedServingEngine(_ServingEngineBase):
                 gone = self._window_released - released
                 sp.set(window_pages_released=gone)
                 serving_metrics()["window_pages_released"].inc(gone)
+            if self._page_kinds:
                 self._update_page_gauges()
         live = [i for i in range(self.B) if self.active[i] is not None]
         if not live:
@@ -561,6 +574,10 @@ class PagedServingEngine(_ServingEngineBase):
             state_rows.update(
                 context_tokens=int(ctx.sum()),
                 window_tokens=int(np.minimum(ctx, self._window.window).sum()))
+        if self._latent:
+            # what `decode_latent` must read this tick, in tokens
+            state_rows.update(latent_tokens=int(
+                self.lengths[live].astype(np.int64).sum() + len(live)))
         sampled = np.flatnonzero(self.temps > 0)  # live rows all: _vacate
         with span("decode_dispatch", rows=len(live),
                   sampled_rows=len(sampled), **self._decode_grid,

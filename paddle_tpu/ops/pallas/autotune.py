@@ -50,7 +50,7 @@ KERNEL_NAMES = (
     "fused_rms_norm_fwd", "fused_rms_norm_bwd",
     "fused_rope", "grouped_gemm",
     "decode_paged", "decode_paged_q8", "decode_dense", "decode_window",
-    "ssm_decode",
+    "decode_latent", "ssm_decode",
 )
 
 _lock = threading.Lock()

@@ -47,6 +47,20 @@ kernel's body under its own name, with one more mask (keys before
 and is `ceil(window / page_size) + 1` wide whatever the longest sequence is:
 the grid does not walk pages the row released long ago.
 
+Latent (`decode_latent`): a latent-attention layer caches per token ONE
+normed latent and ONE rotated key that all the heads share, side by side in a
+row of the pool's `[n_pages, page_size, W]` array (`block_pool.LatentKV`: W =
+640 for 512 + 64, zero behind them). With the key and value projections
+absorbed into the query and the output, a row's H heads are H queries `[ql ;
+q_pe ; 0]` against the SAME keys, and the value of a token is the first
+`latent_dim` of its key: the kernel takes the paged kernel's grid and fetch
+table, reads each page ONCE, and uses it twice, `s = q . page^T` over all W
+and `o += p . page[:, :latent_dim]`. 2 x H x (576 + 512) FLOP a cached token
+against 1,152 bytes is 121 FLOP a byte at 64 heads, half the chip's balance,
+so both products go to the MXU with bf16 operands and f32 accumulation
+(p rounded to bf16 once, as the flash kernels do); an f32 pool takes f32
+operands.
+
 Dense (`decode_dense`): the cache is contiguous, the grid stays (batch,
 kv_head, block) with one [block, D] tile of one head a step and the sequence
 tile autotuned. It shares the online-softmax update with the paged kernel
@@ -78,7 +92,9 @@ from . import interpret_mode, mxu_dot, named_pallas_call
 from .flash_attention import NEG_INF
 
 __all__ = ["paged_decode_attention", "dense_decode_attention",
-           "pages_per_step", "paged_kv_write", "paged_kv_write_q8", "KV_QMAX"]
+           "latent_decode_attention", "pages_per_step",
+           "latent_pages_per_step", "paged_kv_write", "paged_kv_write_q8",
+           "latent_kv_write", "KV_QMAX"]
 
 # symmetric int8 range for KV pages: ±127 (not -128) so the running-max
 # rescale in paged_kv_write_q8 can never overflow the negative extreme
@@ -394,6 +410,124 @@ def _paged_name(quantized, window):
     return "decode_paged_q8" if quantized else "decode_paged"
 
 
+# ---- latent ---------------------------------------------------------------
+
+
+def latent_pages_per_step(ps, W, P, itemsize):
+    """N for the latent kernel, as `pages_per_step`: one array's blocks,
+    double-buffered, plus two pages' worth of f32 temporaries per page."""
+    page = ps * W
+    for n in _PAGES_PER_STEP:
+        if n <= P and 2 * n * page * itemsize + 2 * n * page * 4 <= _VMEM_BUDGET:
+            return n
+    return 1
+
+
+def _latent_kernel(fetch_ref, lens_ref, *refs, scale, ps, n, steps, dv,
+                   dot_dtype):
+    """One grid step: `n` latent pages of row b, each [1, ps, W], used as
+    keys (all W) and as values (the first `dv`). refs: the n page blocks;
+    q [1, H, W]; the output [1, H, dv]; scratch m, l [H, 1], acc [H, dv]."""
+    page_refs, q_ref = refs[:n], refs[n]
+    o_ref, m_scr, l_scr, acc_scr = refs[-4:]
+    b = pl.program_id(0)
+    i = pl.program_id(1)
+
+    @pl.when(i == 0)
+    def _init():
+        m_scr[...] = jnp.full_like(m_scr, NEG_INF)
+        l_scr[...] = jnp.zeros_like(l_scr)
+        acc_scr[...] = jnp.zeros_like(acc_scr)
+
+    length = lens_ref[b]
+    slot_live = [fetch_ref[b, i * n + j] >= 0 for j in range(n)]
+
+    @pl.when(functools.reduce(jnp.logical_or, slot_live))
+    def _compute():
+        q = q_ref[0].astype(dot_dtype)                        # [H, W]
+        pages = [ref[0].astype(dot_dtype) for ref in page_refs]
+        kv = pages[0] if n == 1 else jnp.concatenate(pages, axis=0)  # [T, W]
+        s = mxu_dot(q, kv, (((1,), (1,)), ((), ())),
+                    preferred_element_type=jnp.float32) * scale    # [H, T]
+        lane = jax.lax.broadcasted_iota(jnp.int32, (1, n * ps), 1)
+        live = (i * (n * ps) + lane) < length
+        for j, alive in enumerate(slot_live):
+            in_slot = (lane >= j * ps) & (lane < (j + 1) * ps)
+            live = live & (alive | jnp.logical_not(in_slot))
+        m_new, alpha, p, l_new = _softmax_update(
+            s, live, m_scr[...], l_scr[...])
+        pv = mxu_dot(p.astype(dot_dtype), kv[:, :dv],
+                     (((1,), (0,)), ((), ())),
+                     preferred_element_type=jnp.float32)           # [H, dv]
+        acc_scr[...] = acc_scr[...] * alpha + pv
+        m_scr[...] = m_new
+        l_scr[...] = l_new
+
+    @pl.when(i == steps - 1)
+    def _finish():
+        l = l_scr[...]
+        l_safe = jnp.where(l == 0.0, 1.0, l)
+        o_ref[0] = (acc_scr[...] / l_safe).astype(o_ref.dtype)
+
+
+def latent_decode_attention(q, pages, block_tables, lengths, latent_dim,
+                            scale):
+    """One decode step of latent attention in its absorbed form
+    (`decode_latent`). q: [B, H, W], each head's `[ql ; q_pe]` (the query
+    with the key projection absorbed, then its rotated part) padded with
+    zeros to the pool's row width W; pages: the pool's [n_pages, page_size,
+    W] array, a token's normed latent and rotated key side by side;
+    block_tables: [B, P] (-1 unused); lengths: [B] valid tokens incl. the
+    current one (already written). Returns [B, H, latent_dim]: per head the
+    softmax-weighted sum of the cached LATENTS, to which the caller applies
+    the absorbed value projection. A free row (all -1) comes out zero."""
+    from .autotune import pick_block_sizes
+
+    B, H, W = q.shape
+    ps, P = pages.shape[1], block_tables.shape[1]
+    tile = (latent_pages_per_step(ps, W, P, pages.dtype.itemsize) * ps, W)
+    # the tile follows from the shapes and is the tuner's only candidate:
+    # nothing sweeps inside a serving process
+    tile = pick_block_sizes(
+        "decode_latent", 1, P * ps, tile, lambda bq, bk: None,
+        allow_measure=False, signature=(B, H, W, str(q.dtype), P),
+        candidates=[tile])
+    n = tile[0] // ps
+    lengths = lengths.astype(jnp.int32)
+    fetch = _fetch_table(block_tables, lengths, ps, n)
+    steps = fetch.shape[1] // n
+
+    def page_spec(j):
+        return pl.BlockSpec(
+            (1, ps, W),
+            lambda b, i, fetch, lens: (_page_of(fetch[b, i * n + j]), 0, 0))
+
+    dot_dtype = (jnp.bfloat16
+                 if q.dtype == pages.dtype == jnp.bfloat16 else jnp.float32)
+    kernel = functools.partial(
+        _latent_kernel, scale=scale, ps=ps, n=n, steps=steps, dv=latent_dim,
+        dot_dtype=dot_dtype)
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=2,
+        grid=(B, steps),
+        in_specs=[page_spec(j) for j in range(n)] + [
+            pl.BlockSpec((1, H, W), lambda b, i, fetch, lens: (b, 0, 0))],
+        out_specs=pl.BlockSpec((1, H, latent_dim),
+                               lambda b, i, fetch, lens: (b, 0, 0)),
+        scratch_shapes=[
+            pltpu.VMEM((H, 1), jnp.float32),
+            pltpu.VMEM((H, 1), jnp.float32),
+            pltpu.VMEM((H, latent_dim), jnp.float32),
+        ],
+    )
+    return named_pallas_call(
+        "decode_latent", kernel,
+        grid_spec=grid_spec,
+        out_shape=jax.ShapeDtypeStruct((B, H, latent_dim), q.dtype),
+        interpret=interpret_mode(),
+    )(fetch, lengths, *([pages] * n), q)
+
+
 def _split_heads(q, Hkv):
     B, H, D = q.shape
     g = H // Hkv
@@ -476,6 +610,27 @@ def paged_kv_write(cache, new, block_tables, lengths):
     pg = jnp.where(at_slot, new.astype(cache.dtype)[:, :, None, :],
                    cache[page])
     return cache.at[page].set(pg)
+
+
+def latent_kv_write(pages, new, block_tables, lengths):
+    """Write one decode step's latent rows into the latent pages.
+
+    pages: [n_pages, page_size, W]; new: [B, W] (this step's `[c ; k_pe ;
+    0]` per row); block_tables, lengths and the null page as `paged_kv_write`.
+    Here the page and the slot ARE the array's two major dimensions, so the
+    pool is seen as [n_pages * page_size, W] rows (no data moves) and each
+    row's ONE token row is scattered over the major dimension alone, in
+    place: the write moves B rows of W values whatever the page size, where
+    the whole-page form moves B pages. Parked rows collide on the null
+    page's slots, where one writer's row stays and nothing reads it."""
+    B = new.shape[0]
+    n_pages, ps, W = pages.shape
+    lengths = lengths.astype(jnp.int32)
+    page = block_tables[jnp.arange(B), lengths // ps]
+    page = jnp.where(page < 0, 0, page)
+    rows = pages.reshape(n_pages * ps, W)
+    rows = rows.at[page * ps + lengths % ps].set(new.astype(pages.dtype))
+    return rows.reshape(pages.shape)
 
 
 def paged_kv_write_q8(cache, scales, new, block_tables, lengths):

@@ -294,9 +294,13 @@ def _fwd(q, k, v, scale, causal, sq, skv, bq=None, bk=None, kbias=None,
     """With `window` (causal, no key bias) the kernel runs as
     `flash_fwd_window`: the grid's last axis is as long as the widest band of
     key blocks a query block can see, not as the keys, and the index maps
-    start each query block at its band's first key block."""
+    start each query block at its band's first key block. The values may be
+    of another width than q and k (`Dv`, a latent-attention prefill's 128
+    beside 192): the value and output blocks and the accumulator are `Dv`
+    wide, the body is the same."""
     B, H, Sqp, D = q.shape
     _, Hkv, Skvp, _ = k.shape
+    Dv = v.shape[-1]
     if bq is None or bk is None:
         bq, bk = _block_sizes(Sqp, Skvp, d=D)
     if safe is None:
@@ -330,7 +334,7 @@ def _fwd(q, k, v, scale, causal, sq, skv, bq=None, bk=None, kbias=None,
         pl.BlockSpec((1, 1, bq, D), lambda b, h, i, j: (b, h, i, 0)),
         pl.BlockSpec((1, 1, D, bk),
                      lambda b, h, i, j, g=group: (b, h // g, 0, kblock(i, j))),
-        pl.BlockSpec((1, 1, bk, D),
+        pl.BlockSpec((1, 1, bk, Dv),
                      lambda b, h, i, j, g=group: (b, h // g, kblock(i, j), 0)),
     ]
     args = [q, kt, v]
@@ -344,17 +348,17 @@ def _fwd(q, k, v, scale, causal, sq, skv, bq=None, bk=None, kbias=None,
         grid=(B, H, nq, nk),
         in_specs=in_specs,
         out_specs=[
-            pl.BlockSpec((1, 1, bq, D), lambda b, h, i, j: (b, h, i, 0)),
+            pl.BlockSpec((1, 1, bq, Dv), lambda b, h, i, j: (b, h, i, 0)),
             pl.BlockSpec((1, 1, bq, 1), lambda b, h, i, j: (b, h, i, 0)),
         ],
         out_shape=[
-            jax.ShapeDtypeStruct((B, H, Sqp, D), q.dtype),
+            jax.ShapeDtypeStruct((B, H, Sqp, Dv), q.dtype),
             jax.ShapeDtypeStruct((B, H, Sqp, 1), jnp.float32),
         ],
         scratch_shapes=[
             pltpu.VMEM((bq, 128), jnp.float32),
             pltpu.VMEM((bq, 128), jnp.float32),
-            pltpu.VMEM((bq, D), jnp.float32),
+            pltpu.VMEM((bq, Dv), jnp.float32),
         ],
         interpret=interpret_mode(),
     )(*args)
@@ -742,9 +746,12 @@ def _tuned_blocks(q, k, v, causal, scale):
 
     concrete = not any(isinstance(x, jax.core.Tracer) for x in (q, k, v))
     B, H, _, D = q.shape
+    # a value width of its own is part of the signature; at equal widths
+    # the signature is what it was
+    widths = (D,) if v.shape[-1] == D else (D, v.shape[-1])
     return pick_block_sizes(
         "flash_fwd", sq, skv, default, run_with, allow_measure=concrete,
-        signature=(B, H, k.shape[1], D, str(q.dtype), bool(causal)))
+        signature=(B, H, k.shape[1], *widths, str(q.dtype), bool(causal)))
 
 
 def _flash_fwd_res(q, k, v, causal, scale, bq, bk, safe):
@@ -765,6 +772,9 @@ def _flash_vjp_fwd(q, k, v, causal, scale, bq, bk, safe):
 
 def _flash_vjp_bwd(causal, scale, bq, bk, safe, saved, dout):
     (qp, kp, vp, outp, lse), sq, skv = saved
+    if vp.shape[-1] != qp.shape[-1]:
+        raise NotImplementedError(
+            "flash attention with a value width of its own is forward only")
     dop = jnp.pad(dout, ((0, 0), (0, 0), (0, qp.shape[2] - sq), (0, 0)))
     dq, dk, dv = _bwd(scale, causal, sq, skv, (qp, kp, vp, outp, lse), dop,
                       bq, bk, safe)
@@ -775,7 +785,8 @@ _flash.defvjp(_flash_vjp_fwd, _flash_vjp_bwd)
 
 
 def flash_attention_fwd(q, k, v, causal=False, scale=None, key_bias=None):
-    """Paddle-layout entry: q [B,Sq,H,D], k/v [B,Skv,Hkv,D] → [B,Sq,H,D].
+    """Paddle-layout entry: q [B,Sq,H,D], k/v [B,Skv,Hkv,D] → [B,Sq,H,D]
+    (v may be [B,Skv,Hkv,Dv], the output then [B,Sq,H,Dv]: forward only).
 
     key_bias: optional [B, Skv] ADDITIVE per-key bias (the padding-mask
     case — encoder models), fused into the kernel's logits stream.

@@ -120,7 +120,8 @@ def _held_experts_call(one_chip, held, experts, tokens, top_k, k, n):
     from paddle_tpu.incubate.distributed.models.moe import held_moe
 
     pairs = tokens * top_k
-    block = (held_moe._row_tile(pairs, experts), held_moe._column_tile(n))
+    bm = held_moe._row_tile(pairs, experts)
+    block = (bm, held_moe._column_tile(n, k, bm))
     hlo = _compile(
         lambda rows, w, sizes: grouped_gemm.ragged_matmul(rows, w, sizes,
                                                           block),
@@ -319,3 +320,72 @@ def test_the_decode_program_samples_under_one_conditional(one_chip,
                      r"bf16\[64,50304\]\S*, " + re.escape(_pool_text(pool)),
                      out)
     assert len(re.findall(r"%decode_paged[.\w]* = ", entry)) == 2
+
+
+# serve-kimi-k2-reason-sat: 256 rows, 64 heads; a token's cache in a layer is
+# ONE row of 640 (latent 512, rotated key 64, zeros), pages of 256, a pool of
+# about 4k pages in five arrays, the table 64 wide; prefill buckets 1k-8k
+# with q and k 192 wide and v 128; 12 held experts of 384, 7168 x (2 x 2048)
+# and 2048 x 7168
+
+K_POOL, K_ROWS, K_HEADS, K_TABLE = (4100, 256, 640), 96, 64, 64
+
+
+def test_latent_decode_compiles_in_place_at_the_cells_shapes(one_chip):
+    """The model's own order in one program: the token's row written into
+    its page, then `decode_latent` over the pool as it lies, donated."""
+    from paddle_tpu.ops.pallas.decode_attention import (
+        latent_decode_attention, latent_kv_write, latent_pages_per_step)
+
+    def layer(pages, new, q, tables, lengths):
+        pages = latent_kv_write(pages, new, tables, lengths)
+        return latent_decode_attention(q, pages, tables, lengths + 1, 512,
+                                       0.13087), pages
+
+    args = _args(one_chip, (K_POOL, jnp.bfloat16),
+                 ((K_ROWS, 640), jnp.bfloat16),
+                 ((K_ROWS, K_HEADS, 640), jnp.bfloat16),
+                 ((K_ROWS, K_TABLE), jnp.int32), ((K_ROWS,), jnp.int32))
+    compiled = jax.jit(layer, donate_argnums=(0,)).lower(*args).compile()
+    hlo = compiled.as_text()
+    pool = "bf16[" + ",".join(str(d) for d in K_POOL) + "]"
+    assert not re.search(r"%copy[.\w]* = " + re.escape(pool), hlo)
+    memory = compiled.memory_analysis()
+    pool_bytes = 2 * K_POOL[0] * K_POOL[1] * K_POOL[2]
+    assert memory.alias_size_in_bytes >= pool_bytes
+    assert memory.temp_size_in_bytes < pool_bytes // 8
+    # what benchmark/readers/kernel_roofline_kimi_k2.py holds on to
+    (call,) = [line for line in hlo.splitlines()
+               if re.match(r"\s*(ROOT )?%decode_latent[.\w]* = ", line)]
+    assert 'custom_call_target="tpu_custom_call"' in call and pool in call
+    # a kilotoken a grid step, whatever the page size
+    assert latent_pages_per_step(256, 640, K_TABLE, 2) == 4
+    assert latent_pages_per_step(64, 640, 256, 2) == 16
+
+
+@pytest.mark.parametrize("seq", [1024, 8192])
+def test_flash_fwd_compiles_with_a_value_width_of_its_own(one_chip, seq):
+    """The latent prefill's expanded heads: q and k 192 wide, v 128."""
+    from paddle_tpu.ops.pallas.flash_attention import flash_attention_fwd
+
+    qk = ((1, seq, K_HEADS, 192), jnp.bfloat16)
+    hlo = _compile(
+        lambda q, k, v: flash_attention_fwd(q, k, v, causal=True,
+                                            scale=0.13087),
+        one_chip, qk, qk, ((1, seq, K_HEADS, 128), jnp.bfloat16))
+    (call,) = [line for line in hlo.splitlines()
+               if re.match(r"\s*(ROOT )?%flash_fwd[.\w]* = ", line)]
+    assert 'custom_call_target="tpu_custom_call"' in call
+    assert f"bf16[1,{K_HEADS},{seq},128]" in call
+
+
+@pytest.mark.parametrize("tokens,k,n", [
+    (256, 7168, 4096), (256, 2048, 7168), (4096, 7168, 4096),
+    (4096, 2048, 7168)],
+    ids=["decode-in", "decode-out", "pass-in", "pass-out"])
+def test_grouped_gemm_compiles_at_kimis_held_experts(one_chip, tokens, k, n):
+    """12 of 384 experts held, 8 picks a token: a decode tick's 256 rows and
+    a whole pass of 4096 tokens."""
+    call = _held_experts_call(one_chip, 12, 384, tokens, 8, k, n)
+    # what benchmark/readers/kernel_roofline_kimi_k2.py holds on to
+    assert f"bf16[12,{k},{n}]" in call

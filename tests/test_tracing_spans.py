@@ -419,6 +419,9 @@ def _kernel_jaxprs():
             kv_scales=(scales, scales)), qd),
         "decode_window": fwd(lambda q: da.paged_decode_attention(
             q, pool, pool, tables, lens, window=24), qd),
+        "decode_latent": fwd(lambda q: da.latent_decode_attention(
+            q, jnp.ones((4, 16, 128), f32), tables, lens, 64, 0.1),
+            jnp.ones((2, 2, 128), f32)),
         "decode_dense": fwd(lambda q: da.dense_decode_attention(
             q, dense, dense, lens), qd),
         "ssm_decode": fwd(lambda s: sd.ssm_decode(
@@ -452,7 +455,7 @@ def test_every_kernel_carries_its_name_from_the_table(name):
 
 def test_every_pallas_call_site_goes_through_the_named_call():
     """No bare `pl.pallas_call` outside the helper, and every literal name
-    at a call site is in the table (19 names over 16 call sites)."""
+    at a call site is in the table (22 names over 17 call sites)."""
     sites, helper = [], os.path.join(PKG, "ops", "pallas", "__init__.py")
     for path in glob.glob(os.path.join(PKG, "**", "*.py"), recursive=True):
         tree = ast.parse(open(path).read())
@@ -464,7 +467,7 @@ def test_every_pallas_call_site_goes_through_the_named_call():
                 assert path == helper, f"bare pallas_call in {path}"
             if isinstance(f, ast.Name) and f.id == "named_pallas_call":
                 sites.append((path, node.args[0]))
-    assert len(sites) == 16
+    assert len(sites) == 17
     for path, arg in sites:
         if isinstance(arg, ast.Constant):
             assert arg.value in KERNEL_NAMES, (path, arg.value)
