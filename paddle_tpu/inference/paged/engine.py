@@ -185,9 +185,11 @@ class PagedServingEngine(_ServingEngineBase):
         self.preemption = bool(preemption)
         self._stack = None
         # the paged-decode kernels' grids, for the `decode_dispatch` span:
-        # static, so computed once
+        # the steps a call's table holds are static, so computed once; the
+        # steps a call walks are the tick's live ones (`_live_grid_steps`)
         pages0 = self.pool.kv[self.pool.page_entries[0]][0]
         self._decode_grid = {}
+        self._grid_kinds = {}   # tag -> (kind of page group, window or None)
         for g, table in zip(self.groups, self.group_tables):
             width = table.shape[1]
             n = g.spec.pages_per_step(self.ps, width, pages0.dtype.itemsize)
@@ -195,6 +197,8 @@ class PagedServingEngine(_ServingEngineBase):
             self._decode_grid.update({
                 tag + "pages_per_step": n,
                 tag + "grid_steps": self.B * -(-width // n)})
+            self._grid_kinds[tag] = (
+                g.spec.kind, g.spec.window if g.window else None)
         # a model with routed experts counts its routing in the decode
         # program (incubate/.../held_moe.STAT_NAMES)
         self._moe_groups = getattr(model, "moe_groups", 0)
@@ -495,6 +499,30 @@ class PagedServingEngine(_ServingEngineBase):
                       for grp, t in zip(self.groups, self.group_tables)
                       if grp.spec.kind == kind), kind=kind)
 
+    def _live_grid_steps(self, live) -> dict:
+        """The grid steps ONE call of each kind of paged decode kernel walks
+        this tick (`decode_attention.work_list`'s count, from the live rows'
+        lengths alone: their tables have no hole), for the `decode_dispatch`
+        span; their share of the table's steps goes to the histogram."""
+        from ...ops.pallas.decode_attention import live_step_count
+
+        ctx = self.lengths[live].astype(np.int64) + 1
+        shares = serving_metrics()["decode_live_step_share"]
+        out = {}
+        for tag, (kind, window) in self._grid_kinds.items():
+            # a window group's table and lengths start at the row's first
+            # cached page
+            seen = (ctx if window is None
+                    else ctx - self.window_start[live].astype(np.int64)
+                    * self.ps)
+            steps = live_step_count(
+                seen, self.ps, self._decode_grid[tag + "pages_per_step"],
+                window)
+            out[tag + "live_grid_steps"] = steps
+            shares.observe(steps / self._decode_grid[tag + "grid_steps"],
+                           kind=kind)
+        return out
+
     def _note_routing(self, stats):
         """One decode tick's routing counts (held_moe.STAT_NAMES, summed
         over the layers) into the serving metrics."""
@@ -581,7 +609,7 @@ class PagedServingEngine(_ServingEngineBase):
         sampled = np.flatnonzero(self.temps > 0)  # live rows all: _vacate
         with span("decode_dispatch", rows=len(live),
                   sampled_rows=len(sampled), **self._decode_grid,
-                  **state_rows):
+                  **self._live_grid_steps(live), **state_rows):
             # quantized pool: each layer's cache rides as (k, v, k_scale,
             # v_scale) so the int8 append + dequant-fused attention see
             # payload and scales together inside the one compiled program

@@ -32,6 +32,8 @@ _TPS_BUCKETS = tuple(0.5 * 2 ** i for i in range(14))
 _ROWS_BUCKETS = tuple(float(2 ** i) for i in range(11))
 # rows all held experts of all layers get in a decode tick: 64 .. 128k
 _TICK_ROWS_BUCKETS = tuple(float(2 ** i) for i in range(6, 18))
+# a share of a whole: twentieths
+_SHARE_BUCKETS = tuple(i / 20 for i in range(1, 21))
 
 
 def _build(reg):
@@ -110,6 +112,13 @@ def _build(reg):
             "Pages held by live rows, by kind of page group: full (kept "
             "until the request ends, shareable) or window (expire)",
             ("kind",)),
+        "decode_live_step_share": reg.histogram(
+            "serving_decode_live_step_share",
+            "Per decode tick and kind of page group: the grid steps one "
+            "call of the kind's paged decode kernel walks (the live ones) "
+            "over the steps its block table holds (what the grid walked "
+            "before it followed a work list)",
+            labelnames=("kind",), buckets=_SHARE_BUCKETS),
         "moe_routed_pairs_held": reg.counter(
             "moe_routed_pairs_held",
             "(token, expert) picks of decode ticks that named an expert "

@@ -9,27 +9,38 @@ TPU-native design: decode is HBM-bound — the entire job is streaming the KV
 cache through VMEM exactly once per step — so a grid step does a DMA's worth
 of work, not one head of one page.
 
-Paged (`decode_paged`, `decode_paged_q8`): the grid is (batch, ceil(P / N))
-and a step takes ALL KV heads of N pages of one row: the pool's layout is
+Paged (`decode_paged`, `decode_paged_q8`): a STEP is all KV heads of N
+consecutive table slots of one row: the pool's layout is
 [n_pages, Hkv, page_size, D], so a page of all heads is one contiguous DMA.
 The pool goes into the `pallas_call` as it is, N times, one
 `BlockSpec((1, Hkv, page_size, D))` per page slot whose index_map reads the
 physical page out of a prefetched scalar table (PrefetchScalarGridSpec): no
 gathered copy of the cache is ever materialized (the jnp composite's
 `kc[tables]` gather is exactly what XLA does badly — SURVEY §7 hard parts).
-That table is not the block table itself but `_fetch_table`'s view of it: a
-slot that is dead (past the row's length, a -1 hole, a free row, the padding
-behind P) names the page the same slot held one step earlier, so its block
-index does not change, the pipeline issues no DMA for it, and a step whose
-slots are all dead runs no arithmetic either. N follows from the shapes
-(`pages_per_step`: the largest of 16..1 that fits the VMEM budget and the
-table) and is recorded through the tuner as the tile (N * page_size, D);
-nothing is swept, so a serving process pays no measurement at set-up. N
-need divide neither P nor a row's page count: the last step is masked. A
-step makes ONE online-softmax update over its N * page_size keys for all
-heads: `q.K` and `p.V` are dots batched over the KV heads ([Hkv, g, D] x
-[Hkv, N * ps, D]); running maximum, sum and accumulator are f32 VMEM
-scratch, and the output is normalised once at the row's last step. Operand
+The grid is ONE flat axis over a WORK LIST of the live steps, as long as the
+work (`work_list`): a step is live when any of its N slots is (a slot is dead
+past the row's length, at a -1 hole, in a free row, in the padding behind P),
+and the live steps of all rows are compacted in row-major order into the front
+of prefetched arrays (the step's row, its index within the row, whether it is
+the row's first and its last) with a cumulative sum and one scatter; their
+count, a traced scalar, is the grid's bound. A step without work does not
+exist: a table as wide as the longest request costs a short row nothing, and
+a free row nothing at all. The fetch table is built over the compacted steps:
+a dead SLOT inside a live step names the page the same slot held at the last
+live step, so its block index does not change and the pipeline issues no DMA
+for it. The list follows from the block table and the lengths alone, so the
+layers of a decode program that share a table share one list (XLA merges the
+identical computations). The accumulators start at a row's first live step
+and the output is normalised and written at its last; a row the grid never
+visits has no output block written, and the wrapper returns zeros for it. N
+follows from the shapes (`pages_per_step`: the largest of 16..1 that fits the
+VMEM budget and the table) and is recorded through the tuner as the tile
+(N * page_size, D); nothing is swept, so a serving process pays no
+measurement at set-up. N need divide neither P nor a row's page count: the
+last step is masked. A step makes ONE online-softmax update over its
+N * page_size keys for all heads: `q.K` and `p.V` are dots batched over the
+KV heads ([Hkv, g, D] x [Hkv, N * ps, D]); running maximum, sum and
+accumulator are f32 VMEM scratch. Operand
 types: with q and the pool both bf16 the dots take bf16 operands and
 accumulate in f32 — `q.K` is then exact, and p (f32) goes in as three bf16
 addends that carry its whole mantissa, so nothing is rounded that the
@@ -44,8 +55,9 @@ only a scatter of whole pages leaves the pool where it lies.
 Windowed (`decode_window`): a sliding-window layer's decode is the paged
 kernel's body under its own name, with one more mask (keys before
 `length - window`) and a table that starts at the row's first cached page
-and is `ceil(window / page_size) + 1` wide whatever the longest sequence is:
-the grid does not walk pages the row released long ago.
+and is `ceil(window / page_size) + 1` wide whatever the longest sequence is.
+A page wholly before the window is a dead slot even while the table still
+names it, so a step of such pages is not in the work list.
 
 Latent (`decode_latent`): a latent-attention layer caches per token ONE
 normed latent and ONE rotated key that all the heads share, side by side in a
@@ -53,8 +65,8 @@ row of the pool's `[n_pages, page_size, W]` array (`block_pool.LatentKV`: W =
 640 for 512 + 64, zero behind them). With the key and value projections
 absorbed into the query and the output, a row's H heads are H queries `[ql ;
 q_pe ; 0]` against the SAME keys, and the value of a token is the first
-`latent_dim` of its key: the kernel takes the paged kernel's grid and fetch
-table, reads each page ONCE, and uses it twice, `s = q . page^T` over all W
+`latent_dim` of its key: the kernel takes the paged kernel's work list and
+grid, reads each page ONCE, and uses it twice, `s = q . page^T` over all W
 and `o += p . page[:, :latent_dim]`. 2 x H x (576 + 512) FLOP a cached token
 against 1,152 bytes is 121 FLOP a byte at 64 heads, half the chip's balance,
 so both products go to the MXU with bf16 operands and f32 accumulation
@@ -71,7 +83,7 @@ Single-token decode (q = one step per row), inference only (no VJP).
 Quantized pool: with `kv_scales`, the caches are int8 page payloads and
 `kv_scales` the per-(page, head) f32 dequant scales (`x ≈ q * scale`,
 `BlockPool(quantized=True)` layout). It is the same kernel body on the same
-grid: a page is dequantized in VMEM after its load (its [Hkv] row of scales,
+work list: a page is dequantized in VMEM after its load (its [Hkv] row of scales,
 one multiply per head) and everything after is the f32 path — decode is
 HBM-bound, so halving/quartering the streamed bytes is the whole win. The
 scales ride beside their page as the (8, Hkv) tile of the [n_pages, Hkv]
@@ -82,9 +94,11 @@ not a shape Mosaic tiles — and the kernel reads row `page % 8` of it.
 from __future__ import annotations
 
 import functools
+from typing import NamedTuple
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
@@ -93,7 +107,8 @@ from .flash_attention import NEG_INF
 
 __all__ = ["paged_decode_attention", "dense_decode_attention",
            "latent_decode_attention", "pages_per_step",
-           "latent_pages_per_step", "paged_kv_write", "paged_kv_write_q8",
+           "latent_pages_per_step", "WorkList", "work_list",
+           "live_step_count", "paged_kv_write", "paged_kv_write_q8",
            "latent_kv_write", "KV_QMAX"]
 
 # symmetric int8 range for KV pages: ±127 (not -128) so the running-max
@@ -218,34 +233,113 @@ def pages_per_step(Hkv, ps, D, P, itemsize):
     return 1
 
 
-def _fetch_table(tables, lengths, ps, n, window=None):
-    """[B, steps * n] int32, what slot j of step i of row b fetches and
-    whether it counts. An entry >= 0 is a live slot's physical page: a table
-    entry that is not -1 and starts before the row's length (with `window`,
-    and ends behind `length - window`: a page wholly before the window is
-    dead even while the table still names it). An entry < 0
-    is a dead slot, and `~entry` is the page the same slot held at the step
-    before (steps counted through the rows, in the grid's order): the
-    pipeline sees an unchanged block index and fetches nothing."""
+class WorkList(NamedTuple):
+    """What the paged decode kernels' grid walks, as scalar-prefetch
+    operands. A STEP is `n` consecutive table slots of one row; it is LIVE
+    when any of its slots is. The live steps of all rows, in row-major
+    order, fill the front of the `[B * steps]` arrays; `count` says how
+    many there are, and the grid is that long.
+
+    - `fetch` [B * steps, n]: what slot j of the w-th live step fetches and
+      whether it counts. An entry >= 0 is a live slot's physical page; an
+      entry < 0 is a dead slot, and `~entry` the page the same slot held at
+      the last live step at which it was live: the pipeline sees an
+      unchanged block index and fetches nothing;
+    - `row`, `step` [B * steps]: the live step's row and its index within
+      the row's `steps` (its key positions start at `step * n * ps`);
+    - `first`, `last` [B * steps], 0 or 1: the live step is its row's first
+      (the accumulators start) or its last (the output is written);
+    - `count` []: the number of live steps;
+    - `visited` [B] bool: the row has a live step. The output block of a
+      row that has none is never written."""
+    fetch: jax.Array
+    row: jax.Array
+    step: jax.Array
+    first: jax.Array
+    last: jax.Array
+    count: jax.Array
+    visited: jax.Array
+
+
+def work_list(tables, lengths, ps, n, window=None) -> WorkList:
+    """The live steps of `tables` [B, P] at `n` slots a step. A slot is
+    live when its table entry is not -1 and its page starts before the
+    row's length (with `window`, and ends behind `length - window`: a page
+    wholly before the window is dead even while the table still names it);
+    dead are a slot past the length, a -1 hole, a free row, the padding
+    behind P. The live steps are compacted with a cumulative sum and ONE
+    scatter of their rows (a sort of these few thousand keys compiles for
+    seconds on the chip). It depends on `tables` and `lengths` alone, so the
+    layers of a decode program that share a table share one list."""
     B, P = tables.shape
     steps = -(-P // n)
+    total = B * steps
     t = jnp.pad(tables.astype(jnp.int32), ((0, 0), (0, steps * n - P)),
                 constant_values=-1)
+    lengths = lengths.astype(jnp.int32)
     first_tok = jnp.arange(steps * n, dtype=jnp.int32) * ps
     live = (t >= 0) & (first_tok[None, :] < lengths[:, None])
     if window is not None:
         live = live & (first_tok[None, :] + ps > lengths[:, None] - window)
-    t, live = t.reshape(B * steps, n), live.reshape(B * steps, n)
-    step = jnp.arange(B * steps, dtype=jnp.int32)[:, None]
-    last_live = jax.lax.cummax(jnp.where(live, step, -1), axis=0)
-    held = jnp.where(
-        last_live >= 0,
-        jnp.take_along_axis(t, jnp.maximum(last_live, 0), axis=0), 0)
-    return jnp.where(live, t, ~held).reshape(B, steps * n)
+    t, live = t.reshape(total, n), live.reshape(total, n)
+    live_step = jnp.any(live, axis=1)
+    s = jnp.arange(total, dtype=jnp.int32)
+    before = jnp.cumsum(live_step.astype(jnp.int32))   # live steps up to s
+    count = before[-1]
+    # a stable partition: the live steps to the front in their order, the
+    # dead ones behind them, so every target is written once
+    target = jnp.where(live_step, before - 1, count + s - before)
+    packed = jnp.concatenate(
+        [jnp.where(live, t, -1), s[:, None]], axis=1)
+    packed = jnp.zeros_like(packed).at[target].set(
+        packed, unique_indices=True)
+    t, s_of = packed[:, :n], packed[:, n]
+    live = t >= 0
+    # a dead slot names the page it held when it last was live
+    held = _last_known(jnp.where(live, t, 0), live)
+    row = s_of // steps
+    is_live = s < count
+    edge = jnp.full((1,), -1, jnp.int32)
+    first = is_live & (row != jnp.concatenate([edge, row[:-1]]))
+    last = is_live & ((s == count - 1)
+                      | (row != jnp.concatenate([row[1:], edge])))
+    return WorkList(
+        fetch=jnp.where(live, t, ~held), row=row, step=s_of % steps,
+        first=first.astype(jnp.int32), last=last.astype(jnp.int32),
+        count=count, visited=jnp.any(live_step.reshape(B, steps), axis=1))
+
+
+def _last_known(value, known):
+    """`value` [steps, n] with every entry that is not `known` replaced by
+    the last known one above it in its column (0 where there is none): a
+    scan of log2(steps) shifts and selects. A gather of these scalars
+    (`take_along_axis` at a running maximum's indices) costs the chip 10 ns
+    apiece, a millisecond for a 512-wide table of 224 rows."""
+    size, d = value.shape[0], 1
+    while d < size:
+        above_value = jnp.pad(value, ((d, 0), (0, 0)))[:size]
+        above_known = jnp.pad(known, ((d, 0), (0, 0)))[:size]
+        value = jnp.where(known, value, above_value)
+        known = known | above_known
+        d *= 2
+    return value
+
+
+def live_step_count(lengths, ps, n, window=None) -> int:
+    """`work_list(...).count` on the host, for rows whose tables have no
+    hole before their length (the engine's): numpy over `lengths` [rows],
+    each row's valid tokens counted from its table's first slot. A row of
+    length 0 has no live step."""
+    lengths = np.asarray(lengths, np.int64)
+    last_page = (lengths - 1) // ps
+    first_page = (np.zeros_like(lengths) if window is None
+                  else np.maximum(lengths - window, 0) // ps)
+    per_row = last_page // n - first_page // n + 1
+    return int(np.where(lengths > 0, per_row, 0).sum())
 
 
 def _page_of(entry):
-    """The physical page a `_fetch_table` entry names, live or dead."""
+    """The physical page a `WorkList.fetch` entry names, live or dead."""
     return jnp.where(entry < 0, ~entry, entry)
 
 
@@ -261,27 +355,29 @@ def _split_bf16(x):
     return parts
 
 
-def _paged_kernel(fetch_ref, lens_ref, *refs, scale, ps, n, steps, g,
-                  quantized, dot_dtype, window=None):
-    """One grid step: all KV heads of `n` pages of row b. refs: n K blocks,
-    n V blocks, each [1, Hkv, ps, D]; q [1, Hkv, g, D]; quantized: n K-scale
-    and n V-scale tiles [8, Hkv]; the output [1, Hkv, g, D]; scratch m, l
-    [Hkv, g, 1] and acc [Hkv, g, D], f32."""
+def _paged_kernel(fetch_ref, row_ref, step_ref, first_ref, last_ref,
+                  lens_ref, *refs, scale, ps, n, g, quantized, dot_dtype,
+                  window=None):
+    """One grid step, the w-th LIVE step of the work list: all KV heads of
+    `n` pages of row `row[w]`. refs: n K blocks, n V blocks, each [1, Hkv,
+    ps, D]; q [1, Hkv, g, D]; quantized: n K-scale and n V-scale tiles [8,
+    Hkv]; the output [1, Hkv, g, D]; scratch m, l [Hkv, g, 1] and acc [Hkv,
+    g, D], f32."""
     k_refs, v_refs, q_ref = refs[:n], refs[n:2 * n], refs[2 * n]
     ks_refs, vs_refs = refs[2 * n + 1:3 * n + 1], refs[3 * n + 1:4 * n + 1]
     o_ref, m_scr, l_scr, acc_scr = refs[-4:]
-    b = pl.program_id(0)
-    i = pl.program_id(1)
+    w = pl.program_id(0)
+    i = step_ref[w]
     Hkv = q_ref.shape[1]
 
-    @pl.when(i == 0)
+    @pl.when(first_ref[w] == 1)
     def _init():
         m_scr[...] = jnp.full_like(m_scr, NEG_INF)
         l_scr[...] = jnp.zeros_like(l_scr)
         acc_scr[...] = jnp.zeros_like(acc_scr)
 
-    length = lens_ref[b]
-    slot_live = [fetch_ref[b, i * n + j] >= 0 for j in range(n)]
+    length = lens_ref[row_ref[w]]
+    slot_live = [fetch_ref[w * n + j] >= 0 for j in range(n)]
 
     def pages(refs, scale_refs):
         """The step's n pages as one [Hkv, n * ps, D] array of `dot_dtype`,
@@ -291,7 +387,7 @@ def _paged_kernel(fetch_ref, lens_ref, *refs, scale, ps, n, steps, g,
             if not quantized:
                 out.append(ref[0].astype(dot_dtype))
                 continue
-            phys = _page_of(fetch_ref[b, i * n + j])
+            phys = _page_of(fetch_ref[w * n + j])
             row = scale_refs[j][pl.ds(phys % _SCALE_ROWS, 1), :]   # [1, Hkv]
             head = jax.lax.broadcasted_iota(jnp.int32, row.shape, 1)
             # a head's scale as a (1, 1) array by way of a masked sum: the
@@ -303,35 +399,34 @@ def _paged_kernel(fetch_ref, lens_ref, *refs, scale, ps, n, steps, g,
                 for h in range(Hkv)]))
         return out[0] if n == 1 else jnp.concatenate(out, axis=1)
 
-    @pl.when(functools.reduce(jnp.logical_or, slot_live))
-    def _compute():
-        q = q_ref[0].astype(dot_dtype)                       # [Hkv, g, D]
-        s = mxu_dot(q, pages(k_refs, ks_refs),
-                    (((2,), (2,)), ((0,), (0,))),
-                    preferred_element_type=jnp.float32) * scale  # [Hkv, g, T]
-        # a key counts if it lies before the row's length, in a live slot
-        lane = jax.lax.broadcasted_iota(jnp.int32, (1, 1, n * ps), 2)
-        live = (i * (n * ps) + lane) < length
-        if window is not None:   # the query at length - 1 sees `window` keys
-            live = live & ((i * (n * ps) + lane) >= length - window)
-        for j, alive in enumerate(slot_live):
-            in_slot = (lane >= j * ps) & (lane < (j + 1) * ps)
-            live = live & (alive | jnp.logical_not(in_slot))
-        m_new, alpha, p, l_new = _softmax_update(
-            s, live, m_scr[...], l_scr[...])
-        v = pages(v_refs, vs_refs)                           # [Hkv, T, D]
-        pv_dims = (((2,), (1,)), ((0,), (0,)))
-        if dot_dtype == jnp.bfloat16:
-            pv = sum(mxu_dot(part, v, pv_dims,
-                             preferred_element_type=jnp.float32)
-                     for part in _split_bf16(p))
-        else:
-            pv = mxu_dot(p, v, pv_dims, preferred_element_type=jnp.float32)
-        acc_scr[...] = acc_scr[...] * alpha + pv
-        m_scr[...] = m_new
-        l_scr[...] = l_new
+    # every step of the list has a live slot: there is no step to skip
+    q = q_ref[0].astype(dot_dtype)                       # [Hkv, g, D]
+    s = mxu_dot(q, pages(k_refs, ks_refs),
+                (((2,), (2,)), ((0,), (0,))),
+                preferred_element_type=jnp.float32) * scale  # [Hkv, g, T]
+    # a key counts if it lies before the row's length, in a live slot
+    lane = jax.lax.broadcasted_iota(jnp.int32, (1, 1, n * ps), 2)
+    live = (i * (n * ps) + lane) < length
+    if window is not None:   # the query at length - 1 sees `window` keys
+        live = live & ((i * (n * ps) + lane) >= length - window)
+    for j, alive in enumerate(slot_live):
+        in_slot = (lane >= j * ps) & (lane < (j + 1) * ps)
+        live = live & (alive | jnp.logical_not(in_slot))
+    m_new, alpha, p, l_new = _softmax_update(
+        s, live, m_scr[...], l_scr[...])
+    v = pages(v_refs, vs_refs)                           # [Hkv, T, D]
+    pv_dims = (((2,), (1,)), ((0,), (0,)))
+    if dot_dtype == jnp.bfloat16:
+        pv = sum(mxu_dot(part, v, pv_dims,
+                         preferred_element_type=jnp.float32)
+                 for part in _split_bf16(p))
+    else:
+        pv = mxu_dot(p, v, pv_dims, preferred_element_type=jnp.float32)
+    acc_scr[...] = acc_scr[...] * alpha + pv
+    m_scr[...] = m_new
+    l_scr[...] = l_new
 
-    @pl.when(i == steps - 1)
+    @pl.when(last_ref[w] == 1)
     def _finish():
         l = l_scr[...]
         l_safe = jnp.where(l == 0.0, 1.0, l)
@@ -351,24 +446,23 @@ def _run_paged(q, kc, vc, tables, lengths, scale, n, kv_scales=None,
     ps = kc.shape[2]
     quantized = kv_scales is not None
     lengths = lengths.astype(jnp.int32)
-    fetch = _fetch_table(tables, lengths, ps, n, window)
-    steps = fetch.shape[1] // n
+    work = work_list(tables, lengths, ps, n, window)
 
     def page_spec(j):
         return pl.BlockSpec(
             (1, Hkv, ps, D),
-            lambda b, i, fetch, lens: (_page_of(fetch[b, i * n + j]), 0, 0, 0))
+            lambda w, fetch, *_: (_page_of(fetch[w * n + j]), 0, 0, 0))
 
     def scale_spec(j):
         # the (8, Hkv) tile of the [n_pages, Hkv] scales that holds the
         # page's row: a (1, Hkv) block is not a shape Mosaic tiles
         return pl.BlockSpec(
             (_SCALE_ROWS, Hkv),
-            lambda b, i, fetch, lens: (
-                _page_of(fetch[b, i * n + j]) // _SCALE_ROWS, 0))
+            lambda w, fetch, *_: (
+                _page_of(fetch[w * n + j]) // _SCALE_ROWS, 0))
 
     row_spec = pl.BlockSpec((1, Hkv, g, D),
-                            lambda b, i, fetch, lens: (b, 0, 0, 0))
+                            lambda w, fetch, row, *_: (row[w], 0, 0, 0))
     slots = [page_spec(j) for j in range(n)]
     in_specs = slots + slots + [row_spec]
     operands = [kc] * n + [vc] * n + [q]
@@ -380,11 +474,11 @@ def _run_paged(q, kc, vc, tables, lengths, scale, n, kv_scales=None,
     dot_dtype = (jnp.bfloat16
                  if q.dtype == kc.dtype == jnp.bfloat16 else jnp.float32)
     kernel = functools.partial(
-        _paged_kernel, scale=scale, ps=ps, n=n, steps=steps, g=g,
-        quantized=quantized, dot_dtype=dot_dtype, window=window)
+        _paged_kernel, scale=scale, ps=ps, n=n, g=g, quantized=quantized,
+        dot_dtype=dot_dtype, window=window)
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,
-        grid=(B, steps),
+        num_scalar_prefetch=6,
+        grid=(work.count,),
         in_specs=in_specs,
         out_specs=row_spec,
         scratch_shapes=[
@@ -393,12 +487,28 @@ def _run_paged(q, kc, vc, tables, lengths, scale, n, kv_scales=None,
             pltpu.VMEM((Hkv, g, D), jnp.float32),
         ],
     )
-    return named_pallas_call(
+    out = named_pallas_call(
         _paged_name(quantized, window), kernel,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((B, Hkv, g, D), q.dtype),
         interpret=interpret_mode(),
-    )(fetch, lengths, *operands)
+    )(*_prefetched(work), lengths, *operands)
+    return _zero_unvisited(out, work)
+
+
+def _prefetched(work):
+    """The work list's scalar-prefetch operands, `fetch` as ONE row: SMEM
+    pads a 2-D array's minor dimension to 128 words, which a table of `n`
+    slots a row would pay 8 to 128 times over."""
+    return (work.fetch.reshape(-1), work.row, work.step, work.first,
+            work.last)
+
+
+def _zero_unvisited(out, work):
+    """The grid never comes to a row without a live step, so nothing wrote
+    its output block: such a row's output is zero by definition."""
+    seen = work.visited.reshape((-1,) + (1,) * (out.ndim - 1))
+    return jnp.where(seen, out, jnp.zeros_like(out))
 
 
 def _paged_name(quantized, window):
@@ -423,47 +533,47 @@ def latent_pages_per_step(ps, W, P, itemsize):
     return 1
 
 
-def _latent_kernel(fetch_ref, lens_ref, *refs, scale, ps, n, steps, dv,
-                   dot_dtype):
-    """One grid step: `n` latent pages of row b, each [1, ps, W], used as
-    keys (all W) and as values (the first `dv`). refs: the n page blocks;
-    q [1, H, W]; the output [1, H, dv]; scratch m, l [H, 1], acc [H, dv]."""
+def _latent_kernel(fetch_ref, row_ref, step_ref, first_ref, last_ref,
+                   lens_ref, *refs, scale, ps, n, dv, dot_dtype):
+    """One grid step, the w-th LIVE step of the work list: `n` latent pages
+    of row `row[w]`, each [1, ps, W], used as keys (all W) and as values
+    (the first `dv`). refs: the n page blocks; q [1, H, W]; the output [1,
+    H, dv]; scratch m, l [H, 1], acc [H, dv]."""
     page_refs, q_ref = refs[:n], refs[n]
     o_ref, m_scr, l_scr, acc_scr = refs[-4:]
-    b = pl.program_id(0)
-    i = pl.program_id(1)
+    w = pl.program_id(0)
+    i = step_ref[w]
 
-    @pl.when(i == 0)
+    @pl.when(first_ref[w] == 1)
     def _init():
         m_scr[...] = jnp.full_like(m_scr, NEG_INF)
         l_scr[...] = jnp.zeros_like(l_scr)
         acc_scr[...] = jnp.zeros_like(acc_scr)
 
-    length = lens_ref[b]
-    slot_live = [fetch_ref[b, i * n + j] >= 0 for j in range(n)]
+    length = lens_ref[row_ref[w]]
+    slot_live = [fetch_ref[w * n + j] >= 0 for j in range(n)]
 
-    @pl.when(functools.reduce(jnp.logical_or, slot_live))
-    def _compute():
-        q = q_ref[0].astype(dot_dtype)                        # [H, W]
-        pages = [ref[0].astype(dot_dtype) for ref in page_refs]
-        kv = pages[0] if n == 1 else jnp.concatenate(pages, axis=0)  # [T, W]
-        s = mxu_dot(q, kv, (((1,), (1,)), ((), ())),
-                    preferred_element_type=jnp.float32) * scale    # [H, T]
-        lane = jax.lax.broadcasted_iota(jnp.int32, (1, n * ps), 1)
-        live = (i * (n * ps) + lane) < length
-        for j, alive in enumerate(slot_live):
-            in_slot = (lane >= j * ps) & (lane < (j + 1) * ps)
-            live = live & (alive | jnp.logical_not(in_slot))
-        m_new, alpha, p, l_new = _softmax_update(
-            s, live, m_scr[...], l_scr[...])
-        pv = mxu_dot(p.astype(dot_dtype), kv[:, :dv],
-                     (((1,), (0,)), ((), ())),
-                     preferred_element_type=jnp.float32)           # [H, dv]
-        acc_scr[...] = acc_scr[...] * alpha + pv
-        m_scr[...] = m_new
-        l_scr[...] = l_new
+    # every step of the list has a live slot: there is no step to skip
+    q = q_ref[0].astype(dot_dtype)                        # [H, W]
+    pages = [ref[0].astype(dot_dtype) for ref in page_refs]
+    kv = pages[0] if n == 1 else jnp.concatenate(pages, axis=0)  # [T, W]
+    s = mxu_dot(q, kv, (((1,), (1,)), ((), ())),
+                preferred_element_type=jnp.float32) * scale    # [H, T]
+    lane = jax.lax.broadcasted_iota(jnp.int32, (1, n * ps), 1)
+    live = (i * (n * ps) + lane) < length
+    for j, alive in enumerate(slot_live):
+        in_slot = (lane >= j * ps) & (lane < (j + 1) * ps)
+        live = live & (alive | jnp.logical_not(in_slot))
+    m_new, alpha, p, l_new = _softmax_update(
+        s, live, m_scr[...], l_scr[...])
+    pv = mxu_dot(p.astype(dot_dtype), kv[:, :dv],
+                 (((1,), (0,)), ((), ())),
+                 preferred_element_type=jnp.float32)           # [H, dv]
+    acc_scr[...] = acc_scr[...] * alpha + pv
+    m_scr[...] = m_new
+    l_scr[...] = l_new
 
-    @pl.when(i == steps - 1)
+    @pl.when(last_ref[w] == 1)
     def _finish():
         l = l_scr[...]
         l_safe = jnp.where(l == 0.0, 1.0, l)
@@ -494,38 +604,40 @@ def latent_decode_attention(q, pages, block_tables, lengths, latent_dim,
         candidates=[tile])
     n = tile[0] // ps
     lengths = lengths.astype(jnp.int32)
-    fetch = _fetch_table(block_tables, lengths, ps, n)
-    steps = fetch.shape[1] // n
+    work = work_list(block_tables, lengths, ps, n)
 
     def page_spec(j):
         return pl.BlockSpec(
             (1, ps, W),
-            lambda b, i, fetch, lens: (_page_of(fetch[b, i * n + j]), 0, 0))
+            lambda w, fetch, *_: (_page_of(fetch[w * n + j]), 0, 0))
+
+    def row_spec(width):
+        return pl.BlockSpec((1, H, width),
+                            lambda w, fetch, row, *_: (row[w], 0, 0))
 
     dot_dtype = (jnp.bfloat16
                  if q.dtype == pages.dtype == jnp.bfloat16 else jnp.float32)
     kernel = functools.partial(
-        _latent_kernel, scale=scale, ps=ps, n=n, steps=steps, dv=latent_dim,
+        _latent_kernel, scale=scale, ps=ps, n=n, dv=latent_dim,
         dot_dtype=dot_dtype)
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,
-        grid=(B, steps),
-        in_specs=[page_spec(j) for j in range(n)] + [
-            pl.BlockSpec((1, H, W), lambda b, i, fetch, lens: (b, 0, 0))],
-        out_specs=pl.BlockSpec((1, H, latent_dim),
-                               lambda b, i, fetch, lens: (b, 0, 0)),
+        num_scalar_prefetch=6,
+        grid=(work.count,),
+        in_specs=[page_spec(j) for j in range(n)] + [row_spec(W)],
+        out_specs=row_spec(latent_dim),
         scratch_shapes=[
             pltpu.VMEM((H, 1), jnp.float32),
             pltpu.VMEM((H, 1), jnp.float32),
             pltpu.VMEM((H, latent_dim), jnp.float32),
         ],
     )
-    return named_pallas_call(
+    out = named_pallas_call(
         "decode_latent", kernel,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((B, H, latent_dim), q.dtype),
         interpret=interpret_mode(),
-    )(fetch, lengths, *([pages] * n), q)
+    )(*_prefetched(work), lengths, *([pages] * n), q)
+    return _zero_unvisited(out, work)
 
 
 def _split_heads(q, Hkv):

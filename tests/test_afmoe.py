@@ -663,6 +663,64 @@ def test_engine_reports_both_kinds_of_pages(model):
     eng.run()
 
 
+@pytest.mark.parametrize("prompts,full_steps,window_steps", [
+    # contexts 22 and 11 on the second tick, both inside the window (32): a
+    # step of each table a row
+    ((20, 9), 2, 2),
+    # 142 tokens: 18 of the full table's 20 pages at 16 a step are two
+    # steps; the window's table starts at page 13 (token 104) and the query
+    # sees tokens 110-141, in all five of its slots: two steps at 4 a step.
+    # 70 + 2: one step of the full table; the window's starts at token 40
+    # and holds tokens 40-71 in slots 0-3: one step
+    ((140, 70), 3, 3)],
+    ids=["inside-the-window", "past-the-window"])
+def test_decode_dispatch_counts_both_kernels_live_steps(model, prompts,
+                                                        full_steps,
+                                                        window_steps):
+    """`live_grid_steps` and `window_live_grid_steps` of a seated batch on
+    its second tick: a hand count, and the count of the kernels' own work
+    lists over the engine's tables; each kind's histogram takes one
+    observation a tick."""
+    from paddle_tpu.observability import spans
+    from paddle_tpu.ops.pallas import decode_attention as da
+
+    eng = _engine(model)
+    for i, n in enumerate(prompts):
+        eng.add_request(_prompt(n, i), max_new_tokens=6)
+    eng.step()
+    share = default_registry().get("serving_decode_live_step_share")
+    before = {k: (share.count(kind=k), share.sum(kind=k))
+              for k in ("full", "window")}
+    tl = spans.enable_step_timeline()
+    try:
+        eng.step()
+    finally:
+        tl.uninstall()
+    (attrs,) = [r["attrs"] for r in spans.recorded()
+                if r["path"] == "engine.step/decode_dispatch"][-1:]
+    spans.clear_recorded()
+    assert (attrs["pages_per_step"], attrs["grid_steps"]) == (16, 4 * 2)
+    assert (attrs["window_pages_per_step"],
+            attrs["window_grid_steps"]) == (4, 4 * 2)
+    assert attrs["live_grid_steps"] == full_steps
+    assert attrs["window_live_grid_steps"] == window_steps
+    # what the kernels saw: the lengths after the tick, a window group's
+    # counted from its table's first page
+    seen = jnp.asarray(eng.lengths)
+    assert int(da.work_list(jnp.asarray(eng.group_tables[0]), seen, PS,
+                            16).count) == full_steps
+    for table in eng.group_tables[1:]:
+        work = da.work_list(
+            jnp.asarray(table), seen - jnp.asarray(eng.window_start * PS),
+            PS, 4, window=32)
+        assert int(work.count) == window_steps
+    for kind, steps in (("full", full_steps), ("window", window_steps)):
+        assert share.count(kind=kind) == before[kind][0] + 1
+        assert share.sum(kind=kind) - before[kind][1] == pytest.approx(
+            steps / 8)
+    eng.run()
+
+
 # -- the yardstick's own counts, by hand ------------------------------------ #
 
 def _cell_config():

@@ -363,6 +363,69 @@ def test_latent_decode_compiles_in_place_at_the_cells_shapes(one_chip):
     assert latent_pages_per_step(64, 640, 256, 2) == 16
 
 
+# the paged decode kernels' grid is the work list of live steps, its length a
+# traced scalar: the cells' rows and table widths as the cells run them
+WORK_LIST_CELLS = {
+    # cell: (kernel, rows, table width, pool, q, window, pages a step)
+    "trinity-full": ("decode_paged", 224, 512, T_POOL[0], (32, D), None, 16),
+    "trinity-window": ("decode_window", 224, 65, T_POOL[0], (32, D), 2048,
+                       16),
+    "xl": ("decode_paged", 64, 64, (N_PAGES, 16, PS, D), (16, D), None, 8),
+    "granite": ("decode_paged", 128, 64, (9749, 8, PS, D), (32, D), None,
+                16),
+    "kimi": ("decode_latent", 128, 64, K_POOL, (K_HEADS, 640), None, 4),
+}
+
+
+@pytest.mark.parametrize("cell", sorted(WORK_LIST_CELLS))
+def test_the_work_list_grid_compiles_at_the_cells_shapes(one_chip, cell):
+    """Mosaic takes the dynamic grid bound for all three bodies; two layers
+    that share a table share ONE work list (one scatter of
+    `[rows x steps, n + 1]` integers in the program, no gather of the
+    table's scalars), and the prefetched operands fit SMEM."""
+    from paddle_tpu.ops.pallas.decode_attention import (
+        latent_decode_attention, work_list)
+
+    name, rows, width, pool, q_shape, window, n = WORK_LIST_CELLS[cell]
+
+    def attend(q, pages, tables, lengths):
+        if name == "decode_latent":
+            return latent_decode_attention(q, pages, tables, lengths, 512,
+                                           0.13087)
+        return paged_decode_attention(q, pages, pages, tables, lengths,
+                                      window=window)
+
+    def two_layers(q, pages0, pages1, tables, lengths):
+        out = attend(q, pages0, tables, lengths + 1)
+        if name == "decode_latent":   # [rows, H, 512] back to the q's width
+            out = jnp.pad(out, ((0, 0), (0, 0), (0, 128)))
+        return attend(q + out, pages1, tables, lengths + 1)
+
+    pool = (pool, jnp.bfloat16)
+    hlo = _compile(two_layers, one_chip, ((rows,) + q_shape, jnp.bfloat16),
+                   pool, pool, ((rows, width), jnp.int32),
+                   ((rows,), jnp.int32))
+    calls = [line for line in hlo.splitlines()
+             if re.match(r"\s*(ROOT )?%" + name + r"[.\w]* = ", line)]
+    assert len(calls) == 2
+    steps = rows * -(-width // n)
+    for call in calls:
+        assert 'custom_call_target="tpu_custom_call"' in call
+        # the grid's bound is the call's first operand, a scalar; then the
+        # fetch table as one row and the list's four arrays
+        assert (f"operand_layout_constraints={{s32[], s32[{steps * n}]{{0}}, "
+                f"s32[{steps}]{{0}}") in call
+    assert len(re.findall(rf" = s32\[{steps},{n + 1}\]\S* scatter\(",
+                          hlo)) == 1
+    assert not re.search(rf" = s32\[{steps * n}\]\S* gather\(", hlo)
+    shapes = jax.eval_shape(
+        lambda t, l: work_list(t, l, pool[0][-2] if name == "decode_latent"
+                               else PS, n, window),
+        jax.ShapeDtypeStruct((rows, width), jnp.int32),
+        jax.ShapeDtypeStruct((rows,), jnp.int32))
+    assert shapes.fetch.shape == (steps, n) and shapes.count.shape == ()
+
+
 @pytest.mark.parametrize("seq", [1024, 8192])
 def test_flash_fwd_compiles_with_a_value_width_of_its_own(one_chip, seq):
     """The latent prefill's expanded heads: q and k 192 wide, v 128."""

@@ -154,30 +154,240 @@ def test_cell_shapes_take_several_pages_a_step():
     assert da.pages_per_step(64, 256, 256, 64, 4) == 1
 
 
+def _hand_work_list(tables, lengths, ps, n, window=None):
+    """The live steps by plain loops: [(row, step, [page or None] * n)] in
+    the grid's order, a slot's page None where the slot is dead."""
+    B, P = tables.shape
+    out = []
+    for b in range(B):
+        for i in range(-(-P // n)):
+            slots = []
+            for p in range(i * n, (i + 1) * n):
+                page = int(tables[b, p]) if p < P else -1
+                live = page >= 0 and p * ps < lengths[b] and (
+                    window is None or (p + 1) * ps > lengths[b] - window)
+                slots.append(page if live else None)
+            if any(x is not None for x in slots):
+                out.append((b, i, slots))
+    return out
+
+
+def _work(tables, lengths, ps, n, window=None):
+    work = da.work_list(jnp.asarray(np.asarray(tables, np.int32)),
+                        jnp.asarray(np.asarray(lengths, np.int32)), ps, n,
+                        window)
+    return jax.tree.map(np.asarray, work)
+
+
+WORK_CASES = {
+    # a free row between two live ones; a -1 hole inside a live step; the
+    # padding behind P (6 slots, 4 a step)
+    "free-row-and-hole": dict(
+        tables=[[3, 4, 5, -1, -1, -1], [-1] * 6, [6, -1, 7, -1, -1, -1]],
+        lengths=[20, 1, 24], ps=8, n=4,
+        steps=[(0, 0), (2, 0)], first=[1, 1], last=[1, 1],
+        visited=[True, False, True]),
+    # a hole as wide as a step: the row's steps 0 and 2 are live, 1 is not;
+    # the next row's dead slots name what the slot held at the last LIVE step
+    "hole-of-a-whole-step": dict(
+        tables=[[1, 2, -1, -1, 3, 4], [5, -1, -1, -1, -1, 6]],
+        lengths=[24, 24], ps=4, n=2,
+        steps=[(0, 0), (0, 2), (1, 0), (1, 2)], first=[1, 0, 1, 0],
+        last=[0, 1, 0, 1], visited=[True, True]),
+    "a-row-of-one-page": dict(
+        tables=[[-1] * 4, [7, -1, -1, -1], [-1] * 4], lengths=[1, 1, 1],
+        ps=8, n=2, steps=[(1, 0)], first=[1], last=[1],
+        visited=[False, True, False]),
+    # every step of every row is live: the list is the old grid
+    "rows-that-fill-the-table": dict(
+        tables=[[1, 2, 3, 4, 5, 6], [7, 8, 9, 10, 11, 12]],
+        lengths=[48, 41], ps=8, n=4,
+        steps=[(0, 0), (0, 1), (1, 0), (1, 1)], first=[1, 0, 1, 0],
+        last=[0, 1, 0, 1], visited=[True, True]),
+    # window 4 over pages of 4: row 0 (16 tokens) sees keys 12-15 only, so
+    # its step 0 has passed though the table still names the pages; row 1
+    # (6 tokens) sees keys 2-5 in pages 0 and 1; row 2 (14 tokens, window
+    # start inside page 2) keeps pages 2 and 3
+    "a-window-that-has-passed-pages": dict(
+        tables=[[1, 2, 3, 4], [5, 6, -1, -1], [7, 8, 9, 10]],
+        lengths=[16, 6, 14], ps=4, n=2, window=4,
+        steps=[(0, 1), (1, 0), (2, 1)], first=[1, 1, 1], last=[1, 1, 1],
+        visited=[True, True, True]),
+    "no-live-row": dict(
+        tables=[[-1] * 4, [-1] * 4], lengths=[1, 0], ps=8, n=2, steps=[],
+        first=[], last=[], visited=[False, False]),
+}
+
+
+@pytest.mark.parametrize("case", sorted(WORK_CASES))
+def test_work_list_matches_a_hand_count(case):
+    """`work_list`: the live steps in the grid's order at the front of the
+    arrays, with their rows, their steps, the first and last flags and the
+    count, against the counts written out above and against plain loops."""
+    c = dict(WORK_CASES[case])
+    tables = np.asarray(c.pop("tables"), np.int32)
+    lengths, ps, n = c.pop("lengths"), c.pop("ps"), c.pop("n")
+    window = c.pop("window", None)
+    work = _work(tables, lengths, ps, n, window)
+    total = tables.shape[0] * -(-tables.shape[1] // n)
+    assert work.fetch.shape == (total, n)
+    assert all(getattr(work, f).shape == (total,)
+               for f in ("row", "step", "first", "last"))
+    count = int(work.count)
+    assert count == len(c["steps"])
+    assert list(zip(work.row[:count].tolist(),
+                    work.step[:count].tolist())) == c["steps"]
+    assert work.first[:count].tolist() == c["first"]
+    assert work.last[:count].tolist() == c["last"]
+    # behind the count no step starts or ends a row
+    assert not work.first[count:].any() and not work.last[count:].any()
+    assert work.visited.tolist() == c["visited"]
+    hand = _hand_work_list(tables, lengths, ps, n, window)
+    assert [(b, i) for b, i, _ in hand] == c["steps"]
+    held = [0] * n      # what each slot's block index holds, step by step
+    for w, (_, _, slots) in enumerate(hand):
+        for j, page in enumerate(slots):
+            if page is not None:
+                assert work.fetch[w, j] == page
+                held[j] = page
+            else:
+                # a dead slot of a live step names the page the slot held
+                # at the previous LIVE step: nothing is fetched for it
+                assert work.fetch[w, j] == ~held[j]
+    # the host's count, for tables without holes
+    if case not in ("free-row-and-hole", "hole-of-a-whole-step"):
+        seen = [L if (t >= 0).any() else 0
+                for L, t in zip(lengths, tables)]
+        assert da.live_step_count(seen, ps, n, window) == count
+
+
 def test_dead_slots_refetch_nothing():
-    """_fetch_table: a live slot carries its physical page; a dead one (past
-    the length, a -1 hole, a free row, the padding behind P) carries ~(the
-    page the same slot held one grid step earlier), so its block index does
-    not change and the pipeline issues no DMA for it."""
+    """`work_list`'s fetch table: a live slot carries its physical page; a
+    dead one (past the length, a -1 hole, the padding behind P) carries
+    ~(the page the same slot held one grid step earlier), so its block index
+    does not change and the pipeline issues no DMA for it. A free row has no
+    step in the list at all."""
     tables = np.array([[3, 4, 5, -1, -1, -1],
                        [-1, -1, -1, -1, -1, -1],     # a free row
                        [6, -1, 7, -1, -1, -1]], np.int32)   # a hole
     lengths = np.array([20, 1, 24], np.int32)
     n, ps = 4, 8
-    fetch = np.asarray(da._fetch_table(jnp.asarray(tables),
-                                       jnp.asarray(lengths), ps, n))
-    assert fetch.shape == (3, 8)
+    work = _work(tables, lengths, ps, n)
+    assert work.fetch.shape == (6, 4) and work.count == 2
+    fetch = work.fetch[:2]
     live = fetch >= 0
-    want_live = np.zeros((3, 8), bool)
+    want_live = np.zeros((2, 4), bool)
     want_live[0, :3] = True
-    want_live[2, [0, 2]] = True
+    want_live[1, [0, 2]] = True
     np.testing.assert_array_equal(live, want_live)
     np.testing.assert_array_equal(fetch[live], [3, 4, 5, 6, 7])
-    pages = np.where(live, fetch, ~fetch).reshape(-1, n)    # [step, slot]
+    pages = np.where(live, fetch, ~fetch)    # [step, slot]
     for step in range(1, len(pages)):
-        dead = ~live.reshape(-1, n)[step]
+        dead = ~live[step]
         np.testing.assert_array_equal(pages[step][dead],
                                       pages[step - 1][dead])
+
+
+def _ref_window(q, kc, vc, tables, lengths, window):
+    """`_ref_paged` for a table that starts at the row's first cached page:
+    the query at `length - 1` sees the last `window` keys."""
+    B, H, D = q.shape
+    Hkv = kc.shape[1]
+    out = np.zeros((B, H, D), np.float32)
+    for b in range(B):
+        L = int(lengths[b])
+        pages = [p for p in tables[b] if p >= 0]
+        if not pages:
+            continue
+        keys = np.concatenate([np.asarray(kc)[p] for p in pages], 1)
+        vals = np.concatenate([np.asarray(vc)[p] for p in pages], 1)
+        lo = max(0, L - window)
+        for h in range(H):
+            out[b, h] = _ref_attend(np.asarray(q)[b, h],
+                                    keys[h // (H // Hkv), lo:L],
+                                    vals[h // (H // Hkv), lo:L], L - lo,
+                                    D ** -0.5)
+    return out
+
+
+def _ref_latent(q, pages, tables, lengths, latent_dim, scale):
+    B, H, _ = q.shape
+    out = np.zeros((B, H, latent_dim), np.float32)
+    for b in range(B):
+        held = [p for p in tables[b] if p >= 0]
+        if not held:
+            continue
+        kv = np.concatenate([np.asarray(pages)[p] for p in held])[
+            :int(lengths[b])]
+        s = np.asarray(q)[b] @ kv.T * scale
+        p = np.exp(s - s.max(-1, keepdims=True))
+        out[b] = (p / p.sum(-1, keepdims=True)) @ kv[:, :latent_dim]
+    return out
+
+
+# rows x [tokens cached]: most steps dead (short rows and free rows in a
+# wide table), none dead (every row fills the table), no live row at all
+SCENES = {"mostly-dead": [9, 0, 0, 200, 0, 17, 0, 0],
+          "none-dead": [384, 384, 384],
+          "no-live-row": [0, 0, 0, 0]}
+
+
+def _run_kernel(kernel, lengths):
+    """(what the kernel returns, its reference, the list it walked, the
+    steps its table holds) over a table 48 pages of 8 wide: three steps a
+    row at 16 pages a step."""
+    ps, P, n_pages = 8, 48, 150
+    rng = np.random.default_rng(len(lengths))
+    B = len(lengths)
+    tables = np.full((B, P), -1, np.int32)
+    free = iter(rng.permutation(np.arange(1, n_pages)))
+    for b, L in enumerate(lengths):
+        for slot in range(-(-L // ps)):
+            tables[b, slot] = next(free)
+    lens = np.asarray([max(L, 1) for L in lengths], np.int32)  # free: 0 + 1
+    if kernel == "decode_latent":
+        pages = rng.normal(size=(n_pages, ps, 128)).astype(np.float32)
+        q = rng.normal(size=(B, 4, 128)).astype(np.float32)
+        got = da.latent_decode_attention(
+            jnp.asarray(q), jnp.asarray(pages), jnp.asarray(tables),
+            jnp.asarray(lens), 96, 0.3)
+        ref = _ref_latent(q, pages, tables, lens, 96, 0.3)
+        n, window = da.latent_pages_per_step(ps, 128, P, 4), None
+    else:
+        window = 24 if kernel == "decode_window" else None
+        kc = rng.normal(size=(n_pages, 2, ps, 16)).astype(np.float32)
+        vc = rng.normal(size=(n_pages, 2, ps, 16)).astype(np.float32)
+        q = rng.normal(size=(B, 4, 16)).astype(np.float32)
+        got = paged_decode_attention(
+            jnp.asarray(q), jnp.asarray(kc), jnp.asarray(vc),
+            jnp.asarray(tables), jnp.asarray(lens), window=window)
+        ref = (_ref_window(q, kc, vc, tables, lens, window) if window
+               else _ref_paged(q, kc, vc, tables, lens))
+        n = da.pages_per_step(2, ps, 16, P, 4)
+    work = _work(tables, lens, ps, n, window)
+    return np.asarray(got), ref, work, B * -(-P // n)
+
+
+@pytest.mark.parametrize("scene", sorted(SCENES))
+@pytest.mark.parametrize("kernel", ["decode_paged", "decode_window",
+                                    "decode_latent"])
+def test_kernels_walk_the_live_steps_alone(kernel, scene):
+    """The three paged kernels against their references where most steps of
+    the table are dead, where none is and where no row is live (zeros): the
+    grid is as long as the work."""
+    got, ref, work, walked_before = _run_kernel(kernel, SCENES[scene])
+    assert np.all(np.isfinite(got))
+    np.testing.assert_allclose(got, ref, rtol=1e-4, atol=2e-5)
+    if scene == "mostly-dead":
+        # rows of 2, 25 and 3 pages: 1 + 2 + 1 steps of 24; under the
+        # window row 3 keeps its second step alone
+        assert work.count == (3 if kernel == "decode_window" else 4)
+        assert walked_before == 24
+        assert not got[[1, 2, 4, 6, 7]].any()       # the free rows
+    elif scene == "none-dead" and kernel != "decode_window":
+        assert work.count == walked_before
+    elif scene == "no-live-row":
+        assert work.count == 0 and not got.any()
 
 
 def test_zero_length_row_outputs_zeros():

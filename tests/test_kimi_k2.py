@@ -319,6 +319,43 @@ def test_engine_reports_latent_pages_and_what_the_kernel_must_read(model,
     eng.run()
 
 
+@pytest.mark.parametrize("prompts,steps", [
+    ((20, 11), 2),        # 22 and 13 tokens: 3 and 2 pages, a step a row
+    ((70, 11, 64), 5)],   # 72: 9 pages, two steps; 13: one; 66: 9, two
+    ids=["a-step-a-row", "two-step-rows"])
+def test_decode_dispatch_counts_the_latent_kernels_live_steps(model, prompts,
+                                                              steps):
+    """`live_grid_steps` of a seated batch on its second tick (a table 12
+    pages wide at 8 a step: 2 steps a row, 8 in all): a hand count, and the
+    count of the kernel's own work list; the histogram's `latent` series
+    takes one observation a tick."""
+    from paddle_tpu.observability import spans
+    from paddle_tpu.ops.pallas import decode_attention as da
+
+    eng = _engine(model)
+    for i, n in enumerate(prompts):
+        eng.add_request(_prompt(n, i), max_new_tokens=6)
+    eng.step()
+    share = default_registry().get("serving_decode_live_step_share")
+    before = (share.count(kind="latent"), share.sum(kind="latent"))
+    tl = spans.enable_step_timeline()
+    try:
+        eng.step()
+    finally:
+        tl.uninstall()
+    (attrs,) = [r["attrs"] for r in spans.recorded()
+                if r["path"] == "engine.step/decode_dispatch"][-1:]
+    spans.clear_recorded()
+    assert (attrs["pages_per_step"], attrs["grid_steps"]) == (8, 4 * 2)
+    assert attrs["live_grid_steps"] == steps
+    work = da.work_list(jnp.asarray(eng.tables), jnp.asarray(eng.lengths),
+                        PS, 8)
+    assert int(work.count) == steps
+    assert share.count(kind="latent") == before[0] + 1
+    assert share.sum(kind="latent") - before[1] == pytest.approx(steps / 8)
+    eng.run()
+
+
 # -- 7. YaRN and the softmax scale, by hand ---------------------------------- #
 
 def test_yarn_frequencies_and_the_softmax_scale_of_the_published_config():

@@ -166,6 +166,65 @@ def test_sampling_is_one_span_a_tick_not_one_a_row(traced_ticks):
     assert "sampled_rows" in host["engine.step/decode_dispatch"]
 
 
+def test_decode_dispatch_counts_the_live_grid_steps(traced_ticks):
+    """`live_grid_steps`: the steps one call of the paged decode kernel
+    walks this tick. Here a table is 4 pages wide and a step takes 4, so a
+    live row is one step and the grid of 4 steps shrinks to the live rows;
+    the attribute reaches the profiler's trace."""
+    ring, host = traced_ticks
+    dispatches = [r["attrs"] for r in ring
+                  if r["path"] == "engine.step/decode_dispatch"]
+    assert dispatches
+    for a in dispatches:
+        assert (a["pages_per_step"], a["grid_steps"]) == (4, 4)
+        assert a["live_grid_steps"] == a["rows"]
+    assert len({a["live_grid_steps"] for a in dispatches}) > 1
+    assert "live_grid_steps" in host["engine.step/decode_dispatch"]
+
+
+@pytest.mark.parametrize("prompts,page_size,steps", [
+    # a table 8 pages wide at 8 pages a step: a step a live row
+    ((14, 30), 8, (1, 1)),
+    # 32 pages wide at 16 a step: 70 + 2 tokens are 18 pages, two steps
+    ((70, 9, 40), 4, (2, 1, 1))],
+    ids=["one-step-rows", "a-two-step-row"])
+def test_live_grid_steps_equal_a_hand_count_and_the_work_list(
+        model, prompts, page_size, steps):
+    """A seated batch on its second tick: `live_grid_steps` is the hand
+    count and the kernel's own work list's count; the histogram
+    `serving_decode_live_step_share{kind="full"}` takes one observation a
+    tick, the live steps over the steps the table holds."""
+    from paddle_tpu.ops.pallas import decode_attention as da
+
+    eng = PagedServingEngine(model, max_batch_size=4, max_seq_len=128,
+                             page_size=page_size, prefix_sharing=False)
+    rng = np.random.default_rng(5)
+    for n in prompts:
+        eng.add_request(rng.integers(1, 1000, n).astype(np.int32),
+                        max_new_tokens=4)
+    eng.step()
+    share = default_registry().get("serving_decode_live_step_share")
+    before = (share.count(kind="full"), share.sum(kind="full"))
+    tl = spans.enable_step_timeline()
+    try:
+        eng.step()
+    finally:
+        tl.uninstall()
+    (attrs,) = [r["attrs"] for r in spans.recorded()
+                if r["path"] == "engine.step/decode_dispatch"]
+    n = attrs["pages_per_step"]
+    assert attrs["live_grid_steps"] == sum(steps)
+    assert attrs["grid_steps"] == 4 * -(-eng.P // n)
+    # the second tick's kernel saw lengths - 1 + 1 tokens a row
+    work = da.work_list(jnp.asarray(eng.tables),
+                        jnp.asarray(eng.lengths), page_size, n)
+    assert int(work.count) == sum(steps)
+    assert share.count(kind="full") == before[0] + 1
+    assert share.sum(kind="full") - before[1] == pytest.approx(
+        sum(steps) / attrs["grid_steps"])
+    eng.run()
+
+
 def test_a_request_record_appears_on_retirement(traced_ticks):
     ring, _ = traced_ticks
     reqs = [r for r in ring if r["path"] == "request"]
