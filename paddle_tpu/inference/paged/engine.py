@@ -368,17 +368,18 @@ class PagedServingEngine(_ServingEngineBase):
         return self._stack(kv_layers, np.int32(n))
 
     def _prefill_into(self, row, req):
+        t0_ns = time.perf_counter_ns()
         rid, n = req.req_id, len(req.prompt)
         req._t_admit = time.perf_counter()
         bucket = _bucket(n)
+        compiled = bucket not in self._prefill_cache
         with span("prefill", rid=rid, prompt_len=n, bucket=bucket,
-                  compiled=bucket not in self._prefill_cache,
-                  **self._prefill_attrs(bucket)):
+                  compiled=compiled, **self._prefill_attrs(bucket)) as prefill:
             logits_row, new_c, n, _ = self._run_prefill(req)
         m = _pages_for_prompt(n, self.ps)
         mb = _pages_for_prompt(bucket, self.ps)
         tables, masks = [], []
-        with span("pages", rid=rid, pages=self._prompt_pages(n)) as sp:
+        with span("pages", rid=rid, pages=self._prompt_pages(n)) as pages_sp:
             for gi, group in enumerate(self.groups):
                 # a window group takes the prompt's last window only, and
                 # its pages are the row's own: no key, no registry
@@ -402,31 +403,41 @@ class PagedServingEngine(_ServingEngineBase):
                     pages[j] = page
                 tables.append(pages[first:m])
                 masks.append((pages, mask))
-            hits = sum(len(t) for t in tables) - sum(
-                int(k.sum()) for _, k in masks)
-            sp.set(prefix_hits=hits)
-        if any(k.any() for _, k in masks):
-            with span("write_pages", rid=rid,
-                      pages_written=sum(int(k.sum()) for _, k in masks)):
+            written = sum(int(k.sum()) for _, k in masks)
+            hits = sum(len(t) for t in tables) - written
+            pages_sp.set(prefix_hits=hits)
+        write_pages_s = write_state_s = 0.0
+        if written:
+            with span("write_pages", rid=rid, pages_written=written) as sp:
                 for group, (pages, mask) in zip(self.groups, masks):
                     if not mask.any():
                         continue
                     stacked = self._stack_pages(
                         [new_c[li] for li in group.layers], n)
                     self.pool.write_prompt_pages(pages, mask, *zip(*stacked))
+            write_pages_s = sp.seconds
         if self.pool.state_layers:
-            with span("write_state", rid=rid, row=row):
+            with span("write_state", rid=rid, row=row) as sp:
                 self.pool.write_state(
                     row, [new_c[li] for li in self.pool.state_layers])
+            write_state_s = sp.seconds
         for group, table, pages in zip(self.groups, self.group_tables,
                                        tables):
             table[row, :len(pages)] = pages
             if group.window:
                 self.window_start[row] = group.spec.first_page(n, self.ps)
-        with span("first_token", rid=rid):  # the host waits for the prefill
+        with span("first_token", rid=rid) as first_token:
+            # the host waits for the prefill here
             first = self._pick_token(logits_row, req)
         self._seat(row, req, n, first)
         self._emit(row, first)
+        self._record_admission(
+            "prefill", req, row, t0_ns, bucket=bucket, compiled=compiled,
+            pages_written=written, prefix_hits=hits,
+            queue_wait_s=req._t_admit - req._t_arrival,
+            prefill_s=prefill.seconds, pages_s=pages_sp.seconds,
+            write_pages_s=write_pages_s, write_state_s=write_state_s,
+            first_token_s=first_token.seconds)
 
     def _prefill_attrs(self, bucket):
         """What a model adds to the `prefill` span (`prefill_span_attrs`:
@@ -435,9 +446,10 @@ class PagedServingEngine(_ServingEngineBase):
         return attrs(bucket) if attrs is not None else {}
 
     def _resume_into(self, row, sp: SpilledRequest):
+        t0_ns = time.perf_counter_ns()
         pages, restore_rows, restore_pages = [], [], []
-        with span("resume", rid=sp.req.req_id,
-                  **self._state_attrs()) as resume:
+        state = self._state_attrs()
+        with span("resume", rid=sp.req.req_id, **state) as resume:
             for j, key in enumerate(sp.keys):
                 page = self.pool.lookup_prefix(key)
                 if page is None:
@@ -457,6 +469,10 @@ class PagedServingEngine(_ServingEngineBase):
         self.window_start[row] = sp.window_start
         self._seat(row, sp.req, sp.length, sp.last_tok)
         serving_metrics()["resumes"].inc()
+        self._record_admission(
+            "resume", sp.req, row, t0_ns, resume_s=resume.seconds,
+            pages_restored=len(restore_pages),
+            state_bytes=state.get("state_bytes", 0))
 
     # -- decode write-target maintenance -------------------------------- #
 

@@ -312,6 +312,17 @@ class _ServingEngineBase:
                 preemptions=req.preemptions)
         self.finished.append(req)
 
+    def _record_admission(self, kind, req, row, t0_ns, **attrs):
+        """One `admission` record a request that takes a row (`kind`
+        "prefill", or "resume" for a spilled one coming back), from `t0_ns`
+        to now, ALWAYS written (`spans.record`): the one thing a serving
+        window is read for per request and not per traced tick. `attrs`:
+        what the admitting engine knows, and its phases' own lengths in
+        seconds from the child spans' two clock reads."""
+        _spans.record("admission", t0_ns, time.perf_counter_ns(),
+                      rid=req.req_id, kind=kind, tick=self._tick, row=row,
+                      prompt_len=len(req.prompt), **attrs)
+
     def run(self):
         """Drain: step until every queued/live request finishes; returns
         the finished requests in completion order."""
@@ -452,11 +463,12 @@ class ContinuousBatchingEngine(_ServingEngineBase):
             slot = free.pop(0)
             req = self.waiting.popleft()
             picked += 1
+            t0_ns = time.perf_counter_ns()
             req._t_admit = time.perf_counter()
             bucket = _bucket(len(req.prompt))
+            compiled = bucket not in self._prefill_cache
             with span("prefill", rid=req.req_id, prompt_len=len(req.prompt),
-                      bucket=bucket,
-                      compiled=bucket not in self._prefill_cache):
+                      bucket=bucket, compiled=compiled) as prefill:
                 logits_row, new_c, n, _ = self._run_prefill(req)
                 # scatter the prompt's kv into this slot's cache rows [0, n)
                 for li, (k_, v_) in enumerate(new_c):
@@ -464,10 +476,17 @@ class ContinuousBatchingEngine(_ServingEngineBase):
                     bk = bk.at[slot, :n].set(k_[0, :n])
                     bv = bv.at[slot, :n].set(v_[0, :n])
                     self.caches[li] = (bk, bv)
-            with span("first_token", rid=req.req_id):
+            with span("first_token", rid=req.req_id) as first_token:
                 first = self._pick_token(logits_row, req)
             self._seat(slot, req, n, first)
             self._emit(slot, first)
+            # the paged engine's record; this engine has no page and no slot
+            self._record_admission(
+                "prefill", req, slot, t0_ns, bucket=bucket, compiled=compiled,
+                pages_written=0, prefix_hits=0,
+                queue_wait_s=req._t_admit - req._t_arrival,
+                prefill_s=prefill.seconds, pages_s=0.0, write_pages_s=0.0,
+                write_state_s=0.0, first_token_s=first_token.seconds)
         return picked
 
     # ------------------------------------------------------------------ #

@@ -17,7 +17,9 @@ Every live span is also kept in a bounded in-memory ring (`recorded()`,
 `clear_recorded()`): `{id, parent, path, t0_ns, t1_ns, attrs}` on
 `time.perf_counter_ns`. With no listener `__enter__`/`__exit__` read the
 clock, make the check and return: no stack push, no path join, no ring
-write. See docs/OBSERVABILITY.md for the span catalog.
+write. The rule: spans live-only, the per-request record `admission`
+always (`record`: a dozen a second at most, and what it measures decides
+half a serving window). See docs/OBSERVABILITY.md for the span catalog.
 """
 
 from __future__ import annotations
@@ -47,6 +49,7 @@ __all__ = [
     "fleet_step_summary",
     "overlap_stats",
     "record_span",
+    "record",
 ]
 
 RING_KEEP = 65536  # records; a traced serving tick writes a few dozen
@@ -69,7 +72,8 @@ def live() -> bool:
 
 def recorded() -> list:
     """The ring's records, oldest first: every span that ended while live,
-    `request` and `compile` records among them."""
+    `request` and `compile` records among them, and every `admission`
+    record, listener or none."""
     return list(_ring)
 
 
@@ -152,16 +156,24 @@ class span:
         return wrapped
 
 
-def record_span(name, t0_ns, t1_ns, **attrs):
+def record(name, t0_ns, t1_ns, **attrs):
     """Report an interval measured elsewhere to the span sinks after the
     fact (ring, `Profiler` window, StepTimeline; the profiler's trace cannot
-    be written backwards) — for windows whose qualification is only known at
-    their END (the input-h2d-behind-inflight-step compute credit), and for
-    records that are not a `with` block: a retired `request`, a `compile`."""
+    be written backwards), WHETHER OR NOT anyone listens: for the one record
+    a request that is read over a whole serving window, `admission`. Its
+    parent is the live span it was written under, None when nobody listens."""
+    stack = _span_stack()
+    _emit(name, t0_ns, t1_ns, len(stack), attrs, span.cat, next(_ids),
+          stack[-1]._id if stack else None)
+
+
+def record_span(name, t0_ns, t1_ns, **attrs):
+    """`record` while somebody listens, nothing otherwise — for windows
+    whose qualification is only known at their END (the
+    input-h2d-behind-inflight-step compute credit), and for records that are
+    not a `with` block: a retired `request`, a `compile`."""
     if live():
-        stack = _span_stack()
-        _emit(name, t0_ns, t1_ns, len(stack), attrs, span.cat, next(_ids),
-              stack[-1]._id if stack else None)
+        record(name, t0_ns, t1_ns, **attrs)
 
 
 def _emit(path, t0_ns, t1_ns, depth, attrs, cat, span_id, parent_id):

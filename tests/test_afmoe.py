@@ -939,7 +939,7 @@ def test_the_cell_and_its_metrics_are_in_the_manifest():
     files = {n[:-5] for n in os.listdir(
         os.path.join(ROOT, "benchmark", "layer_metrics"))
         if n.endswith(".tmix.json")}
-    assert set(mine) == files and len(mine) == 28
+    assert set(mine) == files and len(mine) == 29
     name = re.compile(r"^[A-Za-z0-9_][A-Za-z0-9_.-]{0,63}$")
     for metric in mine.values():
         assert name.match(metric["name"]) and name.match(metric["layer"])

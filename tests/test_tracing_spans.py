@@ -71,8 +71,9 @@ def traced_ticks(model, tmp_path_factory):
                                  watermark_pages=0, prefix_sharing=False)
         eng.add_request(rng.integers(1, 1000, 14).astype(np.int32),
                         max_new_tokens=2)
-        eng.run()  # compiles; nobody listens yet
-        assert spans.recorded() == []
+        eng.run()  # compiles; nobody listens yet: the one record is always on
+        assert [r["path"] for r in spans.recorded()] == ["admission"]
+        spans.clear_recorded()
         trace_dir = str(tmp_path_factory.mktemp("trace"))
         jax.profiler.start_trace(trace_dir)
         try:
@@ -118,7 +119,8 @@ def test_children_nest_inside_the_tick_and_cover_it(traced_ticks):
     assert [r["attrs"]["tick"] for r in ticks] == sorted(
         r["attrs"]["tick"] for r in ticks)
     for r in ring:
-        if r["parent"] in by_id and r["path"] not in ("request", "compile"):
+        if r["parent"] in by_id and r["path"] not in ("request", "compile",
+                                                      "admission"):
             p = by_id[r["parent"]]
             assert r["path"].startswith(p["path"] + "/")
             assert p["t0_ns"] <= r["t0_ns"] <= r["t1_ns"] <= p["t1_ns"]
@@ -253,7 +255,132 @@ def test_the_dense_engine_spans_the_same_phases(model):
     assert {"engine.step", "engine.step/admit", "engine.step/admit/prefill",
             "engine.step/admit/first_token", "engine.step/decode_dispatch",
             "engine.step/host_read", "engine.step/emit",
-            "engine.step/emit/sample", "request"} <= paths
+            "engine.step/emit/sample", "request", "admission"} <= paths
+
+
+# -- the admission record: always on --------------------------------------- #
+
+PHASES = ("prefill_s", "pages_s", "write_pages_s", "write_state_s",
+          "first_token_s")
+PREFILL_KEYS = {"rid", "kind", "tick", "row", "prompt_len", "bucket",
+                "compiled", "pages_written", "prefix_hits", "queue_wait_s",
+                *PHASES}
+
+
+def _admissions(ring=None, kind=None):
+    return [r for r in (spans.recorded() if ring is None else ring)
+            if r["path"] == "admission"
+            and kind in (None, r["attrs"]["kind"])]
+
+
+@pytest.mark.parametrize("engine", ["paged", "dense"])
+def test_with_no_listener_an_admitting_tick_leaves_one_record_a_request(
+        model, engine):
+    """No listener, so no span reaches the ring; the `admission` records do:
+    one a request that took a row, with the request's own numbers, phases
+    that are each >= 0 and fit inside the record. Both engines write the
+    same keys."""
+    if engine == "paged":
+        eng = PagedServingEngine(model, max_batch_size=4, max_seq_len=64,
+                                 page_size=8)
+    else:
+        eng = ContinuousBatchingEngine(model, max_batch_size=4,
+                                       max_seq_len=64)
+    rng = np.random.default_rng(11)
+    lens = {eng.add_request(rng.integers(1, 1000, n).astype(np.int32),
+                            max_new_tokens=3): n for n in (9, 17, 30)}
+    assert not spans.live()
+    eng.step()
+    ring = spans.recorded()
+    assert [r["path"] for r in ring] == ["admission"] * 3
+    assert sorted(r["attrs"]["rid"] for r in ring) == sorted(lens)
+    assert len({r["attrs"]["row"] for r in ring}) == 3
+    for r in ring:
+        a = r["attrs"]
+        assert set(a) == PREFILL_KEYS and r["parent"] is None
+        assert (a["kind"], a["tick"]) == ("prefill", 1)
+        assert a["prompt_len"] == lens[a["rid"]]
+        assert a["bucket"] == (16 if a["prompt_len"] <= 16 else 32)
+        assert a["queue_wait_s"] >= 0
+        assert all(a[k] >= 0 for k in PHASES)
+        assert a["prefill_s"] > 0 and a["first_token_s"] > 0
+        assert sum(a[k] for k in PHASES) <= (r["t1_ns"] - r["t0_ns"]) / 1e9
+        if engine == "paged":
+            pages = -(-a["prompt_len"] // 8)
+            assert a["pages_written"] + a["prefix_hits"] == pages
+            assert a["pages_s"] > 0 and a["write_pages_s"] > 0
+        else:
+            assert a["pages_written"] == a["pages_s"] == 0
+    assert all(r["t1_ns"] <= s["t0_ns"] for r, s in zip(ring, ring[1:]))
+    eng.run()
+    assert len(_admissions()) == 3   # a decode tick writes none
+
+
+def test_a_prefill_record_a_first_token_and_a_resume_record_a_resume(model):
+    """An undersized pool, nobody listening: every request that got a first
+    token left one record of kind "prefill", every spilled one that came
+    back a second record of kind "resume" in a later tick."""
+    eng = PagedServingEngine(model, max_batch_size=4, max_seq_len=64,
+                             page_size=16, seed=3, num_pages=6,
+                             watermark_pages=0, prefix_sharing=False)
+    rng = np.random.default_rng(7)
+    for i in range(4):
+        eng.add_request(rng.integers(1, 1000, 14).astype(np.int32),
+                        max_new_tokens=6, priority=-i)
+    resumes0 = default_registry().get("serving_resumes_total").value()
+    done = eng.run()
+    assert {r["path"] for r in spans.recorded()} == {"admission"}
+    first = _admissions(kind="prefill")
+    assert len(first) == sum(bool(r.generated) for r in done) == 4
+    back = _admissions(kind="resume")
+    assert len(back) == (default_registry().get(
+        "serving_resumes_total").value() - resumes0) >= 1
+    assert sum(r.preemptions for r in done) == len(back)
+    by_rid = {r["attrs"]["rid"]: r for r in first}
+    for r in back:
+        a = r["attrs"]
+        assert set(a) == {"rid", "kind", "tick", "row", "prompt_len",
+                          "resume_s", "pages_restored", "state_bytes"}
+        assert a["tick"] > by_rid[a["rid"]]["attrs"]["tick"]
+        assert a["prompt_len"] == 14 and a["pages_restored"] >= 1
+        assert 0 < a["resume_s"] <= (r["t1_ns"] - r["t0_ns"]) / 1e9
+
+
+def test_with_a_listener_the_records_lie_inside_admit_and_cover_it(
+        traced_ticks):
+    ring, _ = traced_ticks
+    admits = {r["id"]: r for r in ring if r["path"] == "engine.step/admit"}
+    records = _admissions(ring)
+    assert {r["attrs"]["kind"] for r in records} == {"prefill", "resume"}
+    covered, prefilled = {}, set()
+    for r in records:
+        admit = admits[r["parent"]]   # written under the tick's admit span
+        assert admit["t0_ns"] <= r["t0_ns"] <= r["t1_ns"] <= admit["t1_ns"]
+        covered[admit["id"]] = covered.get(admit["id"], 0) + (
+            r["t1_ns"] - r["t0_ns"])
+        if r["attrs"]["kind"] == "prefill":
+            prefilled.add(admit["id"])
+    assert sum(a["attrs"]["picked"] for a in admits.values()) == len(records)
+    # what `_admit` does outside the records (free rows, the scheduler's
+    # pick) is under 5 % of a tick's span that prefills, and under a
+    # millisecond beside a resume of a page or two, itself 0.3 ms here
+    assert prefilled
+    for key, ns in covered.items():
+        whole = admits[key]["t1_ns"] - admits[key]["t0_ns"]
+        assert whole - ns <= (0.05 * whole if key in prefilled else 1_000_000)
+    # the phases are the child spans' own clock reads
+    spans_of = {}
+    for r in ring:
+        if r["path"].startswith("engine.step/admit/"):
+            spans_of[(r["attrs"]["rid"], r["path"].rsplit("/", 1)[1])] = r
+    for r in records:
+        a = r["attrs"]
+        names = (["resume"] if a["kind"] == "resume"
+                 else ["prefill", "pages", "write_pages", "first_token"])
+        for name in names:
+            child = spans_of[(a["rid"], name)]
+            assert a[name + "_s"] == pytest.approx(
+                (child["t1_ns"] - child["t0_ns"]) / 1e9)
 
 
 def test_the_step_histogram_takes_the_spans_own_clock_reads(model):
