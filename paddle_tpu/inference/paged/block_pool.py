@@ -127,10 +127,6 @@ class PagedKV:
 
     kind = "full"    # the `serving_pages_live` gauge's label
 
-    def prefill_cache(self, seq, dtype):
-        """The zeroed dense cache a batch-1 prefill of `seq` tokens fills."""
-        return (jnp.zeros((1, seq, self.kv_heads, self.head_dim), dtype),) * 2
-
     def page_arrays(self, page_size):
         """The shape of one page in each array a layer keeps: K and V."""
         return ((self.kv_heads, page_size, self.head_dim),) * 2
@@ -163,10 +159,6 @@ class WindowKV(PagedKV):
 
     kind = "window"
 
-    def prefill_cache(self, seq, dtype):
-        """A prefill from position 0 reads no cache."""
-        return ()
-
     def first_page(self, length, page_size) -> int:
         """The first logical page a row with `length` tokens cached still
         needs: the one holding position `length + 1 - window`, the oldest key
@@ -193,10 +185,6 @@ class LatentKV:
     @property
     def stored_dim(self) -> int:
         return -(-(self.latent_dim + self.rope_dim) // 128) * 128
-
-    def prefill_cache(self, seq, dtype):
-        """A prefill from position 0 reads no cache."""
-        return ()
 
     def page_arrays(self, page_size):
         return ((page_size, self.stored_dim),)
@@ -277,10 +265,6 @@ class RowState:
     def row_nbytes(self, dtype) -> int:
         return sum(math.prod(s) for s in self.shapes) * jnp.dtype(
             dtype).itemsize
-
-    def prefill_cache(self, seq, dtype):
-        """The zero state a batch-1 prefill starts from."""
-        return tuple(jnp.zeros((1,) + tuple(s), dtype) for s in self.shapes)
 
 
 def _quantize_pages(x):

@@ -372,10 +372,10 @@ class PagedServingEngine(_ServingEngineBase):
         rid, n = req.req_id, len(req.prompt)
         req._t_admit = time.perf_counter()
         bucket = _bucket(n)
-        compiled = bucket not in self._prefill_cache
+        compiled = bucket not in self._prefill_programs
         with span("prefill", rid=rid, prompt_len=n, bucket=bucket,
                   compiled=compiled, **self._prefill_attrs(bucket)) as prefill:
-            logits_row, new_c, n, _ = self._run_prefill(req)
+            logits_row, new_c, n, phases = self._run_prefill(req)
         m = _pages_for_prompt(n, self.ps)
         mb = _pages_for_prompt(bucket, self.ps)
         tables, masks = [], []
@@ -435,7 +435,7 @@ class PagedServingEngine(_ServingEngineBase):
             "prefill", req, row, t0_ns, bucket=bucket, compiled=compiled,
             pages_written=written, prefix_hits=hits,
             queue_wait_s=req._t_admit - req._t_arrival,
-            prefill_s=prefill.seconds, pages_s=pages_sp.seconds,
+            prefill_s=prefill.seconds, **phases, pages_s=pages_sp.seconds,
             write_pages_s=write_pages_s, write_state_s=write_state_s,
             first_token_s=first_token.seconds)
 

@@ -144,8 +144,8 @@ class _ServingEngineBase:
         self.finished: list[GenerationRequest] = []
         self._key = jax.random.PRNGKey(seed)
         self._req_seq = 0  # arrival index, keys each request's sample stream
-        self._prefill_cache = BoundedCompileCache(max_prefill_buckets,
-                                                  self.engine_label)
+        self._prefill_programs = BoundedCompileCache(max_prefill_buckets,
+                                                     self.engine_label)
         self._decode_jit = None
         self._tick = 0
         m = serving_metrics()
@@ -186,45 +186,42 @@ class _ServingEngineBase:
         return out
 
     def _run_prefill(self, req):
-        """Batch-1 prefill over a zeroed bucket-length dense cache. Returns
-        (the logits [V] of the prompt's last position, on the device,
-        new_caches per layer — [1, Sp, Hkv, D] K and V, or a recurrent
-        layer's state after token n - 1 — n, Sp). The model cuts the hidden
-        state to that one position before its final norm and its head
+        """Batch-1 prefill of the prompt alone: a prefill from position 0
+        reads no cache, so the host builds none. The program's per-request
+        inputs are the bucket's tokens and the prompt's length; positions,
+        `logits_at` and `seq_lens` are made inside it. Returns (the logits
+        [V] of the prompt's last position, on the device, new_caches per
+        layer — [1, Sp, Hkv, D] K and V, or a recurrent layer's state after
+        token n - 1 — n, the seconds the host took to build and place the
+        inputs and those until the jitted call returned). The model cuts the
+        hidden state to that one position before its final norm and its head
         (`logits_at`): a bucket's worth of logits is never made."""
         n = len(req.prompt)
         Sp = _bucket(n)
 
         def compile_prefill():
-            def prefill(p, b, tok, pos, caches, last, *lens):
+            def prefill(p, b, tok, n):
+                lens = n.reshape(1)
                 # a recurrent layer sees the bucket's zero padding, which
                 # attention never does: such a model is told the real length
-                kw = {"seq_lens": lens[0]} if lens else {}
+                kw = {"seq_lens": lens} if self.cache_specs is not None else {}
+                # `caches` given and empty: every layer returns the prompt's
                 logits, new_c = self._functional_forward(
-                    p, b, tok, pos, caches, jnp.int32(0), logits_at=last,
-                    **kw)
+                    p, b, tok, jnp.arange(Sp, dtype=jnp.int32)[None], (),
+                    jnp.int32(0), logits_at=lens - 1, **kw)
                 return logits[0, 0], new_c
 
             return jax.jit(prefill)
 
-        pf = self._prefill_cache.get_or_compile(Sp, compile_prefill)
-        tok = np.zeros((1, Sp), np.int32)
-        tok[0, :n] = req.prompt
-        pos = np.arange(Sp, dtype=np.int32)[None]
-        cfg = self.cfg
-        if self.cache_specs is None:
-            zero_c = [(jnp.zeros((1, Sp, cfg.kv_heads, cfg.head_dim),
-                                 self.kv_dtype),) * 2
-                      for _ in range(cfg.num_layers)]
-            lens = ()
-        else:
-            zero_c = [spec.prefill_cache(Sp, self.kv_dtype)
-                      for spec in self.cache_specs]
-            lens = (jnp.asarray([n], jnp.int32),)
-        row, new_c = pf(self.params, self.buffers, jnp.asarray(tok),
-                        jnp.asarray(pos), zero_c,
-                        jnp.asarray([n - 1], jnp.int32), *lens)
-        return row, new_c, n, Sp
+        pf = self._prefill_programs.get_or_compile(Sp, compile_prefill)
+        with span("inputs", rid=req.req_id) as inputs:
+            tok = np.zeros((1, Sp), np.int32)
+            tok[0, :n] = req.prompt
+            tok, length = jax.device_put((tok, np.int32(n)))
+        with span("call", rid=req.req_id) as call:
+            row, new_c = pf(self.params, self.buffers, tok, length)
+        return row, new_c, n, {"inputs_s": inputs.seconds,
+                               "call_s": call.seconds}
 
     # -- sampling -------------------------------------------------------- #
 
@@ -466,10 +463,10 @@ class ContinuousBatchingEngine(_ServingEngineBase):
             t0_ns = time.perf_counter_ns()
             req._t_admit = time.perf_counter()
             bucket = _bucket(len(req.prompt))
-            compiled = bucket not in self._prefill_cache
+            compiled = bucket not in self._prefill_programs
             with span("prefill", rid=req.req_id, prompt_len=len(req.prompt),
                       bucket=bucket, compiled=compiled) as prefill:
-                logits_row, new_c, n, _ = self._run_prefill(req)
+                logits_row, new_c, n, phases = self._run_prefill(req)
                 # scatter the prompt's kv into this slot's cache rows [0, n)
                 for li, (k_, v_) in enumerate(new_c):
                     bk, bv = self.caches[li]
@@ -485,8 +482,9 @@ class ContinuousBatchingEngine(_ServingEngineBase):
                 "prefill", req, slot, t0_ns, bucket=bucket, compiled=compiled,
                 pages_written=0, prefix_hits=0,
                 queue_wait_s=req._t_admit - req._t_arrival,
-                prefill_s=prefill.seconds, pages_s=0.0, write_pages_s=0.0,
-                write_state_s=0.0, first_token_s=first_token.seconds)
+                prefill_s=prefill.seconds, **phases, pages_s=0.0,
+                write_pages_s=0.0, write_state_s=0.0,
+                first_token_s=first_token.seconds)
         return picked
 
     # ------------------------------------------------------------------ #

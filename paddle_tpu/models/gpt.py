@@ -20,11 +20,19 @@ TPU-first design decisions:
 - Static shapes throughout; the decode path keeps a static-capacity KV cache
   updated with dynamic_update_slice (reference analog: paged/cached decode
   attention masked_multihead_attention_kernel.cu) — no dynamic shapes under jit.
+- The cache contract is the serving engines' (`tok, pos, caches, off,
+  block_tables=`). With `caches` EMPTY and no `block_tables` the call is a
+  prefill from position 0, which reads no cache: causal attention over the
+  prompt's own K and V (the flash kernel on TPU), each layer returning them.
+  With dense caches `[B, S_max, Hkv, D]` it writes at `off` and attends under
+  an explicit mask (`models/generation.py`, the dense engine's decode step);
+  with pool pages and `block_tables` it is one paged decode step.
 """
 
 from __future__ import annotations
 
 import dataclasses
+import functools
 import math
 
 import jax
@@ -34,6 +42,7 @@ from jax.sharding import PartitionSpec as P
 from ..framework.core import Tensor, run_op
 from .. import nn
 from ..nn import functional as F
+from ..nn.functional.flash_attention import _ref_attention, _use_pallas_kernel
 from ..nn import initializer as I
 from ..distributed.fleet.layers.mpu.mp_layers import (
     ColumnParallelLinear,
@@ -213,7 +222,7 @@ class GPTAttention(nn.Layer):
                 new_cache = (k_all, v_all)
                 out = run_op("paged_decode_attention", _paged_attend,
                              [q, k_all, v_all, block_tables, cache_offset])
-        elif cache is not None:
+        elif cache:
             # static-capacity KV cache: cache.k/v are [B, S_max, Hkv, D]
             k_all = run_op("kv_cache_update", _dyn_update, [cache[0], k, cache_offset])
             v_all = run_op("kv_cache_update", _dyn_update, [cache[1], v, cache_offset])
@@ -223,6 +232,15 @@ class GPTAttention(nn.Layer):
                 q, k_all, v_all, attn_mask=mask, is_causal=False,
                 dropout_p=cfg.attention_dropout_prob, training=self.training,
             )
+        elif cache is not None:
+            # an empty cache: a prefill from position 0 attends over the
+            # prompt's own K and V and returns them (K after the rotation,
+            # `kv_heads` of them)
+            out = run_op(
+                "prompt_attention",
+                functools.partial(_prompt_attention,
+                                  kernel=_use_pallas_kernel()), [q, k, v])
+            new_cache = (k, v)
         elif cfg.context_parallel:
             assert cfg.attention_dropout_prob == 0.0, (
                 "context_parallel ring attention does not support attention "
@@ -257,6 +275,21 @@ class GPTAttention(nn.Layer):
         if cache is not None:
             return out, new_cache
         return out
+
+
+@functools.partial(jax.jit, static_argnames="kernel")
+def _prompt_attention(q, k, v, kernel):
+    """Causal attention of a prompt over its own K and V [B, S, H, D]: the
+    `flash_fwd` kernel the training step runs where the Pallas kernels are
+    available (`kernel`), the causal composite elsewhere. Jitted so that the
+    layers of a prefill program share ONE trace and ONE lowering of the
+    kernel: a trace a layer cost the chip's host a quarter of a second a
+    layer and bucket, 12 s of a 24-layer model's set-up."""
+    if kernel:  # graftlint: disable=GL001 a static argument: a Python bool
+        from ..ops.pallas.flash_attention import flash_attention_fwd
+
+        return flash_attention_fwd(q, k, v, causal=True)
+    return _ref_attention(q, k, v, causal=True)
 
 
 def _dyn_update(buf, new, off):
@@ -461,6 +494,11 @@ class GPTModel(nn.Layer):
         if self.config.sequence_parallel:
             h = mark_as_sequence_parallel(h)
         new_caches = [] if caches is not None else None
+        if caches is not None and not len(caches):
+            # `caches` given and empty: a prefill from position 0, which
+            # reads no cache. Every layer attends over the prompt's own K
+            # and V, causally, and returns them as its new cache
+            caches = [()] * len(self.layers)
 
         if caches is not None and attn_startend_row_indices is not None:
             raise ValueError(

@@ -21,8 +21,10 @@ The cache contract is the serving engines' (`tok, pos, caches, off,
 block_tables=`): `caches[i]` is whatever layer i's kind defines, `(k, v)`
 pages for "attention", `(conv_state, ssm_state)` rows for "mamba"
 (`cache_specs()`; `inference/paged/block_pool.py`). With `caches` and no
-`block_tables` the call is a prefill from position 0 (`seq_lens` = the
-prompts' real lengths inside the padded bucket); with both it is one decode
+`block_tables` the call is a prefill from position 0, which reads no cache
+(the engines pass an empty `caches`: a recurrent layer starts from zeros made
+here) and returns every layer's (`seq_lens` = the prompts' real lengths
+inside the padded bucket); with both it is one decode
 step over every row, in which a row whose table is empty is dead: its state
 is left as it is and it is routed to no expert.
 """
@@ -306,7 +308,7 @@ class GraniteMambaMixer(nn.Layer):
         `seq_lens` [B] a padded prefill. Returns (out, new_cache)."""
         cfg = self.cfg
         proj = self.in_proj(u)
-        if cache is None:
+        if not cache:
             B = proj.shape[0]
             dtype = proj._value.dtype
             cache = (Tensor(jnp.zeros((B, cfg.mamba_d_conv - 1,
@@ -505,7 +507,7 @@ class GraniteHybridForCausalLM(nn.Layer):
         new_caches, stats = [], []
         for i, layer in enumerate(self.layers):
             x, new_cache, st = layer(
-                x, caches[i] if caches is not None else None, cache_offset,
+                x, caches[i] if caches else None, cache_offset,
                 block_tables, live, seq_lens, token_live)
             new_caches.append(new_cache)
             stats.append(st)

@@ -265,21 +265,15 @@ def test_kv_writes_then_paged_decode_leave_the_pool_in_place(one_chip, cell):
     assert _pool_text(pool) + "{3,2,1,0" in call
 
 
-def test_the_decode_program_samples_under_one_conditional(one_chip,
-                                                          monkeypatch):
-    """The engine's whole decode program at `serve-xl-sat`'s widths (64 rows,
-    vocabulary 50304, the cell's pool; two layers of the 24, from shapes
-    alone): the sampler is ONE `conditional` that yields the tokens and the
-    advanced keys, so an all-greedy tick skips the draw; the program returns
-    both beside the logits; and no second pool array is laid beside the pool
-    (S15), sampler or not."""
+def _xl_engine(monkeypatch):
+    """The paged engine at `serve-xl-sat`'s widths over two layers of the 24,
+    from shapes alone: nothing runs, so no value is ever read and the
+    parameters stay the zeros they are made as."""
     import paddle_tpu.amp as amp
     from paddle_tpu.inference.paged import PagedServingEngine
     from paddle_tpu.models import GPTConfig, GPTForCausalLM
     from paddle_tpu.nn import initializer
 
-    # nothing runs, so no value is ever read: parameters stay the zeros
-    # they are made as
     for cls in (initializer.Normal, initializer.XavierUniform):
         monkeypatch.setattr(cls, "__call__", lambda self, param, block=None: param)
     root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
@@ -291,10 +285,21 @@ def test_the_decode_program_samples_under_one_conditional(one_chip,
         num_layers=2, num_heads=cell["num_heads"],
         max_position_embeddings=cell["max_position_embeddings"]))
     amp.decorate(model, level="O2", dtype="bfloat16")
-    eng = PagedServingEngine(
+    return PagedServingEngine(
         model, max_batch_size=serve["max_batch_size"],
         max_seq_len=serve["max_seq_len"], page_size=serve["page_size"],
         num_pages=2)
+
+
+def test_the_decode_program_samples_under_one_conditional(one_chip,
+                                                          monkeypatch):
+    """The engine's whole decode program at `serve-xl-sat`'s widths (64 rows,
+    vocabulary 50304, the cell's pool; two layers of the 24, from shapes
+    alone): the sampler is ONE `conditional` that yields the tokens and the
+    advanced keys, so an all-greedy tick skips the draw; the program returns
+    both beside the logits; and no second pool array is laid beside the pool
+    (S15), sampler or not."""
+    eng = _xl_engine(monkeypatch)
     pool, rows, _ = POOLS["serve-xl-sat"]
     assert (eng.B, eng.P, eng.pool.kv[0][0].shape[1:]) == (rows, P, pool[1:])
 
@@ -320,6 +325,48 @@ def test_the_decode_program_samples_under_one_conditional(one_chip,
                      r"bf16\[64,50304\]\S*, " + re.escape(_pool_text(pool)),
                      out)
     assert len(re.findall(r"%decode_paged[.\w]* = ", entry)) == 2
+
+
+@pytest.mark.parametrize("bucket", [1024, 2048])
+def test_the_gpt_prefill_program_is_flash_over_the_prompt_alone(
+        one_chip, monkeypatch, bucket):
+    """The engine's whole prefill program at `serve-xl-sat`'s two buckets
+    (batch 1, 16 heads of 128, two layers of the 24, from shapes alone): its
+    inputs beside the weights are the tokens and the length, every layer's
+    attention is the `flash_fwd` kernel the training cell runs, and no
+    `f32[16, Sp, Sp]` array of scores is written (S4(c))."""
+    import paddle_tpu.ops.pallas as pallas
+
+    # the dispatch rule of a TPU backend, which this process does not have
+    monkeypatch.setattr(pallas, "kernels_available", lambda: True)
+    eng = _xl_engine(monkeypatch)
+    programs = []
+    compile_prefill = eng._prefill_programs.get_or_compile
+    eng._prefill_programs.get_or_compile = lambda b, fn: programs.append(
+        compile_prefill(b, fn)) or (lambda *args: (None, None))
+    eng._run_prefill(type("Req", (), {"prompt": [1] * (bucket - 5),
+                                      "req_id": 0}))
+
+    def shape(x):
+        return jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=one_chip)
+
+    (program,) = programs
+    hlo = program.lower(
+        jax.tree.map(shape, eng.params), jax.tree.map(shape, eng.buffers),
+        *_args(one_chip, ((1, bucket), jnp.int32), ((), jnp.int32))
+    ).compile().as_text()
+    calls = [line for line in hlo.splitlines()
+             if re.match(r"\s*(ROOT )?%flash_fwd[.\w]* = ", line)]
+    assert len(calls) == 2
+    for call in calls:
+        assert 'custom_call_target="tpu_custom_call"' in call
+        assert f"bf16[1,{H},{bucket},{D}]" in call
+    assert not re.search(rf"f32\[(1,)?{H},{bucket},{bucket}\]", hlo)
+    (out,) = [line for line in hlo[hlo.index("\nENTRY "):].splitlines()
+              if re.match(r"\s*ROOT %\S+ = \(", line)]
+    # the last position's logits row, and K and V a layer as the pages take
+    assert re.search(r"= \(bf16\[50304\]\S*(, bf16\[1,%d,%d,%d\]\S*){4}\) "
+                     % (bucket, H, D), out)
 
 
 # serve-kimi-k2-reason-sat: 256 rows, 64 heads; a token's cache in a layer is

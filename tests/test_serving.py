@@ -151,8 +151,8 @@ class TestServingSatellites:
             eng.add_request(np.arange(1, n + 1, dtype=np.int32),
                             max_new_tokens=1)
             eng.run()
-        assert len(eng._prefill_cache) == 2
-        assert 16 not in eng._prefill_cache and 64 in eng._prefill_cache
+        assert len(eng._prefill_programs) == 2
+        assert 16 not in eng._prefill_programs and 64 in eng._prefill_programs
         eng.add_request(np.arange(1, 6, dtype=np.int32), max_new_tokens=1)
         eng.run()
         assert compiles("16") == c16 + 2  # eviction made the recompile visible

@@ -5,6 +5,8 @@ mixed greedy/sampled workloads (prefix sharing on and off), strictly more
 concurrency than dense at equal HBM page budget, and preemption under an
 undersized pool that recovers every request with no lost tokens."""
 
+import jax
+import jax.numpy as jnp
 import numpy as np
 import pytest
 
@@ -362,6 +364,140 @@ class TestPagedServingEngine:
     # the bounded prefill compile cache is the shared BoundedCompileCache;
     # its cap/eviction/counter behavior is pinned on the dense engine in
     # test_serving.py::TestServingSatellites::test_prefill_compile_cache_capped
+
+
+# --------------------------------------------------------------------------- #
+# a prefill starts from the prompt alone, in every served family
+# --------------------------------------------------------------------------- #
+
+
+def _gpt():
+    return GPTForCausalLM(gpt3_tiny())
+
+
+def _llama():
+    from paddle_tpu.models.llama import LlamaForCausalLM, llama_tiny
+
+    return LlamaForCausalLM(llama_tiny())   # RoPE, 4 heads over 2 KV heads
+
+
+def _granite():
+    from paddle_tpu.models.granite_hybrid import (GraniteHybridForCausalLM,
+                                                  granite_hybrid_tiny)
+
+    return GraniteHybridForCausalLM(granite_hybrid_tiny())
+
+
+def _afmoe():
+    from paddle_tpu.models.afmoe import AfmoeForCausalLM, afmoe_tiny
+
+    return AfmoeForCausalLM(afmoe_tiny())
+
+
+def _kimi():
+    from paddle_tpu.models.kimi_k2 import KimiK2ForCausalLM, kimi_k2_tiny
+
+    return KimiK2ForCausalLM(kimi_k2_tiny())
+
+
+def _zero_cache(eng, sp):
+    """The zeroed cache the engine built on the host for every prefill, an
+    eager array a side, before a prefill started from the prompt alone: K
+    and V a full-attention layer, the zero state a recurrent one, nothing
+    for a window or a latent layer."""
+    from paddle_tpu.inference.paged import PagedKV, RowState
+
+    cfg, specs = eng.cfg, eng.cache_specs
+    if specs is None:
+        specs = [PagedKV(cfg.kv_heads, cfg.head_dim)] * cfg.num_layers
+    entries = []
+    for spec in specs:
+        if isinstance(spec, RowState):
+            entries.append(tuple(jnp.zeros((1,) + tuple(shape), eng.kv_dtype)
+                                 for shape in spec.shapes))
+        elif type(spec) is PagedKV:
+            entries.append((jnp.zeros(
+                (1, sp, spec.kv_heads, spec.head_dim), eng.kv_dtype),) * 2)
+        else:
+            entries.append(())
+    return entries
+
+
+@pytest.mark.parametrize("build", [_gpt, _llama, _granite, _afmoe, _kimi])
+def test_a_prefill_starts_from_the_prompt_alone(build):
+    """In each served family the engine's prefill program takes, beside the
+    parameters and the buffers, two arrays: the bucket's tokens and the
+    prompt's length. No spec builds a cache for it. What the admission
+    leaves behind (the pages, the state rows, the first token) is what the
+    program that started from a zeroed cache left: for GPT and LLaMA that
+    is the dense-cache branch under its explicit mask."""
+    paddle.seed(5)
+    model = build()
+    eng = PagedServingEngine(model, max_batch_size=2, max_seq_len=96,
+                             page_size=8, prefix_sharing=False)
+    for spec in eng.cache_specs or ():
+        assert not hasattr(spec, "prefill_cache")
+    n, sp, ps = 43, 64, 8           # past afmoe_tiny's window of 32
+    prompt = np.random.default_rng(3).integers(1, 100, n).astype(np.int32)
+
+    calls = []
+    compile_prefill = eng._prefill_programs.get_or_compile
+
+    def recorded(bucket, compile_fn):
+        program = compile_prefill(bucket, compile_fn)
+
+        def call(*args):
+            calls.append((program, args))
+            return program(*args)
+
+        return call
+
+    eng._prefill_programs.get_or_compile = recorded
+    rid = eng.add_request(prompt, max_new_tokens=4)
+    assert eng._admit() == 1    # and no decode step behind it yet
+    (req,) = [r for r in eng.active if r is not None]
+    row = eng.active.index(req)
+    assert req.req_id == rid and eng.lengths[row] == n
+
+    # the lowered program's inputs
+    (program, args), = calls
+    shared = len(jax.tree.leaves((eng.params, eng.buffers)))
+    info = jax.tree.leaves(program.lower(*args).args_info)
+    assert [(tuple(a.shape), str(a.dtype)) for a in info[shared:]] == [
+        ((1, sp), "int32"), ((), "int32")]
+
+    # the program that started from the zeroed cache, same weights
+    tok = np.zeros((1, sp), np.int32)
+    tok[0, :n] = prompt
+    ref_logits, ref_c = eng._functional_forward(
+        eng.params, eng.buffers, jnp.asarray(tok), jnp.arange(sp)[None],
+        _zero_cache(eng, sp), jnp.int32(0),
+        logits_at=jnp.asarray([n - 1]),
+        **({} if eng.cache_specs is None
+           else {"seq_lens": jnp.asarray([n], jnp.int32)}))
+    assert req.generated[0] == int(np.argmax(np.asarray(ref_logits)[0, 0]))
+
+    pool, m = eng.pool, -(-n // ps)
+    state = dict(zip(pool.state_layers, pool.read_state(row)))
+    for li, ref in enumerate(ref_c):
+        if li in state:
+            for got, want in zip(state[li], ref):
+                np.testing.assert_allclose(got, np.asarray(want)[0],
+                                           rtol=2e-5, atol=2e-6)
+            continue
+        gi = pool.group_of_layer[li]
+        first = eng.window_start[row] if eng.groups[gi].window else 0
+        pages = eng.group_tables[gi][row, :m - first]
+        assert (pages > 0).all()
+        for got, want in zip(pool.kv[pool.entry_of_layer[li]], ref):
+            want = np.array(want)[0]
+            want[n:] = 0                        # the padded tail is zero
+            want = np.moveaxis(
+                want.reshape((-1, ps) + want.shape[1:]), 1, -2)[first:m]
+            np.testing.assert_allclose(np.asarray(got)[pages], want,
+                                       rtol=2e-5, atol=2e-6)
+    eng.run()
+    assert pool.pages_free == pool.pages_total
 
 
 @pytest.mark.slow

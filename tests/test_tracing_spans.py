@@ -35,7 +35,8 @@ TICK_PHASES = ["engine.step/admit", "engine.step/write_targets",
                "engine.step/decode_dispatch", "engine.step/host_read",
                "engine.step/emit"]
 TICK_PATHS = ["engine.step"] + TICK_PHASES + [
-    "engine.step/admit/prefill", "engine.step/admit/pages",
+    "engine.step/admit/prefill", "engine.step/admit/prefill/inputs",
+    "engine.step/admit/prefill/call", "engine.step/admit/pages",
     "engine.step/admit/write_pages", "engine.step/admit/first_token",
     "engine.step/admit/resume", "engine.step/write_targets/spill",
     "engine.step/emit/sample"]
@@ -262,9 +263,12 @@ def test_the_dense_engine_spans_the_same_phases(model):
 
 PHASES = ("prefill_s", "pages_s", "write_pages_s", "write_state_s",
           "first_token_s")
+# the two halves of `prefill_s`: the host builds and places the program's two
+# inputs, then the jitted call returns
+PREFILL_HALVES = ("inputs_s", "call_s")
 PREFILL_KEYS = {"rid", "kind", "tick", "row", "prompt_len", "bucket",
                 "compiled", "pages_written", "prefix_hits", "queue_wait_s",
-                *PHASES}
+                *PHASES, *PREFILL_HALVES}
 
 
 def _admissions(ring=None, kind=None):
@@ -304,6 +308,8 @@ def test_with_no_listener_an_admitting_tick_leaves_one_record_a_request(
         assert a["queue_wait_s"] >= 0
         assert all(a[k] >= 0 for k in PHASES)
         assert a["prefill_s"] > 0 and a["first_token_s"] > 0
+        assert all(a[k] >= 0 for k in PREFILL_HALVES)
+        assert a["inputs_s"] + a["call_s"] <= a["prefill_s"]
         assert sum(a[k] for k in PHASES) <= (r["t1_ns"] - r["t0_ns"]) / 1e9
         if engine == "paged":
             pages = -(-a["prompt_len"] // 8)
@@ -376,11 +382,39 @@ def test_with_a_listener_the_records_lie_inside_admit_and_cover_it(
     for r in records:
         a = r["attrs"]
         names = (["resume"] if a["kind"] == "resume"
-                 else ["prefill", "pages", "write_pages", "first_token"])
+                 else ["prefill", "inputs", "call", "pages", "write_pages",
+                       "first_token"])
         for name in names:
             child = spans_of[(a["rid"], name)]
             assert a[name + "_s"] == pytest.approx(
                 (child["t1_ns"] - child["t0_ns"]) / 1e9)
+
+
+def test_a_prefill_spans_its_inputs_and_its_call_once_an_admission(
+        traced_ticks):
+    """`engine.step/admit/prefill/inputs` and `/call`: one of each a
+    prefilled request, in that order, inside the request's `prefill` span;
+    both reach the profiler's trace."""
+    ring, host = traced_ticks
+    prefills = {r["id"]: r for r in ring
+                if r["path"] == "engine.step/admit/prefill"}
+    assert len(prefills) == len(_admissions(ring, "prefill")) >= 1
+    halves = {}
+    for r in ring:
+        if r["path"].startswith("engine.step/admit/prefill/"):
+            halves.setdefault(r["parent"], []).append(r)
+    assert set(halves) == set(prefills)
+    for key, (inputs, call) in halves.items():
+        whole = prefills[key]
+        assert (inputs["path"], call["path"]) == (
+            "engine.step/admit/prefill/inputs",
+            "engine.step/admit/prefill/call")
+        assert inputs["attrs"]["rid"] == call["attrs"]["rid"] == (
+            whole["attrs"]["rid"])
+        assert (whole["t0_ns"] <= inputs["t0_ns"] <= inputs["t1_ns"]
+                <= call["t0_ns"] <= call["t1_ns"] <= whole["t1_ns"])
+    assert {"engine.step/admit/prefill/inputs",
+            "engine.step/admit/prefill/call"} <= set(host)
 
 
 def test_the_step_histogram_takes_the_spans_own_clock_reads(model):
