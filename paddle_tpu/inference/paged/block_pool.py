@@ -77,7 +77,26 @@ Every spec says its own page arrays and bytes (`page_arrays`, `page_nbytes`,
 `pages_per_step`); the pool and the engine ask the spec and never multiply
 head counts themselves. An int8 pool of latents is refused.
 
-Physical page 0 is the reserved NULL page: never allocated, never referenced
+Pages of two shapes. Layer kinds may differ in their KV head count (a
+model whose sliding layers keep 8 KV heads beside full layers of 4) while
+key width, value width and page size agree: the larger page is then `span`
+ADJACENT pages of the smaller, which `[units, 4, ps, W]` seen as `[units / 2,
+8, ps, W]` shows to be the same memory. The pool's arrays, its budget and
+its counts (`pages_total`, `pages_free`) are in UNITS of the smallest page;
+a group says how many units its page spans (`PageGroup.span`), a page's
+handle everywhere on the host is its FIRST unit (a multiple of its span),
+and a layer of span k reads the arrays through the k-times-coarser view at
+`handle // k`. One byte budget serves both shapes: the free list keeps whole
+free BLOCKS of `span` aligned units apart from the free units of blocks
+that a smaller page has broken into, hands a small page a unit of a broken
+block first and breaks a whole one only when there is none, and joins a
+block again when its last unit comes back. A large page refused while free
+units lay unpaired is counted (`serving_pool_alloc_refused_total`): that is
+what stranding looks like from inside. Spill, restore, copy-on-write and the
+prompt's scatter move units, so the page programs know one shape.
+
+Physical page 0 is the reserved NULL page (the first block of a pool of two
+shapes: units 0 .. span - 1): never allocated, never referenced
 by a live block table. Parked decode rows (batch padding) route their
 per-step K/V writes there, so the fixed-shape decode program needs no
 conditional writes.
@@ -109,43 +128,64 @@ import numpy as np
 from ..slo import serving_metrics
 
 __all__ = ["BlockPool", "PagedKV", "WindowKV", "LatentKV", "RowState",
-           "PageGroup", "page_layout", "prefix_page_key"]
+           "PageGroup", "page_layout", "prefix_page_key", "stored_width"]
 
 # pages one call of the gather or the scatter program moves at most: larger
 # sets go in several calls, so the bucketed shapes end here
 _PAGES_PER_CALL = 512
 
 
+def stored_width(width):
+    """The width a page array gives rows of `width` values: whole lane tiles
+    of 128 for a row wider than one (a key 192 wide is stored as 256, zero
+    behind it); a row of one tile or less as it is. The device's tiled
+    layout pads such a row to whole tiles anyway, and for a minor dimension
+    that is no multiple of 128 the chip's compiler picks a layout of its own
+    for the pool and re-lays all of it round every kernel that reads it (a
+    copy of the whole pool a layer: `tests/test_chip_compile.py`)."""
+    return width if width <= 128 else -(-width // 128) * 128
+
+
 @dataclasses.dataclass(frozen=True)
 class PagedKV:
-    """An attention layer's cache: K and V pages
-    [n_pages, kv_heads, page_size, head_dim], block tables, prefix keys,
-    copy-on-write; spilled page by page."""
+    """An attention layer's cache: K pages [n_pages, kv_heads, page_size,
+    head_dim] and V pages [..., value_dim] (`value_dim` None: as wide as the
+    keys; each width as `stored_width` stores it: the model hands over, and
+    writes, rows that wide), block tables, prefix keys, copy-on-write;
+    spilled page by page."""
 
     kv_heads: int
     head_dim: int
+    value_dim: int | None = dataclasses.field(default=None, kw_only=True)
 
     kind = "full"    # the `serving_pages_live` gauge's label
 
+    @property
+    def value_width(self) -> int:
+        return self.head_dim if self.value_dim is None else self.value_dim
+
     def page_arrays(self, page_size):
         """The shape of one page in each array a layer keeps: K and V."""
-        return ((self.kv_heads, page_size, self.head_dim),) * 2
+        return ((self.kv_heads, page_size, stored_width(self.head_dim)),
+                (self.kv_heads, page_size, stored_width(self.value_width)))
 
     def page_nbytes(self, page_size, dtype, quantized=False) -> int:
-        """HBM bytes one page costs in ONE layer, both sides: payload plus,
-        when quantized, the per-(page, head) f32 scales."""
-        values = self.kv_heads * page_size * self.head_dim
+        """HBM bytes one page costs in ONE layer, both sides, as stored:
+        payload plus, when quantized, the per-(page, head) f32 scales."""
+        values = self.kv_heads * page_size * (
+            stored_width(self.head_dim) + stored_width(self.value_width))
         if quantized:
-            return 2 * (values + self.kv_heads * 4)
-        return 2 * values * jnp.dtype(dtype).itemsize
+            return values + 2 * self.kv_heads * 4
+        return values * jnp.dtype(dtype).itemsize
 
     def pages_per_step(self, page_size, width, itemsize) -> int:
         """Pages of a row one grid step of this kind's decode kernel takes,
         for a block table `width` wide."""
         from ...ops.pallas.decode_attention import pages_per_step
 
-        return pages_per_step(self.kv_heads, page_size, self.head_dim, width,
-                              itemsize)
+        return pages_per_step(
+            self.kv_heads, page_size, stored_width(self.head_dim), width,
+            itemsize, stored_width(self.value_width))
 
 
 @dataclasses.dataclass(frozen=True)
@@ -207,10 +247,13 @@ _PAGED = (PagedKV, LatentKV)    # `WindowKV` is a `PagedKV`
 @dataclasses.dataclass(frozen=True)
 class PageGroup:
     """`depth` layers of one kind that share block tables: layer `layers[j]`
-    keeps its K and V in the pool's page array j."""
+    keeps its K and V in the pool's page array j. `span`: the units of the
+    pool's smallest page that one page of this group takes (1 in a pool of
+    one page shape)."""
 
     spec: object
     layers: tuple
+    span: int = 1
 
     @property
     def window(self):
@@ -231,11 +274,9 @@ def page_layout(specs):
             kinds.setdefault(spec, []).append(li)
     if not kinds:
         raise ValueError("no paged layer among the cache specs")
-    if len({k.page_arrays(1) for k in kinds}) > 1:
-        raise ValueError("paged layers of different KV head counts or sizes "
-                         "in one pool are not supported")
+    spans = dict(zip(kinds, _page_spans([k.page_arrays(1) for k in kinds])))
     depth = math.gcd(*(len(v) for v in kinds.values()))
-    groups = [PageGroup(spec, tuple(layers[i:i + depth]))
+    groups = [PageGroup(spec, tuple(layers[i:i + depth]), spans[spec])
               for spec, layers in sorted(
                   kinds.items(), key=lambda kv: isinstance(kv[0], WindowKV))
               for i in range(0, len(layers), depth)]
@@ -253,6 +294,32 @@ def page_layout(specs):
         entry_of_layer.append(entry)
     return groups, entry_of_layer, [group_of.get(li)
                                     for li in range(len(specs))]
+
+
+def _page_spans(shapes):
+    """Per kind of paged layer, the units of the smallest page its page
+    takes; `shapes`: each kind's `page_arrays(1)`. Kinds of one shape: all
+    1. Kinds may differ in their arrays' LEADING dimension alone (the KV
+    heads), by one whole factor a kind: the larger page is then that many
+    adjacent smaller ones, and the pool holds two sizes, the unit and one
+    multiple of it."""
+    unit = min(shapes)
+    spans = []
+    for arrays in shapes:
+        ratios = {a[0] / u[0] for a, u in zip(arrays, unit)}
+        alike = (len(arrays) == len(unit) and len(ratios) == 1
+                 and all(a[1:] == u[1:] for a, u in zip(arrays, unit)))
+        span = ratios.pop() if alike else 0
+        if span != int(span) or span < 1:
+            raise ValueError(
+                "paged layers whose pages differ in more than a whole "
+                "multiple of the KV head count in one pool are not "
+                f"supported: {arrays} beside {unit}")
+        spans.append(int(span))
+    if len(set(spans) - {1}) > 1:
+        raise ValueError("paged layers of more than two page sizes in one "
+                         f"pool are not supported: spans {sorted(set(spans))}")
+    return spans
 
 
 @dataclasses.dataclass(frozen=True)
@@ -324,8 +391,19 @@ class BlockPool:
         self.groups, self.entry_of_layer, self.group_of_layer = page_layout(
             self.specs)
         self.depth = len(self.groups[0].layers)
-        # every group's pages are the same bytes: one spec says what a page is
-        self.page_spec = self.groups[0].spec
+        # the unit of the pool is the smallest page: its spec says what the
+        # arrays are (every group's, in a pool of one page shape)
+        self.page_spec = min(self.groups, key=lambda g: g.span).spec
+        # units of the largest page: a free BLOCK is that many aligned units
+        self.span = max(g.span for g in self.groups)
+        if self.span > 1 and self.quantized:
+            raise ValueError("an int8 pool of two page shapes is not "
+                             "supported")
+        # whole blocks only: the coarser view of the arrays must divide them
+        self.num_pages -= self.num_pages % self.span
+        if self.num_pages < 2 * self.span:
+            raise ValueError(f"num_pages must be >= {2 * self.span} (the "
+                             "first block is reserved)")
         self.kv_heads = getattr(self.page_spec, "kv_heads", None)
         self.head_dim = getattr(self.page_spec, "head_dim", None)
         self.page_layers = [i for i, g in enumerate(self.group_of_layer)
@@ -365,9 +443,14 @@ class BlockPool:
                        if self.quantized else None)
         self._gather = self._scatter = None
         self._write_state = self._read_state = None
+        # whole free blocks (a pool of one page shape: free pages), and per
+        # broken block the units of it that are free
         self.free: collections.deque = collections.deque(
-            range(1, self.num_pages))
+            range(1, self.num_pages // self.span))
+        self._broken: dict[int, list] = {}
+        self._units_free = self.num_pages - self.span
         self.ref = np.zeros(self.num_pages, np.int32)
+        self._span_of = np.ones(self.num_pages, np.int32)  # by first unit
         self._prefix: dict[bytes, int] = {}   # key -> page
         self._page_key: dict[int, bytes] = {}  # page -> key (registered only)
         self.allocs_total = 0  # lifetime allocations (tests/introspection)
@@ -377,17 +460,18 @@ class BlockPool:
 
     @staticmethod
     def page_nbytes(num_layers, kv_heads, head_dim, page_size,
-                    dtype=jnp.float32, quantized=False) -> int:
+                    dtype=jnp.float32, quantized=False, value_dim=None) -> int:
         """HBM bytes one physical page costs across all layers and both K/V
         sides — payload plus, when quantized, the per-(page, head) f32
         scales. The unit of the equal-budget serving A/B."""
-        return int(num_layers) * PagedKV(kv_heads, head_dim).page_nbytes(
-            page_size, dtype, quantized)
+        spec = PagedKV(kv_heads, head_dim, value_dim=value_dim)
+        return int(num_layers) * spec.page_nbytes(page_size, dtype, quantized)
 
     @property
     def bytes_per_page(self) -> int:
-        """HBM bytes one physical page costs, all `depth` arrays: what its
-        spec says of one layer's page, times the depth."""
+        """HBM bytes one physical page (a unit, in a pool of two shapes)
+        costs, all `depth` arrays: what its spec says of one layer's page,
+        times the depth."""
         return self._bytes_per_page
 
     @property
@@ -401,14 +485,22 @@ class BlockPool:
         """KV HBM bytes one cached token costs (all layers, K+V, amortized
         scale overhead) while every group still holds it — the
         `serving_kv_bytes_per_token` series."""
-        return self.bytes_per_page * len(self.groups) / self.page_size
+        return (self.bytes_per_page * sum(g.span for g in self.groups)
+                / self.page_size)
 
     @property
     def pages_total(self) -> int:
-        return self.num_pages - 1  # null page is not allocatable
+        """Allocatable units (the null block is not): with `pages_free` the
+        share of the pool's BYTES in use, whatever shapes hold them."""
+        return self.num_pages - self.span
 
     @property
     def pages_free(self) -> int:
+        return self._units_free
+
+    @property
+    def blocks_free(self) -> int:
+        """Whole free blocks: what a page of the larger shape can take."""
         return len(self.free)
 
     def update_gauges(self):
@@ -419,14 +511,48 @@ class BlockPool:
 
     # -- allocation / refcounts ------------------------------------------ #
 
-    def alloc(self) -> int | None:
-        """One free page with refcount 1, or None when the pool is dry."""
-        if not self.free:
+    def alloc(self, span=1) -> int | None:
+        """One free page of `span` units with refcount 1 (its handle: its
+        first unit), or None when the pool has none. A page of the pool's
+        largest shape takes a whole block, from the far end of the list; a
+        smaller one a unit of a broken block, and breaks the nearest whole
+        block only when there is none."""
+        if span == self.span:
+            if not self.free:
+                if self._units_free >= span:   # free bytes, unpaired
+                    serving_metrics()["pool_alloc_refused"].inc(kind=next(
+                        g.spec.kind for g in self.groups if g.span == span))
+                return None
+            page = span * (self.free.popleft() if span == 1
+                           else self.free.pop())
+        elif self._broken:
+            block = next(reversed(self._broken))
+            page = self._broken[block].pop()
+            if not self._broken[block]:
+                del self._broken[block]
+        elif self.free:
+            block = self.free.popleft()
+            first = block * self.span
+            page, self._broken[block] = first, list(
+                range(first + self.span - 1, first, -1))
+        else:
             return None
-        page = self.free.popleft()
-        self.ref[page] = 1
+        self.ref[page], self._span_of[page] = 1, span
+        self._units_free -= span
         self.allocs_total += 1
         return page
+
+    def units_of(self, pages):
+        """The units of `pages` (handles), each page's side by side."""
+        pages = np.asarray(pages, np.int32).reshape(-1)
+        if self.span == 1:
+            return pages
+        spans = self._span_of[pages]
+        first = np.repeat(pages, spans)
+        # 0 .. span - 1 within each page
+        within = np.arange(len(first)) - np.repeat(
+            np.cumsum(spans) - spans, spans)
+        return (first + within).astype(np.int32)
 
     def incref(self, page: int):
         assert self.ref[page] > 0, f"incref on unallocated page {page}"
@@ -436,9 +562,20 @@ class BlockPool:
         """Drop one reference; a page at zero is unregistered and freed."""
         assert self.ref[page] > 0, f"release of unallocated page {page}"
         self.ref[page] -= 1
-        if self.ref[page] == 0:
-            self.unregister_page(page)
-            self.free.append(page)
+        if self.ref[page] > 0:
+            return
+        self.unregister_page(page)
+        span = int(self._span_of[page])
+        self._units_free += span
+        block = page // self.span
+        if span == self.span:
+            self.free.append(block)
+            return
+        units = self._broken.setdefault(block, [])
+        units.append(page)
+        if len(units) == self.span:   # whole again: the next to be broken
+            del self._broken[block]
+            self.free.appendleft(block)
 
     def is_shared(self, page: int) -> bool:
         return self.ref[page] > 1
@@ -547,11 +684,13 @@ class BlockPool:
             self._set_page_arrays(
                 self._scatter(self._page_arrays(), idx, vals))
 
-    def write_prompt_pages(self, pages, write_mask, *sides):
+    def write_prompt_pages(self, pages, write_mask, *sides, span=1):
         """Scatter a prefilled prompt into its pages, every array of the
         pool (one group's layers). `sides`: one list per array a layer keeps
         (K's and V's, `k_layers, v_layers`; a latent layer's one), each with
-        an entry per page array.
+        an entry per page array. `span`: the units a page of this group
+        takes; its stacked pages [m, span * Hkv, ...] are then written as
+        [m * span, Hkv, ...] units, the same bytes.
 
         pages: m physical pages in logical order; write_mask[j] False for a
         page that is not to be written (a shared page, whose content is
@@ -565,6 +704,12 @@ class BlockPool:
         if not tgt.any():
             return
         values = [a for layer in zip(*sides) for a in layer]
+        if span > 1:
+            # an unwritten page's units all go to the null block's first
+            tgt = np.where(tgt[:, None] > 0,
+                           tgt[:, None] + np.arange(span), 0).reshape(-1)
+            values = [a.reshape((-1, a.shape[1] // span) + a.shape[2:])
+                      for a in values]
         if self.quantized:
             quant = [_quantize_pages(a) for a in values]
             values = [q for q, _ in quant] + [s for _, s in quant]
@@ -577,12 +722,14 @@ class BlockPool:
         arrays; payload + scales for a quantized pool), through the one-page
         forms of the gather and scatter programs: the page never leaves the
         device and nothing waits. Caller owns refcount/table updates."""
-        self._write([dst], self._gather_pages(np.asarray([src], np.int32)))
+        self._write(self.units_of([dst]),
+                    self._gather_pages(self.units_of([src])))
         self.cow_copies_total += 1
         serving_metrics()["cow_copies"].inc()
 
     def read_pages(self, pages) -> list[tuple]:
-        """Host copies of the given pages, per page array — the preemption
+        """Host copies of the given pages (units, in a pool of two shapes:
+        `units_of` a row's pages), per page array — the preemption
         spill buffer. Unquantized: [(k, v), ...] each [m, Hkv, page_size, D]
         (a latent layer's: [(latents,), ...], [m, page_size, stored_dim]);
         quantized: [(k, v, k_scale, v_scale), ...] with [m, Hkv] scales

@@ -40,6 +40,15 @@ shapes matter (`kv_budget_bytes` -> `num_pages`, the page stacking of a
 prefill, the `decode_dispatch` span's grid) and handles every paged kind
 alike on the host: a latent-attention model's pages hold one latent and one
 rotated key a token, and nothing here knows.
+
+Pages of two shapes (a model whose sliding layers keep more KV heads than
+its full ones; `block_pool` module docstring): the pool counts in UNITS of
+the smaller page, a block table holds each page's handle (its first unit;
+the decode program gets `handle // span`, the page's index in the layer's
+own view of the arrays), and admission charges a request its units AND the
+whole blocks its larger pages need (`_cost`: the scheduler compares both
+with what is free, so a prompt is not admitted onto free bytes that lie
+unpaired). A pool of one shape is charged in pages, as a plain number.
 """
 
 from __future__ import annotations
@@ -66,10 +75,10 @@ class SpilledRequest:
     recomputing anything."""
 
     __slots__ = ("req", "length", "last_tok", "kv_host", "keys",
-                 "state_host", "group_pages", "window_start")
+                 "state_host", "group_pages", "window_start", "n_pages")
 
     def __init__(self, req, length, last_tok, kv_host, keys, state_host=(),
-                 group_pages=None, window_start=0):
+                 group_pages=None, window_start=0, cost=None):
         self.req = req
         self.length = int(length)
         self.last_tok = int(last_tok)
@@ -83,10 +92,8 @@ class SpilledRequest:
         self.group_pages = (list(group_pages) if group_pages is not None
                             else [len(keys)])
         self.window_start = int(window_start)
-
-    @property
-    def n_pages(self) -> int:
-        return len(self.keys)
+        # what its readmission is charged (`PagedServingEngine._cost`)
+        self.n_pages = len(keys) if cost is None else cost
 
 
 class PagedServingEngine(_ServingEngineBase):
@@ -128,7 +135,9 @@ class PagedServingEngine(_ServingEngineBase):
         groups = page_layout(specs)[0]
         if num_pages is None:
             if kv_budget_bytes is not None:
-                page_b = len(groups[0].layers) * groups[0].spec.page_nbytes(
+                # the pool's unit: the smallest page, in all its arrays
+                unit = min(groups, key=lambda g: g.span)
+                page_b = len(unit.layers) * unit.spec.page_nbytes(
                     self.ps, self.kv_dtype, self.kv_quant)
                 # budget covers the whole pool, reserved null page included,
                 # and first of all every row's recurrent-state slot
@@ -179,9 +188,14 @@ class PagedServingEngine(_ServingEngineBase):
         self._latent = any(g.spec.kind == "latent" for g in self.groups)
         kinds = sorted({g.spec.kind for g in self.groups})
         self._page_kinds = kinds if kinds != ["full"] else []
+        # a pool of two shapes is charged in (units, whole blocks)
+        total = self.pool.pages_total
+        self._capacity = (total if self.pool.span == 1 else
+                          np.array([total, total // self.pool.span]))
         self.sched = TwoQueueScheduler(
-            self.ps, watermark_pages, pages_for=self._prompt_pages,
-            groups=len(self.groups))
+            self.ps, watermark_pages,
+            pages_for=lambda n: self._cost(self._prompt_by_group(n)),
+            groups=self._cost([1] * len(self.groups)))
         self.preemption = bool(preemption)
         self._stack = None
         # the paged-decode kernels' grids, for the `decode_dispatch` span:
@@ -214,6 +228,9 @@ class PagedServingEngine(_ServingEngineBase):
             m[name].inc(0)
         if self._windowed:
             m["window_pages_released"].inc(0)
+        if self.pool.span > 1:
+            for kind in kinds:
+                m["pool_alloc_refused"].inc(0, kind=kind)
         if self._moe_groups:
             for name in ("moe_routed_pairs_held", "moe_dropped_pairs"):
                 m[name].inc(0)
@@ -227,8 +244,9 @@ class PagedServingEngine(_ServingEngineBase):
             raise ValueError(
                 f"prompt length {n} >= max_seq_len {self.S}")
         # lifetime page need (capacity retirement caps a row at S tokens)
-        worst = self._held_pages(min(self.S, n + req.max_new_tokens))
-        if worst > self.pool.pages_total:
+        worst = self._cost(
+            self._held_by_group(min(self.S, n + req.max_new_tokens)))
+        if np.any(worst > self._capacity):
             raise ValueError(
                 f"request needs up to {worst} pages but the pool only has "
                 f"{self.pool.pages_total}; grow num_pages or shrink the "
@@ -236,19 +254,43 @@ class PagedServingEngine(_ServingEngineBase):
         self.sched.enqueue_prefill(req)
         return req.req_id
 
-    def _held_pages(self, length) -> int:
-        """Pages a row with `length` tokens cached holds at most, all
-        groups: a full group's grow with it, a window group's stop at its
-        table's width."""
+    def _held_by_group(self, length) -> list:
+        """Pages a row with `length` tokens cached holds at most, a group:
+        a full group's grow with it, a window group's stop at its table's
+        width."""
         m = _pages_for_prompt(length, self.ps)
-        return sum(min(m, t.shape[1]) for t in self.group_tables)
+        return [min(m, t.shape[1]) for t in self.group_tables]
 
-    def _prompt_pages(self, n) -> int:
-        """Pages the admission of an `n`-token prompt takes, all groups: a
+    def _held_pages(self, length) -> int:
+        return sum(self._held_by_group(length))
+
+    def _prompt_by_group(self, n) -> list:
+        """Pages the admission of an `n`-token prompt takes, a group: a
         window group only those of the prompt's last window."""
         m = _pages_for_prompt(n, self.ps)
-        return sum(m - (g.spec.first_page(n, self.ps) if g.window else 0)
-                   for g in self.groups)
+        return [m - (g.spec.first_page(n, self.ps) if g.window else 0)
+                for g in self.groups]
+
+    def _prompt_pages(self, n) -> int:
+        return sum(self._prompt_by_group(n))
+
+    def _cost(self, counts):
+        """What `counts` pages a group cost the pool, in what the scheduler
+        compares with what is free: pages, of a pool of one shape; (units,
+        whole blocks) of a pool of two, since a page of the larger shape
+        needs its units side by side."""
+        if self.pool.span == 1:
+            return sum(counts)
+        return np.array(
+            [sum(c * g.span for c, g in zip(counts, self.groups)),
+             sum(c for c, g in zip(counts, self.groups)
+                 if g.span == self.pool.span)])
+
+    def _free(self):
+        """What the pool has free, as `_cost` counts."""
+        if self.pool.span == 1:
+            return self.pool.pages_free
+        return np.array([self.pool.pages_free, self.pool.blocks_free])
 
     def has_work(self):
         return (self.sched.has_waiting()
@@ -260,9 +302,9 @@ class PagedServingEngine(_ServingEngineBase):
 
     # -- allocation / preemption ---------------------------------------- #
 
-    def _alloc_or_preempt(self, requester_row=None) -> int:
+    def _alloc_or_preempt(self, requester_row=None, span=1) -> int:
         while True:
-            page = self.pool.alloc()
+            page = self.pool.alloc(span)
             if page is not None:
                 return page
             if not self.preemption or not self._preempt_lowest(requester_row):
@@ -293,7 +335,7 @@ class PagedServingEngine(_ServingEngineBase):
         pages = [p for group in by_group for p in group]
         with span("spill", rid=req.req_id, pages=len(pages),
                   **self._state_attrs()):
-            kv_host = self.pool.read_pages(pages)
+            kv_host = self.pool.read_pages(self.pool.units_of(pages))
             state_host = self.pool.read_state(row)
             keys = [self.pool.page_key(p) for p in pages]
             for p in pages:
@@ -301,7 +343,8 @@ class PagedServingEngine(_ServingEngineBase):
         req.preemptions += 1
         self.sched.enqueue_resume(SpilledRequest(
             req, self.lengths[row], self.last_tok[row], kv_host, keys,
-            state_host, [len(g) for g in by_group], self.window_start[row]))
+            state_host, [len(g) for g in by_group], self.window_start[row],
+            self._cost([len(g) for g in by_group])))
         self._clear_tables(row)
         self._vacate(row)
         m = serving_metrics()
@@ -333,7 +376,7 @@ class PagedServingEngine(_ServingEngineBase):
         free_rows = [i for i in range(self.B) if self.active[i] is None]
         if not free_rows:
             return 0
-        work = self.sched.pick(len(free_rows), self.pool.pages_free,
+        work = self.sched.pick(len(free_rows), self._free(),
                                self.live_count)
         for item in work:
             row = free_rows.pop(0)
@@ -396,7 +439,7 @@ class PagedServingEngine(_ServingEngineBase):
                                + (bytes([gi]) if gi else b""))
                     page = self.pool.lookup_prefix(key)
                     if page is None:
-                        page = self._alloc_or_preempt()
+                        page = self._alloc_or_preempt(span=group.span)
                         if key is not None:
                             self.pool.register_prefix(key, page)
                         mask[j] = True
@@ -414,7 +457,8 @@ class PagedServingEngine(_ServingEngineBase):
                         continue
                     stacked = self._stack_pages(
                         [new_c[li] for li in group.layers], n)
-                    self.pool.write_prompt_pages(pages, mask, *zip(*stacked))
+                    self.pool.write_prompt_pages(pages, mask, *zip(*stacked),
+                                                 span=group.span)
             write_pages_s = sp.seconds
         if self.pool.state_layers:
             with span("write_state", rid=rid, row=row) as sp:
@@ -450,16 +494,22 @@ class PagedServingEngine(_ServingEngineBase):
         pages, restore_rows, restore_pages = [], [], []
         state = self._state_attrs()
         with span("resume", rid=sp.req.req_id, **state) as resume:
-            for j, key in enumerate(sp.keys):
+            # the spilled pages' units lie one after the other in `kv_host`
+            spans = [g.span for g, count in zip(self.groups, sp.group_pages)
+                     for _ in range(count)]
+            at = 0
+            for key, units in zip(sp.keys, spans):
                 page = self.pool.lookup_prefix(key)
                 if page is None:
-                    page = self._alloc_or_preempt()
+                    page = self._alloc_or_preempt(span=units)
                     if key is not None:
                         self.pool.register_prefix(key, page)
-                    restore_rows.append(j)
+                    restore_rows.extend(range(at, at + units))
                     restore_pages.append(page)
                 pages.append(page)
-            self.pool.restore_pages(restore_pages, sp.kv_host, restore_rows)
+                at += units
+            self.pool.restore_pages(self.pool.units_of(restore_pages),
+                                    sp.kv_host, restore_rows)
             self.pool.write_state(row, sp.state_host)
             resume.set(pages_restored=len(restore_pages))
         at = 0
@@ -496,7 +546,8 @@ class PagedServingEngine(_ServingEngineBase):
                 j -= start
             page = int(table[row, j])
             if page < 0:
-                table[row, j] = self._alloc_or_preempt(requester_row=row)
+                table[row, j] = self._alloc_or_preempt(requester_row=row,
+                                                       span=group.span)
             elif self.pool.is_shared(page):
                 dst = self._alloc_or_preempt(requester_row=row)
                 self.pool.copy_page(page, dst)
@@ -509,11 +560,15 @@ class PagedServingEngine(_ServingEngineBase):
     def _update_page_gauges(self):
         """Live pages by kind of group, for a model whose pages are not all
         full K and V."""
-        g = serving_metrics()["pages_live"]
+        m = serving_metrics()
         for kind in self._page_kinds:
-            g.set(sum(int((t >= 0).sum())
-                      for grp, t in zip(self.groups, self.group_tables)
-                      if grp.spec.kind == kind), kind=kind)
+            held = [(int((t >= 0).sum()), grp.span)
+                    for grp, t in zip(self.groups, self.group_tables)
+                    if grp.spec.kind == kind]
+            m["pages_live"].set(sum(n for n, _ in held), kind=kind)
+            m["pool_bytes_live"].set(
+                sum(n * span for n, span in held) * self.pool.bytes_per_page,
+                kind=kind)
 
     def _live_grid_steps(self, live) -> dict:
         """The grid steps ONE call of each kind of paged decode kernel walks
@@ -635,7 +690,11 @@ class PagedServingEngine(_ServingEngineBase):
             if not self._windowed:   # one group: one table, no starts
                 tables, starts = jnp.asarray(self.tables), ()
             else:
-                tables = tuple(jnp.asarray(t) for t in self.group_tables)
+                # a layer of span k reads the arrays k units a page: its
+                # page's index there is handle // k (a hole stays -1)
+                tables = tuple(
+                    jnp.asarray(t if g.span == 1 else t // g.span)
+                    for g, t in zip(self.groups, self.group_tables))
                 starts = (jnp.asarray(self.window_start * self.ps),)
             tokens, keys, logits, new_kv, stats = self._decode_jit(
                 self.params, self.buffers, jnp.asarray(self.last_tok),

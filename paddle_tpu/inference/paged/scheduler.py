@@ -22,6 +22,12 @@ charged what ALL its groups need (`pages_for`: a window group only the
 prompt's last window), a spilled one every page it held, and the reserve is a
 page per live request and group.
 
+A pool of pages of two shapes (`block_pool`: the larger page is `span`
+adjacent units of the smaller) charges in two resources at once, units and
+whole free blocks: `pages_for`, a spilled request's `n_pages`, `groups` and
+`pick`'s `pages_free` are then arrays of the two, and a request fits when
+it fits in both. The arithmetic below is the same on numbers and on arrays.
+
 Admission is by rows AND pages: `pick` also takes the free decode rows, and
 for a model with recurrent layers a row is a resource of its own (the row's
 state slot, `block_pool.RowState`): where pages are plentiful, rows are what
@@ -40,6 +46,8 @@ from __future__ import annotations
 
 import collections
 
+import numpy as np
+
 from ..serving import _bucket
 from ..slo import serving_metrics
 
@@ -52,17 +60,19 @@ def _pages_for_prompt(n_tokens: int, page_size: int) -> int:
 
 class TwoQueueScheduler:
     def __init__(self, page_size: int, watermark_pages: int | None = None,
-                 pages_for=None, groups: int = 1):
+                 pages_for=None, groups=1):
         """`pages_for(n)`: the pages the admission of an n-token prompt
         takes (default: ceil(n / page_size), one group of layers that keep
         everything); `groups`: the page groups a live row holds a write page
-        in (a model with window layers beside full ones has several)."""
+        in (a model with window layers beside full ones has several; a
+        pool of two page shapes: what a page in every group costs, as
+        `pages_for` counts)."""
         self.page_size = int(page_size)
         # None -> dynamic: one reserved page per live request and group
         self.watermark_pages = watermark_pages
         self.pages_for = pages_for or (
             lambda n: _pages_for_prompt(n, self.page_size))
-        self.groups = int(groups)
+        self.groups = groups
         self._seq = 0
         # bucket -> deque[(seq, req)]; FIFO within, arrival-merged across
         self.prefill: dict[int, collections.deque] = {}
@@ -126,13 +136,13 @@ class TwoQueueScheduler:
             # live + 1: the reserve must cover the candidate itself once
             # admitted, or the pool runs one page short of the documented
             # one-reserved-page-per-live-request invariant
-            if budget - need >= self._watermark(live + 1):
+            if np.all(budget - need >= self._watermark(live + 1)):
                 return True
             # idle-engine fallback: with nothing live and nothing admitted
             # yet, the head request admits whenever it fits AT ALL — a
             # request needing the whole pool must not deadlock an empty
             # engine behind its own watermark
-            return live == 0 and not out and budget >= need
+            return live == 0 and not out and bool(np.all(budget >= need))
 
         while free_rows and self.resume:
             need = self.resume[0].n_pages

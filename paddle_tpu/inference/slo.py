@@ -112,6 +112,17 @@ def _build(reg):
             "Pages held by live rows, by kind of page group: full (kept "
             "until the request ends, shareable) or window (expire)",
             ("kind",)),
+        "pool_bytes_live": reg.gauge(
+            "serving_pool_bytes_live",
+            "HBM bytes of the pages held by live rows, by kind of page "
+            "group (a pool of two page shapes: both out of one budget)",
+            ("kind",)),
+        "pool_alloc_refused": reg.counter(
+            "serving_pool_alloc_refused_total",
+            "Pages of a kind's shape refused while as many free bytes lay "
+            "in the pool unpaired (units of blocks that smaller pages had "
+            "broken): stranding, seen from inside",
+            ("kind",)),
         "decode_live_step_share": reg.histogram(
             "serving_decode_live_step_share",
             "Per decode tick and kind of page group: the grid steps one "

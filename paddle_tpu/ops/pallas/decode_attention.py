@@ -59,6 +59,17 @@ and is `ceil(window / page_size) + 1` wide whatever the longest sequence is.
 A page wholly before the window is a dead slot even while the table still
 names it, so a step of such pages is not in the work list.
 
+Two widths and a sink. The values may be narrower than the keys (K pages
+[.., Dk], V pages [.., Dv], q [B, H, Dk] -> [B, H, Dv]: 192 and 128): the
+K and V blocks, the accumulator and the output take each its own width and
+the body is the same. `sink` [H] (f32 logits, one a query head: a sliding
+layer's learned sink) adds ONE term to the softmax's denominator that brings
+no value: the running softmax of a row starts from `m = sink`, `l = 1` (the
+sink's own term under its own maximum) where it otherwise starts from
+`-inf`, `0`, and every later step rescales that term with the rest. Both are
+static at trace time: with `Dv == Dk` and no sink the traced kernel is the
+one it was.
+
 Latent (`decode_latent`): a latent-attention layer caches per token ONE
 normed latent and ONE rotated key that all the heads share, side by side in a
 row of the pool's `[n_pages, page_size, W]` array (`block_pool.LatentKV`: W =
@@ -219,15 +230,16 @@ _VMEM_BUDGET = 12 * 2 ** 20
 _PAGES_PER_STEP = (16, 8, 4, 2, 1)
 
 
-def pages_per_step(Hkv, ps, D, P, itemsize):
+def pages_per_step(Hkv, ps, D, P, itemsize, Dv=None):
     """N, the pages of one row a grid step of the paged kernel takes: the
     largest of 16, 8, 4, 2, 1 that is no larger than the table's width and
     whose VMEM need fits `_VMEM_BUDGET`. From shapes alone: nothing is
-    measured, so nothing sweeps inside a serving process."""
-    page = Hkv * ps * D
+    measured, so nothing sweeps inside a serving process. `D`: the keys'
+    width, `Dv` the values' (None: the same)."""
+    page = Hkv * ps * (D + (D if Dv is None else Dv))   # K and V
     for n in _PAGES_PER_STEP:
-        blocks = 2 * 2 * n * page * itemsize   # K and V, double-buffered
-        temporaries = 2 * n * page * 4
+        blocks = 2 * n * page * itemsize   # double-buffered
+        temporaries = n * page * 4
         if n <= P and blocks + temporaries <= _VMEM_BUDGET:
             return n
     return 1
@@ -357,12 +369,13 @@ def _split_bf16(x):
 
 def _paged_kernel(fetch_ref, row_ref, step_ref, first_ref, last_ref,
                   lens_ref, *refs, scale, ps, n, g, quantized, dot_dtype,
-                  window=None):
+                  window=None, sink=False):
     """One grid step, the w-th LIVE step of the work list: all KV heads of
-    `n` pages of row `row[w]`. refs: n K blocks, n V blocks, each [1, Hkv,
-    ps, D]; q [1, Hkv, g, D]; quantized: n K-scale and n V-scale tiles [8,
-    Hkv]; the output [1, Hkv, g, D]; scratch m, l [Hkv, g, 1] and acc [Hkv,
-    g, D], f32."""
+    `n` pages of row `row[w]`. refs: n K blocks [1, Hkv, ps, Dk], n V blocks
+    [1, Hkv, ps, Dv]; q [1, Hkv, g, Dk]; `sink`: the heads' sink logits
+    [Hkv, g, 1] f32; quantized: n K-scale and n V-scale tiles [8, Hkv]; the
+    output [1, Hkv, g, Dv]; scratch m, l [Hkv, g, 1] and acc [Hkv, g, Dv],
+    f32."""
     k_refs, v_refs, q_ref = refs[:n], refs[n:2 * n], refs[2 * n]
     ks_refs, vs_refs = refs[2 * n + 1:3 * n + 1], refs[3 * n + 1:4 * n + 1]
     o_ref, m_scr, l_scr, acc_scr = refs[-4:]
@@ -372,8 +385,12 @@ def _paged_kernel(fetch_ref, row_ref, step_ref, first_ref, last_ref,
 
     @pl.when(first_ref[w] == 1)
     def _init():
-        m_scr[...] = jnp.full_like(m_scr, NEG_INF)
-        l_scr[...] = jnp.zeros_like(l_scr)
+        if sink:   # the sink's term, under its own maximum
+            m_scr[...] = refs[2 * n + 1][...]
+            l_scr[...] = jnp.ones_like(l_scr)
+        else:
+            m_scr[...] = jnp.full_like(m_scr, NEG_INF)
+            l_scr[...] = jnp.zeros_like(l_scr)
         acc_scr[...] = jnp.zeros_like(acc_scr)
 
     length = lens_ref[row_ref[w]]
@@ -434,23 +451,24 @@ def _paged_kernel(fetch_ref, row_ref, step_ref, first_ref, last_ref,
 
 
 def _run_paged(q, kc, vc, tables, lengths, scale, n, kv_scales=None,
-               window=None):
+               window=None, sink=None):
     """q: [B, Hkv, g, D]; kc/vc: the pool's [n_pages, Hkv, ps, D] arrays as
-    they are, each passed `n` times, once per page slot of a grid step;
+    they are (vc may be [.., Dv], the output then [B, Hkv, g, Dv]), each
+    passed `n` times, once per page slot of a grid step;
     tables: [B, P]; kv_scales: (k_scale, v_scale) f32 [n_pages, Hkv] for
     int8 pools. With `window` the same body runs as `decode_window`: slot 0
     of a row's table is the page of its FIRST cached position, positions and
     `lengths` count from there, and keys before `length - window` are
-    masked."""
+    masked. `sink`: [Hkv, g, 1] f32, the heads' sink logits."""
     B, Hkv, g, D = q.shape
-    ps = kc.shape[2]
+    ps, Dv = kc.shape[2], vc.shape[3]
     quantized = kv_scales is not None
     lengths = lengths.astype(jnp.int32)
     work = work_list(tables, lengths, ps, n, window)
 
-    def page_spec(j):
+    def page_spec(j, width):
         return pl.BlockSpec(
-            (1, Hkv, ps, D),
+            (1, Hkv, ps, width),
             lambda w, fetch, *_: (_page_of(fetch[w * n + j]), 0, 0, 0))
 
     def scale_spec(j):
@@ -461,11 +479,18 @@ def _run_paged(q, kc, vc, tables, lengths, scale, n, kv_scales=None,
             lambda w, fetch, *_: (
                 _page_of(fetch[w * n + j]) // _SCALE_ROWS, 0))
 
-    row_spec = pl.BlockSpec((1, Hkv, g, D),
+    def row_spec(width):
+        return pl.BlockSpec((1, Hkv, g, width),
                             lambda w, fetch, row, *_: (row[w], 0, 0, 0))
-    slots = [page_spec(j) for j in range(n)]
-    in_specs = slots + slots + [row_spec]
+
+    in_specs = ([page_spec(j, D) for j in range(n)]
+                + [page_spec(j, Dv) for j in range(n)] + [row_spec(D)])
     operands = [kc] * n + [vc] * n + [q]
+    if sink is not None:
+        if quantized:
+            raise NotImplementedError("a sink over an int8 pool")
+        in_specs.append(pl.BlockSpec((Hkv, g, 1), lambda w, *_: (0, 0, 0)))
+        operands.append(sink)
     if quantized:
         scale_slots = [scale_spec(j) for j in range(n)]
         in_specs += scale_slots + scale_slots
@@ -475,22 +500,22 @@ def _run_paged(q, kc, vc, tables, lengths, scale, n, kv_scales=None,
                  if q.dtype == kc.dtype == jnp.bfloat16 else jnp.float32)
     kernel = functools.partial(
         _paged_kernel, scale=scale, ps=ps, n=n, g=g, quantized=quantized,
-        dot_dtype=dot_dtype, window=window)
+        dot_dtype=dot_dtype, window=window, sink=sink is not None)
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=6,
         grid=(work.count,),
         in_specs=in_specs,
-        out_specs=row_spec,
+        out_specs=row_spec(Dv),
         scratch_shapes=[
             pltpu.VMEM((Hkv, g, 1), jnp.float32),
             pltpu.VMEM((Hkv, g, 1), jnp.float32),
-            pltpu.VMEM((Hkv, g, D), jnp.float32),
+            pltpu.VMEM((Hkv, g, Dv), jnp.float32),
         ],
     )
     out = named_pallas_call(
         _paged_name(quantized, window), kernel,
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((B, Hkv, g, D), q.dtype),
+        out_shape=jax.ShapeDtypeStruct((B, Hkv, g, Dv), q.dtype),
         interpret=interpret_mode(),
     )(*_prefetched(work), lengths, *operands)
     return _zero_unvisited(out, work)
@@ -647,9 +672,11 @@ def _split_heads(q, Hkv):
 
 
 def paged_decode_attention(q, key_cache, value_cache, block_tables, lengths,
-                           scale=None, kv_scales=None, window=None):
+                           scale=None, kv_scales=None, window=None,
+                           sink=None):
     """q: [B, H, D] (one decode step); key/value_cache:
-    [n_pages, Hkv, page_size, D]; block_tables: [B, P] physical page ids
+    [n_pages, Hkv, page_size, D] (the values may be [.., Dv]: the output is
+    then [B, H, Dv]); block_tables: [B, P] physical page ids
     (-1 unused); lengths: [B] valid tokens incl. the current one (caller has
     already written the step's K/V into the cache). With `kv_scales`
     (= (k_scale, v_scale) f32 [n_pages, Hkv]) the caches are int8 payloads
@@ -659,20 +686,27 @@ def paged_decode_attention(q, key_cache, value_cache, block_tables, lengths,
     `block_tables` [B, ceil(window / ps) + 1] starts at the page of the row's
     first CACHED position, from which `lengths` counts too (the engine
     releases the pages before it: `inference/paged/block_pool.WindowKV`), so
-    its width does not grow with the longest sequence. Returns [B, H, D]."""
+    its width does not grow with the longest sequence. `sink` [H] f32: one
+    logit a query head that joins the softmax's denominator and brings no
+    value (a row the grid visits then has a denominator of at least the
+    sink's term; a row without a live key still comes out zero). Returns
+    [B, H, D]."""
     B, H, D = q.shape
     Hkv = key_cache.shape[1]
     if scale is None:
         scale = D ** -0.5
     q4, g = _split_heads(q, Hkv)
     n = _consult_tuner_paged(q4, key_cache, block_tables,
-                             _paged_name(kv_scales is not None, window))
+                             _paged_name(kv_scales is not None, window),
+                             value_cache.shape[-1])
+    if sink is not None:
+        sink = sink.astype(jnp.float32).reshape(Hkv, g, 1)
     out = _run_paged(q4, key_cache, value_cache, block_tables, lengths,
-                     scale, n, kv_scales=kv_scales, window=window)
-    return out.reshape(B, H, D)
+                     scale, n, kv_scales=kv_scales, window=window, sink=sink)
+    return out.reshape(B, H, value_cache.shape[-1])
 
 
-def _consult_tuner_paged(q4, kc, tables, name="decode_paged"):
+def _consult_tuner_paged(q4, kc, tables, name="decode_paged", Dv=None):
     """N, the pages a grid step takes, by way of the tuner. N follows from
     the shapes (`pages_per_step`) and the page size is the POOL's physical
     layout, so the tile (N * page_size, D) is the tuner's only candidate:
@@ -685,11 +719,13 @@ def _consult_tuner_paged(q4, kc, tables, name="decode_paged"):
 
     B, Hkv, g, D = q4.shape
     ps, P = kc.shape[2], tables.shape[1]
-    tile = (pages_per_step(Hkv, ps, D, P, kc.dtype.itemsize) * ps, D)
+    widths = () if Dv in (None, D) else (Dv,)   # the values' own width
+    tile = (pages_per_step(Hkv, ps, D, P, kc.dtype.itemsize, *widths) * ps,
+            D)
     tile = pick_block_sizes(
         name, 1, P * ps, tile, lambda bq, bk: None,
         allow_measure=False,
-        signature=(B, Hkv, g, D, str(q4.dtype), P),
+        signature=(B, Hkv, g, D, str(q4.dtype), P) + widths,
         candidates=[tile])
     return tile[0] // ps
 

@@ -134,12 +134,10 @@ def _band_first_block(q_start, off, window, bk):
 
 def _fwd_kernel(q_ref, kt_ref, v_ref, *rest_refs,
                 scale, causal, sq, skv, bq, bk, nk, safe, has_kbias,
-                window=None):
-    if has_kbias:
-        kb_ref, o_ref, lse_ref, m_scr, l_scr, acc_scr = rest_refs
-    else:
-        o_ref, lse_ref, m_scr, l_scr, acc_scr = rest_refs
-        kb_ref = None
+                window=None, has_sink=False):
+    o_ref, lse_ref, m_scr, l_scr, acc_scr = rest_refs[-5:]
+    kb_ref = rest_refs[0] if has_kbias else None
+    sink_ref = rest_refs[-6] if has_sink else None
     i = pl.program_id(2)
     j = pl.program_id(3)
 
@@ -280,6 +278,12 @@ def _fwd_kernel(q_ref, kt_ref, v_ref, *rest_refs,
     @pl.when(j == last)
     def _finish():
         l = l_scr[:, :1]
+        if sink_ref is not None:
+            # the head's sink: one more term of the denominator, under the
+            # maximum (safe) or the clamp (fast) the other terms have
+            b = sink_ref[0, :1, :1]
+            l = l + (jnp.exp(b - m_scr[:, :1]) if safe
+                     else jnp.exp(jnp.minimum(b, _CLAMP)))
         l_safe = jnp.where(l == 0.0, 1.0, l)
         o_ref[0, 0] = (acc_scr[:] / l_safe).astype(o_ref.dtype)
         # lse rides as [B, H, Sq, 1]: a trailing singleton keeps the block's
@@ -290,14 +294,15 @@ def _fwd_kernel(q_ref, kt_ref, v_ref, *rest_refs,
 
 
 def _fwd(q, k, v, scale, causal, sq, skv, bq=None, bk=None, kbias=None,
-         safe=None, window=None):
+         safe=None, window=None, sink=None):
     """With `window` (causal, no key bias) the kernel runs as
     `flash_fwd_window`: the grid's last axis is as long as the widest band of
     key blocks a query block can see, not as the keys, and the index maps
     start each query block at its band's first key block. The values may be
     of another width than q and k (`Dv`, a latent-attention prefill's 128
     beside 192): the value and output blocks and the accumulator are `Dv`
-    wide, the body is the same."""
+    wide, the body is the same. `sink` [H] f32: a logit a head that joins
+    the softmax's denominator at the end and brings no value."""
     B, H, Sqp, D = q.shape
     _, Hkv, Skvp, _ = k.shape
     Dv = v.shape[-1]
@@ -329,6 +334,7 @@ def _fwd(q, k, v, scale, causal, sq, skv, bq=None, bk=None, kbias=None,
         _fwd_kernel, scale=scale, causal=causal, sq=sq, skv=skv,
         bq=bq, bk=bk, nk=nk, safe=safe,
         has_kbias=kbias is not None, window=window,
+        has_sink=sink is not None,
     )
     in_specs = [
         pl.BlockSpec((1, 1, bq, D), lambda b, h, i, j: (b, h, i, 0)),
@@ -343,6 +349,12 @@ def _fwd(q, k, v, scale, causal, sq, skv, bq=None, bk=None, kbias=None,
                                         lambda b, h, i, j: (b, 0, j))
         in_specs.append(spec)
         args.append(arg)
+    if sink is not None:
+        # one (8, 128) f32 tile a head, the logit in every place of it
+        in_specs.append(
+            pl.BlockSpec((1, 8, 128), lambda b, h, i, j: (h, 0, 0)))
+        args.append(jnp.broadcast_to(
+            sink.astype(jnp.float32)[:, None, None], (H, 8, 128)))
     out, lse = named_pallas_call(
         "flash_fwd" if window is None else "flash_fwd_window", kernel,
         grid=(B, H, nq, nk),
@@ -835,10 +847,13 @@ def _flash_local(q, k, v, causal, scale, key_bias):
     return jnp.swapaxes(out, 1, 2)
 
 
-def flash_window_fwd(q, k, v, window, scale=None):
+def flash_window_fwd(q, k, v, window, scale=None, sink=None):
     """Causal sliding-window attention forward (`flash_fwd_window`),
-    paddle layout: q [B, S, H, D], k/v [B, S, Hkv, D] -> [B, S, H, D]; query
-    i sees keys j with 0 <= i - j < window. Blocks wholly behind the window
+    paddle layout: q [B, S, H, D], k/v [B, S, Hkv, D] -> [B, S, H, D] (v may
+    be [B, S, Hkv, Dv], the output then [B, S, H, Dv]); query
+    i sees keys j with 0 <= i - j < window. `sink` [H]: a learned logit a
+    head whose exponential joins the softmax's denominator and brings no
+    value. Blocks wholly behind the window
     are neither fetched nor computed: the grid walks each query block's band
     only. Inference only (no VJP); single device."""
     from .autotune import pick_block_sizes
@@ -851,7 +866,11 @@ def flash_window_fwd(q, k, v, window, scale=None):
     B, H, S, D = qt.shape
     # the tile follows from the shapes and is the tuner's only candidate:
     # nothing sweeps inside a serving process
-    default = _block_sizes(S, S, d=D)
+    bq, bk = _block_sizes(S, S, d=D)
+    # a key block no wider than the window needs (its next power of two, a
+    # lane tile at least): a band of 128 keys walked in blocks of 1024
+    # would compute eight times what it sees
+    default = (bq, min(bk, max(128, 1 << (int(window) - 1).bit_length())))
     bq, bk = pick_block_sizes(
         "flash_fwd_window", S, S, default, lambda bq, bk: None,
         allow_measure=False,
@@ -859,7 +878,7 @@ def flash_window_fwd(q, k, v, window, scale=None):
         candidates=[default])
     out, _ = _fwd(_pad_seq(qt, bq), _pad_seq(kt, bk), _pad_seq(vt, bk),
                   scale, True, S, S, bq=bq, bk=bk, safe=_safe_softmax(),
-                  window=int(window))
+                  window=int(window), sink=sink)
     return jnp.swapaxes(out[:, :, :S], 1, 2)
 
 

@@ -316,8 +316,11 @@ def test_page_groups_of_equal_depth_share_one_free_list(model):
     # one kind of paged layer: a group, an entry a layer, as before
     plain, entries, _ = page_layout([full] * 3)
     assert len(plain) == 1 and entries == [0, 1, 2]
+    # twice the heads at equal widths is two adjacent pages since PR 39
+    # (tests/test_mimo_v2.py); another width is still refused
+    assert [g.span for g in page_layout([full, PagedKV(4, 16)])[0]] == [1, 2]
     with pytest.raises(ValueError):
-        page_layout([full, PagedKV(4, 16)])
+        page_layout([full, PagedKV(2, 32)])
 
 
 # -- 6, 7, 8. the expert layer ----------------------------------------------- #

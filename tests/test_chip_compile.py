@@ -499,3 +499,123 @@ def test_grouped_gemm_compiles_at_kimis_held_experts(one_chip, tokens, k, n):
     call = _held_experts_call(one_chip, 12, 384, tokens, 8, k, n)
     # what benchmark/readers/kernel_roofline_kimi_k2.py holds on to
     assert f"bf16[12,{k},{n}]" in call
+
+
+# serve-mimo-v2-agent-sat: 176 rows, 64 query heads; full layers of 4 KV
+# heads, sliding layers of 8, keys 192 wide STORED in 256 lanes and values
+# of 128, pages of 32; ONE pool of about 60k units [units, 4, 32, W] that a
+# sliding layer reads as [units / 2, 8, 32, W]; the full layers' table 512
+# wide, the sliding layers' 5 (window 128); prefill buckets to 16k; 8 held
+# experts of 4096 x (2 x 2048) and 2048 x 4096
+
+M_UNITS, M_ROWS, M_HEADS = 60000, 176, 64
+M_K = ((M_UNITS, 4, PS, 256), jnp.bfloat16)
+M_V = ((M_UNITS, 4, PS, 128), jnp.bfloat16)
+
+
+def _view(pool, heads):
+    shape, dtype = pool
+    return ((shape[0] * shape[1] // heads, heads) + shape[2:], dtype)
+
+
+@pytest.mark.parametrize("heads,width,window,name,n", [
+    (4, 512, None, "decode_paged", 16), (8, 5, 128, "decode_window", 4)])
+def test_mimo_decode_kernels_compile_at_two_widths(one_chip, heads, width,
+                                                   window, name, n):
+    from paddle_tpu.ops.pallas.decode_attention import pages_per_step
+
+    sink = [((M_HEADS,), jnp.bfloat16)] if window else []
+    hlo = _compile(
+        lambda q, kc, vc, tables, lengths, *b: paged_decode_attention(
+            q, kc, vc, tables, lengths, scale=192 ** -0.5, window=window,
+            sink=b[0] if b else None),
+        one_chip, ((M_ROWS, M_HEADS, 256), jnp.bfloat16), _view(M_K, heads),
+        _view(M_V, heads), ((M_ROWS, width), jnp.int32),
+        ((M_ROWS,), jnp.int32), *sink)
+    (call,) = [line for line in hlo.splitlines()
+               if re.match(r"\s*(ROOT )?%" + name + r"[.\w]* = ", line)]
+    assert 'custom_call_target="tpu_custom_call"' in call
+    # the output is as wide as the values
+    assert f"bf16[{M_ROWS},{heads},{M_HEADS // heads},128]" in call
+    assert pages_per_step(heads, PS, 256, width, 2, 128) == n
+
+
+@pytest.mark.parametrize("seq", [1024, 16384])
+def test_mimo_window_prefill_compiles_with_a_sink(one_chip, seq):
+    """q and k 192 wide as the projections give them, v 128, 8 KV heads, a
+    sink a query head; the key block follows the window of 128."""
+    from paddle_tpu.ops.pallas.flash_attention import flash_window_fwd
+
+    hlo = _compile(
+        lambda q, k, v, b: flash_window_fwd(q, k, v, 128, scale=192 ** -0.5,
+                                            sink=b),
+        one_chip, ((1, seq, M_HEADS, 192), jnp.bfloat16),
+        ((1, seq, 8, 192), jnp.bfloat16), ((1, seq, 8, 128), jnp.bfloat16),
+        ((M_HEADS,), jnp.bfloat16))
+    (call,) = [line for line in hlo.splitlines()
+               if re.match(r"\s*(ROOT )?%flash_fwd_window[.\w]* = ", line)]
+    assert 'custom_call_target="tpu_custom_call"' in call
+    assert f"bf16[1,{M_HEADS},{seq},128]" in call
+    assert "bf16[1,8,192,%d]" % seq in hlo       # K transposed, 192 wide
+
+
+def _two_shapes_layers(kc, vc, k4, v4, k8, v8, q, t4, t8, n4, n8, sink):
+    """A full layer, a sliding layer through the coarser view, a full layer
+    again: the model's own order over the ONE pool."""
+    def layer(kc, vc, k, v, tables, lengths, **kw):
+        heads = k.shape[1]
+        ks, vs = kc.shape, vc.shape
+        kc = kc.reshape((-1, heads) + ks[2:])
+        vc = vc.reshape((-1, heads) + vs[2:])
+        kc = paged_kv_write(kc, k, tables, lengths)
+        vc = paged_kv_write(vc, v, tables, lengths)
+        out = paged_decode_attention(q, kc, vc, tables, lengths + 1,
+                                     scale=192 ** -0.5, **kw)
+        return out, kc.reshape(ks), vc.reshape(vs)
+
+    o1, kc, vc = layer(kc, vc, k4, v4, t4, n4)
+    o2, kc, vc = layer(kc, vc, k8, v8, t8, n8, window=128, sink=sink)
+    o3, kc, vc = layer(kc, vc, k4, v4, t4, n4)
+    return o1 + o2 + o3, kc, vc
+
+
+@pytest.mark.parametrize("key_lanes", [256, 192])
+def test_the_pool_of_two_shapes_stays_where_it_lies(one_chip, key_lanes):
+    """Keys stored in whole lane tiles (256 for 192): the append and both
+    decode kernels, through both views, leave the donated pool in place and
+    the views are no copy. Stored 192 wide, the compiler lays the pool out
+    its own way and copies ALL of it round the kernels: why
+    `block_pool.stored_width` pads."""
+    k_pool = (M_K[0][:3] + (key_lanes,), jnp.bfloat16)
+    args = _args(
+        one_chip, k_pool, M_V, ((M_ROWS, 4, key_lanes), jnp.bfloat16),
+        ((M_ROWS, 4, 128), jnp.bfloat16),
+        ((M_ROWS, 8, key_lanes), jnp.bfloat16),
+        ((M_ROWS, 8, 128), jnp.bfloat16),
+        ((M_ROWS, M_HEADS, key_lanes), jnp.bfloat16),
+        ((M_ROWS, 512), jnp.int32), ((M_ROWS, 5), jnp.int32),
+        ((M_ROWS,), jnp.int32), ((M_ROWS,), jnp.int32),
+        ((M_HEADS,), jnp.bfloat16))
+    compiled = jax.jit(_two_shapes_layers, donate_argnums=(0, 1)).lower(
+        *args).compile()
+    copies = re.findall(r"%copy[.\w]* = bf16\[(?:60000,4|30000,8),32,",
+                        compiled.as_text())
+    memory = compiled.memory_analysis()
+    k_bytes = 2 * M_UNITS * 4 * PS * 256
+    if key_lanes == 256:
+        assert not copies
+        assert memory.alias_size_in_bytes >= k_bytes + k_bytes // 2
+        assert memory.temp_size_in_bytes < k_bytes // 100
+    else:
+        assert copies and memory.temp_size_in_bytes >= k_bytes
+
+
+@pytest.mark.parametrize("tokens,k,n", [
+    (176, 4096, 4096), (176, 2048, 4096), (4096, 4096, 4096),
+    (4096, 2048, 4096)],
+    ids=["decode-in", "decode-out", "pass-in", "pass-out"])
+def test_grouped_gemm_compiles_at_mimos_held_experts(one_chip, tokens, k, n):
+    """8 of 256 experts held, 8 picks a token: a decode tick's 176 rows and
+    a whole pass of 4096 tokens."""
+    call = _held_experts_call(one_chip, 8, 256, tokens, 8, k, n)
+    assert f"bf16[8,{k},{n}]" in call
