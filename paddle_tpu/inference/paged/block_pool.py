@@ -542,6 +542,22 @@ class BlockPool:
         self.allocs_total += 1
         return page
 
+    def can_alloc(self, spans) -> bool:
+        """Whether `alloc` gives a page of each of `spans`, one after the
+        other with nothing released between (a release only ever adds to
+        what is free): the allocator's walk on its counts alone."""
+        blocks = len(self.free)
+        loose = sum(len(units) for units in self._broken.values())
+        for span in spans:
+            if span != self.span and loose:
+                loose -= 1
+            elif blocks:
+                blocks -= 1
+                loose += self.span - span
+            else:
+                return False
+        return True
+
     def units_of(self, pages):
         """The units of `pages` (handles), each page's side by side."""
         pages = np.asarray(pages, np.int32).reshape(-1)

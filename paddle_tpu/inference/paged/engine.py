@@ -49,10 +49,24 @@ own view of the arrays), and admission charges a request its units AND the
 whole blocks its larger pages need (`_cost`: the scheduler compares both
 with what is free, so a prompt is not admitted onto free bytes that lie
 unpaired). A pool of one shape is charged in pages, as a plain number.
+
+One decode tick in flight. Every row's next token is drawn inside the decode
+program, so the inputs of tick N+1 exist on the device when tick N ends, and
+what ends a request other than an EOS (its budget, the row's capacity) is
+known on the host a tick ahead. `step()` therefore dispatches tick N+1 BEFORE
+it reads tick N (`_step`): the host's page upkeep, the program's call, the
+emit loop and whatever the caller does between steps run while the device
+decodes. A step reads N first (it drains) only where N's tokens decide what
+N+1 is: an admission the scheduler would make now, or a write target the
+pool cannot give without a preemption. Admissions, spills and tokens are
+then those of a step that read each tick before the next. A row whose
+request ends at N by an EOS was already put into N+1: its N+1 token is
+dropped, and its pages go back at the next step.
 """
 
 from __future__ import annotations
 
+import dataclasses
 import time
 
 import jax
@@ -94,6 +108,24 @@ class SpilledRequest:
         self.window_start = int(window_start)
         # what its readmission is charged (`PagedServingEngine._cost`)
         self.n_pages = len(keys) if cost is None else cost
+
+
+@dataclasses.dataclass(slots=True)
+class _Tick:
+    """A decode tick dispatched and not read yet: its rows and their
+    requests, the lengths it was given (a copy: the host's move on), its
+    sampled rows, and the program's outputs that are not the pool — the
+    tokens and keys (the next tick's inputs, where it follows on the
+    device), the routing counts and the logits."""
+
+    rows: list
+    reqs: list
+    lengths: np.ndarray
+    sampled: np.ndarray
+    tokens: jax.Array
+    keys: jax.Array
+    stats: list
+    logits: jax.Array
 
 
 class PagedServingEngine(_ServingEngineBase):
@@ -197,6 +229,7 @@ class PagedServingEngine(_ServingEngineBase):
             pages_for=lambda n: self._cost(self._prompt_by_group(n)),
             groups=self._cost([1] * len(self.groups)))
         self.preemption = bool(preemption)
+        self._inflight = None   # the `_Tick` on the device, unread
         self._stack = None
         # the paged-decode kernels' grids, for the `decode_dispatch` span:
         # the steps a call's table holds are static, so computed once; the
@@ -234,6 +267,8 @@ class PagedServingEngine(_ServingEngineBase):
         if self._moe_groups:
             for name in ("moe_routed_pairs_held", "moe_dropped_pairs"):
                 m[name].inc(0)
+        for path in ("overlapped", "drained"):
+            m["decode_ticks"].inc(0, path=path)
 
     # ------------------------------------------------------------------ #
 
@@ -293,7 +328,7 @@ class PagedServingEngine(_ServingEngineBase):
         return np.array([self.pool.pages_free, self.pool.blocks_free])
 
     def has_work(self):
-        return (self.sched.has_waiting()
+        return (self._inflight is not None or self.sched.has_waiting()
                 or any(r is not None for r in self.active))
 
     @property
@@ -307,6 +342,8 @@ class PagedServingEngine(_ServingEngineBase):
             page = self.pool.alloc(span)
             if page is not None:
                 return page
+            # a spill takes the row's last token: never with a tick unread
+            assert self._inflight is None, "preemption with a tick in flight"
             if not self.preemption or not self._preempt_lowest(requester_row):
                 raise RuntimeError(
                     "KV page pool exhausted with no preemptible request; "
@@ -557,6 +594,27 @@ class PagedServingEngine(_ServingEngineBase):
                 self.pool.unregister_page(page)
         self.window_start[row] = start
 
+    def _target_allocs(self, rows) -> list:
+        """The spans of the pages `_ensure_write_target` allocates for
+        `rows`, in order, or more (a page shared now may be the row's own by
+        its turn): what the next tick's write targets ask of the pool."""
+        spans = []
+        for row in rows:
+            L = int(self.lengths[row])
+            start = (self._window.first_page(L, self.ps) if self._windowed
+                     else 0)
+            gone = max(0, start - int(self.window_start[row]))
+            for group, table in zip(self.groups, self.group_tables):
+                j = L // self.ps
+                if group.window:   # the slot before the window moves on
+                    j += gone - start
+                page = int(table[row, j]) if j < table.shape[1] else -1
+                if page < 0:
+                    spans.append(group.span)
+                elif self.pool.is_shared(page):
+                    spans.append(1)
+        return spans
+
     def _update_page_gauges(self):
         """Live pages by kind of group, for a model whose pages are not all
         full K and V."""
@@ -630,20 +688,72 @@ class PagedServingEngine(_ServingEngineBase):
 
         return jax.jit(decode, donate_argnums=(7,))
 
+    def _live_rows(self) -> list:
+        return [i for i in range(self.B) if self.active[i] is not None]
+
     def _step(self, tick):
-        """Admit (resumes then prefills), ensure every live row has a
-        writable page, advance all live rows by one token with the single
-        compiled paged-decode program."""
-        with span("admit") as sp:
-            sp.set(picked=self._admit())
-        live = [i for i in range(self.B) if self.active[i] is not None]
+        """One decode tick in flight (module docstring). With tick N on the
+        device: the rows move past N on the host (`_advance`); unless N's
+        tokens decide what comes next (`_must_drain`), N+1's write targets
+        are made and N+1 dispatched, and THEN N read and emitted. A drained
+        step reads N first and goes on as a step with nothing in flight:
+        admit (resumes then prefills), ensure every live row has a writable
+        page, dispatch. Returns the tokens of the tick read ({} when none
+        was in flight)."""
+        prev, out = self._inflight, {}
+        if prev is not None:
+            self._advance(prev)
+            if self._must_drain():
+                out = self._read(prev, ahead=False)
+                prev = self._inflight = None
+        if prev is None:
+            with span("admit") as sp:
+                sp.set(picked=self._admit())
+        live = self._live_rows()
         tick.set(live=len(live), waiting=self.sched.waiting_prefill)
         self.sched.update_gauges(self.engine_label, len(live))
         self.pool.update_gauges()
         if self.pool.state_layers:
             serving_metrics()["state_rows_live"].set(len(live))
-        if not live:
-            return {}
+        if live:
+            self._write_targets(live)
+            live = self._live_rows()   # a write target may have spilled
+        nxt = self._dispatch(live, prev) if live else None
+        if nxt is not None:
+            serving_metrics()["decode_ticks"].inc(
+                path="drained" if prev is None else "overlapped")
+        tick.set(overlapped=prev is not None and nxt is not None)
+        if prev is not None:
+            out = self._read(prev, ahead=nxt is not None)
+        self._inflight = nxt
+        self.pool.update_gauges()
+        return out
+
+    def _advance(self, prev):
+        """The host's rows move past the tick in flight before its tokens
+        are read: a row whose request it ends (its budget or the row's
+        capacity, known here; or an EOS read a step ago) gives its pages and
+        its row back now — every later use of the pool follows that tick on
+        the device — and every other row counts the token it caches."""
+        for i, req in zip(prev.rows, prev.reqs):
+            if req.done or any(self._limits(
+                    req, len(req.generated) + 1, int(prev.lengths[i]) + 1)):
+                self._release_row(i)
+            else:
+                self.lengths[i] += 1
+
+    def _must_drain(self) -> bool:
+        """Whether the tick in flight must be read before the next is
+        dispatched: the scheduler would admit now (a first token is drawn
+        on the host, from the prefill), or the live rows' write targets
+        need pages the pool does not have (a spill takes a row's last
+        token)."""
+        live = self._live_rows()
+        if self.sched.would_pick(self.B - len(live), self._free(), len(live)):
+            return True
+        return not self.pool.can_alloc(self._target_allocs(live))
+
+    def _write_targets(self, live):
         with span("write_targets") as sp:
             allocs, cows = self.pool.allocs_total, self.pool.cow_copies_total
             released = self._window_released
@@ -658,12 +768,13 @@ class PagedServingEngine(_ServingEngineBase):
                 serving_metrics()["window_pages_released"].inc(gone)
             if self._page_kinds:
                 self._update_page_gauges()
-        live = [i for i in range(self.B) if self.active[i] is not None]
-        if not live:
-            return {}
+
+    def _dispatch(self, live, prev) -> _Tick:
+        """Call the decode program on the live rows; their last tokens and
+        keys are `prev`'s outputs on the device where a tick is in flight,
+        the host's otherwise. Returns the tick, unread."""
         if self._decode_jit is None:
             self._decode_jit = self._decode_program()
-
         state_rows = ({"state_rows": len(live)} if self.pool.state_layers
                       else {})
         if self._windowed:
@@ -687,29 +798,70 @@ class PagedServingEngine(_ServingEngineBase):
             caches = ([kv + sc
                        for kv, sc in zip(self.pool.kv, self.pool.scales)]
                       if self.kv_quant else self.pool.kv)
+            # copies: the host moves its arrays on while the tick runs
+            lengths = self.lengths.copy()
             if not self._windowed:   # one group: one table, no starts
-                tables, starts = jnp.asarray(self.tables), ()
+                tables, starts = jnp.asarray(self.tables.copy()), ()
             else:
                 # a layer of span k reads the arrays k units a page: its
                 # page's index there is handle // k (a hole stays -1)
                 tables = tuple(
-                    jnp.asarray(t if g.span == 1 else t // g.span)
+                    jnp.asarray(t.copy() if g.span == 1 else t // g.span)
                     for g, t in zip(self.groups, self.group_tables))
                 starts = (jnp.asarray(self.window_start * self.ps),)
+            temps, keys = self._sampling_inputs(sampled)
+            if prev is None:
+                tok = jnp.asarray(self.last_tok.copy())
+            else:
+                # no row was seated since `prev`: its rows' tokens and
+                # streams are this tick's, where the device left them
+                tok = prev.tokens
+                if len(sampled):
+                    keys = prev.keys
             tokens, keys, logits, new_kv, stats = self._decode_jit(
-                self.params, self.buffers, jnp.asarray(self.last_tok),
-                jnp.asarray(self.lengths), tables,
-                *self._sampling_inputs(sampled), caches, *starts)
+                self.params, self.buffers, tok, jnp.asarray(lengths), tables,
+                temps, keys, caches, *starts)
             if self.kv_quant:
                 self.pool.kv = [tuple(c[:2]) for c in new_kv]
                 self.pool.scales = [tuple(c[2:]) for c in new_kv]
             else:
                 self.pool.kv = [tuple(c) for c in new_kv]
-            self.last_logits = logits  # device array; tests probe divergence
+        return _Tick(live, [self.active[i] for i in live], lengths, sampled,
+                     tokens, keys, stats, logits)
+
+    def _read(self, prev, ahead) -> dict:
+        """Wait for `prev` and emit its tokens; `ahead`: the next tick is
+        already on the device with `prev`'s rows in it, so a row whose
+        request an EOS ends here keeps its pages until the next step (its
+        next token is dropped there). Returns {req_id: token}."""
         with span("host_read"):  # the host waits for the decode here
-            tokens, keys, stats = jax.device_get((tokens, keys, stats))
+            tokens, keys, stats = jax.device_get(
+                (prev.tokens, prev.keys, prev.stats))
+        self.last_logits = prev.logits  # device array; tests probe divergence
         if stats:
             self._note_routing(stats[0])
-        out = self._emit_decoded(live, sampled, tokens, keys)
-        self.pool.update_gauges()
+        out = {}
+        with span("emit", rows=len(prev.rows),
+                  sampled_rows=len(prev.sampled)):
+            # a row still seated with the request the tick decoded for
+            rows = [(i, r, self.active[i] is r)
+                    for i, r in zip(prev.rows, prev.reqs)]
+            if len(prev.sampled):
+                drawn = [(i, s) for i, r, s in rows
+                         if r.temperature > 0 and not r.done]
+                with span("sample", rows=len(prev.sampled)):
+                    keep = [i for i, s in drawn if s]
+                    self.keys[keep] = keys[keep]
+                serving_metrics()["sampled_tokens"].inc(
+                    len(drawn), engine=self.engine_label)
+            for i, req, s in rows:
+                if req.done:
+                    continue   # past its EOS: the token is dropped
+                tok = int(tokens[i])
+                out[req.req_id] = tok
+                if s:
+                    self.last_tok[i] = tok
+                ended = self._take_token(req, tok, int(prev.lengths[i]) + 1)
+                if ended and s and not ahead:
+                    self._release_row(i)
         return out

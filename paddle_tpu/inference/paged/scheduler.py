@@ -123,6 +123,32 @@ class TwoQueueScheduler:
                 best = b
         return best
 
+    def _fits(self, need, budget, live, first) -> bool:
+        # live + 1: the reserve must cover the candidate itself once
+        # admitted, or the pool runs one page short of the documented
+        # one-reserved-page-per-live-request invariant
+        if np.all(budget - need >= self._watermark(live + 1)):
+            return True
+        # idle-engine fallback: with nothing live and nothing admitted yet,
+        # the head request admits whenever it fits AT ALL — a request
+        # needing the whole pool must not deadlock an empty engine behind
+        # its own watermark
+        return live == 0 and first and bool(np.all(budget >= need))
+
+    def would_pick(self, free_rows: int, pages_free, live: int) -> bool:
+        """Whether `pick` with these arguments admits anything, without
+        taking it: its first candidate fits."""
+        if not free_rows:
+            return False
+        if self.resume:
+            need = self.resume[0].n_pages
+        else:
+            b = self._head_bucket()
+            if b is None:
+                return False
+            need = self.pages_for(len(self.prefill[b][0][1].prompt))
+        return self._fits(need, pages_free, live, True)
+
     def pick(self, free_rows: int, pages_free: int, live: int) -> list:
         """Admissions for this tick, in order: resumes (FIFO), then prefill
         arrivals (FIFO across buckets). Page needs are charged at their
@@ -133,16 +159,7 @@ class TwoQueueScheduler:
         budget = pages_free
 
         def fits(need):
-            # live + 1: the reserve must cover the candidate itself once
-            # admitted, or the pool runs one page short of the documented
-            # one-reserved-page-per-live-request invariant
-            if np.all(budget - need >= self._watermark(live + 1)):
-                return True
-            # idle-engine fallback: with nothing live and nothing admitted
-            # yet, the head request admits whenever it fits AT ALL — a
-            # request needing the whole pool must not deadlock an empty
-            # engine behind its own watermark
-            return live == 0 and not out and bool(np.all(budget >= need))
+            return self._fits(need, budget, live, not out)
 
         while free_rows and self.resume:
             need = self.resume[0].n_pages

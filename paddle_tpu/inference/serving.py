@@ -273,14 +273,19 @@ class _ServingEngineBase:
             m["ttft"].observe(req._t_first - req._t_arrival,
                               engine=self.engine_label)
 
+    def _limits(self, req, generated, row_len):
+        """(budget done, capacity hit) once `req` has `generated` tokens and
+        its row `row_len` cached: what ends a request whatever its tokens
+        are, so the host knows it a tick ahead."""
+        return generated >= req.max_new_tokens, row_len + 1 >= self.S
+
     def _retire_decision(self, req, tok, row_len):
         """(done, truncated) after appending `tok` with `row_len` tokens
         already in the cache. Capacity retirement that cut the request short
         is surfaced as truncation instead of silently ending it."""
         hit_eos = (req.eos_token_id is not None
                    and int(tok) == req.eos_token_id)
-        budget_done = len(req.generated) >= req.max_new_tokens
-        cap_hit = row_len + 1 >= self.S
+        budget_done, cap_hit = self._limits(req, len(req.generated), row_len)
         done = hit_eos or budget_done or cap_hit
         truncated = cap_hit and not hit_eos and not budget_done
         return done, truncated
@@ -332,8 +337,10 @@ class _ServingEngineBase:
         """One scheduler tick (the subclass's `_step`) under the
         `engine.step` span, whose own two clock reads feed
         `serving_step_seconds`. Returns {req_id: new_token} for the decode
-        advance only — each request's FIRST token is emitted at admission
-        (onto req.generated and serving_tokens_total), not in this dict."""
+        advance only, of the tick the step READ (the paged engine's was
+        dispatched a step before) — each request's FIRST token is emitted
+        at admission (onto req.generated and serving_tokens_total), not in
+        this dict."""
         self._tick += 1
         with span("engine.step", tick=self._tick) as tick:
             out = self._step(tick)
@@ -368,40 +375,26 @@ class _ServingEngineBase:
         """(`temps`, `keys`) on the device for the decode program. A tick
         with no sampled row places nothing: the program's `cond` reads
         zeros that have been there since construction."""
-        if len(sampled):
-            return jnp.asarray(self.temps), jnp.asarray(self.keys)
+        if len(sampled):   # copies: the host's rows may move on meanwhile
+            return (jnp.asarray(self.temps.copy()),
+                    jnp.asarray(self.keys.copy()))
         return self._greedy_inputs
 
     # -- token emission -------------------------------------------------- #
 
     def _emit(self, row, tok):
-        req = self.active[row]
-        req.generated.append(int(tok))
-        self._note_token(req, tok)
-        done, truncated = self._retire_decision(req, tok, self.lengths[row])
-        if done:
-            self._note_finished(req, truncated)
+        if self._take_token(self.active[row], tok, self.lengths[row]):
             self._release_row(row)
 
-    def _emit_decoded(self, live, sampled, tokens, keys) -> dict:
-        """The decoded tick's tail, on the host copy of what the program
-        chose: the `sampled` rows keep their advanced keys, every live row's
-        length and last token advance, the token is emitted. Returns
-        {req_id: token}."""
-        out = {}
-        with span("emit", rows=len(live), sampled_rows=len(sampled)):
-            if len(sampled):
-                with span("sample", rows=len(sampled)):
-                    self.keys[sampled] = keys[sampled]
-                serving_metrics()["sampled_tokens"].inc(
-                    len(sampled), engine=self.engine_label)
-            for i in live:
-                tok = int(tokens[i])
-                self.lengths[i] += 1
-                self.last_tok[i] = tok
-                out[self.active[i].req_id] = tok
-                self._emit(i, tok)
-        return out
+    def _take_token(self, req, tok, row_len) -> bool:
+        """`tok` joins `req`'s output, its row holding `row_len` tokens;
+        True when that ends the request (then noted finished)."""
+        req.generated.append(int(tok))
+        self._note_token(req, tok)
+        done, truncated = self._retire_decision(req, tok, row_len)
+        if done:
+            self._note_finished(req, truncated)
+        return done
 
     # subclass contract
     def has_work(self) -> bool:
@@ -526,3 +519,23 @@ class ContinuousBatchingEngine(_ServingEngineBase):
         with span("host_read"):
             tokens, keys = jax.device_get((tokens, keys))
         return self._emit_decoded(live, sampled, tokens, keys)
+
+    def _emit_decoded(self, live, sampled, tokens, keys) -> dict:
+        """The decoded tick's tail, on the host copy of what the program
+        chose: the `sampled` rows keep their advanced keys, every live row's
+        length and last token advance, the token is emitted. Returns
+        {req_id: token}."""
+        out = {}
+        with span("emit", rows=len(live), sampled_rows=len(sampled)):
+            if len(sampled):
+                with span("sample", rows=len(sampled)):
+                    self.keys[sampled] = keys[sampled]
+                serving_metrics()["sampled_tokens"].inc(
+                    len(sampled), engine=self.engine_label)
+            for i in live:
+                tok = int(tokens[i])
+                self.lengths[i] += 1
+                self.last_tok[i] = tok
+                out[self.active[i].req_id] = tok
+                self._emit(i, tok)
+        return out

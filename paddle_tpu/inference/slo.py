@@ -130,6 +130,13 @@ def _build(reg):
             "over the steps its block table holds (what the grid walked "
             "before it followed a work list)",
             labelnames=("kind",), buckets=_SHARE_BUCKETS),
+        "decode_ticks": reg.counter(
+            "serving_decode_ticks_total",
+            "Decode ticks the paged engine dispatched: `overlapped` while "
+            "the tick before was still on the device and unread, `drained` "
+            "with nothing in flight (an admission, a preemption or an "
+            "empty pipeline came first)",
+            ("path",)),
         "moe_routed_pairs_held": reg.counter(
             "moe_routed_pairs_held",
             "(token, expert) picks of decode ticks that named an expert "

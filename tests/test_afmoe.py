@@ -195,7 +195,8 @@ def test_window_pages_are_released_as_the_row_passes_them(model):
         eng.step()
         if eng.active[0] is None:
             break
-        L = int(eng.lengths[0])          # tokens cached after this tick
+        # tokens cached once the tick in flight is done
+        L = int(eng.lengths[0]) + 1
         # the tick's query sat at L - 1 and saw keys L - W .. L - 1: the
         # pages the row holds are exactly those from the one holding key
         # L - W (never one fewer) to the one it wrote into (never one more
@@ -707,9 +708,9 @@ def test_decode_dispatch_counts_both_kernels_live_steps(model, prompts,
             attrs["window_grid_steps"]) == (4, 4 * 2)
     assert attrs["live_grid_steps"] == full_steps
     assert attrs["window_live_grid_steps"] == window_steps
-    # what the kernels saw: the lengths after the tick, a window group's
-    # counted from its table's first page
-    seen = jnp.asarray(eng.lengths)
+    # what the kernels see: the lengths of the tick in flight, its own token
+    # in, a window group's counted from its table's first page
+    seen = jnp.asarray(eng.lengths) + 1
     assert int(da.work_list(jnp.asarray(eng.group_tables[0]), seen, PS,
                             16).count) == full_steps
     for table in eng.group_tables[1:]:

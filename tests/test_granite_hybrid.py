@@ -241,19 +241,28 @@ def test_a_preempted_row_resumes_token_for_token(model):
                                  page_size=8)
         ids = [eng.add_request(p, max_new_tokens=12) for p in prompts]
         logits = {i: [] for i in ids}
+
+        def record(out):
+            for rid in out:
+                row = _row_of(eng, rid)
+                if row is not None:   # the step that retires a row: skipped
+                    logits[rid].append(np.asarray(eng.last_logits[row]))
+
         tick = 0
         while eng.has_work():
             if preempt and tick == 4:
+                # a spill takes the row's last token: the tick in flight is
+                # read first, as the engine's own drained step reads it
+                prev, eng._inflight = eng._inflight, None
+                eng._advance(prev)
+                record(eng._read(prev, ahead=False))
                 eng._spill_row(_row_of(eng, ids[0]))
                 assert eng.sched.resume[0].state_host
                 # keep it out for two ticks while the others decode
                 parked = eng.sched.resume.popleft()
             if preempt and tick == 6:
                 eng.sched.enqueue_resume(parked)
-            for rid in eng.step():
-                row = _row_of(eng, rid)
-                if row is not None:   # the step that retires a row: skipped
-                    logits[rid].append(np.asarray(eng.last_logits[row]))
+            record(eng.step())
             tick += 1
         by = {r.req_id: r for r in eng.finished}
         return [by[i].generated for i in ids], [logits[i] for i in ids]

@@ -348,8 +348,9 @@ def test_decode_dispatch_counts_the_latent_kernels_live_steps(model, prompts,
     spans.clear_recorded()
     assert (attrs["pages_per_step"], attrs["grid_steps"]) == (8, 4 * 2)
     assert attrs["live_grid_steps"] == steps
-    work = da.work_list(jnp.asarray(eng.tables), jnp.asarray(eng.lengths),
-                        PS, 8)
+    # the tick in flight's kernel sees lengths + 1 tokens a row
+    work = da.work_list(jnp.asarray(eng.tables),
+                        jnp.asarray(eng.lengths) + 1, PS, 8)
     assert int(work.count) == steps
     assert share.count(kind="latent") == before[0] + 1
     assert share.sum(kind="latent") - before[1] == pytest.approx(steps / 8)

@@ -590,7 +590,8 @@ def test_window_pages_are_released_as_the_row_passes_them(model):
         eng.step()
         if eng.active[0] is None:
             break
-        L = int(eng.lengths[0])          # tokens cached after this tick
+        # tokens cached once the tick in flight is done
+        L = int(eng.lengths[0]) + 1
         first = max(0, L - W) // PS
         held = (L - 1) // PS - first + 1
         assert eng.window_start[0] == first
@@ -686,7 +687,8 @@ def test_decode_dispatch_says_what_both_kernels_must_read(model):
     (attrs,) = [r["attrs"] for r in spans.recorded()
                 if r["path"] == "engine.step/decode_dispatch"][-1:]
     spans.clear_recorded()
-    ctx = [int(n) for n in eng.lengths[:2]]   # the tick's keys, its own in
+    # the tick in flight's keys, its own in
+    ctx = [int(n) + 1 for n in eng.lengths[:2]]
     assert attrs["context_tokens"] == sum(ctx)
     assert attrs["window_tokens"] == sum(min(c, W) for c in ctx)
     for name in ("live_grid_steps", "window_live_grid_steps",

@@ -317,10 +317,14 @@ class TestPagedServingEngine:
                             max_new_tokens=4, temperature=t)
         want = sum(t > 0 for t in temps)
         n0 = _counter("serving_sampled_tokens_total", engine=engine)
+        if engine == "paged":
+            # admits the three (first tokens) and dispatches their first
+            # tick, which the next step reads, behind the second's dispatch
+            assert eng.step() == {}
         spans.clear_recorded()
         tl = spans.enable_step_timeline()
         try:
-            out = eng.step()   # admits the three (first tokens) and decodes
+            out = eng.step()   # the dense engine: admits the three, decodes
         finally:
             tl.uninstall()
         ring = spans.recorded()

@@ -29,11 +29,20 @@ from paddle_tpu.profiler import Profiler, RecordEvent
 PKG = os.path.dirname(os.path.abspath(paddle.__file__))
 
 # every path of a tick (docs/OBSERVABILITY.md); a tick holds each of the
-# first six once, `emit/sample` once if it has sampled rows, the others once
-# per admitted or preempted request
+# first six at most once, `emit/sample` once if it has sampled rows, the
+# others once per admitted or preempted request
 TICK_PHASES = ["engine.step/admit", "engine.step/write_targets",
                "engine.step/decode_dispatch", "engine.step/host_read",
                "engine.step/emit"]
+ADMIT, TARGETS, DISPATCH, READ, EMIT = TICK_PHASES
+# the phases of a paged step in their order, by what it found in flight: a
+# tick that nothing forces it to read first (dispatch the next, then read
+# it), one it reads first (an admission or a spill depends on its tokens),
+# nothing, or a last tick with nothing behind it
+STEP_ORDERS = {"overlapped": [TARGETS, DISPATCH, READ, EMIT],
+               "drained": [READ, EMIT, ADMIT, TARGETS, DISPATCH],
+               "empty": [ADMIT, TARGETS, DISPATCH],
+               "last": [READ, EMIT]}
 TICK_PATHS = ["engine.step"] + TICK_PHASES + [
     "engine.step/admit/prefill", "engine.step/admit/prefill/inputs",
     "engine.step/admit/prefill/call", "engine.step/admit/pages",
@@ -127,14 +136,21 @@ def test_children_nest_inside_the_tick_and_cover_it(traced_ticks):
             assert p["t0_ns"] <= r["t0_ns"] <= r["t1_ns"] <= p["t1_ns"]
     whole = [t for t in ticks if "live" in t["attrs"] and t["attrs"]["live"]]
     assert whole
+    seen = set()
     for t in whole:
         kids = sorted((r for r in ring if r["parent"] == t["id"]
                        and r["path"] in TICK_PHASES),
                       key=lambda r: r["t0_ns"])
-        # every phase of the tick is a span, in order, none overlapping
-        assert [k["path"] for k in kids] == TICK_PHASES
+        # every phase of the tick is a span, in one of the step's orders,
+        # none overlapping: at most one dispatch and one read a step
+        order = [k["path"] for k in kids]
+        (kind,) = [k for k, o in STEP_ORDERS.items() if o == order]
+        assert t["attrs"]["overlapped"] == (kind == "overlapped")
+        seen.add(kind)
         assert all(a["t1_ns"] <= b["t0_ns"] for a, b in zip(kids, kids[1:]))
-        assert {"tick", "live", "waiting"} <= set(t["attrs"])
+        assert {"tick", "live", "waiting", "overlapped"} <= set(t["attrs"])
+    # the spill and the admissions drain; the ticks between them overlap
+    assert {"overlapped", "drained", "empty"} <= seen
 
 
 @pytest.mark.parametrize("path", PER_REQUEST)
@@ -151,20 +167,21 @@ def test_sampling_is_one_span_a_tick_not_one_a_row(traced_ticks):
     `sampled_rows` of the tick's `emit` and `decode_dispatch`), none in a
     tick that has none."""
     ring, host = traced_ticks
-    ticks = {r["id"]: {} for r in ring if r["path"] == "engine.step"}
-    emits = {r["id"]: r for r in ring if r["path"] == "engine.step/emit"}
+    # a tick is emitted in a later step than it is dispatched: the k-th
+    # emit is the k-th dispatch's
+    emits = [r for r in ring if r["path"] == "engine.step/emit"]
+    sent = [r["attrs"]["sampled_rows"] for r in ring
+            if r["path"] == "engine.step/decode_dispatch"]
+    samples = {}
     for r in ring:
-        if r["path"] in ("engine.step/emit", "engine.step/decode_dispatch"):
-            ticks[r["parent"]][r["path"]] = r["attrs"]["sampled_rows"]
-        elif r["path"] == "engine.step/emit/sample":
-            ticks[emits[r["parent"]]["parent"]].setdefault(
-                "samples", []).append(r["attrs"])
-    decoded = [t for t in ticks.values() if "engine.step/emit" in t]
-    assert {t["engine.step/emit"] for t in decoded} >= {0, 1}
-    for t in decoded:
-        n = t["engine.step/emit"]
-        assert t["engine.step/decode_dispatch"] == n
-        assert t.get("samples", []) == ([{"rows": n}] if n else [])
+        if r["path"] == "engine.step/emit/sample":
+            samples.setdefault(r["parent"], []).append(r["attrs"])
+    assert len(emits) == len(sent)
+    assert {e["attrs"]["sampled_rows"] for e in emits} >= {0, 1}
+    for e, n_sent in zip(emits, sent):
+        n = e["attrs"]["sampled_rows"]
+        assert n_sent == n
+        assert samples.get(e["id"], []) == ([{"rows": n}] if n else [])
     assert "rows" in host["engine.step/emit/sample"]
     assert "sampled_rows" in host["engine.step/decode_dispatch"]
 
@@ -218,9 +235,9 @@ def test_live_grid_steps_equal_a_hand_count_and_the_work_list(
     n = attrs["pages_per_step"]
     assert attrs["live_grid_steps"] == sum(steps)
     assert attrs["grid_steps"] == 4 * -(-eng.P // n)
-    # the second tick's kernel saw lengths - 1 + 1 tokens a row
+    # the second tick is in flight: its kernel saw lengths + 1 tokens a row
     work = da.work_list(jnp.asarray(eng.tables),
-                        jnp.asarray(eng.lengths), page_size, n)
+                        jnp.asarray(eng.lengths) + 1, page_size, n)
     assert int(work.count) == sum(steps)
     assert share.count(kind="full") == before[0] + 1
     assert share.sum(kind="full") - before[1] == pytest.approx(
