@@ -12,6 +12,7 @@ import numpy as np
 import pytest
 
 from benchmark import costs, harness, serving, trace_reduce, traffic_gen
+from benchmark.run import layer_metrics
 
 ROOT = harness.ROOT
 HERE = harness.HERE
@@ -167,6 +168,24 @@ def test_layer_metric_files_agree_with_the_manifest(manifest):
     assert seen == set(listed)
 
 
+READING_KEYS = ("reader", "args", "unit", "better", "source", "layer", "moves")
+FROZEN = harness.load_json("tests", "data", "cell_readings.json")
+
+
+@pytest.mark.parametrize("cell", sorted(FROZEN))
+def test_no_cell_loses_a_reading(cell):
+    """Each cell is still read by every quantity it was read by, whatever the
+    entry's name: folding twins into one entry that lists their cells keeps
+    the reader, its arguments and what the metric says, byte for byte."""
+    def key(reading):
+        return json.dumps(reading, sort_keys=True)
+
+    have = {key([spec.get(k, {}) for k in READING_KEYS])
+            for spec in layer_metrics(cell).values()}
+    lost = [r for r in FROZEN[cell] if key(r) not in have]
+    assert not lost, lost
+
+
 # -- traffic --------------------------------------------------------------- #
 
 MIX = {"prompt_len": {"lo": 128, "hi": 1024, "levels": 16},
@@ -206,6 +225,65 @@ def test_first_wave_has_evenly_spread_residual_answers():
     full = max(traffic_gen.levels(MIX["answer_len"]))
     assert all(1 <= r.max_new_tokens <= full for r in wave)
     assert len({r.max_new_tokens for r in wave}) > 8  # spread, not one value
+
+
+def test_a_mix_that_is_not_balanced_deals_what_it_always_dealt():
+    # frozen from the generator before `balanced` existed: the cells whose
+    # mixes do not ask for it keep their requests, seed for seed
+    stream = traffic_gen.RequestStream(MIX, 50304, 7)
+    got = stream.first_wave(6) + [stream.next() for _ in range(6)]
+    assert [(len(r.prompt), r.max_new_tokens) for r in got] == [
+        (1024, 39), (169, 10), (588, 1), (256, 40), (446, 5), (223, 20),
+        (338, 29), (128, 64), (294, 29), (676, 35), (147, 20), (776, 64)]
+    assert [int(r.prompt[0]) for r in got] == [
+        29304, 3840, 34230, 26099, 4747, 20626, 18603, 9032, 16657, 45473,
+        32754, 5712]
+
+
+@pytest.mark.parametrize("n_prompts,n_answers", [(8, 8), (8, 9), (4, 6), (16, 8)])
+def test_pair_order_holds_every_pair_once_and_each_run_every_level(
+        n_prompts, n_answers):
+    order = traffic_gen.pair_order(n_prompts, n_answers)
+    assert sorted(order) == [(p, a) for p in range(n_prompts)
+                             for a in range(n_answers)]
+    for k in range(0, len(order), n_prompts):
+        assert sorted(p for p, _ in order[k:k + n_prompts]) == \
+            list(range(n_prompts))
+    for k in range(0, len(order), n_answers):
+        assert sorted(a for _, a in order[k:k + n_answers]) == \
+            list(range(n_answers))
+
+
+BALANCED = dict(MIX, prompt_len={"lo": 128, "hi": 1024, "levels": 8},
+                answer_len={"lo": 16, "hi": 64, "levels": 9}, balanced=True)
+
+
+def _pairs(requests):
+    return sorted((len(r.prompt), r.max_new_tokens) for r in requests)
+
+
+def test_balanced_stream_deals_every_pair_in_each_round_for_every_seed():
+    rounds = 8 * 9
+    a, c = (traffic_gen.RequestStream(BALANCED, 50304, s) for s in (5, 6))
+    ra, rc = ([s.next() for _ in range(2 * rounds)] for s in (a, c))
+    every = [(p, min(n, BALANCED["max_total"] - p))
+             for p in traffic_gen.levels(BALANCED["prompt_len"])
+             for n in traffic_gen.levels(BALANCED["answer_len"])]
+    for k in (0, rounds):
+        assert _pairs(ra[k:k + rounds]) == _pairs(rc[k:k + rounds]) == \
+            sorted(every)
+    assert [len(r.prompt) for r in ra] != [len(r.prompt) for r in rc]
+    assert [r.temperature for r in ra[:8]] == [0, 0, 0, 0.7, 0, 0, 0, 0.7]
+
+
+def test_balanced_first_wave_is_the_same_requests_for_every_seed():
+    a, c = (traffic_gen.RequestStream(BALANCED, 50304, s).first_wave(100)
+            for s in (5, 6))
+    assert _pairs(a) == _pairs(c)
+    assert [r.max_new_tokens for r in a] != [r.max_new_tokens for r in c]
+    full = max(traffic_gen.levels(BALANCED["answer_len"]))
+    assert all(1 <= r.max_new_tokens <= full for r in a)
+    assert len({r.max_new_tokens for r in a}) > 40  # spread, not one value
 
 
 def test_train_batches_and_seeds_take_a_seed_beyond_32_bits():

@@ -10,6 +10,7 @@ import types
 import pytest
 
 from benchmark import harness, program_spans, trace_reduce
+from benchmark.run import layer_metrics
 
 SAT = ["bm.engine_step", "engine.step"]
 CLOCKS_APART = 50.0   # trace clock - perf_counter, seconds
@@ -203,25 +204,30 @@ def _cut(monkeypatch, name):
     return types.SimpleNamespace(trace=trace_reduce.Trace.from_json(obj)), obj
 
 
-def _metric(run, name, obs=None):
-    spec = harness.load_json("layer_metrics", name + ".json")
+def _metric(run, quantity, cell):
+    """`cell`'s reading of `quantity` (a metric's name before its suffix),
+    by whichever entry lists the cell: one that lists it alone or among
+    others."""
+    (spec,) = [s for name, s in layer_metrics(cell).items()
+               if name.split(".")[0] == quantity]
     return harness.load_plugin("readers", spec["reader"]).read(
-        run, obs or {}, **spec.get("args", {}))
+        run, {}, **spec.get("args", {}))
 
 
+SAT_CELL = "serve-xl-sat"
 SAT_METRICS = [
-    "engine_step_ms_p50.sat", "admit_ms_mean.sat", "write_targets_ms_p50.sat",
-    "decode_dispatch_ms_p50.sat", "host_read_ms_p50.sat", "sample_ms_p50.sat",
-    "step_unspanned_ms_p50.sat", "idle_admit_share.sat",
-    "idle_dispatch_share.sat", "idle_host_read_share.sat",
-    "idle_emit_share.sat", "decode_paged_share.sat"]
-TRAIN_METRICS = ["flash_share.train", "fused_norm_share.train",
-                 "place_inputs_ms_p50.train", "train_dispatch_ms_p50.train"]
+    "engine_step_ms_p50", "admit_ms_mean", "write_targets_ms_p50",
+    "decode_dispatch_ms_p50", "host_read_ms_p50", "sample_ms_p50",
+    "step_unspanned_ms_p50", "idle_admit_share", "idle_dispatch_share",
+    "idle_host_read_share", "idle_emit_share", "decode_paged_share"]
+TRAIN_CELL = "train-xl-2k"
+TRAIN_METRICS = ["flash_share", "fused_norm_share", "place_inputs_ms_p50",
+                 "train_dispatch_ms_p50"]
 
 
 def test_the_serving_cut_reads_and_hangs_together(monkeypatch, capsys):
     run, obj = _cut(monkeypatch, "pr27_sat_cut.json")
-    got = {m: _metric(run, m) for m in SAT_METRICS}
+    got = {m: _metric(run, m, SAT_CELL) for m in SAT_METRICS}
     assert all(v is not None for v in got.values()), got
     assert capsys.readouterr().err == ""   # the mapping held, nothing refused
     spans = program_spans.on_trace_clock(run, SAT)
@@ -230,8 +236,8 @@ def test_the_serving_cut_reads_and_hangs_together(monkeypatch, capsys):
     # inside its twin: at most 1 ms shorter than the driver's own reading
     for k, tick in enumerate(obj["ticks"]):
         assert 0 <= tick["ms"] - ticks[k] * 1e3 <= 1.0
-    assert got["step_unspanned_ms_p50.sat"] <= 0.02 * got["engine_step_ms_p50.sat"]
-    assert 400 < got["host_read_ms_p50.sat"] < got["engine_step_ms_p50.sat"]
+    assert got["step_unspanned_ms_p50"] <= 0.02 * got["engine_step_ms_p50"]
+    assert 400 < got["host_read_ms_p50"] < got["engine_step_ms_p50"]
     # the four idle shares are the idle inside engine.step, which is all of
     # the device's idle time but what falls between two ticks
     idle = harness.load_plugin("readers", "idle_by_span")
@@ -244,21 +250,21 @@ def test_the_serving_cut_reads_and_hangs_together(monkeypatch, capsys):
     by_operand = harness.load_plugin("readers", "trace_op_share").read(
         run, {}, over="busy", pattern=r"custom-call\(.*\[1746,16,32,128\].*"
         'custom_call_target="tpu_custom_call"')
-    assert got["decode_paged_share.sat"] == pytest.approx(by_operand)
+    assert got["decode_paged_share"] == pytest.approx(by_operand)
     assert 80 < by_operand < 90
 
 
 def test_the_training_cut_reads_and_hangs_together(monkeypatch, capsys):
     run, obj = _cut(monkeypatch, "pr27_train_cut.json")
-    got = {m: _metric(run, m) for m in TRAIN_METRICS}
+    got = {m: _metric(run, m, TRAIN_CELL) for m in TRAIN_METRICS}
     assert all(v is not None and v > 0 for v in got.values()), got
     assert capsys.readouterr().err == ""
-    pallas = _metric(run, "pallas_share.train")
-    assert got["flash_share.train"] + got["fused_norm_share.train"] == \
+    pallas = _metric(run, "pallas_share", TRAIN_CELL)
+    assert got["flash_share"] + got["fused_norm_share"] == \
         pytest.approx(pallas, abs=0.5)
     outside = min(s["dispatch_ms"] for s in obj["steps"])
-    assert got["place_inputs_ms_p50.train"] < \
-        got["train_dispatch_ms_p50.train"] < outside + 1.0
+    assert got["place_inputs_ms_p50"] < \
+        got["train_dispatch_ms_p50"] < outside + 1.0
     spans = program_spans.on_trace_clock(run, ["bm.train_step", "train_step"])
     assert {s.path for s in spans} == {
         "train_step", "train_step/place_inputs", "train_step/dispatch",
