@@ -511,52 +511,60 @@ class BlockPool:
 
     # -- allocation / refcounts ------------------------------------------ #
 
+    def _walk(self, spans):
+        """What `alloc` takes for a page of each of `spans`, one after the
+        other (a release between only adds to what is free), on the free
+        lists' counts: a page of the pool's largest shape a whole "block";
+        a smaller one a "unit" of a broken block, and "breaks" a whole block
+        only when there is none; None where the pool has nothing for it,
+        and the walk ends there."""
+        blocks = len(self.free)
+        units = self._units_free - blocks * self.span   # of broken blocks
+        for span in spans:
+            if span != self.span and units:
+                units -= 1
+                yield "unit"
+            elif blocks:
+                blocks -= 1
+                units += self.span - span
+                yield "block" if span == self.span else "break"
+            else:
+                yield None
+                return
+
+    def fits(self, spans) -> bool:
+        """Whether `alloc` gives a page of each of `spans` in turn."""
+        return None not in self._walk(spans)
+
     def alloc(self, span=1) -> int | None:
         """One free page of `span` units with refcount 1 (its handle: its
-        first unit), or None when the pool has none. A page of the pool's
-        largest shape takes a whole block, from the far end of the list; a
-        smaller one a unit of a broken block, and breaks the nearest whole
-        block only when there is none."""
-        if span == self.span:
-            if not self.free:
-                if self._units_free >= span:   # free bytes, unpaired
-                    serving_metrics()["pool_alloc_refused"].inc(kind=next(
-                        g.spec.kind for g in self.groups if g.span == span))
-                return None
+        first unit), or None, where `_walk` says: a whole block from the far
+        end of the list, a unit of the newest broken block, or the nearest
+        whole block broken."""
+        take = next(self._walk([span]))
+        if take == "block":
             page = span * (self.free.popleft() if span == 1
                            else self.free.pop())
-        elif self._broken:
+        elif take == "unit":
             block = next(reversed(self._broken))
             page = self._broken[block].pop()
             if not self._broken[block]:
                 del self._broken[block]
-        elif self.free:
+        elif take == "break":
             block = self.free.popleft()
             first = block * self.span
             page, self._broken[block] = first, list(
                 range(first + self.span - 1, first, -1))
         else:
+            if span == self.span and self._units_free >= span:
+                # free bytes, unpaired
+                serving_metrics()["pool_alloc_refused"].inc(kind=next(
+                    g.spec.kind for g in self.groups if g.span == span))
             return None
         self.ref[page], self._span_of[page] = 1, span
         self._units_free -= span
         self.allocs_total += 1
         return page
-
-    def can_alloc(self, spans) -> bool:
-        """Whether `alloc` gives a page of each of `spans`, one after the
-        other with nothing released between (a release only ever adds to
-        what is free): the allocator's walk on its counts alone."""
-        blocks = len(self.free)
-        loose = sum(len(units) for units in self._broken.values())
-        for span in spans:
-            if span != self.span and loose:
-                loose -= 1
-            elif blocks:
-                blocks -= 1
-                loose += self.span - span
-            else:
-                return False
-        return True
 
     def units_of(self, pages):
         """The units of `pages` (handles), each page's side by side."""
@@ -592,9 +600,6 @@ class BlockPool:
         if len(units) == self.span:   # whole again: the next to be broken
             del self._broken[block]
             self.free.appendleft(block)
-
-    def is_shared(self, page: int) -> bool:
-        return self.ref[page] > 1
 
     # -- prefix sharing -------------------------------------------------- #
 

@@ -57,11 +57,14 @@ known on the host a tick ahead. `step()` therefore dispatches tick N+1 BEFORE
 it reads tick N (`_step`): the host's page upkeep, the program's call, the
 emit loop and whatever the caller does between steps run while the device
 decodes. A step reads N first (it drains) only where N's tokens decide what
-N+1 is: an admission the scheduler would make now, or a write target the
-pool cannot give without a preemption. Admissions, spills and tokens are
-then those of a step that read each tick before the next. A row whose
-request ends at N by an EOS was already put into N+1: its N+1 token is
-dropped, and its pages go back at the next step.
+N+1 is: an admission the scheduler plans now, or write targets the pool
+cannot give without a preemption. Each is decided once, by the function
+whose answer the step then carries out: `TwoQueueScheduler.plan` (`pick`
+takes exactly its admissions), `_target_plan` (`_write_targets` makes it)
+and the allocator's walk (`BlockPool.fits`; `alloc` takes what it says).
+Admissions, spills and tokens are those of a step that read each tick
+before the next. A row whose request ends at N by an EOS was already put
+into N+1: its N+1 token is dropped, and its pages go back at the next step.
 """
 
 from __future__ import annotations
@@ -230,6 +233,7 @@ class PagedServingEngine(_ServingEngineBase):
             groups=self._cost([1] * len(self.groups)))
         self.preemption = bool(preemption)
         self._inflight = None   # the `_Tick` on the device, unread
+        self._targets = []   # the write targets `_must_drain` planned
         self._stack = None
         # the paged-decode kernels' grids, for the `decode_dispatch` span:
         # the steps a call's table holds are static, so computed once; the
@@ -411,10 +415,8 @@ class PagedServingEngine(_ServingEngineBase):
     def _admit(self) -> int:
         """Admit what the scheduler picks; returns how many."""
         free_rows = [i for i in range(self.B) if self.active[i] is None]
-        if not free_rows:
-            return 0
         work = self.sched.pick(len(free_rows), self._free(),
-                               self.live_count)
+                               self.B - len(free_rows))
         for item in work:
             row = free_rows.pop(0)
             if isinstance(item, SpilledRequest):
@@ -563,57 +565,40 @@ class PagedServingEngine(_ServingEngineBase):
 
     # -- decode write-target maintenance -------------------------------- #
 
-    def _ensure_write_target(self, row):
-        """Guarantee this row can scatter its next K/V in every group:
-        release the window pages the row's length has passed, allocate at
-        page boundaries, copy-on-write off shared pages, unregister a
-        private page before its first divergent write."""
-        L = int(self.lengths[row])
-        start = self._window.first_page(L, self.ps) if self._windowed else 0
-        gone = start - int(self.window_start[row])
-        for group, table in zip(self.groups, self.group_tables):
-            j = L // self.ps
-            if group.window:
-                if gone > 0:
-                    for p in table[row, :gone]:
-                        self.pool.release(int(p))
-                    table[row, :-gone] = table[row, gone:]
-                    table[row, -gone:] = -1
-                    self._window_released += gone
-                j -= start
-            page = int(table[row, j])
-            if page < 0:
-                table[row, j] = self._alloc_or_preempt(requester_row=row,
-                                                       span=group.span)
-            elif self.pool.is_shared(page):
-                dst = self._alloc_or_preempt(requester_row=row)
-                self.pool.copy_page(page, dst)
-                self.pool.release(page)
-                table[row, j] = dst
-            elif self.pool.is_registered(page):
-                self.pool.unregister_page(page)
-        self.window_start[row] = start
-
-    def _target_allocs(self, rows) -> list:
-        """The spans of the pages `_ensure_write_target` allocates for
-        `rows`, in order, or more (a page shared now may be the row's own by
-        its turn): what the next tick's write targets ask of the pool."""
-        spans = []
+    def _target_plan(self, rows) -> list:
+        """What `rows` need, one after the other, to write their next K and
+        V in every group: (row, group, start, passed, slot, page, act) a row
+        and group that needs anything, in that order. A window group first
+        releases the `passed` pages the row's length has left behind and
+        moves its table on (the row's window groups then start at logical
+        page `start`); then the `slot`'s `page` (-1: none) is allocated
+        ("alloc"), copied to a page of the row's own where it is shared
+        ("copy"), or taken out of the prefix registry before its first
+        divergent write ("unregister"). The refcounts are followed as the
+        earlier rows' copies change them: a page two rows share is the
+        second's own by its turn."""
+        plan, copied = [], {}
         for row in rows:
             L = int(self.lengths[row])
             start = (self._window.first_page(L, self.ps) if self._windowed
                      else 0)
             gone = max(0, start - int(self.window_start[row]))
-            for group, table in zip(self.groups, self.group_tables):
-                j = L // self.ps
-                if group.window:   # the slot before the window moves on
-                    j += gone - start
-                page = int(table[row, j]) if j < table.shape[1] else -1
+            for gi, table in enumerate(self.group_tables):
+                slot, passed = L // self.ps, 0
+                if self.groups[gi].window:   # the table moves on first
+                    slot, passed = slot - start, gone
+                at = slot + passed   # where the slot's page lies before
+                page = int(table[row, at]) if at < table.shape[1] else -1
+                act = None
                 if page < 0:
-                    spans.append(group.span)
-                elif self.pool.is_shared(page):
-                    spans.append(1)
-        return spans
+                    act = "alloc"
+                elif self.pool.ref[page] - copied.get(page, 0) > 1:
+                    act, copied[page] = "copy", copied.get(page, 0) + 1
+                elif self.pool.is_registered(page):
+                    act = "unregister"
+                if act or passed:
+                    plan.append((row, gi, start, passed, slot, page, act))
+        return plan
 
     def _update_page_gauges(self):
         """Live pages by kind of group, for a model whose pages are not all
@@ -695,11 +680,12 @@ class PagedServingEngine(_ServingEngineBase):
         """One decode tick in flight (module docstring). With tick N on the
         device: the rows move past N on the host (`_advance`); unless N's
         tokens decide what comes next (`_must_drain`), N+1's write targets
-        are made and N+1 dispatched, and THEN N read and emitted. A drained
-        step reads N first and goes on as a step with nothing in flight:
-        admit (resumes then prefills), ensure every live row has a writable
-        page, dispatch. Returns the tokens of the tick read ({} when none
-        was in flight)."""
+        are made as that question planned them (a decision is made once,
+        and carried out as made), N+1 dispatched, and THEN N read and
+        emitted. A drained step reads N first and goes on as a step with
+        nothing in flight: admit what `sched.plan` allows, plan and make
+        the write targets, dispatch. Returns the tokens of the tick read
+        ({} when none was in flight)."""
         prev, out = self._inflight, {}
         if prev is not None:
             self._advance(prev)
@@ -716,7 +702,8 @@ class PagedServingEngine(_ServingEngineBase):
         if self.pool.state_layers:
             serving_metrics()["state_rows_live"].set(len(live))
         if live:
-            self._write_targets(live)
+            # overlapped: nothing has moved since `_must_drain` planned
+            self._write_targets(live, None if prev is None else self._targets)
             live = self._live_rows()   # a write target may have spilled
         nxt = self._dispatch(live, prev) if live else None
         if nxt is not None:
@@ -744,22 +731,52 @@ class PagedServingEngine(_ServingEngineBase):
 
     def _must_drain(self) -> bool:
         """Whether the tick in flight must be read before the next is
-        dispatched: the scheduler would admit now (a first token is drawn
-        on the host, from the prefill), or the live rows' write targets
-        need pages the pool does not have (a spill takes a row's last
-        token)."""
+        dispatched: the scheduler plans an admission now (a first token is
+        drawn on the host, from the prefill), or the live rows' write
+        targets (`_target_plan`, kept for `_step`) need pages the pool does
+        not have (a spill takes a row's last token)."""
         live = self._live_rows()
-        if self.sched.would_pick(self.B - len(live), self._free(), len(live)):
+        if self.sched.plan(self.B - len(live), self._free(), len(live)):
             return True
-        return not self.pool.can_alloc(self._target_allocs(live))
+        self._targets = self._target_plan(live)
+        return not self.pool.fits([self.groups[gi].span
+                                   for _, gi, *_, act in self._targets
+                                   if act in ("alloc", "copy")])
 
-    def _write_targets(self, live):
+    def _write_targets(self, live, plan=None):
+        """Make `plan` (`_target_plan`; None: the live rows' now). A page
+        the pool lacks spills the lowest-priority other row (nothing is in
+        flight then), and what is left is planned again."""
         with span("write_targets") as sp:
             allocs, cows = self.pool.allocs_total, self.pool.cow_copies_total
             released = self._window_released
-            for i in live:
-                if self.active[i] is not None:  # an earlier COW may have spilled i
-                    self._ensure_write_target(i)
+            plan = self._target_plan(live) if plan is None else plan
+            i = 0
+            while i < len(plan):
+                row, gi, start, passed, slot, page, act = plan[i]
+                i, table = i + 1, self.group_tables[gi]
+                if passed:
+                    for p in table[row, :passed]:
+                        self.pool.release(int(p))
+                    table[row, :-passed] = table[row, passed:]
+                    table[row, -passed:] = -1
+                    self._window_released += passed
+                spills = self.sched.waiting_resume
+                if act == "unregister":
+                    self.pool.unregister_page(page)
+                elif act is not None:
+                    new = self._alloc_or_preempt(row, self.groups[gi].span)
+                    if act == "copy":
+                        self.pool.copy_page(page, new)
+                        self.pool.release(page)
+                    table[row, slot] = new
+                if gi == len(self.groups) - 1:
+                    self.window_start[row] = start
+                if self.sched.waiting_resume > spills:   # a row spilled
+                    plan = [t for t in self._target_plan(   # what is left
+                        [r for r in self._live_rows() if r >= row])
+                        if t[0] > row or t[1] > gi]
+                    i = 0
             sp.set(pages_allocated=self.pool.allocs_total - allocs,
                    cow_copies=self.pool.cow_copies_total - cows)
             if self._windowed:

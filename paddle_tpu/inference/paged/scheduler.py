@@ -25,10 +25,10 @@ page per live request and group.
 A pool of pages of two shapes (`block_pool`: the larger page is `span`
 adjacent units of the smaller) charges in two resources at once, units and
 whole free blocks: `pages_for`, a spilled request's `n_pages`, `groups` and
-`pick`'s `pages_free` are then arrays of the two, and a request fits when
+`plan`'s `pages_free` are then arrays of the two, and a request fits when
 it fits in both. The arithmetic below is the same on numbers and on arrays.
 
-Admission is by rows AND pages: `pick` also takes the free decode rows, and
+Admission is by rows AND pages: `plan` also takes the free decode rows, and
 for a model with recurrent layers a row is a resource of its own (the row's
 state slot, `block_pool.RowState`): where pages are plentiful, rows are what
 runs out, and a spilled request needs a free row again before it resumes.
@@ -39,12 +39,15 @@ starvation (a big request is never overtaken forever by small ones), and
 admission order equals the dense engine's — which keeps the sampling-key
 stream identical across engines for the same workload, the property the
 parity tests pin. Bucket structure is for compile management and
-observability, not reordering.
+observability, not reordering. `plan` decides and leaves the queues alone;
+`pick` takes exactly what it decided.
 """
 
 from __future__ import annotations
 
 import collections
+import heapq
+import itertools
 
 import numpy as np
 
@@ -115,14 +118,6 @@ class TwoQueueScheduler:
             return self.watermark_pages
         return max(1, live) * self.groups
 
-    def _head_bucket(self):
-        """Bucket holding the earliest-arrived waiting request."""
-        best = None
-        for b, d in self.prefill.items():
-            if d and (best is None or d[0][0] < self.prefill[best][0][0]):
-                best = b
-        return best
-
     def _fits(self, need, budget, live, first) -> bool:
         # live + 1: the reserve must cover the candidate itself once
         # admitted, or the pool runs one page short of the documented
@@ -135,52 +130,31 @@ class TwoQueueScheduler:
         # its own watermark
         return live == 0 and first and bool(np.all(budget >= need))
 
-    def would_pick(self, free_rows: int, pages_free, live: int) -> bool:
-        """Whether `pick` with these arguments admits anything, without
-        taking it: its first candidate fits."""
-        if not free_rows:
-            return False
-        if self.resume:
-            need = self.resume[0].n_pages
-        else:
-            b = self._head_bucket()
-            if b is None:
-                return False
-            need = self.pages_for(len(self.prefill[b][0][1].prompt))
-        return self._fits(need, pages_free, live, True)
-
-    def pick(self, free_rows: int, pages_free: int, live: int) -> list:
-        """Admissions for this tick, in order: resumes (FIFO), then prefill
-        arrivals (FIFO across buckets). Page needs are charged at their
-        upper bound (prefix-sharing hits only under-run the budget). Stops
-        at the first request that would dip below the watermark —
-        head-of-line blocking by design (see module docstring)."""
-        out = []
-        budget = pages_free
-
-        def fits(need):
-            return self._fits(need, budget, live, not out)
-
-        while free_rows and self.resume:
-            need = self.resume[0].n_pages
-            if not fits(need):
-                return out
-            sp = self.resume.popleft()
-            out.append(sp)
-            free_rows -= 1
-            live += 1
-            budget -= need
-
-        while free_rows:
-            b = self._head_bucket()
-            if b is None:
+    def plan(self, free_rows: int, pages_free, live: int) -> list:
+        """The admissions these free rows and pages allow, in order, left in
+        the queues: resumes (FIFO), then prefill arrivals (FIFO across
+        buckets). Page needs are charged at their upper bound (prefix-sharing
+        hits only under-run the budget). Stops at the first request that
+        would dip below the watermark — head-of-line blocking by design
+        (see module docstring)."""
+        waiting = itertools.chain(
+            ((sp, sp.n_pages) for sp in self.resume),
+            ((req, self.pages_for(len(req.prompt)))
+             for _, req in heapq.merge(*self.prefill.values())))
+        out, budget = [], pages_free
+        for item, need in itertools.islice(waiting, free_rows):
+            if not self._fits(need, budget, live + len(out), not out):
                 break
-            need = self.pages_for(len(self.prefill[b][0][1].prompt))
-            if not fits(need):
-                return out
-            _, req = self.prefill[b].popleft()
-            out.append(req)
-            free_rows -= 1
-            live += 1
-            budget -= need
+            out.append(item)
+            budget = budget - need
+        return out
+
+    def pick(self, free_rows: int, pages_free, live: int) -> list:
+        """`plan`'s admissions, taken from the queues."""
+        out = self.plan(free_rows, pages_free, live)
+        for item in out:
+            if self.resume and item is self.resume[0]:
+                self.resume.popleft()
+            else:
+                self.prefill[_bucket(len(item.prompt))].popleft()
         return out

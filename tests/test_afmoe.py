@@ -928,29 +928,36 @@ def test_one_wrong_token_is_not_correct_by_the_worst_position():
 
 
 def test_the_cell_and_its_metrics_are_in_the_manifest():
-    """The manifest's own checks run under `tests/test_benchmark_suite.py`."""
-    import re
+    """What this family brings to the manifest, whose own checks (names,
+    files, readings, room) run under `tests/test_benchmark_suite.py`: the
+    cell with its configuration, mix and chips, `serve_tok_s` over it, the
+    mix's lengths, the cut against the catalog, and each of the family's
+    kernels read against its roofline by the family's reader, under
+    whatever entry lists the cell."""
+    from benchmark.run import layer_metrics
 
     with open(os.path.join(ROOT, "BENCHMARK.json")) as f:
         manifest = json.load(f)
     cell = "serve-trinity-mixed-sat"
-    assert cell in [w["name"] for w in manifest["workloads"]]
+    (entry,) = [w for w in manifest["workloads"] if w["name"] == cell]
+    assert (entry["config"], entry["traffic"], entry["chips"]) == (
+        "trinity-mini", "saturated-mixed-16k", 1)
     (serve,) = [m for m in manifest["end_to_end"]
                 if m["name"] == "serve_tok_s"]
     assert cell in serve["workloads"]
-    mine = {m["name"]: m for m in manifest["per_layer"]
-            if m.get("workloads") == [cell]}
-    files = {n[:-5] for n in os.listdir(
-        os.path.join(ROOT, "benchmark", "layer_metrics"))
-        if n.endswith(".tmix.json")}
-    assert set(mine) == files and len(mine) == 29
-    name = re.compile(r"^[A-Za-z0-9_][A-Za-z0-9_.-]{0,63}$")
-    for metric in mine.values():
-        assert name.match(metric["name"]) and name.match(metric["layer"])
-        assert metric["moves"] == "serve_tok_s"
-    for kernel in ("decode_window", "window_prefill", "decode_attn",
+    readings = {(m["reader"], json.dumps(m.get("args", {}), sort_keys=True)):
+                m for m in layer_metrics(cell).values()}
+    for kernel in ("decode_window", "flash_fwd_window", "decode_paged",
                    "grouped_gemm"):
-        assert mine[kernel + "_roofline.tmix"]["unit"] == "%"
+        metric = readings["kernel_roofline_afmoe",
+                          json.dumps({"kernel": kernel})]
+        assert (metric["unit"], metric["moves"]) == ("%", "serve_tok_s")
+    with open(os.path.join(ROOT, "benchmark", "traffic",
+                           "saturated-mixed-16k.json")) as f:
+        mix = json.load(f)
+    assert (mix["prompt_len"], mix["answer_len"], mix["max_total"]) == (
+        {"lo": 512, "hi": 12288, "levels": 12},
+        {"lo": 128, "hi": 768, "levels": 8}, 16384)
     config = _cell_config()
     with open("/opt/skills/guides/model-configs/architectures.jsonl") as f:
         (row,) = [json.loads(line) for line in f

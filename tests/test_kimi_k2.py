@@ -779,35 +779,36 @@ def test_a_wrong_token_and_a_lower_precision_are_not_correct():
 
 
 def test_the_cell_and_its_metrics_are_in_the_manifest():
-    """The manifest's own checks run under `tests/test_benchmark_suite.py`."""
-    import re
+    """What this family brings to the manifest, whose own checks (names,
+    files, readings, room) run under `tests/test_benchmark_suite.py`: the
+    cell with its configuration, mix and chips, `serve_tok_s` over it, the
+    mix's lengths, the cut against the catalog, and each of the family's
+    kernels read against its roofline by the family's reader, under
+    whatever entry lists the cell."""
+    from benchmark.run import layer_metrics
 
     with open(os.path.join(ROOT, "BENCHMARK.json")) as f:
         manifest = json.load(f)
-    assert CELL in [w["name"] for w in manifest["workloads"]]
     (cell,) = [w for w in manifest["workloads"] if w["name"] == CELL]
     assert (cell["config"], cell["traffic"], cell["chips"]) == (
         "kimi-k2-instruct", "saturated-reasoning-16k", 1)
     (serve,) = [m for m in manifest["end_to_end"]
                 if m["name"] == "serve_tok_s"]
     assert CELL in serve["workloads"]
-    mine = {m["name"]: m for m in manifest["per_layer"]
-            if m.get("workloads") == [CELL]}
-    files = {n[:-5] for n in os.listdir(
-        os.path.join(ROOT, "benchmark", "layer_metrics"))
-        if n.endswith(".k2r.json")}
-    assert set(mine) == files and len(mine) == 24
-    name = re.compile(r"^[A-Za-z0-9_][A-Za-z0-9_.-]{0,63}$")
-    for metric in mine.values():
-        assert name.match(metric["name"]) and name.match(metric["layer"])
-        assert metric["moves"] == "serve_tok_s"
-    for kernel in ("decode_latent", "mla_prefill", "grouped_gemm"):
-        assert mine[kernel + "_roofline.k2r"]["unit"] == "%"
+    readings = {(m["reader"], json.dumps(m.get("args", {}), sort_keys=True)):
+                m for m in layer_metrics(CELL).values()}
+    for kernel in ("decode_latent", "flash_fwd", "grouped_gemm"):
+        metric = readings["kernel_roofline_kimi_k2",
+                          json.dumps({"kernel": kernel})]
+        assert (metric["unit"], metric["moves"]) == ("%", "serve_tok_s")
     with open(os.path.join(ROOT, "benchmark", "traffic",
                            "saturated-reasoning-16k.json")) as f:
         mix = json.load(f)
     assert mix["kind"] == "saturated" and "decode_latent" in mix[
         "expected_kernels"]
+    assert (mix["prompt_len"], mix["answer_len"], mix["max_total"]) == (
+        {"lo": 1024, "hi": 8192, "levels": 8},
+        {"lo": 1024, "hi": 8192, "levels": 8}, 16384)
     config = _cell_config()
     with open("/opt/skills/guides/model-configs/architectures.jsonl") as f:
         (row,) = [json.loads(line) for line in f

@@ -8,6 +8,7 @@ engine's where it can serve the model; admissions and spills at the same
 steps; `serving_decode_ticks_total{path}` and the `overlapped` attribute
 against a hand count; at most one dispatch and one read a step."""
 
+import collections
 import copy
 
 import numpy as np
@@ -202,37 +203,85 @@ def test_the_tick_counter_and_the_step_attribute_match_a_hand_count():
     assert [len(done[r]) for r in (a, b, c)] == [3, 5, 2]
 
 
+def _taken(s, *args):
+    """What `pick` takes from a copy of `s`'s queues (the same requests),
+    and whether the copy's queues lost exactly those."""
+    c = copy.copy(s)
+    c.resume = collections.deque(s.resume)
+    c.prefill = {b: collections.deque(d) for b, d in s.prefill.items()}
+    got = c.pick(*args)
+    left = list(c.resume) + [r for d in c.prefill.values() for _, r in d]
+    waiting = list(s.resume) + [r for d in s.prefill.values() for _, r in d]
+    return got, left == [r for r in waiting if r not in got]
+
+
 def test_the_drain_sees_an_admission_the_scheduler_would_make():
-    """`would_pick` is `pick` without the taking: no row, no waiting work or
-    a head over the watermark picks nothing; the idle-engine fallback and a
-    resume ahead of the prefills are seen as `pick` sees them."""
+    """The drain asks `plan`, and `pick` takes exactly its admissions: no
+    row, no waiting work or a head over the watermark plans nothing; the
+    idle-engine fallback and a resume ahead of the prefills are planned as
+    `pick` takes them, and planning leaves the queues as they were."""
     s = TwoQueueScheduler(8)
-    assert not s.would_pick(4, 100, 0)
-    s.enqueue_prefill(type("R", (), {"prompt": np.zeros(40)})())
-    assert s.would_pick(4, 100, 1) and not s.would_pick(0, 100, 1)
+    assert s.plan(4, 100, 0) == []
+    req = type("R", (), {"prompt": np.zeros(40)})()
+    s.enqueue_prefill(req)
+    assert s.plan(4, 100, 1) == [req] and s.plan(0, 100, 1) == []
     # 5 pages and a watermark of 2 (live 1, plus the candidate)
-    assert not s.would_pick(4, 6, 1) and s.would_pick(4, 7, 1)
-    assert s.would_pick(4, 5, 0)         # nothing live: fits at all
+    assert s.plan(4, 6, 1) == [] and s.plan(4, 7, 1) == [req]
+    assert s.plan(4, 5, 0) == [req]      # nothing live: fits at all
     s.enqueue_resume(type("S", (), {"n_pages": 9})())
     for args in ((4, 100, 1), (4, 6, 1), (4, 7, 1), (4, 9, 0), (4, 8, 0),
                  (4, 11, 1), (4, 12, 1)):
-        assert s.would_pick(*args) == bool(copy.deepcopy(s).pick(*args))
-    assert not s.would_pick(4, 7, 1)     # the resume comes first
+        assert _taken(s, *args) == (s.plan(*args), True)
+    assert s.plan(4, 7, 1) == []         # the resume comes first
+    assert s.waiting_prefill == s.waiting_resume == 1
 
 
-@pytest.mark.parametrize("spans_asked,free,ok", [
-    ([1, 1, 1, 1], 2, True), ([1] * 5, 2, False), ([2, 2], 2, True),
-    ([1, 2], 2, True), ([1, 2, 2], 2, False)],
+@pytest.mark.parametrize("spans_asked,free,walk", [
+    ([1, 1, 1, 1], 2, ["break", "unit", "break", "unit"]),
+    ([1] * 5, 2, ["break", "unit", "break", "unit", None]),
+    ([2, 2], 2, ["block", "block"]), ([1, 2], 2, ["break", "block"]),
+    ([1, 2, 2], 2, ["break", "block", None])],
     ids=["units-of-broken-blocks", "one-too-many", "blocks",
          "a-block-after-a-unit", "a-unit-broke-a-block"])
 def test_the_pool_says_whether_a_tick_of_allocations_fits(spans_asked, free,
-                                                          ok):
-    """`can_alloc` walks the allocator on its counts alone: on a pool of
-    two page shapes (blocks of 2 units) a unit breaks a whole block only
-    when no broken one has a unit left, and a block needs a whole one."""
+                                                          walk):
+    """`fits` is the allocator's own walk on its counts: on a pool of two
+    page shapes (blocks of 2 units) a unit breaks a whole block only when no
+    broken one has a unit left, and a block needs a whole one; `alloc` takes
+    a page where the walk says, and none where it says None."""
     specs = [PagedKV(1, 4), PagedKV(2, 4)]   # units of 1 and blocks of 2
     pool = BlockPool(2, page_size=4, num_pages=2 * (free + 1), specs=specs)
     assert pool.span == 2 and pool.blocks_free == free
-    assert pool.can_alloc(spans_asked) == ok
-    got = [pool.alloc(s) for s in spans_asked]
-    assert all(p is not None for p in got) == ok
+    assert list(pool._walk(spans_asked)) == walk
+    assert pool.fits(spans_asked) == (None not in walk)
+    for span, step in zip(spans_asked, walk):
+        blocks = pool.blocks_free
+        assert (pool.alloc(span) is None) == (step is None)
+        assert pool.blocks_free == blocks - (step in ("block", "break"))
+
+
+def test_a_tail_page_two_rows_share_is_copied_once():
+    """Two rows admitted from one prompt share its tail page, and a pool
+    with ONE page free: the first row's copy-on-write leaves the page the
+    second's own, so the write targets need one page, fit, and the tick in
+    flight is not drained; `_write_targets` carries out that plan."""
+    paddle.seed(0)
+    eng = PagedServingEngine(GPTForCausalLM(gpt3_tiny()), max_batch_size=4,
+                             max_seq_len=64, page_size=8, num_pages=12,
+                             watermark_pages=0)
+    prompt = np.arange(1, 13, dtype=np.int32)   # a page and a half
+    for _ in range(2):
+        eng.add_request(prompt, max_new_tokens=4)
+    assert eng._admit() == 2
+    (a, b) = live = eng._live_rows()
+    tail = int(eng.tables[a, 1])
+    assert int(eng.tables[b, 1]) == tail and eng.pool.ref[tail] == 2
+    while eng.pool.pages_free > 1:
+        eng.pool.alloc()
+    assert not eng._must_drain()
+    assert [act for *_, act in eng._targets] == ["copy", "unregister"]
+    eng._write_targets(live, eng._targets)
+    assert eng.pool.pages_free == 0 and eng.pool.cow_copies_total == 1
+    assert int(eng.tables[b, 1]) == tail != int(eng.tables[a, 1])
+    assert eng.pool.ref[tail] == 1 and not eng.pool.is_registered(tail)
+    assert not eng.sched.waiting_resume   # nothing spilled

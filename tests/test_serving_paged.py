@@ -80,9 +80,9 @@ class TestBlockPool:
         key = prefix_page_key(np.arange(4, dtype=np.int32), 0, 4)
         p = pool.alloc()
         pool.register_prefix(key, p)
-        assert pool.lookup_prefix(key) == p and pool.is_shared(p)
+        assert pool.lookup_prefix(key) == p and pool.ref[p] == 2
         pool.release(p)            # one holder left
-        assert not pool.is_shared(p) and pool.is_registered(p)
+        assert pool.ref[p] == 1 and pool.is_registered(p)
         pool.unregister_page(p)    # first divergent write would do this
         assert pool.lookup_prefix(key) is None
         pool.release(p)
