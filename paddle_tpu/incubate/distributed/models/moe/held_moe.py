@@ -12,9 +12,10 @@ Nothing here stands in for the other chips or their exchange.
 The layout has ONE form. The (token, choice) pairs are sorted by held
 expert once (stable; a pair not computed here, an expert of another share or
 a row that is not live, sorts behind every held expert) and their tokens'
-rows are gathered in that order into one dense `[tokens x top_k, d_model]`
-buffer: the held experts' rows lie END TO END, each group starting where the
-one before ended, with no stride between them. `grouped_gemm` takes the
+rows are gathered in that order into one dense buffer of `tokens x top_k`
+rows (of the capacity's, below, where the layer compacts): the held
+experts' rows lie END TO END, each group starting where the one before
+ended, with no stride between them. `grouped_gemm` takes the
 groups ragged (`ops/pallas/grouped_gemm.ragged_matmul`), `y` is gathered
 back by the inverse order, and each token's gated picks are summed. Every
 held pair has its row by construction, so no token can be dropped, whatever
@@ -30,6 +31,18 @@ The gate is part of the layer's definition (`gate=`):
   over their sum (+ 1e-20), times `route_scale`.
 
 Experts are gated MLPs, `w_out (silu(a) * b)` with `[a | b] = w_in x`.
+
+A share of 1/16 of the experts or less gets a small part of the pairs (at
+top-8 and 1/32, a quarter of a pair a token): such a layer is COMPACTING
+(`_capacity`). Its pass gathers and multiplies only the first `capacity`
+sorted places, four times the held pairs the routing sends here on average,
+in whole row tiles; the held pairs are the first of them. Its combine reads
+`_SLOTS` rows a token, a token's held picks in pick order and a zero row for
+each it lacks, so no buffer of a row a pair is built. A pass whose held
+pairs overflow the capacity, or one of whose tokens has more held picks than
+slots, takes the whole buffer instead (one `lax.cond`), so the layer is
+exact whatever the routing. A larger share (Granite's and Trinity's half)
+always takes the whole buffer.
 
 A pass's largest buffer is `tokens x top_k x d_model`, which bounds the
 tokens of one pass: a call of more than `CHUNK_TOKENS` tokens (a prefill
@@ -58,28 +71,51 @@ __all__ = ["HeldExpertsMoE", "STAT_NAMES", "CHUNK_TOKENS", "chunks_for",
 CHUNK_TOKENS = 4096
 
 
+# the held picks of one token a compacting pass combines; a token with more
+# sends its pass to the whole buffer. At 1/32 of the experts and top-8 a
+# token has five or more once in about 600,000 under uniform routing: about
+# one 4096-token pass in 150
+_SLOTS = 4
+
+
 def chunks_for(tokens: int) -> int:
     """Passes of the layer a call of `tokens` tokens makes."""
     return -(-tokens // CHUNK_TOKENS)
 
-# what `forward(..., with_stats=True)` counts, in the order of its int32 row
+# what `forward(..., with_stats=True)` counts, in the order of its int32 row:
+# `passes` the passes made (a skipped dead pass is none), `whole_passes` those
+# that took the whole buffer (every pass of a layer that does not compact, a
+# compacting layer's pass whose held pairs overflowed its capacity or a
+# token's its slots)
 STAT_NAMES = ("routed_pairs_held", "expert_rows_max", "expert_rows_sum",
-              "dropped_pairs", "tile_rows")
+              "dropped_pairs", "tile_rows", "passes", "whole_passes")
 
 
 def total_stats(*rows):
     """The stat rows of a model's expert layers as ONE row: every count
     summed over the layers, `expert_rows_max` the largest."""
     rows = jnp.stack(rows)
-    return jnp.stack([rows[:, 0].sum(), rows[:, 1].max(), rows[:, 2].sum(),
-                      rows[:, 3].sum(), rows[:, 4].sum()])
+    total = rows.sum(0)
+    return total.at[1].set(rows[:, 1].max())
+
+
+def _capacity(pairs, held, num_experts):
+    """The sorted places a pass of `pairs` pairs computes where its layer
+    compacts, else None: four times the pairs the routing sends to `held` of
+    `num_experts` experts on average, rounded up to whole 128-row tiles, if
+    that is at most a quarter of the pairs. Kimi's decode tick (12 of 384,
+    1,024 pairs) takes 128, MiMo's (8 of 256, 1,408) 256, a 4,096-token pass
+    of either 4,096 of 32,768; a share of half the experts never compacts."""
+    cap = -(-4 * pairs * held // num_experts)
+    cap = -(-cap // 128) * 128
+    return cap if 4 * cap <= pairs else None
 
 
 def _sort_by_expert(key, held):
-    """(order, back, counts): the stable sort of the pairs by `key` (0 ..
-    held, `held` for a pair not computed here) as a COUNTING sort. `back`
-    [pairs] is where each pair goes, `order` its inverse (which pair lies at
-    each sorted place), `counts` [held] the held experts' rows.
+    """(back, counts): the stable sort of the pairs by `key` (0 .. held,
+    `held` for a pair not computed here) as a COUNTING sort. `back` [pairs]
+    is where each pair goes (its inverse: `_places`), `counts` [held] the
+    held experts' rows.
 
     A pair's place is its expert's first row, plus the pairs of that expert
     in the blocks before its own, plus its rank inside its block of 256,
@@ -104,9 +140,15 @@ def _sort_by_expert(key, held):
     # gather of 32768 scalars takes the chip ten times as long)
     back = ((first[:, None, :] + within - 1) * onehot).sum(-1)
     back = back.reshape(-1)[:n].astype(jnp.int32)
-    order = jnp.zeros(n, jnp.int32).at[back].set(
-        jnp.arange(n, dtype=jnp.int32))
-    return order, back, counts[:held].astype(jnp.int32)
+    return back, counts[:held].astype(jnp.int32)
+
+
+def _places(back, size):
+    """`order` [size]: the pair that lies at each of the first `size` sorted
+    places, the inverse of `back` cut to them (a pair sorted further back is
+    dropped from the scatter)."""
+    return jnp.zeros(size, jnp.int32).at[back].set(
+        jnp.arange(back.shape[0], dtype=jnp.int32), mode="drop")
 
 
 def _row_tile(pairs, num_experts):
@@ -148,7 +190,7 @@ class HeldExpertsMoE(nn.Layer):
 
     forward(x [..., d_model], live=None, with_stats=False): `live`
     [tokens] bool leaves rows out of the routing altogether (batch padding,
-    free decode rows). With `with_stats` also returns an int32 [5] row, see
+    free decode rows). With `with_stats` also returns an int32 [7] row, see
     STAT_NAMES (rows are counted over the held experts; `tile_rows` is the
     rows the grouped GEMM's visited row tiles cover, so `expert_rows_sum`
     over it is the share of the kernel's rows that are real)."""
@@ -202,10 +244,10 @@ class HeldExpertsMoE(nn.Layer):
         sigmoid, route_scale = self.gate == "sigmoid", self.route_scale
         whole, tokens = tokens, min(tokens, CHUNK_TOKENS)
         pairs = tokens * k
-        bm = _row_tile(pairs, self.num_experts)
+        cap = _capacity(pairs, held, self.num_experts)
         use_kernel = kernels_available()
 
-        def gmm(rows, w, counts, visits):
+        def gmm(rows, w, counts, visits, bm):
             if not use_kernel:
                 return jax.lax.ragged_dot(
                     rows, w, counts, preferred_element_type=jnp.float32
@@ -216,9 +258,9 @@ class HeldExpertsMoE(nn.Layer):
             block = (bm, _column_tile(w.shape[2], w.shape[1], bm,
                                       w.dtype.itemsize))
             tile = pick_block_sizes(
-                "grouped_gemm", pairs, w.shape[2], block,
+                "grouped_gemm", rows.shape[0], w.shape[2], block,
                 lambda bm, bn: None, allow_measure=False,
-                signature=(held, pairs, w.shape[1], w.shape[2],
+                signature=(held, rows.shape[0], w.shape[1], w.shape[2],
                            str(rows.dtype)),
                 candidates=[block])
             return ragged_matmul(rows, w, counts, tuple(tile), visits)
@@ -250,45 +292,89 @@ class HeldExpertsMoE(nn.Layer):
                 here = here & live[0][:, None]
             return jnp.where(here, local, held).reshape(-1), gates
 
-        def experts(x, w_in, w_out, key, gates):
-            """(out [T, d], counts [held], rows the visited tiles cover):
-            the pairs sorted by held expert ONCE, their tokens' rows
-            gathered end to end in that order, both grouped GEMMs over the
-            same groups, and `y` gathered back by the inverse order. A pair
-            not computed here lies behind every group, where the kernel
-            writes zeros: it adds nothing to its token."""
-            order, back, counts = _sort_by_expert(key, held)
-            visits = end_to_end_visits(counts, pairs, bm)
-            ab = gmm(x[order // k], w_in, counts, visits)
+        def sorted_pass(x, w_in, w_out, back, counts, rows, reads, weights):
+            """(out [T, d], rows the visited tiles cover): the tokens' rows
+            at the first `rows` sorted places gathered end to end and both
+            grouped GEMMs over the same groups into `y`; then each token
+            sums `weights[j] * y[reads[j]]` over j in order (`reads`,
+            `weights` [terms, T]). A place of no group is zero in `y`, and
+            so is row `rows` behind them: a pair not computed here adds
+            nothing to its token."""
+            bm = _row_tile(rows, self.num_experts)
+            visits = end_to_end_visits(counts, rows, bm)
+            ab = gmm(x[_places(back, rows) // k], w_in, counts, visits, bm)
             hidden = (jax.nn.silu(ab[:, :f].astype(jnp.float32))
                       * ab[:, f:].astype(jnp.float32)).astype(x.dtype)
-            y = gmm(hidden, w_out, counts, visits)
-            # gathered choice-major and summed pick after pick, so that the
-            # k terms are k contiguous slices of ONE gather and the sum is
-            # one fusion over them ([tokens, k, d] with k = 10 is a relayout
-            # of the whole buffer in f32)
-            picked = y[back.reshape(tokens, k).T.reshape(-1)]
+            y = gmm(hidden, w_out, counts, visits, bm)
+            if rows < pairs:
+                y = jnp.pad(y, ((0, 1), (0, 0)))
+            # gathered term-major and summed term after term, so that the
+            # terms are contiguous slices of ONE gather and the sum is one
+            # fusion over them ([tokens, k, d] with k = 10 is a relayout of
+            # the whole buffer in f32)
+            picked = y[reads.reshape(-1)]
             out = None
-            for j in range(k):
-                term = gates[:, j, None] * picked[
+            for j in range(reads.shape[0]):
+                term = weights[j, :, None] * picked[
                     j * tokens:(j + 1) * tokens].astype(jnp.float32)
                 out = term if out is None else out + term
-            out = out.astype(x.dtype)
-            return out, counts, visits.n[0] * bm
+            return out.astype(x.dtype), visits.n[0] * bm
 
-        def stats_of(counts, tile_rows):
+        def experts(x, w_in, w_out, key, gates):
+            """(counts [held], out [T, d], tile rows, whole passes): the
+            pairs sorted by held expert ONCE, then the compacting pass where
+            its held pairs fit the capacity and each token's the slots, else
+            the whole buffer's."""
+            back, counts = _sort_by_expert(key, held)
+            places = back.reshape(tokens, k)
+
+            def whole_pass():
+                # every pick, in pick order, read where its pair was sorted
+                out, tile_rows = sorted_pass(x, w_in, w_out, back, counts,
+                                             pairs, places.T, gates.T)
+                return out, tile_rows, jnp.ones((), jnp.int32)
+
+            if cap is None:
+                return counts, *whole_pass()
+            mine = key.reshape(tokens, k) < held
+            slots = min(k, _SLOTS)
+
+            def compact_pass():
+                # slot r reads a token's r-th held pick (in pick order) and
+                # its gate, as a sum against the pick's mask (exact: one
+                # value and zeros); `cap`, the zero row, where it has fewer:
+                # each token's terms summed in pick order, as the whole
+                # buffer sums them, with no term of a pair not held here
+                rank = jnp.cumsum(mine, axis=1) - 1
+                at = [mine & (rank == r) for r in range(slots)]
+                reads = jnp.stack([jnp.where(a, places, cap).min(1)
+                                   for a in at])
+                weights = jnp.stack([jnp.where(a, gates, 0.0).sum(1)
+                                     for a in at])
+                out, tile_rows = sorted_pass(x, w_in, w_out, back, counts,
+                                             cap, reads, weights)
+                return out, tile_rows, jnp.zeros((), jnp.int32)
+
+            fits = (counts.sum() <= cap) & (mine.sum(1).max() <= slots)
+            return counts, *jax.lax.cond(fits, compact_pass, whole_pass)
+
+        def stats_of(counts, tile_rows, passes, whole_passes):
             # every held pair has its row by construction: dropped is 0
-            return jnp.stack([counts.sum(), counts.max(), counts.sum(),
-                              0, tile_rows]).astype(jnp.int32)
+            return jnp.stack([counts.sum(), counts.max(), counts.sum(), 0,
+                              tile_rows, passes, whole_passes]
+                             ).astype(jnp.int32)
 
         def one_pass(x, router, w_in, w_out, bias, live):
             key, gates = route(x, router, bias, live)
-            return experts(x, w_in, w_out, key, gates)
+            counts, out, tile_rows, whole_passes = experts(
+                x, w_in, w_out, key, gates)
+            return (out, counts, tile_rows, jnp.ones((), jnp.int32),
+                    whole_passes)
 
         def fn(x, router, w_in, w_out, *rest):
-            out, counts, tile_rows = one_pass(
+            out, *counted = one_pass(
                 x, router, w_in, w_out, rest[:sigmoid], rest[sigmoid:])
-            return out, stats_of(counts, tile_rows)
+            return out, stats_of(*counted)
 
         if whole <= tokens:
             return fn
@@ -317,11 +403,11 @@ class HeldExpertsMoE(nn.Layer):
                                          (alive,)),
                         lambda: (jnp.zeros_like(xc),
                                  jnp.zeros(held, jnp.int32),
-                                 jnp.zeros((), jnp.int32)))
+                                 *[jnp.zeros((), jnp.int32)] * 3))
 
-            _, (out, counts, tile_rows) = jax.lax.scan(one, None, scanned)
+            _, (out, *counted) = jax.lax.scan(one, None, scanned)
             return (out.reshape(n * tokens, -1)[:whole],
-                    stats_of(counts.sum(0), tile_rows.sum()))
+                    stats_of(*(c.sum(0) for c in counted)))
 
         return chunked
 

@@ -271,6 +271,8 @@ class PagedServingEngine(_ServingEngineBase):
         if self._moe_groups:
             for name in ("moe_routed_pairs_held", "moe_dropped_pairs"):
                 m[name].inc(0)
+            for path in ("compact", "whole"):
+                m["moe_passes"].inc(0, path=path)
         for path in ("overlapped", "drained"):
             m["decode_ticks"].inc(0, path=path)
 
@@ -640,11 +642,13 @@ class PagedServingEngine(_ServingEngineBase):
     def _note_routing(self, stats):
         """One decode tick's routing counts (held_moe.STAT_NAMES, summed
         over the layers) into the serving metrics."""
-        pairs, rows_max, rows_sum, dropped, tile_rows = (
-            int(v) for v in stats)
+        (pairs, rows_max, rows_sum, dropped, tile_rows, passes,
+         whole) = (int(v) for v in stats)
         m = serving_metrics()
         m["moe_routed_pairs_held"].inc(pairs)
         m["moe_dropped_pairs"].inc(dropped)
+        m["moe_passes"].inc(passes - whole, path="compact")
+        m["moe_passes"].inc(whole, path="whole")
         m["moe_expert_rows_max"].observe(rows_max)
         m["moe_expert_rows_mean"].observe(rows_sum / self._moe_groups)
         m["moe_rows_live"].observe(rows_sum)

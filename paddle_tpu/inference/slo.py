@@ -145,6 +145,14 @@ def _build(reg):
             "moe_dropped_pairs",
             "Held picks that found no row in the expert's group: must "
             "stay 0, every held pick has its row by construction"),
+        "moe_passes": reg.counter(
+            "moe_passes_total",
+            "Passes of the expert layers in decode ticks, summed over "
+            "layers, by the buffer they took: `compact` the held pairs "
+            "alone (a layer that holds 1/16 of the experts or less), "
+            "`whole` every pair (a larger share, or held pairs that "
+            "overflowed the capacity or a token's slots)",
+            ("path",)),
         "moe_expert_rows_max": reg.histogram(
             "moe_expert_rows_max",
             "Per decode tick: the most rows any held expert of any layer "

@@ -553,7 +553,7 @@ def test_a_ticks_routing_is_observed_once_on_both_row_histograms(model):
     mean = reg.get("moe_expert_rows_mean")
     before = (live.count(), live.sum(), tiled.count(), tiled.sum(),
               mean.count(), mean.sum())
-    eng._note_routing(np.array([70, 9, 70, 0, 192], np.int32))
+    eng._note_routing(np.array([70, 9, 70, 0, 192, 8, 8], np.int32))
     assert live.count() == before[0] + 1 and live.sum() == before[1] + 70
     assert tiled.count() == before[2] + 1 and tiled.sum() == before[3] + 192
     # one observation a decode tick, as the other routing series

@@ -619,3 +619,40 @@ def test_grouped_gemm_compiles_at_mimos_held_experts(one_chip, tokens, k, n):
     a whole pass of 4096 tokens."""
     call = _held_experts_call(one_chip, 8, 256, tokens, 8, k, n)
     assert f"bf16[8,{k},{n}]" in call
+
+
+@pytest.mark.parametrize("tokens,d,experts,held,cap", [
+    (128, 7168, 384, 12, 128), (4096, 4096, 256, 8, 4096)],
+    ids=["kimi-decode", "mimo-pass"])
+def test_the_compacting_pass_compiles_at_kimis_and_mimos_shapes(
+        one_chip, monkeypatch, tokens, d, experts, held, cap):
+    """The held layer WHOLE (route, sort, both branches of the capacity's
+    cond) at Kimi's decode tick (1,024 pairs of 7,168-wide rows, 12 held)
+    and MiMo's 4096-token pass (32,768 pairs of 4,096, 8 held), beside the
+    kernel alone above: the compacting branch's two `grouped_gemm` calls
+    take `cap` rows, the whole buffer's two every pair."""
+    import paddle_tpu.ops.pallas as pallas
+    from paddle_tpu.incubate.distributed.models.moe import held_moe
+
+    # on the CPU backend the layer takes `ragged_dot`; here the kernel
+    monkeypatch.setattr(pallas, "kernels_available", lambda: True)
+    f, k, pairs = 2048, 8, tokens * 8
+    assert held_moe._capacity(pairs, held, experts) == cap
+    layer = held_moe.HeldExpertsMoE.__new__(held_moe.HeldExpertsMoE)
+    for name, v in dict(gate="sigmoid", route_scale=2.5, held=(0, held),
+                        top_k=k, d_expert=f, num_experts=experts,
+                        d_model=d).items():
+        object.__setattr__(layer, name, v)
+    hlo = _compile(layer._build_fn(tokens, True), one_chip,
+                   ((tokens, d), jnp.bfloat16), ((d, experts), jnp.bfloat16),
+                   ((held, d, 2 * f), jnp.bfloat16),
+                   ((held, f, d), jnp.bfloat16), ((experts,), jnp.float32),
+                   ((tokens,), jnp.bool_))
+    calls = [line for line in hlo.splitlines()
+             if re.match(r"\s*(ROOT )?%grouped_gemm[.\w]* = ", line)]
+    assert all('custom_call_target="tpu_custom_call"' in c for c in calls)
+    for rows in (cap, pairs):
+        assert any(f"bf16[{rows},{d}]" in c and f"bf16[{held},{d},{2 * f}]"
+                   in c for c in calls)
+        assert any(f"bf16[{rows},{f}]" in c and f"bf16[{held},{f},{d}]"
+                   in c for c in calls)

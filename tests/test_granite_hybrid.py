@@ -193,9 +193,11 @@ def test_no_token_is_dropped_when_every_row_goes_to_one_expert():
     u = np.abs(np.random.default_rng(6).normal(size=(70, 64))).astype(
         np.float32) + 0.1
     out, stats = layer(paddle.to_tensor(u), with_stats=True)
-    pairs, rows_max, rows_sum, dropped, tile_rows = (
+    pairs, rows_max, rows_sum, dropped, tile_rows, passes, whole = (
         int(v) for v in stats._value)
     assert (pairs, rows_max, rows_sum, dropped) == (70, 70, 70, 0)
+    # half the experts held: the layer takes the whole buffer, in one pass
+    assert (passes, whole) == (1, 1)
     # the one group's 70 rows lie from row 0 of the 140 pairs (the 70 picks
     # of expert 6, held elsewhere, sort behind them): the row tiles that
     # hold them and no others are visited
@@ -221,7 +223,8 @@ def test_no_token_is_dropped_when_every_row_goes_to_one_expert():
     router[0, 2], router[0, 7] = 0.0, 3.0
     layer.router._value = jnp.asarray(router)
     out, stats = layer(paddle.to_tensor(u), with_stats=True)
-    assert [int(v) for v in stats._value] == [0, 0, 0, 0, 0]
+    # (one pass made, over the whole buffer: half the experts are held)
+    assert [int(v) for v in stats._value] == [0, 0, 0, 0, 0, 1, 1]
     assert not np.asarray(out._value).any()
 
 
